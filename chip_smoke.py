@@ -1,0 +1,485 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system starts on the chip.
+
+Drives the main path once through the entry points a user calls, on a
+TPU v5e, at Qwen3-8B's published widths (hidden 4096, intermediate
+12288, 32 q / 8 kv heads, head_dim 128, vocab 151 936, bf16, untied
+head; weights random from --seed):
+
+  one chip (default)  `models.Engine` + `serve.Scheduler` answer four
+      requests (prompts of 128/256/512/512 tokens, 32 new tokens each,
+      greedy, max_len 2048), then one `mega.MegaQwen3` decode step.
+      The only cut is DEPTH, to what one 16 GB chip holds.
+  --chips 4           the cross-chip path and nothing else: full depth
+      (36 layers), tp=4 over the four chips, the same four requests.
+
+What comes out is checked against a plain reference on the same
+weights — the `xla` formulation of `models.dense.forward` with XLA
+attention (no Pallas kernel in it), teacher-forced on the tokens that
+were served — and every phase says which Pallas kernels are in the
+program it ran, by name, so a route that quietly gave way to XLA
+cannot pass for a kernel run. Any phase that raises exits non-zero.
+
+One process; it starts no other. Exits non-zero before building
+anything when JAX's first device is not a TPU. Last line of stdout:
+{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}
+"""
+
+import argparse
+import json
+import statistics
+import sys
+import time
+
+import numpy as np
+
+PROMPT_LENS = (128, 256, 512, 512)
+NEW_TOKENS = 32
+MAX_LEN = 2048
+SLOTS = 4
+# The bf16 tolerance on a logit, as a multiple of
+#   2**-8 * sqrt(depth) * std(reference logits).
+# Every layer re-rounds the residual stream to bf16 (relative step
+# 2**-8) and two correct formulations round differently, so their
+# logits differ by about that product RMS: on the v5e at depth 21 the
+# SAME Pallas-free XLA program at two paddings differs by 1.03x it RMS
+# and 5.5x it at the largest of 4.9M entries; prefill-then-decode
+# through the cache against one full pass, both Pallas-free, by 1.9x
+# RMS and 10.3x-11.6x at the largest (PERF.md, PR 24). 16 leaves a
+# factor 1.4 over the largest difference seen between two formulations
+# that hold no kernel of ours; an fp8 computation (2**-4) lands 16x out.
+TOL_ULPS = 16
+# HBM kept free on one chip beyond weights and caches: the megakernel's
+# per-layer fusion transient (0.4 GB), the serve step's logits block,
+# compiled programs, allocator fragmentation.
+RESERVE_BYTES = 3 << 29
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+class CacheCounter:
+    """Persistent-compilation-cache hits and misses of this process."""
+
+    def __init__(self):
+        import jax
+
+        self.hits = self.misses = 0
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, name: str, **_kw) -> None:
+        if name == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+
+def layer_bytes(cfg, keys=("w_qkv", "w_o", "w_gate", "w_up", "w_down")):
+    """bf16 bytes of one layer's weights (all of them, or `keys`)."""
+    from triton_dist_tpu.models.dense import param_shapes
+
+    shapes = param_shapes(cfg, 1).layers
+    return sum(2 * int(np.prod(getattr(shapes, k)[1:])) for k in keys)
+
+
+def depth_for_one_chip(cfg, bytes_limit: int) -> int:
+    """The largest layer count one chip holds through every phase. The
+    binding phase is the megakernel's: MegaQwen3 streams a tile-major
+    fused gate|up copy, which lives BESIDE the Engine's split gate and
+    up (the two phases share every other weight)."""
+    fixed = 2 * 2 * cfg.vocab_size * cfg.hidden_size  # embed + head
+    kv_row = 2 * 2 * cfg.num_kv_heads * MAX_LEN * cfg.head_dim
+    per_layer = (layer_bytes(cfg) + layer_bytes(cfg, ("w_gate", "w_up"))
+                 + kv_row)
+    depth = (bytes_limit - fixed - RESERVE_BYTES) // per_layer
+    say(f"depth: bytes_limit={bytes_limit / 1e9:.2f}GB - embed+head "
+        f"{fixed / 1e9:.2f}GB - reserve {RESERVE_BYTES / 1e9:.2f}GB over "
+        f"{per_layer / 1e9:.4f}GB/layer (weights "
+        f"{layer_bytes(cfg) / 1e9:.4f} + megakernel gate|up copy "
+        f"{layer_bytes(cfg, ('w_gate', 'w_up')) / 1e9:.4f} + kv) "
+        f"-> {depth}")
+    if depth < 1:
+        raise RuntimeError("not one layer fits this device")
+    return int(min(depth, cfg.num_layers))
+
+
+def compile_and_name(jitted, *args):
+    """AOT-compile `jitted` for `args`: (kernel names in the compiled
+    program, compile seconds). The entry point's own first call then
+    finds the program in the compile cache."""
+    from triton_dist_tpu.lang.core import pallas_kernels_in
+
+    t0 = time.perf_counter()
+    compiled = jitted.lower(*args).compile()
+    return pallas_kernels_in(compiled.as_text()), time.perf_counter() - t0
+
+
+def require(kernels: dict, wanted, where: str) -> None:
+    """Fail unless a kernel of each wanted name prefix is in `kernels`."""
+    missing = [w for w in wanted
+               if not any(name.startswith(w) for name in kernels)]
+    if missing:
+        raise RuntimeError(
+            f"{where}: expected Pallas kernel(s) {missing} are not in "
+            f"the compiled program (found {kernels or 'none'}) — the "
+            "route gave way to XLA")
+
+
+def check_memory_spread(mesh, model_bytes: int) -> None:
+    """After init on several chips: all hold about the same, none holds
+    the model."""
+    used = [d.memory_stats()["bytes_in_use"] for d in mesh.devices.flat]
+    say("memory after init, GB per device: "
+        + " ".join(f"{u / 1e9:.2f}" for u in used)
+        + f" (model {model_bytes / 1e9:.2f})")
+    if max(used) > 1.1 * min(used) or max(used) > 0.6 * model_bytes:
+        raise RuntimeError("parameters are not spread evenly over the "
+                           f"chips: {used}")
+
+
+def check_ring(mesh) -> None:
+    ring = list(mesh.devices.flat)
+    coords = [tuple(d.coords) for d in ring]
+    say("tp ring (device id @ chip coords): "
+        + " -> ".join(f"{d.id}@{c}" for d, c in zip(ring, coords)))
+    for a, b in zip(coords, coords[1:] + coords[:1]):
+        if sum(abs(x - y) for x, y in zip(a, b)) != 1:
+            raise RuntimeError(f"tp neighbours {a} and {b} are not ICI "
+                               "neighbours")
+
+
+# -- phases -------------------------------------------------------------------
+
+
+def serve_phase(eng, prompts, want_kernels):
+    """Four requests through serve.Scheduler (background thread,
+    streamed), the calls examples/11_model_server.py makes."""
+    import jax.numpy as jnp
+
+    from triton_dist_tpu.lang.core import pallas_call_count
+    from triton_dist_tpu.serve import Scheduler
+
+    calls0 = pallas_call_count()
+    sch = Scheduler(eng, slots=SLOTS)
+    pool, w = sch.pool, sch.worker
+    kernels, t_compile = compile_and_name(
+        w._fn, eng.params, jnp.zeros((SLOTS, sch.chunk), jnp.int32),
+        pool.k, pool.v, jnp.asarray(pool.table), jnp.asarray(pool.lengths),
+        jnp.zeros((SLOTS,), jnp.int32), jnp.zeros((SLOTS,), jnp.float32),
+        jnp.zeros((SLOTS, 2), jnp.uint32))
+    say(f"serve: Scheduler(slots={SLOTS}, chunk={sch.chunk}, "
+        f"page={pool.page}) step compiled in {t_compile:.1f}s; kernels in "
+        f"it: {kernels or 'none'}; pallas_call_count +"
+        f"{pallas_call_count() - calls0}")
+    require(kernels, want_kernels, "serve step")
+
+    sch.start()
+    t0 = time.perf_counter()
+    reqs = [sch.submit(p, max_new_tokens=NEW_TOKENS, stream=True)
+            for p in prompts]
+    streamed = [[tok for tok, _piece in r.stream] for r in reqs]
+    wall = time.perf_counter() - t0
+    sch.stop()  # re-raises what killed the serving thread, if anything
+    for i, (r, s) in enumerate(zip(reqs, streamed)):
+        if not (r.done and r.finish_reason == "length"
+                and len(r.out_tokens) == NEW_TOKENS and s == r.out_tokens):
+            raise RuntimeError(
+                f"request {i}: state={r.state} reason={r.finish_reason} "
+                f"returned {len(r.out_tokens)} streamed {len(s)} tokens")
+    steps = [(h["t1"] - h["t0"]) / 1e9 for h in sch.history
+             if h["kind"] == "step"]
+    say(f"serve: 4/4 requests finished, {4 * NEW_TOKENS} tokens streamed "
+        f"== returned, {len(steps)} steps in {wall:.2f}s (set-up "
+        f"information: first step {steps[0]:.3f}s, median step "
+        f"{statistics.median(steps):.4f}s)")
+    served = [list(r.out_tokens) for r in reqs]
+
+    # finding, not a gate: docs/serving.md promises each request's
+    # tokens are bitwise those of the same Engine decoding it alone
+    del sch, pool, w, reqs
+    alone_sch = Scheduler(eng, slots=SLOTS)
+    alone = []
+    for p in prompts:
+        r = alone_sch.submit(p, max_new_tokens=NEW_TOKENS)
+        alone_sch.run()
+        alone.append(list(r.out_tokens))
+    same = [a == s for a, s in zip(alone, served)]
+    say(f"serve finding: batched streams bitwise equal to each request "
+        f"decoded alone: {same} ({'holds' if all(same) else 'DOES NOT HOLD'}"
+        " on this device)")
+    return served
+
+
+def reference_logits(eng, prompts, served):
+    """The plain reference: dense.forward's `xla` formulation with XLA
+    attention over [prompt + served tokens] (teacher forcing), one
+    request at a time, padded to one width (causal: the padding behind
+    a row cannot reach it) so all four share one program; row j of
+    request i scores served token j."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from triton_dist_tpu.models.dense import (
+        cache_specs,
+        forward,
+        param_specs,
+    )
+
+    cfg, axis = eng.cfg, eng.axis
+    n = int(eng.mesh.shape[axis])
+    # one width for all four; the xla formulation shards the rows by tp
+    width = -(-(max(PROMPT_LENS) + NEW_TOKENS) // n) * n
+
+    def per_rank(params, tokens, cache, first):
+        logits, _ = forward(cfg, params, tokens, cache, mode="xla",
+                            axis=axis, return_full_logits=True,
+                            attn_impl="xla")
+        return jax.lax.dynamic_slice_in_dim(logits[0], first, NEW_TOKENS)
+
+    fn = jax.jit(jax.shard_map(
+        per_rank, mesh=eng.mesh,
+        in_specs=(param_specs(axis), P(), cache_specs(axis), P()),
+        out_specs=P(), check_vma=False))
+
+    def args_of(p, s):
+        seq = np.zeros((1, width), np.int32)
+        seq[0, :len(p) + len(s)] = p + s
+        return (eng.params, jnp.asarray(seq), eng.new_cache(1),
+                jnp.asarray(len(p) - 1, jnp.int32))
+
+    kernels, t_compile = compile_and_name(fn, *args_of(prompts[0],
+                                                       served[0]))
+    if kernels:
+        raise RuntimeError(f"the reference holds Pallas kernels {kernels}")
+    t0 = time.perf_counter()
+    ref = np.stack([np.asarray(fn(*args_of(p, s)))
+                    for p, s in zip(prompts, served)])  # (4, NEW, V) f32
+    say(f"reference: xla formulation, XLA attention, no Pallas kernel; "
+        f"compiled in {t_compile:.1f}s, four requests ran in "
+        f"{time.perf_counter() - t0:.2f}s")
+    if not np.isfinite(ref).all():
+        raise RuntimeError("reference logits are not finite")
+    return ref
+
+
+def check_served_tokens(ref, served, tol: float) -> None:
+    ties = 0
+    worst = 0.0
+    for i, toks in enumerate(served):
+        for j, tok in enumerate(toks):
+            gap = float(ref[i, j].max() - ref[i, j, tok])
+            if gap > 0.0:  # served token is not the reference's argmax
+                ties += 1
+                worst = max(worst, gap)
+                if gap >= tol:
+                    raise RuntimeError(
+                        f"request {i} token {j}: served {tok} scores "
+                        f"{gap:.4f} under the reference's best "
+                        f"{int(ref[i, j].argmax())} — outside the "
+                        f"tolerance {tol:.4f}")
+    say(f"served tokens vs reference argmax: {len(served) * NEW_TOKENS - ties}"
+        f"/{len(served) * NEW_TOKENS} equal, {ties} near-ties (largest "
+        f"gap {worst:.4f} < tolerance {tol:.4f})")
+
+
+def engine_phase(eng, prompts, served, ref, tol, want_prefill,
+                 want_decode):
+    """Engine.prefill + Engine.decode_step (the Engine's default modes)
+    teacher-forced on the served tokens, logits position by position
+    against the reference. Returns what the megakernel phase compares
+    with: request 0's first decode step."""
+    import jax.numpy as jnp
+
+    from triton_dist_tpu.lang.core import pallas_call_count
+
+    calls0 = pallas_call_count()
+    worst = 0.0
+    decode_kernels = None
+    first_decode = None
+    for i, (p, toks) in enumerate(zip(prompts, served)):
+        ids = jnp.asarray([p], jnp.int32)
+        cache = eng.new_cache(1)
+        kernels, t_compile = compile_and_name(eng._prefill, eng.params,
+                                              ids, cache)
+        t0 = time.perf_counter()
+        logits, cache = eng.prefill(ids, cache)
+        got = [np.asarray(logits[0])]
+        t_first = time.perf_counter() - t0
+        say(f"engine prefill S={len(p)} ({eng.prefill_mode}): compiled in "
+            f"{t_compile:.1f}s, ran in {t_first:.3f}s; kernels: "
+            f"{kernels or 'none'}")
+        if len(p) == max(PROMPT_LENS):
+            require(kernels, want_prefill, f"prefill S={len(p)}")
+        if decode_kernels is None:
+            tok0 = jnp.asarray(toks[:1], jnp.int32)[:, None]
+            decode_kernels, t_compile = compile_and_name(
+                eng._decode, eng.params, tok0, cache)
+            say(f"engine decode B=1 ({eng.decode_mode}): compiled in "
+                f"{t_compile:.1f}s; kernels: "
+                + (str(decode_kernels) if decode_kernels or want_decode
+                   else "none — by design at world=1: nothing to overlap, "
+                   "the step is XLA matmuls and XLA attention"))
+            require(decode_kernels, want_decode, "decode step")
+        steps = []
+        for tok in toks[:-1]:
+            t0 = time.perf_counter()
+            logits, cache = eng.decode_step([tok], cache)
+            got.append(np.asarray(logits[0]))
+            steps.append(time.perf_counter() - t0)
+        if first_decode is None:
+            first_decode = dict(prompt=p, token=toks[0], logits=got[1])
+        diff = float(np.abs(np.stack(got) - ref[i]).max())
+        worst = max(worst, diff)
+        say(f"engine request {i}: {len(got)} positions, largest logit "
+            f"difference from the reference {diff:.4f} (set-up "
+            f"information: median decode step "
+            f"{statistics.median(steps):.4f}s)")
+    say(f"engine vs reference: largest difference {worst:.4f}, tolerance "
+        f"{tol:.4f}; pallas_call_count +{pallas_call_count() - calls0}")
+    if not worst < tol:
+        raise RuntimeError(f"engine logits differ from the reference by "
+                           f"{worst:.4f} >= {tol:.4f}")
+    return first_decode
+
+
+def mega_phase(eng, first_decode, tol: float) -> None:
+    """One MegaQwen3 decode step, same widths and depth, against the
+    Engine's decode logits on the same prefilled cache."""
+    import jax.numpy as jnp
+
+    from triton_dist_tpu.lang.core import pallas_call_count
+    from triton_dist_tpu.mega.qwen3 import MegaKVCache, MegaQwen3
+
+    calls0 = pallas_call_count()
+    t0 = time.perf_counter()
+    mega = MegaQwen3(eng.cfg, eng.mesh, batch=1, s_max=MAX_LEN,
+                     params=eng.params)
+    say(f"megakernel: built in {time.perf_counter() - t0:.1f}s; scheduler: "
+        + ("native C++ (csrc/scheduler.cc, built from the committed "
+           "source)" if mega.sched.native
+           else "pure Python (TDT_NO_NATIVE=1)"))
+    _, cache = eng.prefill(jnp.asarray([first_decode["prompt"]], jnp.int32),
+                           eng.new_cache(1))
+    mcache = MegaKVCache.from_dense(cache, MAX_LEN)
+    del cache
+    tok = jnp.asarray([first_decode["token"]], jnp.int32)
+    kernels, t_compile = compile_and_name(
+        mega._decode, mega.params, mega._w_gate_up, tok, mcache)
+    t0 = time.perf_counter()
+    logits, mcache = mega.decode_step(tok, mcache)
+    logits = np.asarray(logits[0])
+    t_first = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    np.asarray(mega.decode_step(tok, mcache)[0])
+    t_steady = time.perf_counter() - t0
+    diff = float(np.abs(logits - first_decode["logits"]).max())
+    say(f"megakernel decode step: compiled in {t_compile:.1f}s, first "
+        f"{t_first:.3f}s, second {t_steady:.4f}s (set-up information); "
+        f"kernels: {kernels or 'none'}; pallas_call_count +"
+        f"{pallas_call_count() - calls0}; largest logit difference from "
+        f"the Engine's decode step {diff:.4f}, tolerance {tol:.4f}")
+    require(kernels, ["mega_qwen3"], "megakernel step")
+    if not (np.isfinite(logits).all() and diff < tol):
+        raise RuntimeError(f"megakernel logits differ from the Engine's "
+                           f"by {diff:.4f} >= {tol:.4f}")
+
+
+def run(cfg, mesh, seed: int, prompts, cross_chip: bool) -> None:
+    """Every phase on `mesh`. cross_chip: the tp>1 contract (kernels of
+    the overlapped collectives by name, no megakernel phase)."""
+    import jax
+
+    from triton_dist_tpu.models import Engine
+
+    t0 = time.perf_counter()
+    eng = Engine(cfg, mesh, max_len=MAX_LEN, seed=seed, fast_init=True)
+    jax.block_until_ready(eng.params)
+    say(f"engine: prefill_mode={eng.prefill_mode} decode_mode="
+        f"{eng.decode_mode}, parameters drawn sharded from seed {seed} in "
+        f"{time.perf_counter() - t0:.1f}s")
+    if cross_chip:
+        check_ring(mesh)
+        model = sum(x.size * x.dtype.itemsize
+                    for x in jax.tree.leaves(eng.params))
+        check_memory_spread(mesh, model)
+
+    served = serve_phase(
+        eng, prompts,
+        want_kernels=["_gemm_rs_kernel"] if cross_chip
+        else ["_fp_local_kernel"])
+    ref = reference_logits(eng, prompts, served)
+    tol = (TOL_ULPS * 2.0 ** -8 * cfg.num_layers ** 0.5
+           * float(ref.std()))
+    say(f"tolerance: {tol:.4f} = {TOL_ULPS} x 2^-8 x sqrt(depth "
+        f"{cfg.num_layers}) x the reference logits' standard deviation "
+        f"{float(ref.std()):.4f}")
+    check_served_tokens(ref, served, tol)
+    first_decode = engine_phase(
+        eng, prompts, served, ref, tol,
+        want_prefill=(["_ag_gemm_kernel", "_gemm_rs_kernel"] if cross_chip
+                      else ["_fp_local_kernel"]),
+        want_decode=["_one_shot_ar_kernel"] if cross_chip else [])
+    if not cross_chip:
+        mega_phase(eng, first_decode, tol)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: the cross-chip path (tp=4, full depth) and "
+                         "its reference, no other phase")
+    args = ap.parse_args()
+
+    import jax
+
+    from triton_dist_tpu import lang
+    from triton_dist_tpu.models import ModelConfig
+    from triton_dist_tpu.runtime import enable_compile_cache, make_mesh
+
+    dev = jax.devices()[0]
+    say(f"device: platform={dev.platform} kind={dev.device_kind} "
+        f"count={len(jax.devices())} jax={jax.__version__}")
+    if dev.platform != "tpu" or lang.use_interpret():
+        print("chip_smoke.py needs a TPU (JAX's first device is "
+              f"{dev.platform!r}, interpret={lang.use_interpret()}): "
+              "nothing was built", file=sys.stderr)
+        return 2
+    if len(jax.devices()) < args.chips:
+        print(f"--chips {args.chips} needs {args.chips} devices, JAX "
+              f"found {len(jax.devices())}", file=sys.stderr)
+        return 2
+    cache = CacheCounter()
+    say(f"compile cache: {enable_compile_cache()}")
+
+    full = ModelConfig.qwen3_8b(max_positions=MAX_LEN)
+    if args.chips == 1:
+        depth = depth_for_one_chip(full, dev.memory_stats()["bytes_limit"])
+    else:
+        depth = full.num_layers
+    cfg = ModelConfig.qwen3_8b(num_layers=depth, max_positions=MAX_LEN)
+    say(f"model: Qwen3-8B widths untouched (hidden {cfg.hidden_size}, "
+        f"intermediate {cfg.intermediate_size}, {cfg.num_q_heads} q / "
+        f"{cfg.num_kv_heads} kv heads, head_dim {cfg.head_dim}, vocab "
+        f"{cfg.vocab_size}, {cfg.dtype}, untied head); reduced: depth "
+        f"{depth} of {full.num_layers}"
+        + ("" if depth < full.num_layers else " (nothing cut)"))
+    rng = np.random.default_rng(args.seed)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in PROMPT_LENS]
+
+    run(cfg, make_mesh((args.chips,), ("tp",)), args.seed, prompts,
+        cross_chip=args.chips > 1)
+
+    say(f"compile cache: {cache.hits} hits, {cache.misses} misses")
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(jax.devices())}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

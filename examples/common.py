@@ -18,7 +18,8 @@ def bootstrap(world: int = 4):
 
     Default: a virtual CPU mesh with spare devices (interpret-mode Pallas
     simulates the inter-chip DMA; see tests/conftest.py for why spares
-    matter). `--tpu` uses whatever real TPU devices exist (world clamps);
+    matter). `--tpu` uses the real TPU devices and fails when there are
+    fewer than `world` (pass `--world N` to ask for what is there);
     `--world N` overrides the mesh size.
     """
     if "--world" in sys.argv:
@@ -37,7 +38,11 @@ def bootstrap(world: int = 4):
 
     if not use_tpu:
         jax.config.update("jax_platforms", "cpu")
-    n = min(world, len(jax.devices()))
-    from triton_dist_tpu.runtime import make_mesh
+    from triton_dist_tpu.runtime import enable_compile_cache, make_mesh
 
-    return jax, make_mesh((n,), ("tp",))
+    enable_compile_cache()  # before the first compile
+    if len(jax.devices()) < world:
+        raise SystemExit(
+            f"this example wants a {world}-device mesh and JAX found "
+            f"{len(jax.devices())}: pass --world {len(jax.devices())}")
+    return jax, make_mesh((world,), ("tp",))

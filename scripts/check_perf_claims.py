@@ -61,10 +61,9 @@ SCAN_GLOBS = (
 # batching claim (ISSUE 6). Removing the sentence is as loud as
 # contradicting it.
 REQUIRED_CLAIMS = (
-    ("pallas_vs_xla", "triton_dist_tpu/kernels/allgather_gemm.py"),
-    ("pallas_vs_xla", "docs/performance.md"),
-    ("gemm_rs_vs_xla", "triton_dist_tpu/kernels/gemm_reduce_scatter.py"),
-    ("gemm_rs_vs_xla", "docs/performance.md"),
+    # (pallas_vs_xla / gemm_rs_vs_xla left this list in PR 24 with the
+    # chip records that backed them: a claim no artifact measures is
+    # removed, not required)
     ("serve_vs_seq_tokens", "docs/serving.md"),
     ("sp_prefill_vs_ring", "triton_dist_tpu/kernels/flash_prefill.py"),
     ("sp_prefill_vs_ring", "docs/performance.md"),

@@ -4,8 +4,10 @@ The reference tests run under torchrun on 8 real GPUs (ref:
 scripts/launch.sh). Here every test runs on an 8-device mesh carved out of
 12 virtual CPU devices with Pallas TPU kernels in interpret mode, which
 simulates inter-chip remote DMA + semaphores, so the full distributed
-kernel library is exercised without TPU hardware. On a real TPU slice the
-same tests run natively (set TDT_TEST_TPU=1).
+kernel library is exercised without TPU hardware. The suite always
+forces the CPU: the chip is checked by `python chip_smoke.py` (one
+process on the machine that holds it) and, without a chip, by the
+compiles of tests/test_chip_compile.py.
 
 Why 12 virtual devices for an 8-device mesh: XLA:CPU sizes its thunk
 executor thread pool by device count, and interpret-mode kernels BLOCK pool
@@ -25,16 +27,13 @@ import os
 # winners inject them explicitly via autotuner.set_tune_cache.
 os.environ.setdefault("TDT_TUNE_CACHE", "")
 
-if os.environ.get("TDT_TEST_TPU", "") != "1":
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=12"
-    )
-    import jax
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "")
+    + " --xla_force_host_platform_device_count=12"
+)
+import jax  # noqa: E402
 
-    jax.config.update("jax_platforms", "cpu")
-else:
-    import jax  # noqa: F401
+jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

@@ -1,0 +1,319 @@
+"""The main path's kernels and steps, compiled for a described TPU v5e.
+
+Every other tier-1 test runs the Pallas kernels in interpret mode on
+the CPU, which accepts programs the chip's compiler refuses: a slice
+that is not a whole packed tile, a block whose last dim is not a lane
+multiple, more scoped VMEM than the kernel asked for (all three were
+found at Qwen3-8B widths — CHANGES.md, PR 24). libtpu is installed
+here and compiles for a chip that is described and not attached, so
+these cases ask it — shapes only, nothing runs — for what
+chip_smoke.py will execute on one chip and on four, and assert by name
+that the expected kernel is IN the compiled program (a route that gave
+way to XLA compiles too).
+
+The topology is described inside a module-scoped fixture and nowhere
+else: only the xdist worker that is handed this file loads libtpu.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from triton_dist_tpu.lang import core
+from triton_dist_tpu.models import Engine, ModelConfig
+from triton_dist_tpu.models.dense import (
+    cache_specs,
+    param_shapes,
+    param_specs,
+)
+from triton_dist_tpu.models.kv_cache import KVCache
+from triton_dist_tpu.runtime import make_mesh
+
+MAX_LEN = 2048
+BF16 = jnp.bfloat16
+SDS = jax.ShapeDtypeStruct
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no logs in /tmp
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def chip(topo, monkeypatch):
+    """Steer every backend-dependent branch to the described chip: the
+    kernels compile natively (not interpret), the perf model prices a
+    v5e (not the CPU ranking stub), so the planner routes as it will
+    on the device."""
+    from triton_dist_tpu import perf_model
+
+    monkeypatch.setattr(core, "backend_platform", lambda: "tpu")
+    monkeypatch.setattr(core, "backend_device", lambda: topo.devices[0])
+    perf_model.detect_chip.cache_clear()
+    yield topo
+    perf_model.detect_chip.cache_clear()
+
+
+def _mesh(topo, n):
+    return make_mesh((n,), ("tp",), devices=topo.devices)
+
+
+def _engine(topo, n, num_layers=2):
+    """Engine at Qwen3-8B widths over n described chips; params are
+    shapes (a described device holds no array)."""
+    cfg = ModelConfig.qwen3_8b(num_layers=num_layers,
+                               max_positions=MAX_LEN)
+    mesh = _mesh(topo, n)
+    params = jax.tree.map(
+        lambda shape, spec: SDS(shape, BF16,
+                                sharding=NamedSharding(mesh, spec)),
+        param_shapes(cfg, n), param_specs("tp"),
+        is_leaf=lambda x: type(x) is tuple)
+    return Engine(cfg, mesh, params=params, max_len=MAX_LEN)
+
+
+def _step_args(eng, batch, seq):
+    cfg, mesh = eng.cfg, eng.mesh
+    kv = (cfg.num_layers, batch, MAX_LEN, cfg.num_kv_heads, cfg.head_dim)
+    specs = cache_specs("tp")
+    cache = KVCache(
+        k=SDS(kv, BF16, sharding=NamedSharding(mesh, specs.k)),
+        v=SDS(kv, BF16, sharding=NamedSharding(mesh, specs.v)),
+        length=SDS((batch,), jnp.int32,
+                   sharding=NamedSharding(mesh, specs.length)))
+    tokens = SDS((batch, seq), jnp.int32,
+                 sharding=NamedSharding(mesh, P()))
+    return eng.params, tokens, cache
+
+
+def _kernels(compiled) -> dict:
+    return core.pallas_kernels_in(compiled.as_text())
+
+
+def _shard_map_compile(mesh, fn, in_specs, out_specs, *shapes):
+    args = [SDS(s, BF16, sharding=NamedSharding(mesh, spec))
+            for s, spec in zip(shapes, in_specs)]
+    return jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False)).lower(*args).compile()
+
+
+# -- one chip ---------------------------------------------------------------
+
+
+def test_flash_prefill_s512(chip):
+    """The kernel the seed's gate let through and Mosaic refused: 512
+    query rows x 32 heads against a 2048-token cache."""
+    from triton_dist_tpu.kernels.flash_prefill import (
+        flash_prefill_fits,
+        flash_prefill_local,
+    )
+
+    assert flash_prefill_fits(512, MAX_LEN, 32, 8, 128)
+    mesh = _mesh(chip, 1)
+    compiled = _shard_map_compile(
+        mesh, lambda q, k, v: flash_prefill_local(q, k, v),
+        (P(), P(), P()), P(),
+        (1, 512, 32, 128), (1, MAX_LEN, 8, 128), (1, MAX_LEN, 8, 128))
+    assert _kernels(compiled) == {"_fp_local_kernel": 1}
+
+
+def test_engine_prefill_one_chip(chip):
+    eng = _engine(chip, 1)
+    compiled = eng._prefill.lower(*_step_args(eng, 1, 512)).compile()
+    assert _kernels(compiled) == {"_fp_local_kernel": 1}
+
+
+def test_engine_decode_one_chip(chip):
+    """By design, not by accident: at world=1 there is nothing to
+    overlap, so the `ar` decode step is XLA matmuls and XLA attention
+    and holds no kernel of ours. chip_smoke.py prints the same."""
+    eng = _engine(chip, 1)
+    compiled = eng._decode.lower(*_step_args(eng, 1, 1)).compile()
+    assert _kernels(compiled) == {}
+
+
+def test_serve_step_one_chip(chip):
+    """The Scheduler's step at its default geometry for this model
+    (4 slots x the chooser's 128-token chunk, 64-token pages)."""
+    eng = _engine(chip, 1)
+    slots, chunk, page = 4, 128, 64
+    max_pages = MAX_LEN // page
+    mesh, cfg = eng.mesh, eng.cfg
+    rep = NamedSharding(mesh, P())
+    pool = SDS((cfg.num_layers, cfg.num_kv_heads, 1 + slots * max_pages,
+                page, cfg.head_dim), BF16,
+               sharding=NamedSharding(mesh, P(None, "tp")))
+    compiled = eng.make_serve_step(slots, chunk, page, max_pages).lower(
+        eng.params, SDS((slots, chunk), jnp.int32, sharding=rep),
+        pool, pool, SDS((slots, max_pages), jnp.int32, sharding=rep),
+        SDS((slots,), jnp.int32, sharding=rep),
+        SDS((slots,), jnp.int32, sharding=rep),
+        SDS((slots,), jnp.float32, sharding=rep),
+        SDS((slots, 2), jnp.uint32, sharding=rep)).compile()
+    assert _kernels(compiled) == {"_fp_local_kernel": 1}
+
+
+def test_mega_decode_step_one_chip(chip):
+    """The megakernel exactly as MegaQwen3 builds it (graph, schedule,
+    compile_graph), one decode step at batch 1. Its Mosaic compile
+    takes over a minute at these widths — the slow case of this file."""
+    from triton_dist_tpu.mega.kernel import _kv_chunk, compile_graph
+    from triton_dist_tpu.mega.qwen3 import build_qwen3_graph
+    from triton_dist_tpu.mega.scheduler import schedule_graph
+
+    num_layers, batch = 1, 1
+    cfg = ModelConfig.qwen3_8b(num_layers=num_layers,
+                               max_positions=MAX_LEN)
+    mb, _ = build_qwen3_graph(cfg, batch, 1, MAX_LEN, "tp")
+    cm = compile_graph(mb.graph, schedule_graph(mb.graph), BF16,
+                       name="mega_qwen3_tp1",
+                       tiled_weights=("w_gate_up",))
+    h, d, inter = cfg.hidden_size, cfg.head_dim, cfg.intermediate_size
+    tn = cm.tile_cols("w_gate_up")
+    chunk = _kv_chunk(MAX_LEN, 0)
+    pages = batch * MAX_LEN // chunk
+    ws = jax.eval_shape(lambda: cm.workspace(BF16))
+    one = NamedSharding(_mesh(chip, 1), P())
+
+    def sds(shape, dtype=BF16):
+        return SDS(shape, dtype, sharding=one)
+
+    weights = {
+        "w_qkv": sds((num_layers, h, (cfg.num_q_heads
+                                      + 2 * cfg.num_kv_heads) * d)),
+        "w_o": sds((num_layers, cfg.num_q_heads * d, h)),
+        "w_gate_up": sds((num_layers, 2 * inter // tn, h, tn)),
+        "w_down": sds((num_layers, inter, h)),
+    }
+    kv = sds((num_layers, cfg.num_kv_heads, pages, chunk, d))
+    compiled = jax.jit(cm.run).lower(
+        sds((batch,), jnp.int32), sds((batch, pages), jnp.int32),
+        sds(ws.shape), weights,
+        sds(((4 * num_layers + 1) * 8, cm.norm_width), jnp.float32),
+        sds((MAX_LEN * 8, d), jnp.float32), kv, kv).compile()
+    assert _kernels(compiled) == {"mega_qwen3_tp1": 1}
+
+
+# -- four chips: the cross-chip path ------------------------------------------
+
+
+def test_mesh_ring_follows_ici(topo):
+    """make_mesh lays tp neighbours on ICI neighbours: every hop of the
+    ring, the wrap-around included, moves one step on the 2x2 grid."""
+    ring = list(_mesh(topo, 4).devices.flat)
+    for a, b in zip(ring, ring[1:] + ring[:1]):
+        assert sum(abs(x - y) for x, y in zip(a.coords, b.coords)) == 1, (
+            [d.coords for d in ring])
+
+
+def test_ag_gemm_tp4(chip):
+    """AG+GEMM with the MLP's silu_pair epilogue at the shape that was
+    refused (a (4096, 3072) gate/up shard tiled (512, 192))."""
+    from triton_dist_tpu.kernels.allgather_gemm import ag_gemm
+
+    compiled = _shard_map_compile(
+        _mesh(chip, 4),
+        lambda a, g, u: ag_gemm(a, (g[0], u[0]), "tp",
+                                epilogue="silu_pair"),
+        (P("tp"), P("tp"), P("tp")), P(None, "tp"),
+        (512, 4096), (4, 4096, 3072), (4, 4096, 3072))
+    assert _kernels(compiled) == {"_ag_gemm_kernel": 1}
+
+
+def test_gemm_rs_tp4(chip):
+    """GEMM+RS at the MLP down-projection of a 512-token prefill."""
+    from triton_dist_tpu.kernels.gemm_reduce_scatter import gemm_rs
+
+    compiled = _shard_map_compile(
+        _mesh(chip, 4), lambda a, b: gemm_rs(a, b[0], "tp"),
+        (P(None, "tp"), P("tp")), P("tp"),
+        (512, 4 * 3072), (4, 3072, 4096))
+    assert _kernels(compiled) == {"_gemm_rs_kernel_streamed": 1}
+
+
+@pytest.mark.parametrize("batch", [1, 8])
+def test_ar_decode_step_tp4(chip, batch):
+    """The `ar` decode step: one one-shot AR per projection back into
+    the residual stream. batch=1 is the case Mosaic refused (a one-row
+    bf16 slot is not a whole packed tile)."""
+    eng = _engine(chip, 4)
+    compiled = eng._decode.lower(*_step_args(eng, batch, 1)).compile()
+    assert _kernels(compiled) == {"_one_shot_ar_kernel": 2}
+
+
+def test_dist_prefill_step_tp4(chip):
+    """The whole `dist` prefill step at S=512: AG+GEMM into QKV and
+    into gate|up, GEMM+RS out of the o- and down-projections (resident
+    and streamed regimes), flash attention between."""
+    eng = _engine(chip, 4)
+    compiled = eng._prefill.lower(*_step_args(eng, 1, 512)).compile()
+    assert _kernels(compiled) == {
+        "_ag_gemm_kernel": 2, "_fp_local_kernel": 1,
+        "_gemm_rs_kernel": 1, "_gemm_rs_kernel_streamed": 1}
+
+
+def test_watchdog_native_branch_tp4(chip):
+    """faults.guard's bounded-poll loop over `pl.semaphore_read`: the
+    installed interpreter has no rule for that primitive (the 18 tier-1
+    tests that build a guard on the CPU mesh fail on it), Mosaic does —
+    this compile is the only coverage the hardware branch has."""
+    from triton_dist_tpu.faults import guard
+    from triton_dist_tpu.kernels.allgather import ring_all_gather
+
+    def gathered(x):
+        with guard.building():
+            out, gbuf = ring_all_gather(x, "tp")
+        return out, gbuf[None]
+
+    compiled = _shard_map_compile(
+        _mesh(chip, 4), gathered, (P("tp"),), (P(), P("tp")), (512, 4096))
+    assert _kernels(compiled) == {"_ring_ag_kernel": 1}
+
+
+# -- chip_smoke.py's own control flow, rehearsed at a tiny size ---------------
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_chip_smoke_rehearsal(monkeypatch, capsys, chips):
+    """The first two rehearsals of the on-chip-measurement guide, kept:
+    chip_smoke.py's phases end to end on the CPU (interpret-mode
+    kernels) on one device and on four virtual ones, at a tiny size.
+    `main()` itself refuses a CPU, so the phases are driven through
+    `run`; what only a chip has — kernels named in compiled text, chip
+    coordinates, memory_stats — is what test_* above and the chip run
+    check instead."""
+    import numpy as np
+
+    import chip_smoke
+
+    monkeypatch.setattr(chip_smoke, "PROMPT_LENS", (4, 8, 8, 8))
+    monkeypatch.setattr(chip_smoke, "NEW_TOKENS", 2)
+    monkeypatch.setattr(chip_smoke, "MAX_LEN", 64)
+    wanted = []
+    monkeypatch.setattr(chip_smoke, "require",
+                        lambda kernels, want, where: wanted.append(where))
+    monkeypatch.setattr(chip_smoke, "check_ring", lambda mesh: None)
+    monkeypatch.setattr(chip_smoke, "check_memory_spread",
+                        lambda mesh, model_bytes: None)
+    cfg = ModelConfig.tiny(max_positions=64)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in chip_smoke.PROMPT_LENS]
+    chip_smoke.run(cfg, make_mesh((chips,), ("tp",)), 0, prompts,
+                   cross_chip=chips > 1)
+    out = capsys.readouterr().out
+    assert "serve: 4/4 requests finished, 8 tokens streamed" in out
+    assert "engine vs reference: largest difference" in out
+    assert ("megakernel decode step" in out) == (chips == 1)
+    assert "serve step" in wanted and "decode step" in wanted

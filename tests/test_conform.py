@@ -142,7 +142,7 @@ def test_conform_broadcast_skip_is_loud():
     findings, report = conform.check_shipped(["broadcast"])
     assert findings == []
     assert len(report) == 2
-    assert all("SKIP" in ln and "XLA fallback" in ln for ln in report)
+    assert all("SKIP" in ln and "rank-divergent" in ln for ln in report)
 
 
 def test_conform_buffer_overflow_raises():

@@ -47,7 +47,7 @@ def chain_graph(n=12):
 @pytest.fixture(params=["native", "python"])
 def backend(request):
     if request.param == "native" and _native.load() is None:
-        pytest.skip("no C++ toolchain")
+        pytest.skip("TDT_NO_NATIVE=1: Python scheduler asked for")
     return request.param == "native"
 
 
@@ -95,7 +95,7 @@ def test_slot_reuse(backend):
 
 def test_native_and_python_agree():
     if _native.load() is None:
-        pytest.skip("no C++ toolchain")
+        pytest.skip("TDT_NO_NATIVE=1: Python scheduler asked for")
     g = diamond_graph()
     a = schedule_graph(g, num_cores=2, strategy="blocked", use_native=True)
     b = schedule_graph(g, num_cores=2, strategy="blocked", use_native=False)

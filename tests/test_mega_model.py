@@ -7,20 +7,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from triton_dist_tpu.lang.core import (
-    multicore_interpret_supported,
-    use_interpret,
-)
 from triton_dist_tpu.mega.qwen3 import MegaKVCache, MegaQwen3
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.engine import Engine
 from triton_dist_tpu.runtime.init import make_mesh
-
-
-def _require_multicore_interpret():
-    if use_interpret() and not multicore_interpret_supported():
-        pytest.skip("this jax's Pallas interpreter cannot emulate "
-                    "multiple TensorCores (needs InterpretParams)")
 
 
 @pytest.fixture(scope="module")
@@ -117,7 +107,6 @@ def test_mega_decode_two_cores_matches_engine(tiny_cfg, world,
     watermark waits, the HB slot plan, and the drain rows all execute.
     Race detection is enabled at world=1 (it slows the interpreter;
     one world covers the data-race question)."""
-    _require_multicore_interpret()
     if world == 1:
         monkeypatch.setenv("TDT_MEGA_RACES", "1")
     cfg = tiny_cfg
@@ -250,7 +239,6 @@ def test_mega_ar_under_rank_skew(tiny_cfg, skew_rank):
     early by those deliveries and reads a stale mailbox, which this
     decode-parity check catches (2 cores, world=4, several steps so
     both parities are exercised under skew)."""
-    _require_multicore_interpret()
     cfg = tiny_cfg
     mesh = _mesh(4)
     B, S = 4, 4

@@ -46,33 +46,15 @@ def f(s):
     gathered = jax.lax.all_gather(s, "tp", tiled=True)
     return total.reshape(1), gathered
 
-from triton_dist_tpu.lang import _compat
-
-try:
-    total, gathered = jax.jit(jax.shard_map(
-        f, mesh=mesh, in_specs=P("tp"), out_specs=(P("tp"), P(None, "tp")),
-        check_vma=False,
-    ))(x)
-except RuntimeError as e:
-    # jaxlib 0.4.x CPU cannot EXECUTE cross-process computations at all
-    # (XlaRuntimeError, a RuntimeError) — the DCN bring-up this test
-    # exists for (rendezvous, global device view, spanning mesh, global
-    # array construction) has already succeeded above, so accept
-    # exactly that failure on the legacy line and nothing broader: any
-    # other error here is a real bring-up regression and must surface.
-    if not (_compat.LEGACY_JAX
-            and "Multiprocess computations aren't implemented on the "
-                "CPU backend" in str(e)):
-        raise
-    local = x.addressable_shards[0].data
-    assert local.shape == (4, 128), local.shape
-    print(f"MULTIHOST_OK pid={jax.process_index()} total=bringup-only")
-else:
-    want_total = sum(r * 4 * 128 for r in range(n))
-    got = float(
-        np.asarray(jax.device_get(total.addressable_shards[0].data))[0])
-    assert got == want_total, (got, want_total)
-    print(f"MULTIHOST_OK pid={jax.process_index()} total={got}")
+total, gathered = jax.jit(jax.shard_map(
+    f, mesh=mesh, in_specs=P("tp"), out_specs=(P("tp"), P(None, "tp")),
+    check_vma=False,
+))(x)
+want_total = sum(r * 4 * 128 for r in range(n))
+got = float(
+    np.asarray(jax.device_get(total.addressable_shards[0].data))[0])
+assert got == want_total, (got, want_total)
+print(f"MULTIHOST_OK pid={jax.process_index()} total={got}")
 """
 
 

@@ -289,7 +289,8 @@ def test_ag_gemm_arrival_feeds_gemm_rs(mesh8):
 def test_gemm_rs_streamed_matches_ref(mesh8):
     """The streamed-b regime (b too large for VMEM): the budget is sized
     against the PER-SHARD K_loc=32 the kernel actually sees (resident
-    needs 162 KiB; streamed tn=128 needs 130 KiB) so the streamed ring
+    needs 194 KiB; streamed tn=128 needs 162 KiB — both count the fold
+    temporary beside acc x2 + stage, PR 24) so the streamed ring
     runs for real — the round-4 verdict's N-tiling, at test scale. The
     regime hook asserts the dispatch (the round-5 reviewer caught this
     test's first budget, sized against the GLOBAL K, silently running
@@ -304,7 +305,7 @@ def test_gemm_rs_streamed_matches_ref(mesh8):
         jax.shard_map(
             functools.partial(
                 gemm_rs, axis="tp",
-                config=GemmRsConfig(tile_m=8, vmem_budget=150 << 10)),
+                config=GemmRsConfig(tile_m=8, vmem_budget=180 << 10)),
             mesh=mesh8, in_specs=(P(None, "tp"), P("tp", None)),
             out_specs=P("tp", None), check_vma=False,
         )

@@ -103,7 +103,8 @@ def test_stable_series_and_neutral_keys_are_clean(tmp_path):
 def test_acknowledgement_is_kind_scoped(tmp_path):
     """An ack mutes exactly its (key, kind): a WATERMARK break on the
     acknowledged key still fails the gate (the overbroad-mute class)."""
-    key, kind = next(iter(trend.ACKNOWLEDGED))
+    key, kind = next(k for k in trend.ACKNOWLEDGED
+                     if k[1] == "trend_regression")
     _write_round(tmp_path, 1, {key: 10.0})
     _write_round(tmp_path, 2, {key: 10.2})
     _write_round(tmp_path, 3, {key: 99.0})  # way past watermark_tol
@@ -136,24 +137,24 @@ def test_strict_mode_raises_on_unreadable_artifact(tmp_path):
 
 
 def test_real_series_has_zero_unacknowledged_flags():
-    """The sentinel on the committed r01–r06 artifacts: zero FALSE
-    positives — every flag carries an ACKNOWLEDGED reason (today:
-    exactly the retired a2a_dispatch_us alias), so the CI gate exits
-    0. A new unexplained flag here means either a real regression (fix
-    it) or a detector bug (fix that) — never 'loosen the test'."""
+    """The sentinel on the committed artifacts (r01 and the cpu-world1
+    r06-r09; the r02-r05 chip records were deleted in PR 24): zero
+    FALSE positives — every flag carries an ACKNOWLEDGED reason
+    (today: the two rig-local absolute arms), so the CI gate exits 0. A
+    new unexplained flag here means either a real regression (fix it)
+    or a detector bug (fix that) — never 'loosen the test'."""
     rep = trend.analyze(repo=REPO, strict=True)
     unack = trend.unacknowledged(rep)
     assert unack == [], unack
-    assert any(f["key"] == "a2a_dispatch_us" and f["acknowledged"]
-               for f in rep["flags"])
+    acked = {(f["key"], f["kind"]) for f in rep["flags"]
+             if f["acknowledged"]}
+    assert acked == set(trend.ACKNOWLEDGED), acked
     # every ACKNOWLEDGED entry still earns its keep on the real series
     assert not any(n["kind"] == "stale_ack" for n in rep["notes"])
-    # rigs never mixed: the cpu rig's serving keys must not be in a
-    # default-rig series
-    assert "serve_tokens_per_s [cpu-world1]" in rep["series"]
-    assert "serve_tokens_per_s [default]" not in rep["series"]
-    # the multi-point TPU series all survived
-    assert len(rep["series"]["engine_decode_ms [default]"]) == 3
+    # what remains is one rig: no default-rig (chip) series is left to
+    # mix with, and the cpu rig's multi-point series all survived
+    assert not any(k.endswith("[default]") for k in rep["series"])
+    assert len(rep["series"]["serve_tokens_per_s [cpu-world1]"]) == 4
 
 
 def test_report_document_roundtrip_and_strictness(tmp_path):
@@ -166,7 +167,7 @@ def test_report_document_roundtrip_and_strictness(tmp_path):
                             "flags": [], "notes": []})
     md = trend.render_markdown(rep)
     assert "Perf-trend sentinel report" in md
-    assert "a2a_dispatch_us" in md
+    assert "plan_decode_ms" in md
 
 
 # ---------- the CLI (the CI gate's exact entry point) ----------

@@ -258,7 +258,7 @@ def test_bench_schema_accepts_wellformed(bench_mod):
     assert bench_mod.check_result(good) == []
     # measurement-failure line stays valid (tracked outcome)
     fail = {"metric": "mega_decode_qwen3_8b_ms", "value": -1.0,
-            "unit": "ms", "vs_baseline": -1.0, "error": "tunnel glitch"}
+            "unit": "ms", "vs_baseline": -1.0, "error": "measurement failed"}
     assert bench_mod.check_result(fail) == []
 
 

@@ -391,7 +391,7 @@ def test_verifier_hb_edges_agree_with_trace_replay(mesh8):
     trace/attribution.a2a_step_waits' delivery replay: sender of step i
     at receiver q is (q - i) mod n. Static HB and dynamic trace are two
     views of one protocol; this pins them together (through the shared
-    verify/trace op taxonomy, events.VERIFY_OP_REGIONS)."""
+    verify/trace op classification, events.VERIFY_OP_REGIONS)."""
     from triton_dist_tpu.kernels.all_to_all import (
         _a2a_chunked_protocol,
         all_to_all_chunked,

@@ -28,13 +28,6 @@ models/, megakernel/, tools/, csrc/ in the reference's inventory).
 
 __version__ = "0.1.0"
 
-# Legacy-jax namespace back-fills (shard_map / get_abstract_mesh /
-# axis_size) live with the rest of the compat surface in lang._compat;
-# they must install before runtime/kernels import below.
-from triton_dist_tpu.lang import _compat as _lang_compat
-
-_lang_compat.install_jax_namespace()
-
 from triton_dist_tpu.runtime import (  # noqa: F401
     initialize_distributed,
     get_default_mesh,

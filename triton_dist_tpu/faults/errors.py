@@ -1,4 +1,4 @@
-"""Failure taxonomy of the guarded-execution plane (docs/robustness.md).
+"""Failure classification of the guarded-execution plane (docs/robustness.md).
 
 Every guard in the framework converts a would-be hang or silent
 corruption into exactly one of these exception classes, raised HOST-side

@@ -2,10 +2,8 @@
 
 A semaphore-granular overlap kernel has exactly one catastrophic
 failure mode: a wait whose signal never arrives. Unguarded, that is a
-hang (hardware) or a silently-wrong answer (the legacy interpreter's
-`semaphore_wait` discharge subtracts below zero without complaint —
-lang/_compat.py). The guard plane converts both into a STRUCTURED,
-attributable failure:
+hang. The guard plane converts it into a STRUCTURED, attributable
+failure:
 
   - while a `guards.building()` block is active, instrumented kernels
     compile every guarded wait as a bounded poll: read the semaphore,
@@ -19,10 +17,11 @@ attributable failure:
     polls, bit-identical programs with unchanged `pallas_call_count`
     (the trace/verify zero-cost-off discipline, test-enforced).
 
-Poll semantics per backend: under the lockstep interpreter all signals
-whose program point precedes the wait have already discharged, so ONE
-read decides — satisfied now or never (deterministic detection). On
-hardware the poll is a deadline-bounded re-read loop.
+Poll semantics: a deadline-bounded re-read loop over
+`pl.semaphore_read`. Mosaic lowers it; the installed (0.9.0) Pallas
+TPU interpreter has no rule for `semaphore_read`, so guarded builds
+compile for the chip only (tests/test_chip_compile.py keeps that
+compile) and the CPU-mesh tests that construct one fail at lowering.
 
 Buffer layout mirrors trace/events.py: (1 + cap, GUARD_WORDS) i32 SMEM,
 header row [GMAGIC, trip_count, cap, rank, deadline, 0, 0, 0], trip rows
@@ -49,9 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from triton_dist_tpu.faults.errors import DeadlineExceeded
 
-# jax moved semaphore_read between the tpu and generic pallas modules
-# across versions; resolve once.
-_sem_read = getattr(pltpu, "semaphore_read", None) or pl.semaphore_read
+_sem_read = pl.semaphore_read
 
 GUARD_WORDS = 8
 GMAGIC = 0x6D7A  # 'guard' header tag
@@ -90,8 +87,7 @@ class GuardBuild:
     re-reads, so the default budget is ~deadline * poll_ns = 2.56 ms —
     far above any healthy ICI delivery, far below forever. A raw
     back-to-back re-read loop would burn its budget in microseconds
-    and trip on benign latency. Interpret mode ignores both knobs (one
-    read decides)."""
+    and trip on benign latency."""
 
     cap: int = 32          # max recorded trips per buffer
     deadline: int = 256    # hardware polls per wait
@@ -336,25 +332,18 @@ def _watchdog_override(impl: str):
 
 
 def _satisfied(sem, amount, deadline, poll_ns=10_000):
-    """Bounded-poll readiness. Interpreter: one read decides (all
-    preceding signals have discharged — satisfied now or never).
-    Hardware: up to `deadline` re-reads with a `poll_ns` pl.delay
-    between them, so the budget is wall-time-shaped (~deadline *
-    poll_ns) and exits early once satisfied — a raw back-to-back
-    re-read loop would burn its budget in microseconds and trip on
-    benign delivery latency."""
-    from triton_dist_tpu.lang.core import use_interpret
-
+    """Bounded-poll readiness: up to `deadline` re-reads with a
+    `poll_ns` pl.delay between them, so the budget is wall-time-shaped
+    (~deadline * poll_ns) and exits early once satisfied — a raw
+    back-to-back re-read loop would burn its budget in microseconds and
+    trip on benign delivery latency."""
     amt = jnp.asarray(amount, jnp.int32)
     if watchdog_impl() == "reset_poll":
         # MUTANT: the poll budget "resets" on every re-read, so the
         # deadline is never reached — modeled as a wait that always
         # declares success and consumes blindly (on hardware this is
-        # the spin that never gives up; on the interpreter it is the
-        # silent negative-semaphore wrong answer guards exist to kill).
+        # the spin that never gives up).
         return jnp.asarray(True)
-    if use_interpret():
-        return _sem_read(sem) >= amt
 
     def cond(carry):
         it, ok = carry

@@ -9,7 +9,7 @@ without touching kernel code. Outside a plan the primitives take their
 original code paths — one None-check, bit-identical programs, unchanged
 `pallas_call_count` (test-enforced).
 
-Fault classes (the taxonomy of docs/robustness.md):
+Fault classes (the classification of docs/robustness.md):
 
   DelayedSend(rank, nanos)   one rank stalls between kernel entry and
                              its sends — the classic race provocation
@@ -39,10 +39,8 @@ Fault classes (the taxonomy of docs/robustness.md):
                              degradation ladder (retry -> quarantine).
 
 The drop mask is VALUE-level (`inc * (me != rank)`), never control-flow
-divergence: the legacy interpreter discharges remote signals into
-lockstep collectives that every rank must execute, and a `pl.when`
-around them would hang the discharge (lang/_compat.py) — the masked
-signal is exact on both the interpreter and hardware.
+divergence — the masked signal is exact on both the interpreter and
+hardware.
 """
 
 from __future__ import annotations

@@ -152,9 +152,8 @@ def _a2a_chunked_kernel(axis, n, q, rows, straggler, build, gbuild,
 
     Semaphore slots are indexed by RING STEP i (source offset me-i), not
     absolute source rank: every rank's descriptor for step (i, c) then
-    names the same static slot, which is what both the hardware DMA
-    (slot on the destination chip) and the legacy interpreter's lockstep
-    discharge (slot on the local instance) require to agree.
+    names the same static slot — the DMA's delivery semaphore lives on
+    the destination chip, so sender and receiver must agree on it.
 
     `build` (trace.events.TraceBuild or None) gates the event records:
     instants per chunk send, spans per delivery wait, and the straggle

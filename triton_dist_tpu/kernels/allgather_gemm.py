@@ -22,19 +22,11 @@ and the own shard is read straight from a_ref, so the workspace copy and
 the ring forward start ride the first tiles' compute instead of blocking
 it.
 
-world=1 tax, per the artifact of record (the driver-captured
-bench.py candidate search, not this repo's own sweeps): the tuned
-forced kernel measured ~1.10x XLA's matmul at the Qwen3-32B bench
-shape for rounds 3-5 (1.104 / 1.136 / 1.104) [perf:pallas_vs_xla=0.90-1.13].
-Local slope-timer sweeps (benchmark/sweep_ag_gemm.py) have read as low
-as 0.98x for the same tiles, but three rounds of driver numbers never
-came in under 1.10 — the sweep figure is NOT the claim. The residual
-tax is grid-step overhead plus accumulator traffic; the round-6
-candidate search adds the wide-tm / nk==1 direct-store frontier the
-old 15 MiB prune budget excluded (autotuner.ag_gemm_config_space).
-scripts/check_perf_claims.py lints the bracketed claim against the
-latest driver artifact, so this paragraph can no longer drift from the
-measurement.
+world=1 tax: not measured on today's code. The rounds 3-5 records
+that backed a ratio to XLA's matmul here were deleted with the rig that
+produced them (CHANGES.md, PR 24); at world=1 the forced kernel has
+nothing to overlap and can only lose by its grid-step overhead plus
+accumulator traffic. How much is for the chip benchmark to say.
 
 epilogue="silu_pair" fuses the TP-MLP gate/up activation into the store:
 b is the fused (K, 2*I) gate|up weight, the kernel keeps one accumulator
@@ -350,14 +342,10 @@ def _ag_gemm_kernel(axis: str, n: int, mt: int, nt: int, nk: int,
                     _conform.note_wait_recv(idents)
                 else:
                     # bounded ring-step watchdog: readiness is the full
-                    # chunk's element count (interpreter discharge) or
-                    # byte count (hardware DMA semaphore)
-                    from triton_dist_tpu.lang.core import use_interpret
-
+                    # chunk's byte count (what a DMA semaphore tallies)
                     _guard.set_progress(s, ctx=gctx)
-                    elems = m_loc * ws_ref.shape[1]
-                    amount = elems if use_interpret() else \
-                        elems * jnp.dtype(ws_ref.dtype).itemsize
+                    amount = (m_loc * ws_ref.shape[1]
+                              * jnp.dtype(ws_ref.dtype).itemsize)
                     _guard.watchdog_wait(
                         prev.wait_recv, recv_sems.at[s - 1], amount,
                         "ring", slot=s, ctx=gctx)

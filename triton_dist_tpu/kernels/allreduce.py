@@ -29,10 +29,13 @@ from jax.experimental.pallas import tpu as pltpu
 from triton_dist_tpu.faults import guard as _guard
 from triton_dist_tpu.lang import shmem
 from triton_dist_tpu.lang.core import (
+    cdiv,
     tpu_call,
     compiler_params,
+    min_tile,
     next_collective_id,
     interpret_no_headroom,
+    round_up,
 )
 from triton_dist_tpu.kernels.allgather import ring_all_gather
 from triton_dist_tpu.kernels.reduce_scatter import ring_reduce_scatter
@@ -103,17 +106,26 @@ def one_shot_all_reduce(x: jax.Array, axis: str = TP_AXIS) -> jax.Array:
     n = jax.lax.axis_size(axis)
     if n == 1:
         return x
-    vmem_need = (n + 1) * x.size * x.dtype.itemsize
+    # The sum is elementwise, so the kernel runs on a lane-dense
+    # (rows, 128) view padded to whole sublane tiles: a decode row
+    # (B=1, H) as it stands gives Mosaic a (n, 1, H) workspace whose
+    # one-row slot is not a whole packed tile ("slice shape must be
+    # aligned to tiling" at B=1, bf16).
+    sub, lane = min_tile(x.dtype)
+    rows = round_up(cdiv(x.size, lane), sub)
+    vmem_need = (n + 1) * rows * lane * x.dtype.itemsize
     if vmem_need > _ONE_SHOT_VMEM_BUDGET or interpret_no_headroom():
         return jax.lax.psum(x, axis)
-    return tpu_call(
+    x2 = jnp.pad(x.reshape(-1), (0, rows * lane - x.size)).reshape(
+        rows, lane)
+    out = tpu_call(
         functools.partial(_one_shot_ar_kernel, axis, n),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((n,) + x.shape, x.dtype),
-            pltpu.VMEM(x.shape, x.dtype),
+            pltpu.VMEM((n,) + x2.shape, x.dtype),
+            pltpu.VMEM(x2.shape, x.dtype),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
@@ -123,7 +135,8 @@ def one_shot_all_reduce(x: jax.Array, axis: str = TP_AXIS) -> jax.Array:
             collective_id=next_collective_id(f"one_shot_ar_{axis}"),
             vmem_limit_bytes=vmem_need + (2 << 20),
         ),
-    )(x)
+    )(x2)
+    return out.reshape(-1)[:x.size].reshape(x.shape)
 
 
 def two_shot_all_reduce(x: jax.Array, axis: str = TP_AXIS,

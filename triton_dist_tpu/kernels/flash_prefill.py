@@ -120,35 +120,72 @@ def fit_block(t: int, block: Optional[int] = None) -> int:
     return _kv_block(t, int(block)) if block else _kv_block(t)
 
 
+# f32 (rows, Hq*D) Q slab one grid step of the local kernel holds. The
+# per-head online-softmax state scales with it (see
+# flash_prefill_vmem_bytes), so this bounds the kernel's VMEM whatever
+# the prompt length: 128 rows at Qwen3-8B's 32 heads, 512 at a tp=4
+# shard's 8.
+Q_TILE_BYTES = 2 << 20
+
+# Mosaic margin on top of the modeled residents (DMA descriptors,
+# pipeline bookkeeping, operands XLA itself pins in VMEM around the
+# call) — added by BOTH the routing gate and the launch's
+# vmem_limit_bytes, so the two cannot disagree.
+VMEM_MARGIN = 8 << 20
+
+
+def fit_q_rows(s: int, hq: int, d: int) -> int:
+    """Query rows one grid step of the LOCAL kernel folds: the largest
+    divisor of s, a multiple of 16 (a packed bf16 sublane tile), whose
+    f32 (rows, Hq*D) slab stays within Q_TILE_BYTES; whole-s when no
+    such divisor exists (short or odd chunks)."""
+    cap = max(Q_TILE_BYTES // (hq * d * 4), 16)
+    cands = [c for c in range(16, min(cap, s) + 1, 16) if s % c == 0]
+    return cands[-1] if cands else s
+
+
 def flash_prefill_vmem_bytes(s_q: int, hq: int, hkv: int, d: int,
                              block: int, dtype=jnp.bfloat16,
-                             batch: int = 1) -> int:
-    """Per-grid-step resident VMEM of the flash-prefill kernels: the
-    double-buffered K+V page pair plus the f32 Q slab and per-head
-    m/l/acc states (the wrapper's vmem_limit accounting, shared with
-    the pruner's fit rule and the routing gate). batch: rows resident
-    AT ONCE — 1 for the local kernel (grid=(B,): one row per step), B
-    for the SP kernel (grid=(1,): every row's state lives across the
-    whole segment sweep)."""
+                             batch: int = 1,
+                             q_rows: Optional[int] = None) -> int:
+    """Per-grid-step resident VMEM of the flash-prefill kernels — THE
+    accounting behind the launch's vmem_limit_bytes, the routing gate
+    (flash_prefill_fits) and the autotuner's pruner.
+
+    KV side: the double-buffered K+V page pair in the cache dtype plus
+    the f32 cast of the page being folded. Q side, per resident row
+    block of `q_rows` rows: ten (rows, Hq*D) f32 slabs — the scaled Q
+    slab, acc, and m and l (each (rows, 1) per head, which Mosaic pads
+    to a full 128-lane tile: as large as acc at D=128), the loop's
+    carried copy of those three, the finalized output, and the
+    double-buffered bf16 Q/O blocks. The multiplier was read off the
+    chip compiler's scoped-VMEM reports at Qwen3-8B widths (PR 24).
+
+    q_rows: rows resident at once — default the local kernel's tile
+    (fit_q_rows); the SP kernel keeps all s_q rows of all `batch` rows
+    live across its segment sweep and passes q_rows=s_q, batch=B."""
     isz = jnp.dtype(dtype).itemsize
-    return 4 * block * hkv * d * isz + batch * 5 * s_q * hq * d * 4
+    rows = fit_q_rows(s_q, hq, d) if q_rows is None else q_rows
+    kv = 4 * block * hkv * d * isz + 2 * block * hkv * d * 4
+    return kv + batch * 10 * rows * hq * max(d, 128) * 4
 
 
 def flash_prefill_fits(s_q: int, t: int, hq: int, hkv: int, d: int,
                        block: Optional[int] = None,
-                       dtype=jnp.bfloat16, batch: int = 1) -> bool:
+                       dtype=jnp.bfloat16, batch: int = 1,
+                       q_rows: Optional[int] = None) -> bool:
     """Memory-feasibility gate for auto routing: the per-grid-step
-    state must fit the forced-kernel VMEM ceiling (with the Mosaic
-    compile margin). Long-context prefills whose (S, Hq*D) f32 state
-    exceeds it stay on the fallback path (blockwise-xla locally, the
-    ppermute ring for SP) instead of failing at Mosaic allocation.
-    batch: see flash_prefill_vmem_bytes — pass B when gating the SP
+    residents plus VMEM_MARGIN must fit the forced-kernel VMEM ceiling
+    — exactly what the launch will ask Mosaic for. What does not fit
+    stays on the fallback path (blockwise-xla locally, the ppermute
+    ring for SP) instead of failing at Mosaic allocation. batch/q_rows:
+    see flash_prefill_vmem_bytes — pass B and s_q when gating the SP
     kernel."""
     from triton_dist_tpu.perf_model import kernel_vmem_ceiling
 
     need = flash_prefill_vmem_bytes(s_q, hq, hkv, d, fit_block(t, block),
-                                    dtype, batch=batch)
-    return need + (8 << 20) <= kernel_vmem_ceiling()
+                                    dtype, batch=batch, q_rows=q_rows)
+    return need + VMEM_MARGIN <= kernel_vmem_ceiling()
 
 
 # -- shared fold math (kernel body AND the bit-exact host replay) ------------
@@ -231,13 +268,15 @@ def _q_slabs(qf, hq: int, d: int, scale: float):
 def _fp_local_kernel(hq, hkv, d, s, t, blk, causal, scale,
                      len_ref, q_ref, qpos_ref, k_ref, v_ref, o_ref,
                      vkv, sems):
-    """One grid step = one batch row: stream (blk, Hkv*D) KV pages
-    double-buffered from HBM and fold each into the per-head online-
-    softmax states (the prefill generalization of
-    flash_decode._fd_partial_kernel: S query rows instead of 1, per-head
-    2-D matmuls instead of the block-diagonal operand — prefill is
-    MXU-bound, so the decode kernel's Hkv-times FLOP inflation is not
-    free here)."""
+    """One grid step = `s` query rows (one fit_q_rows tile) of one batch
+    row: stream (blk, Hkv*D) KV pages double-buffered from HBM and fold
+    each into the per-head online-softmax states (the prefill
+    generalization of flash_decode._fd_partial_kernel: S query rows
+    instead of 1, per-head 2-D matmuls instead of the block-diagonal
+    operand — prefill is MXU-bound, so the decode kernel's Hkv-times
+    FLOP inflation is not free here). Rows are independent, so tiling
+    them changes no bit of any row's fold; a causal tile also stops at
+    ITS last row's page, not the chunk's."""
     b = pl.program_id(0)
     g = hq // hkv
     nblk = t // blk
@@ -320,8 +359,10 @@ def flash_prefill_local(
     w = hkv * d
     scale = float(scale if scale is not None else d ** -0.5)
     blk = int(block or _kv_block(t))
+    tq = fit_q_rows(s, hq, d)
+    grid = (b, s // tq)
     _last_launch = {"kernel": "flash_prefill", "path": "local",
-                    "block": blk, "grid": (b,),
+                    "block": blk, "grid": grid,
                     "overridden": block is not None}
     t_valid = t
     if t % blk:
@@ -339,30 +380,29 @@ def flash_prefill_local(
     # (no in-kernel minor-dim reshape for Mosaic to lower)
     qpos = q_positions.astype(jnp.int32).reshape(b, s, 1)
     itemsize = jnp.dtype(k.dtype).itemsize
-    state_bytes = 5 * s * hq * d * 4  # q slab + acc/m/l states + out row
     out = tpu_call(
-        functools.partial(_fp_local_kernel, hq, hkv, d, s, t, blk,
+        functools.partial(_fp_local_kernel, hq, hkv, d, tq, t, blk,
                           causal, scale),
-        grid=(b,),
+        grid=grid,
         out_shape=jax.ShapeDtypeStruct((b, s, hq * d), q.dtype),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, s, hq * d), lambda i: (i, 0, 0),
+            pl.BlockSpec((1, tq, hq * d), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, s, 1), lambda i: (i, 0, 0),
+            pl.BlockSpec((1, tq, 1), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, s, hq * d), lambda i: (i, 0, 0),
+        out_specs=pl.BlockSpec((1, tq, hq * d), lambda i, j: (i, j, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
             pltpu.VMEM((2, 2, blk, w), k.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         compiler_params=compiler_params(
-            vmem_limit_bytes=4 * 2 * blk * w * itemsize + state_bytes
-            + (8 << 20),
+            vmem_limit_bytes=flash_prefill_vmem_bytes(
+                s, hq, hkv, d, blk, k.dtype, q_rows=tq) + VMEM_MARGIN,
         ),
         cost_estimate=cost_estimate(
             flops=4 * b * s * hq * t * d,
@@ -575,8 +615,9 @@ def sp_flash_prefill(
         compiler_params=compiler_params(
             has_side_effects=True,
             collective_id=next_collective_id(f"flash_prefill_{axis}"),
-            vmem_limit_bytes=4 * 2 * blk * w * itemsize
-            + b * (5 * s * hq * d) * 4 + (8 << 20),
+            vmem_limit_bytes=flash_prefill_vmem_bytes(
+                s, hq, hkv, d, blk, k.dtype, batch=b, q_rows=s)
+            + VMEM_MARGIN,
         ),
         cost_estimate=cost_estimate(
             flops=4 * b * s * hq * n * s * d,
@@ -679,7 +720,7 @@ def sp_prefill_attention(
                 and flash_prefill_fits(
                     s, s, hq, hkv, d,
                     block=config.block if config else None,
-                    dtype=k.dtype, batch=b)):
+                    dtype=k.dtype, batch=b, q_rows=s)):
             impl = "ring"
         else:
             from triton_dist_tpu.perf_model import choose_sp_prefill_impl

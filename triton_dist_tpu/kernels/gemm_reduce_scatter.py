@@ -34,13 +34,11 @@ regimes, chosen by VMEM fit.
   streams once — trades it for nt x smaller, latency-exposed hops; not
   implemented.)
 
-world=1 tax, per the artifact of record (driver-captured bench.py): the
-forced local blocked-matmul regime at the 32B down-proj shape measured
-1.07-1.10x XLA's dot across rounds 4-5 [perf:gemm_rs_vs_xla=0.90-1.12].
-The round-6 candidate search reaches the few-grid-step nk==1
-direct-store corner (e.g. (1024, 2560, 3200) — a 4-step sweep) the old
-14 MiB prune budget excluded. scripts/check_perf_claims.py lints the
-bracketed claim against the latest driver artifact.
+world=1 tax: not measured on today's code (the rounds 4-5 records that
+backed a ratio to XLA's dot were deleted with the rig that produced
+them; CHANGES.md, PR 24). The round-6 candidate search reaches the
+few-grid-step nk==1 direct-store corner (e.g. (1024, 2560, 3200) — a
+4-step sweep) the old 14 MiB prune budget excluded.
 """
 
 from __future__ import annotations
@@ -442,8 +440,10 @@ def gemm_rs(
         if out_dtype != jnp.float32:
             ring_bytes += m_loc * n_full * out_itemsize
     else:
-        # Ring residents shared by both regimes: acc 2x(m_loc, N) + stage.
-        ring_bytes = 3 * m_loc * n_full * out_itemsize
+        # Ring residents shared by both regimes: acc 2x(m_loc, N) + stage
+        # + the (m_loc, N) value Mosaic materializes for the fold
+        # (acc + stage), as the chip compiler sizes it (PR 24).
+        ring_bytes = 4 * m_loc * n_full * out_itemsize
     # resident regime adds b plus the A tile double buffer.
     vmem_resident = (
         ring_bytes

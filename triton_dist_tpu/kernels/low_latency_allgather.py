@@ -388,9 +388,8 @@ def segment_collect_start(dst_slot_at, srcs, send_sem, seg_sem_at,
 
     dst_slot_at(t, i): the symmetric destination slot ref for tensor t,
     source-offset i (1..n-1) — every rank's descriptor for offset i
-    names the same static slot, which is what both the hardware DMA and
-    the legacy interpreter's lockstep discharge require to agree (the
-    PR-2 slot rule). seg_sem_at(t, i): that slot's delivery semaphore.
+    names the same static slot (the delivery semaphore lives on the
+    destination chip — the PR-2 slot rule). seg_sem_at(t, i): that slot's delivery semaphore.
     srcs: the local tensors to push (each goes to every peer).
     on_send(i): optional per-offset hook (trace instants).
 

@@ -28,7 +28,6 @@ from triton_dist_tpu.lang.core import (
     compiler_params,
     next_collective_id,
     interpret_no_headroom,
-    interpret_divergence_unsafe,
 )
 from triton_dist_tpu.runtime.init import PP_AXIS
 
@@ -88,8 +87,7 @@ def p2p_send(x: jax.Array, src_rank: int, dst_rank: int,
     n = jax.lax.axis_size(axis)
     if n == 1:
         return x
-    # divergence: only src puts, only dst waits (pl.when in _p2p_kernel)
-    if interpret_no_headroom() or interpret_divergence_unsafe():
+    if interpret_no_headroom():
         me = jax.lax.axis_index(axis)
         shifted = jax.lax.ppermute(x, axis, [(src_rank, dst_rank)])
         return jnp.where(me == dst_rank, shifted, x)
@@ -212,7 +210,6 @@ def _ring_shift_conform(n, shift=1):
     doc="root-guarded fan-out (rank-divergent; see skip reason)")
 def _broadcast_conform(n, root=0):
     return _conform.Skip(
-        "rank-divergent protocol (root-guarded fan-out): the legacy "
-        "lockstep interpreter cannot execute divergent Pallas branches, "
-        "so broadcast routes to the value-level XLA fallback on this "
-        "rig, which records no kernel stream")
+        "rank-divergent protocol (root-guarded fan-out): no runner "
+        "records its kernel stream yet (tests/test_shmem_ext.py "
+        "executes shmem.broadcast itself)")

@@ -10,9 +10,12 @@ including inter-chip remote DMA — runs on a virtual
 
 from __future__ import annotations
 
+import collections
 import functools
-import inspect
+import math
 import os
+import re
+import zlib
 from typing import Any, Optional
 
 import jax
@@ -22,36 +25,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 _FORCE_INTERPRET = os.environ.get("TDT_FORCE_INTERPRET", "") == "1"
 
-# Older jax (< 0.6) names the params class TPUCompilerParams and drives
-# interpret mode with a plain boolean (no InterpretParams class).
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-_HAS_INTERPRET_PARAMS = hasattr(pltpu, "InterpretParams")
 
-
-def multicore_interpret_supported() -> bool:
-    """True when this jax's interpreter can emulate multiple TensorCores
-    (InterpretParams(num_cores_or_threads=...)). The 0.4.x interpreter
-    cannot; multi-core megakernel tests skip there."""
-    return _HAS_INTERPRET_PARAMS
-
-
-def interpret_params(**kw):
-    """pltpu.InterpretParams when available, else the legacy boolean
-    (kw like num_cores_or_threads only exist on the modern class)."""
-    if _HAS_INTERPRET_PARAMS:
-        return pltpu.InterpretParams(**kw)
-    if kw:
-        raise RuntimeError(
-            "this jax version's interpret mode does not support "
-            f"InterpretParams({kw}); upgrade jax for multi-core interpret"
-        )
-    return True
+@functools.lru_cache(maxsize=None)
+def backend_device():
+    """The device every backend-dependent decision is read from: which
+    platform kernels compile for (backend_platform), which chip the
+    perf model prices (perf_model.detect_chip), how many TensorCores
+    the megakernel may use (mega.kernel.physical_core_count)."""
+    return jax.devices()[0]
 
 
 @functools.lru_cache(maxsize=None)
 def backend_platform() -> str:
-    return jax.devices()[0].platform
+    return backend_device().platform
 
 
 def use_interpret() -> bool:
@@ -70,6 +56,23 @@ def pallas_call_count() -> int:
     return _PALLAS_CALLS
 
 
+_HLO_KERNEL = re.compile(
+    r'%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*'
+    r'custom_call_target="tpu_custom_call"')
+
+
+def pallas_kernels_in(hlo_text: str) -> dict:
+    """{kernel name: count} of the Mosaic kernels in a COMPILED
+    program's text (`compiled.as_text()`): XLA names each
+    `tpu_custom_call` instruction after the call's innermost name
+    scope, which `tpu_call` sets to the kernel's name. Counting traces
+    (pallas_call_count) says a kernel was BUILT; this says it is in the
+    program the device runs — what separates a kernel run from a route
+    that gave way to XLA after tracing."""
+    return dict(sorted(collections.Counter(
+        _HLO_KERNEL.findall(hlo_text)).items()))
+
+
 # Conformance-recording hook (verify/conform.py installs this at import;
 # lang stays free of any verify import). With no recording active the
 # hook returns None and tpu_call takes its unmodified path — the
@@ -78,11 +81,23 @@ _CONFORM_INSTRUMENT = None
 
 
 def tpu_call(kernel, **kwargs):
-    """pl.pallas_call with automatic interpret-mode fallback off-TPU."""
+    """pl.pallas_call with automatic interpret-mode fallback off-TPU.
+
+    Every kernel is NAMED — `name=` or, by default, its body function's
+    own name (functools.partial unwrapped), so a name found in a
+    compiled program or a profiler trace greps straight to its `def`.
+    The name scopes the call's op metadata, which is how chip_smoke.py
+    and tests/test_chip_compile.py tell a kernel that ran from a route
+    that quietly gave way to XLA (pallas_kernels_in)."""
     global _PALLAS_CALLS
     _PALLAS_CALLS += 1
+    if "name" not in kwargs:
+        body = kernel
+        while isinstance(body, functools.partial):
+            body = body.func
+        kwargs["name"] = body.__name__
     if use_interpret() and "interpret" not in kwargs:
-        kwargs["interpret"] = interpret_params()
+        kwargs["interpret"] = pltpu.InterpretParams()
     if _CONFORM_INSTRUMENT is not None:
         instrumented = _CONFORM_INSTRUMENT(kernel, kwargs)
         if instrumented is not None:
@@ -107,37 +122,14 @@ def interpret_no_headroom() -> bool:
     """
     if not use_interpret():
         return False
-    from triton_dist_tpu.lang import _compat
-
-    if _compat.LEGACY_JAX:
-        # The 0.4.x interpreter is discharge-based (remote DMA/signals
-        # lower to lockstep all_gathers at trace time): nothing blocks a
-        # thunk-executor thread, so the pool-exhaustion deadlock this
-        # guard exists for cannot occur — always run the real protocol.
-        return False
     m = jax.sharding.get_abstract_mesh()
     if m is not None and m.shape:
-        import math
-
         mesh_total = math.prod(m.shape.values())
         return mesh_total >= len(jax.devices())
     # Unknown mesh under interpret mode: the safe default is the
     # non-blocking XLA path (a wrong False here deadlocks; a wrong True
     # only skips the overlap protocol).
     return True
-
-
-def interpret_divergence_unsafe() -> bool:
-    """True when kernels whose remote ops sit under rank-divergent
-    control flow (``pl.when(me == r)`` around a put/signal) must take
-    their XLA fallback: the legacy interpreter discharges remote DMA and
-    signals into lockstep collectives that EVERY rank must execute, so a
-    rank skipping the branch hangs the gather. Uniform-flow kernels
-    (every rank puts each step) are exact under that discharge and keep
-    the real protocol — see interpret_no_headroom."""
-    from triton_dist_tpu.lang import _compat
-
-    return _compat.legacy_interpret_active()
 
 
 def cdiv(a: int, b: int) -> int:
@@ -150,8 +142,14 @@ def round_up(a: int, b: int) -> int:
 
 def fit_tile(tile: int, dim: int) -> int:
     """Largest divisor of dim that is <= tile, preferring lane multiples
-    (shared tile-fitting rule of the blocked GEMM kernels)."""
+    (shared tile-fitting rule of the blocked GEMM kernels). The search
+    walks lane multiples only: a request that is not one itself (the
+    silu_pair half-tile 3200 // 2 = 1600) must not step through 1472,
+    ..., 192 and hand Mosaic a block whose last dim is not a multiple
+    of 128."""
     t = min(tile, dim)
+    if t > 128 and dim % t:
+        t -= t % 128
     while t > 128 and dim % t:
         t -= 128
     while dim % t:
@@ -171,8 +169,6 @@ def min_tile(dtype) -> tuple:
 
 def compute_vmem_bytes(*shaped) -> int:
     """Sum byte sizes of (shape, dtype) pairs or arrays, for vmem_limit."""
-    import math
-
     total = 0
     for s in shaped:
         if hasattr(s, "shape") and hasattr(s, "dtype"):
@@ -196,8 +192,6 @@ def next_collective_id(name: str) -> int:
     collisions are detected per process and are a hard error (two distinct
     collectives sharing a barrier semaphore could race if XLA overlaps
     them)."""
-    import zlib
-
     if name not in _COLLECTIVE_IDS:
         # int16 space: the Pallas interpreter stores collective ids as int16.
         cid = zlib.crc32(name.encode()) & 0x7FFF
@@ -211,23 +205,15 @@ def next_collective_id(name: str) -> int:
     return _COLLECTIVE_IDS[name]
 
 
-# probed once, like _COMPILER_PARAMS_CLS: older jax has no
-# remote_bytes_transferred field on CostEstimate
-_COST_ESTIMATE_FIELDS = frozenset(
-    inspect.signature(pl.CostEstimate).parameters)
-
-
 def cost_estimate(flops: int = 0, bytes_accessed: int = 0,
                   remote_bytes: int = 0) -> "pl.CostEstimate":
     """Kernel cost metadata — the reference's `launch_metadata` flops/
     bytes reporting (ref: allgather_gemm.py:145-155) — consumed by the
     XLA scheduler and surfaced in profiles."""
-    args = dict(
+    return pl.CostEstimate(
         flops=int(flops), bytes_accessed=int(bytes_accessed),
         transcendentals=0, remote_bytes_transferred=int(remote_bytes),
     )
-    return pl.CostEstimate(
-        **{k: v for k, v in args.items() if k in _COST_ESTIMATE_FIELDS})
 
 
 def compiler_params(
@@ -243,8 +229,4 @@ def compiler_params(
         args["collective_id"] = collective_id
     if vmem_limit_bytes is not None:
         args["vmem_limit_bytes"] = vmem_limit_bytes
-    import dataclasses
-
-    known = {f.name for f in dataclasses.fields(_COMPILER_PARAMS_CLS)}
-    return _COMPILER_PARAMS_CLS(
-        **{k: v for k, v in args.items() if k in known})
+    return pltpu.CompilerParams(**args)

@@ -36,12 +36,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from triton_dist_tpu.faults import guard as _guard
 from triton_dist_tpu.faults import plan as _fplan
-from triton_dist_tpu.lang import _compat
 from triton_dist_tpu.obs import stats as _obs
 from triton_dist_tpu.verify import capture as _vcap
 from triton_dist_tpu.verify import conform as _conform
-
-_compat.install()
 
 # --- signal ops / comparison constants (ref: libshmem_device.py:293-323) ---
 SIGNAL_SET = 0
@@ -91,9 +88,7 @@ def team_device_id(axis: AxisName, pe) -> dict:
 
 def _dma_device_id(axis: AxisName, pe) -> tuple:
     """(device_id, device_id_type) for a remote DMA addressing `pe` on
-    team `axis` — always the mesh-coordinate dict; under the legacy
-    interpreter the _compat discharge rule gives single-entry dicts
-    exact lockstep semantics on any mesh."""
+    team `axis` — always the mesh-coordinate dict."""
     return team_device_id(axis, pe), pltpu.DeviceIdType.MESH
 
 
@@ -112,24 +107,17 @@ def team_linear_device_id(axes: Sequence[str], pe) -> dict:
 class PutHandle:
     """Handle for a non-blocking put (ref: *_nbi variants + quiet).
 
-    `recv_sem`/`elems`/`nbytes` describe the symmetric incoming payload
-    so an active guard build (faults.guard) can bound the delivery wait:
-    readiness is `recv_sem >= amount` where the amount is the element
-    count under the interpreter's discharge and the byte count on
-    hardware (what the DMA semaphore actually tallies in each world)."""
+    `recv_sem`/`nbytes` describe the symmetric incoming payload so an
+    active guard build (faults.guard) can bound the delivery wait:
+    readiness is `recv_sem >= nbytes` (a DMA semaphore tallies bytes, on
+    the chip and in the interpreter alike)."""
 
     copy: Any
     recv_sem: Any = None
-    elems: int = 0
     nbytes: int = 0
     # semaphore identities the conformance recorder threaded through
     # note_put (None whenever recording is off — the common case)
     conform_idents: Any = None
-
-    def _recv_amount(self) -> int:
-        from triton_dist_tpu.lang.core import use_interpret
-
-        return self.elems if use_interpret() else self.nbytes
 
     def wait_send(self):
         _conform.note_wait_send(self.conform_idents)
@@ -147,7 +135,7 @@ class PutHandle:
             self.copy.wait_recv()
         else:
             _guard.watchdog_wait(self.copy.wait_recv, self.recv_sem,
-                                 self._recv_amount(), "recv", slot=slot)
+                                 self.nbytes, "recv", slot=slot)
         _obs.meter_wait("sem_wait")
 
     def wait(self):
@@ -183,14 +171,14 @@ def putmem_nbi(
         device_id_type=id_type,
     )
     copy.start()
-    elems = int(math.prod(src_ref.shape))
-    nbytes = elems * jnp.dtype(src_ref.dtype).itemsize
+    nbytes = int(math.prod(src_ref.shape)) * jnp.dtype(
+        src_ref.dtype).itemsize
     # stat-row metering (obs/stats.py): nbytes is what is actually on
     # the wire — quantized legs put int8 wire images, so the byte
     # ledger is per-format without a side channel
     _obs.meter_send(nbytes)
     idents = _conform.note_put(send_sem, recv_sem, pe, dst_ref, nbytes)
-    return PutHandle(copy, recv_sem=recv_sem, elems=elems, nbytes=nbytes,
+    return PutHandle(copy, recv_sem=recv_sem, nbytes=nbytes,
                      conform_idents=idents)
 
 
@@ -230,9 +218,9 @@ def putmem_signal_nbi(
 
 def _fault_signal_mask(value, axis: AxisName, label: Optional[str]):
     """Apply an active FaultPlan's dropped-signal fault: the faulted
-    rank's inc masks to 0 (VALUE-level — never control-flow divergence,
-    which would hang the legacy interpreter's lockstep discharge). No
-    plan -> the value passes through untouched (zero cost off)."""
+    rank's inc masks to 0 (VALUE-level — never control-flow
+    divergence). No plan -> the value passes through untouched (zero
+    cost off)."""
     plan = _fplan.active()
     if plan is None:
         return value
@@ -318,8 +306,7 @@ def signal_read(sig_sem) -> jax.Array:
             "control flow the verifier cannot see) — protocols under "
             "verify.capturing() must be wait-structured"
         )
-    read = getattr(pltpu, "semaphore_read", None) or pl.semaphore_read
-    return read(sig_sem)
+    return pl.semaphore_read(sig_sem)
 
 
 def fence() -> None:
@@ -379,7 +366,7 @@ def barrier_all(axis: AxisName) -> None:
                                  bsem, n, "barrier")
         _obs.meter_wait("sem_wait")
 
-    _compat.scoped_collective_sem(with_sem)
+    with_sem(pltpu.get_barrier_semaphore())
 
 
 def neighbor_barrier(axis: str, me, n: int) -> None:
@@ -419,7 +406,7 @@ def neighbor_barrier(axis: str, me, n: int) -> None:
                                  bsem, 2, "barrier")
         _obs.meter_wait("sem_wait")
 
-    _compat.scoped_collective_sem(with_sem)
+    with_sem(pltpu.get_barrier_semaphore())
 
 
 def sync_all(axis: AxisName) -> None:
@@ -470,7 +457,7 @@ def straggler_delay(axis: AxisName, rank, nanos: int, sem=None) -> None:
                 jax.lax.fori_loop(0, max(1, nanos // 5000), churn, 0)
 
             if sem is None:
-                _compat.scoped_collective_sem(with_sem)
+                with_sem(pltpu.get_barrier_semaphore())
             else:
                 with_sem(sem)
         else:
@@ -580,15 +567,6 @@ def broadcast(dst_ref, src_ref, send_sem, recv_sem, root, axis: str,
                 h.wait_send()
         with cap.when(me != root):
             cap.wait(recv_sem, 1)
-        return
-    if _compat.legacy_interpret_active():
-        # The 0.4.x interpreter discharges remote DMA through lockstep
-        # all_gathers: the divergent root-only send below would deadlock
-        # the gather. Value-level broadcast is exact in that lockstep
-        # model (interpret only — never reached on hardware).
-        data = jax.lax.all_gather(src_ref[...], axis)
-        dst_ref[...] = jax.lax.dynamic_index_in_dim(data, root, 0,
-                                                    keepdims=False)
         return
     me = my_pe(axis)
 

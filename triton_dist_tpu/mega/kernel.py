@@ -62,6 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from triton_dist_tpu.lang import shmem
 from triton_dist_tpu.lang.core import (
+    backend_device,
     compiler_params,
     min_tile,
     next_collective_id,
@@ -105,19 +106,21 @@ from triton_dist_tpu.trace import events as trace_ev
 ROW = 15
 
 
-def physical_core_count():
+def physical_core_count() -> int:
     """TensorCores per chip, from the device-kind table (PJRT devices do
-    not reliably expose num_cores). TDT_NUM_CORES overrides; unknown
-    kinds return None (caller proceeds and lets Mosaic decide)."""
+    not reliably expose num_cores). TDT_NUM_CORES overrides; a kind the
+    table does not know is an error, not a guess."""
     env = os.environ.get("TDT_NUM_CORES")
     if env:
         return int(env)
-    kind = getattr(jax.devices()[0], "device_kind", "").lower()
-    if "v4" in kind or "v5p" in kind:
-        return 2  # megacore chips
+    kind = backend_device().device_kind.lower()
     if "lite" in kind or "v5e" in kind or "v6e" in kind:
         return 1
-    return None
+    if "v4" in kind or "v5" in kind:
+        return 2  # megacore chips (v4, v5p)
+    raise RuntimeError(
+        f"unknown TPU device_kind {kind!r}: add its TensorCore count to "
+        "mega.kernel.physical_core_count (or set TDT_NUM_CORES)")
 
 
 def tile_weight_major(w, tn: int):
@@ -1178,7 +1181,12 @@ def compile_graph(
         + 2 * kmax * tnmax * isz
         + min(2, SMAX // SCHUNK) * 2 * B * SCHUNK * D * isz
         + 2 * world * PB * arw * isz
-        + (4 << 20)
+        # margin for the branch math's values (f32 rows, matmul
+        # operands in flight): the chip compiler sized them 8.8 MiB at
+        # Qwen3-8B widths on one chip and 11.5 MiB on a tp=4 shard —
+        # the 4 MiB this replaces failed both (63.56M needed vs 58.75M
+        # asked; PR 24)
+        + (16 << 20)
     )
 
     # world/axis for the trace header rank (the AR/barrier branch keys
@@ -1360,20 +1368,13 @@ def compile_graph(
             from triton_dist_tpu.lang.core import use_interpret
 
             if use_interpret():
-                from triton_dist_tpu.lang.core import interpret_params
-
-                extra["interpret"] = interpret_params(
+                extra["interpret"] = pltpu.InterpretParams(
                     num_cores_or_threads=nc,
                     detect_races=os.environ.get("TDT_MEGA_RACES") == "1",
                 )
             else:
                 phys = physical_core_count()
-                if phys is not None and phys < nc:
-                    # only a POSITIVELY-known-insufficient chip raises;
-                    # unknown device kinds proceed and let Mosaic decide
-                    # (round-4 ADVICE: PJRT devices don't reliably expose
-                    # num_cores, and a fail-closed default made the
-                    # multi-core path unreachable on real megacore chips)
+                if phys < nc:
                     raise RuntimeError(
                         f"megakernel schedule uses {nc} cores but this "
                         f"chip has {phys} TensorCore(s); re-schedule with "
@@ -1385,6 +1386,7 @@ def compile_graph(
             if build is not None else ())
         fn = tpu_call(
             kernel,
+            name=name,
             grid_spec=grid_spec,
             out_shape=out_shape if build is not None else out_shape[0],
             # inputs: queue(0) pos(1) table(2) ws(3) weights(4..) ...

@@ -47,13 +47,6 @@ class MegaKVCache(NamedTuple):
     length: jax.Array  # (B,)
 
     @staticmethod
-    def create(cfg: ModelConfig, batch: int, s_max: int, hkv_loc: int):
-        shape = (cfg.num_layers, hkv_loc, batch, s_max, cfg.head_dim)
-        dt = jnp.dtype(cfg.dtype)
-        return MegaKVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt),
-                           jnp.zeros((batch,), jnp.int32))
-
-    @staticmethod
     def from_dense(cache, s_max: Optional[int] = None) -> "MegaKVCache":
         """Convert a models.kv_cache.KVCache (L, B, T, Hkv, D) — e.g. the
         output of an Engine prefill — into megakernel layout."""
@@ -289,10 +282,16 @@ class MegaQwen3:
         # params) stripping frees their HBM.
         from triton_dist_tpu.mega.kernel import tile_weight_major
 
+        # One layer at a time (lax.map): fusing the whole stack in one
+        # op costs 1.5x the copy in transient HBM on top of it (0.3 GB
+        # per Qwen3-8B layer), which no real depth survives beside the
+        # split originals; per layer the transient is two layers' worth
+        # whatever the depth.
         gu_tn = self.cm.tile_cols("w_gate_up")
         self._w_gate_up = jax.jit(
-            lambda g, u: tile_weight_major(
-                jnp.concatenate([g, u], axis=-1), gu_tn),
+            lambda g, u: jax.lax.map(
+                lambda gu: tile_weight_major(
+                    jnp.concatenate(gu, axis=-1), gu_tn), (g, u)),
             out_shardings=NamedSharding(mesh, P(None, axis)),
         )(self.params.layers.w_gate, self.params.layers.w_up)
         self.params = self.params._replace(
@@ -512,14 +511,15 @@ class MegaQwen3:
         )
 
     def new_cache(self) -> MegaKVCache:
-        cache = MegaKVCache.create(self.cfg, self.batch, self.s_max,
-                                   self.hkv_loc * self.world)
-        specs = MegaKVCache(k=P(None, self.axis),
-                            v=P(None, self.axis), length=P())
-        return jax.tree.map(
-            lambda a, s: jax.device_put(a, NamedSharding(self.mesh, s)),
-            cache, specs,
-        )
+        shape = (self.cfg.num_layers, self.hkv_loc * self.world,
+                 self.batch, self.s_max, self.cfg.head_dim)
+        kv = NamedSharding(self.mesh, P(None, self.axis))
+        # zeros created IN the sharding: never whole on one device
+        return MegaKVCache(
+            jnp.zeros(shape, self.dtype, device=kv),
+            jnp.zeros(shape, self.dtype, device=kv),
+            jnp.zeros((self.batch,), jnp.int32,
+                      device=NamedSharding(self.mesh, P())))
 
     def decode_step(self, tokens, cache: MegaKVCache):
         """tokens (B,) -> (logits (B, V) f32, cache)."""

@@ -36,32 +36,37 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
+    # The published presets fix every WIDTH; depth (`num_layers`) is the
+    # one cut a single chip may force, so it is the presets' only
+    # geometry parameter — everything else in **kw is a non-geometry
+    # knob (max_positions, dtype, ...).
+
     @staticmethod
-    def qwen3_32b(**kw) -> "ModelConfig":
+    def qwen3_32b(num_layers: int = 64, **kw) -> "ModelConfig":
         """Qwen3-32B geometry (the reference's headline e2e model,
         ref: docs/getting-started/e2e/e2e_dense.md)."""
         return ModelConfig(
             vocab_size=151_936, hidden_size=5120, intermediate_size=25_600,
-            num_layers=64, num_q_heads=64, num_kv_heads=8, head_dim=128,
-            **kw,
+            num_layers=num_layers, num_q_heads=64, num_kv_heads=8,
+            head_dim=128, **kw,
         )
 
     @staticmethod
-    def qwen3_8b(**kw) -> "ModelConfig":
+    def qwen3_8b(num_layers: int = 36, **kw) -> "ModelConfig":
         return ModelConfig(
             vocab_size=151_936, hidden_size=4096, intermediate_size=12_288,
-            num_layers=36, num_q_heads=32, num_kv_heads=8, head_dim=128,
-            **kw,
+            num_layers=num_layers, num_q_heads=32, num_kv_heads=8,
+            head_dim=128, **kw,
         )
 
     @staticmethod
-    def qwen3_30b_a3b(**kw) -> "ModelConfig":
+    def qwen3_30b_a3b(num_layers: int = 48, **kw) -> "ModelConfig":
         """Qwen3-30B-A3B MoE geometry (the reference's Qwen3MoE model,
         ref: models/qwen_moe.py:50-206)."""
         return ModelConfig(
             vocab_size=151_936, hidden_size=2048, intermediate_size=6144,
-            num_layers=48, num_q_heads=32, num_kv_heads=4, head_dim=128,
-            num_experts=128, num_experts_per_tok=8,
+            num_layers=num_layers, num_q_heads=32, num_kv_heads=4,
+            head_dim=128, num_experts=128, num_experts_per_tok=8,
             moe_intermediate_size=768, **kw,
         )
 

@@ -100,18 +100,11 @@ def cache_specs(axis: str = TP_AXIS, batch_axis: Optional[str] = None):
     )
 
 
-def init_params(
-    cfg: ModelConfig, mesh, seed: int = 0, axis: str = TP_AXIS,
-    fast: bool = False,
-) -> DenseLLMParams:
-    """Random-init global arrays laid out for shard_map (the reference
-    streams HF weights at init, dense.py:150-167; random init keeps the
-    framework dependency-free — `load_hf` maps real checkpoints).
-
-    fast=True draws on-device with jax.random instead of host numpy —
-    O(seconds) instead of O(minutes) at multi-billion-param scale; use it
-    whenever the exact host RNG stream doesn't matter (benchmarks)."""
-    n = int(mesh.shape[axis])
+def param_shapes(cfg: ModelConfig, n: int) -> DenseLLMParams:
+    """GLOBAL shape of every DenseLLMParams leaf at tp size n (the
+    module-doc layout; None for the leaves this config does not have).
+    One definition for init_params' two RNG paths and for callers that
+    need the tree as `jax.ShapeDtypeStruct`s (compile-only checks)."""
     assert cfg.num_q_heads % n == 0 and cfg.num_kv_heads % n == 0, (
         f"num_q_heads={cfg.num_q_heads} and num_kv_heads={cfg.num_kv_heads} "
         f"must both divide the tp size {n} (pick a smaller tp for this "
@@ -121,59 +114,163 @@ def init_params(
         (cfg.moe_intermediate_size if cfg.is_moe else cfg.intermediate_size)
         % n == 0
     ), "vocab/intermediate sizes must divide the tp size"
-    rng = np.random.default_rng(seed)
-    dt = jnp.dtype(cfg.dtype)
     h, d = cfg.hidden_size, cfg.head_dim
     hq_l, hkv_l = cfg.num_q_heads // n, cfg.num_kv_heads // n
-    i_l = cfg.intermediate_size // n
-    v_l = cfg.vocab_size // n
     L = cfg.num_layers
-
-    if fast:
-        key_box = [jax.random.PRNGKey(seed)]
-
-        def mk(shape, scale=0.02):
-            key_box[0], sub = jax.random.split(key_box[0])
-            return (jax.random.normal(sub, shape, jnp.float32) * scale
-                    ).astype(dt)
-    else:
-        def mk(shape, scale=0.02):
-            return jnp.asarray(rng.standard_normal(shape) * scale, dt)
-
     if cfg.is_moe:
         e = cfg.num_experts
         mi_l = cfg.moe_intermediate_size // n
         ffn = dict(
-            w_gate_up=mk((L, n, e, h, 2 * mi_l)),
-            w_down=mk((L, n, e, mi_l, h)),
-            w_router=mk((L, h, e)),
+            w_gate_up=(L, n, e, h, 2 * mi_l),
+            w_down=(L, n, e, mi_l, h),
+            w_router=(L, h, e),
         )
     else:
+        i_l = cfg.intermediate_size // n
         ffn = dict(
-            w_gate=mk((L, n, h, i_l)),
-            w_up=mk((L, n, h, i_l)),
-            w_down=mk((L, n, i_l, h)),
-            w_router=None,
+            w_gate=(L, n, h, i_l),
+            w_up=(L, n, h, i_l),
+            w_down=(L, n, i_l, h),
         )
     layers = DenseLayerParams(
-        input_ln=jnp.ones((L, h), dt),
-        post_attn_ln=jnp.ones((L, h), dt),
-        w_qkv=mk((L, n, h, (hq_l + 2 * hkv_l) * d)),
-        w_o=mk((L, n, hq_l * d, h)),
-        q_norm=jnp.ones((L, d), dt),
-        k_norm=jnp.ones((L, d), dt),
+        input_ln=(L, h), post_attn_ln=(L, h),
+        w_qkv=(L, n, h, (hq_l + 2 * hkv_l) * d),
+        w_o=(L, n, hq_l * d, h),
+        q_norm=(L, d), k_norm=(L, d),
         **ffn,
     )
-    params = DenseLLMParams(
-        embed=mk((cfg.vocab_size, h)),
-        layers=layers,
-        final_ln=jnp.ones((h,), dt),
-        lm_head=mk((n, h, v_l)),
+    return DenseLLMParams(
+        embed=(cfg.vocab_size, h), layers=layers, final_ln=(h,),
+        lm_head=(n, h, cfg.vocab_size // n),
     )
+
+
+# norm gains (*_ln, *_norm) start at one; every other leaf is
+# N(0, _INIT_SCALE)
+_INIT_SCALE = 0.02
+# elements one jax.random draw may produce at once (its f32 values and
+# u32 bits are transient device memory: ~0.7 GB at 2**26)
+_DRAW_ELEMS = 1 << 26
+
+
+def _named_leaves(shapes: DenseLLMParams, specs: DenseLLMParams) -> dict:
+    """{field name: (shape, spec, is_norm)} over the leaves this config
+    has (field names are unique across the two NamedTuples)."""
+    out = {}
+    for shp, spc in ((shapes, specs), (shapes.layers, specs.layers)):
+        for f in shp._fields:
+            shape = getattr(shp, f)
+            if type(shape) is tuple:  # not the nested tuple, not None
+                out[f] = (shape, getattr(spc, f),
+                          f.endswith(("_ln", "_norm")))
+    return out
+
+
+def _assemble(leaves: dict) -> DenseLLMParams:
+    lay = {f: leaves.get(f) for f in DenseLayerParams._fields}
+    top = {f: leaves[f] for f in ("embed", "final_ln", "lm_head")}
+    return DenseLLMParams(layers=DenseLayerParams(**lay), **top)
+
+
+def _draw(key, shape, dt):
+    """N(0, _INIT_SCALE) of `shape` in dtype dt, drawn in slabs of at
+    most _DRAW_ELEMS elements along the leading dims so the transient
+    f32/bit buffers stay bounded whatever the tensor's size."""
+    total = int(np.prod(shape))
+    if total <= _DRAW_ELEMS or len(shape) == 1:
+        return (jax.random.normal(key, shape, jnp.float32)
+                * _INIT_SCALE).astype(dt)
+    lead, rest = shape[0], shape[1:]
+    # largest divisor of the leading dim whose slab fits the budget;
+    # none (one leading slice is already too large): slab the rest
+    c = max((c for c in range(1, lead + 1)
+             if lead % c == 0 and c * (total // lead) <= _DRAW_ELEMS),
+            default=0)
+    if c:
+        def slab(k):
+            return _draw(k, (c,) + rest, dt)
+    else:
+        c = 1
+
+        def slab(k):
+            return _draw(k, rest, dt)
+    return jax.lax.map(slab, jax.random.split(key, lead // c)).reshape(
+        shape)
+
+
+def _init_on_mesh(mesh, seed: int, axis: str, dt, named: dict,
+                  specs: DenseLLMParams) -> DenseLLMParams:
+    """fast=True init: one shard_map'd program in which every device
+    draws exactly the shards it keeps (tp-sharded leaves under a
+    rank-folded key, replicated leaves under the shared one). Nothing
+    is ever built whole on one device: Qwen3-8B's w_gate alone is
+    7.2 GB in f32."""
+
+    def per_rank(key):
+        rank = jax.lax.axis_index(axis)
+        out = {}
+        for i, (f, (shape, spec, is_norm)) in enumerate(named.items()):
+            local = tuple(
+                1 if j < len(spec) and spec[j] == axis else s
+                for j, s in enumerate(shape))
+            if is_norm:
+                out[f] = jnp.ones(local, dt)
+                continue
+            k = jax.random.fold_in(key, i)
+            if local != shape:
+                k = jax.random.fold_in(k, rank)
+            out[f] = _draw(k, local, dt)
+        return _assemble(out)
+
+    return jax.jit(jax.shard_map(
+        per_rank, mesh=mesh, in_specs=P(), out_specs=specs,
+        check_vma=False))(jax.random.PRNGKey(seed))
+
+
+# host-stream draw order: part of what a seed means (FFN, attention,
+# then embed and head; norms consume none of it)
+_HOST_ORDER = ("w_gate_up", "w_gate", "w_up", "w_down", "w_router",
+               "w_qkv", "w_o", "embed", "lm_head")
+
+
+def init_params(
+    cfg: ModelConfig, mesh, seed: int = 0, axis: str = TP_AXIS,
+    fast: bool = False,
+) -> DenseLLMParams:
+    """Random-init global arrays laid out for shard_map (the reference
+    streams HF weights at init, dense.py:150-167; random init keeps the
+    framework dependency-free — `load_hf` maps real checkpoints).
+
+    Both paths place every tensor straight into its NamedSharding — no
+    tensor is first built whole on the default device.
+
+    fast=False draws the GLOBAL tensors from one host numpy stream
+    (identical values whatever the tp size — what parity tests want)
+    and transfers each shard to its device. fast=True draws on the
+    devices instead (_init_on_mesh: each shard where it lives, values
+    depend on the tp size) — O(seconds) instead of O(minutes) at
+    multi-billion-param scale, and the only path that fits a real model
+    on the chips; use it whenever the exact host RNG stream doesn't
+    matter (benchmarks, chip_smoke.py)."""
+    n = int(mesh.shape[axis])
     specs = param_specs(axis, cfg.is_moe)
-    return jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), params, specs
-    )
+    named = _named_leaves(param_shapes(cfg, n), specs)
+    dt = jnp.dtype(cfg.dtype)
+    if fast:
+        return _init_on_mesh(mesh, seed, axis, dt, named, specs)
+    rng = np.random.default_rng(seed)
+
+    def mk(f):
+        # host array in the final dtype (f64 -> f32 -> dt, the rounding
+        # jnp.asarray applies), then one transfer per shard
+        shape, spec, is_norm = named[f]
+        x = (np.ones(shape, np.float32) if is_norm else np.asarray(
+            rng.standard_normal(shape) * _INIT_SCALE, np.float32))
+        return jax.device_put(x.astype(dt), NamedSharding(mesh, spec))
+
+    order = [f for f in _HOST_ORDER if f in named]
+    order += [f for f in named if f not in order]
+    return _assemble({f: mk(f) for f in order})
 
 
 def _layer_fwd(cfg: ModelConfig, spec: TPAttnSpec, cos, sin, positions,

@@ -792,16 +792,19 @@ class Engine:
     # -- API ----------------------------------------------------------------
 
     def new_cache(self, batch: int) -> KVCache:
-        cache = KVCache.create(
-            self.cfg.num_layers, batch, self.max_len,
-            self._hkv_loc * int(self.mesh.shape[self.axis]),
-            self.cfg.head_dim, jnp.dtype(self.cfg.dtype),
-        )
+        shape = (self.cfg.num_layers, batch, self.max_len,
+                 self._hkv_loc * int(self.mesh.shape[self.axis]),
+                 self.cfg.head_dim)
         specs = cache_specs(self.axis, self.batch_axis)
-        return jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
-            cache, specs,
-        )
+
+        def zeros(shp, dt, spec):  # created IN the sharding
+            return jnp.zeros(shp, dt,
+                             device=NamedSharding(self.mesh, spec))
+
+        dt = jnp.dtype(self.cfg.dtype)
+        return KVCache(k=zeros(shape, dt, specs.k),
+                       v=zeros(shape, dt, specs.v),
+                       length=zeros((batch,), jnp.int32, specs.length))
 
     def prefill(self, input_ids, cache: Optional[KVCache] = None):
         """input_ids: (B, S) -> (last-token logits (B, V), cache)."""

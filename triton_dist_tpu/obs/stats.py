@@ -453,7 +453,7 @@ def consume_rows(buf, kernel: str) -> None:
 #
 # The resident serve loop (models/engine.make_resident_loop) is pure
 # XLA — it has no semaphores or DMA queues of its own, so its wait
-# taxonomy is the loop-level analog of sem_wait/dma_wait: RING POLLS
+# classification is the loop-level analog of sem_wait/dma_wait: RING POLLS
 # (boundary drains of the injection ring) and IDLE POLLS (poll-budget
 # burn while nothing is active). Under obs.stats.building() the loop
 # returns one trailing (1 + slots, 1, STAT_WORDS) i32 output — the

@@ -102,12 +102,9 @@ HIGHER_IS_BETTER = {
 # reported as a stale_ack note (the series recovered — delete the
 # entry).
 ACKNOWLEDGED = {
-    ("a2a_dispatch_us", "trend_regression"): (
-        "retired key: renamed a2a_dispatch_world1_us in round 6 "
-        "(round-5 verdict — the bare name beside the 32-rank DeepEP "
-        "baseline invited a false read). The r04->r05 +39% move is on "
-        "the dead alias; the world1 key restarts the series on the "
-        "next default-rig artifact."),
+    # the ("a2a_dispatch_us", "trend_regression") ack left in PR 24
+    # with the r02-r05 records whose +39% move it explained: with the
+    # series gone it matched no flag (a stale_ack)
     ("allreduce_wire_native_us", "watermark_break"): (
         "2-core rig-local absolute arm, not a codec change: r08 read "
         "the native ring at 1221us vs the 798-819us of r06/r07 while "

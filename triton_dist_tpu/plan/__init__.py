@@ -31,7 +31,7 @@ The acceptance oracle is the house discipline: planned execution is
 bit-identical to the hand-routed path it selects (tier-1-pinned), and a
 new naively-wired model config gets fused paths with zero layer code.
 
-See docs/performance.md "Fusion planner" for the triple taxonomy,
+See docs/performance.md "Fusion planner" for the triple classification,
 decision inputs, and fallback rules; scripts/plan_report.py renders a
 plan with per-triple pricing.
 """

@@ -6,6 +6,7 @@ TPU-native analog of the reference's host runtime
 """
 
 from triton_dist_tpu.runtime.init import (  # noqa: F401
+    enable_compile_cache,
     initialize_distributed,
     finalize_distributed,
     get_default_mesh,

@@ -17,6 +17,7 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 # Canonical axis names. NVSHMEM teams map to mesh axes
@@ -29,6 +30,23 @@ DP_AXIS = "dp"
 
 _DEFAULT_MESH: Optional[Mesh] = None
 _INITIALIZED = False
+
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; call before the first
+    compile. `JAX_COMPILATION_CACHE_DIR`, when set, is the place (JAX
+    reads it itself) and nothing else is touched. Otherwise one fixed,
+    git-ignored directory inside the checkout — the path is part of
+    the cache key's world, so never a temporary name, a pid or a time.
+    Returns the directory in use."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _maybe_init_multihost() -> None:
@@ -63,18 +81,31 @@ def make_mesh(
 
     Defaults to a 1-D mesh over all devices on axis "tp" — the analog of the
     reference's world-spanning TP group (ref: utils.py:198-201).
+
+    On TPU the first n devices are laid out by
+    `mesh_utils.create_device_mesh`, so that neighbours along a mesh
+    axis are ICI neighbours: enumeration order on a v5e 2x2 is
+    (0,0) (1,0) (0,1) (1,1), whose 1-D ring 0->1->2->3->0 crosses the
+    diagonal twice; the ring kernels' hop is then two links, not one.
+    Other platforms have no topology and keep enumeration order.
     """
     if devices is None:
         devices = jax.devices()
-    devices = np.asarray(devices, dtype=object)
+    devices = list(devices)
     if mesh_shape is None:
         mesh_shape = (len(devices),) + (1,) * (len(axis_names) - 1)
     n = int(np.prod(mesh_shape))
-    if n > devices.size:
+    if n > len(devices):
         raise ValueError(
-            f"mesh shape {tuple(mesh_shape)} needs {n} devices, have {devices.size}"
+            f"mesh shape {tuple(mesh_shape)} needs {n} devices, have "
+            f"{len(devices)}"
         )
-    return Mesh(devices[:n].reshape(mesh_shape), tuple(axis_names))
+    if n > 1 and devices[0].platform == "tpu":
+        grid = mesh_utils.create_device_mesh(tuple(mesh_shape),
+                                             devices=devices[:n])
+    else:
+        grid = np.asarray(devices[:n], dtype=object).reshape(mesh_shape)
+    return Mesh(grid, tuple(axis_names))
 
 
 def split_mesh(mesh: Mesh, axis: str, sizes: Sequence[int],

@@ -44,7 +44,6 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -92,8 +91,9 @@ class KVPool:
                  cfg.head_dim)
         sharding = NamedSharding(engine.mesh,
                                  P(None, engine.axis, None, None, None))
-        self.k = jax.device_put(jnp.zeros(shape, dt), sharding)
-        self.v = jax.device_put(jnp.zeros(shape, dt), sharding)
+        # zeros created IN the sharding: never whole on one device
+        self.k = jnp.zeros(shape, dt, device=sharding)
+        self.v = jnp.zeros(shape, dt, device=sharding)
 
         self.table = np.zeros((slots, self.max_pages), np.int32)
         self.lengths = np.zeros((slots,), np.int32)

@@ -1209,7 +1209,7 @@ class Scheduler:
             out["resident_steps"] = snap.get("serve_resident_steps", 0)
             out["ring_depth"] = self.worker.pending_records()
             # metered loops (obs.stats.building at construction) fold
-            # the window rows' poll taxonomy in; 0 when unmetered
+            # the window rows' poll classification in; 0 when unmetered
             out["ring_polls"] = snap.get("serve_resident_ring_polls", 0)
             out["idle_polls"] = snap.get("serve_resident_idle_polls", 0)
         if self.slo is not None and self.slo.last is not None:

@@ -1,7 +1,7 @@
 """Stall attribution: classify traced time and diff it against the
 scheduler's predictions.
 
-Taxonomy (events.REGION_CLASS): every span region maps to one of
+Classification (events.REGION_CLASS): every span region maps to one of
 
   compute   — MXU/VPU work (megakernel task bodies, GEMM+RS partials,
               per-chunk grouped FFN marks)

@@ -36,8 +36,7 @@ injected straggler delays ride as REGION "straggle" payload ticks so
 skew is visible deterministically on the lockstep CPU interpreter.
 `t_lo/t_hi` are reserved for a real cycle-counter stamp on hardware —
 `TraceCtx.stamp` is the single injection point; today it returns zeros
-(documented limitation: in-kernel host callbacks segfault under the
-0.4.x Shardy partitioner, and Mosaic has no portable cycle read).
+(documented limitation: Mosaic has no portable cycle read).
 
 Zero cost when off: every helper is a trace-time no-op when its ctx (or
 the active build) is None — no refs are added, no stores are emitted,
@@ -103,7 +102,7 @@ REGIONS = {
 }
 _REGION_NAMES = {v: k for k, v in REGIONS.items()}
 
-# Attribution taxonomy (trace/attribution.py): how each region's span
+# Attribution classification (trace/attribution.py): how each region's span
 # time is classified. Regions absent here are structural (instants).
 REGION_CLASS = {
     "a2a.local": "dma_wait",
@@ -127,7 +126,7 @@ PHASE_DISPATCH = 1
 PHASE_FFN = 2
 PHASE_COMBINE = 3
 
-# Shared verify/trace event taxonomy: which trace region OBSERVES each
+# Shared verify/trace event classification: which trace region OBSERVES each
 # static-verifier op kind at run time, per instrumented protocol. The
 # static HB engine (verify/engine.py) proves ordering over "put" and
 # "wait_recv" ops; the trace subsystem measures the same events as

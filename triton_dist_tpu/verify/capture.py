@@ -359,7 +359,7 @@ class Capture:
     def tagging(self, **meta):
         """Attach metadata to every op recorded inside (nested tags
         merge). The engine carries tags onto HB edges — the verify-side
-        half of the shared verify/trace event taxonomy
+        half of the shared verify/trace event classification
         (trace.events.VERIFY_OP_REGIONS)."""
         self._tags.append(meta)
         try:

@@ -739,9 +739,9 @@ def team_mesh(shape, axis_names=("tp",)):
 
 @dataclasses.dataclass(frozen=True)
 class Skip:
-    """A conformance grid point this rig cannot execute (divergent-flow
-    kernels under the legacy interpreter; not enough devices). Loud in
-    the report, never a silent pass."""
+    """A conformance grid point this rig cannot execute (not enough
+    devices; a grid the kernel does not define). Loud in the report,
+    never a silent pass."""
 
     reason: str
 
