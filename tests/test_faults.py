@@ -556,8 +556,8 @@ def test_serve_transient_fault_retries_bitwise(mesh1):
     assert [r.out_tokens for r in reqs_f] == \
         [r.out_tokens for r in reqs_c]
     # the retry is attributable in the span timeline
-    assert any(name.startswith("step/retry")
-               for name, _t0, _t1 in sch_f._spans)
+    assert any(name == "step.retry"
+               for name, _t0, _t1 in sch_f.timeline().host_spans)
 
 
 def test_serve_persistent_fault_quarantines_poisoner(mesh1):
@@ -590,7 +590,7 @@ def test_serve_persistent_fault_quarantines_poisoner(mesh1):
     # pool invariants hold after the quarantine path
     sch.pool.check()
     assert any(name.endswith("/quarantined")
-               for name, _t0, _t1 in sch._spans)
+               for name, _t0, _t1 in sch.timeline().host_spans)
 
 
 def test_serve_programming_errors_stay_loud(mesh1):

@@ -36,13 +36,23 @@ class KVCache(NamedTuple):
         the garbage they gather sits beyond each sequence's `lengths`
         and is masked by attention's kv_len/causal bounds."""
         L, Hkv, _, page, D = pool_k.shape
-        B, maxp = table.shape
-        t = maxp * page
+        B = table.shape[0]
+        t = KVCache.dense_view_tokens(table.shape, page) // B
         k = jnp.moveaxis(pool_k[:, :, table].reshape(L, Hkv, B, t, D),
                          1, 3)
         v = jnp.moveaxis(pool_v[:, :, table].reshape(L, Hkv, B, t, D),
                          1, 3)
         return KVCache(k, v, lengths)
+
+    @staticmethod
+    def dense_view_tokens(table_shape, page: int) -> int:
+        """Token positions one `dense_view` of a `table_shape` table
+        gathers, a layer and kv head: every entry's whole page, live or
+        null. The gather's own size — the serve plane's
+        `serve_kv_tokens_gathered` counts this, so the two change
+        together."""
+        slots, maxp = table_shape
+        return slots * maxp * page
 
     @staticmethod
     def create(num_layers, batch, max_len, num_kv_heads, head_dim,

@@ -26,6 +26,10 @@ traffic:
             guard-trip rate) evaluated into a structured HealthStatus;
             `action="degrade"` rules feed the PR-9 degradation ladder
             (guard.degrade -> fallback="xla" routes).
+  spans     the serve plane's host-span log: a bounded ring of
+            (id, parent, name, t0, t1, step, request) records the
+            scheduler and the worker write every step, mirrored as
+            `tdt.*` annotations into any running jax.profiler session.
   export    Prometheus text format + JSON snapshots (the examples/11
             socket server's `/metrics` command; scripts/trace_report.py
             --metrics renders both snapshot and flight-dump files).
@@ -67,6 +71,11 @@ from triton_dist_tpu.obs.health import (  # noqa: F401
     HealthStatus,
     SLOMonitor,
     SLORule,
+)
+from triton_dist_tpu.obs.spans import (  # noqa: F401
+    SpanLog,
+    SpanRecord,
+    default_log,
 )
 from triton_dist_tpu.obs.export import (  # noqa: F401
     load_snapshot,

@@ -48,6 +48,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from triton_dist_tpu.models.kv_cache import KVCache
+
 
 def pages_for(n_tokens: int, page: int) -> int:
     """ceil(n_tokens / page) — the page demand of a sequence."""
@@ -118,6 +120,15 @@ class KVPool:
             ps = self._pages[slot]
             return 0 if ps is None else len(ps)
         return sum(len(p) for p in self._pages if p is not None)
+
+    def live_tokens(self, slots) -> int:
+        """Valid token positions held by `slots`, together."""
+        return sum(int(self.lengths[s]) for s in slots)
+
+    def dense_view_tokens(self) -> int:
+        """Token positions a serve step's `KVCache.dense_view` of this
+        pool's table gathers (a layer and kv head)."""
+        return KVCache.dense_view_tokens(self.table.shape, self.page)
 
     def refcount(self, page: int) -> int:
         return int(self._refs[page])
@@ -369,8 +380,6 @@ class KVPool:
     def to_dense(self):
         """Host-side dense (L, B, T, Hkv, D) models.KVCache snapshot
         (pure gather; bitwise — tests and the mega bridge use it)."""
-        from triton_dist_tpu.models.kv_cache import KVCache
-
         return KVCache.dense_view(self.k, self.v,
                                   jnp.asarray(self.table),
                                   jnp.asarray(self.lengths))

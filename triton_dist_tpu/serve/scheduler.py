@@ -65,6 +65,7 @@ from triton_dist_tpu.faults.errors import FaultError
 from triton_dist_tpu.obs.health import SLOMonitor
 from triton_dist_tpu.obs.recorder import FlightRecorder
 from triton_dist_tpu.obs.registry import Registry
+from triton_dist_tpu.obs.spans import new_default_log
 from triton_dist_tpu.serve.kv_pool import KVPool, PoolExhausted, pages_for
 from triton_dist_tpu.serve.prefix import PrefixCache
 from triton_dist_tpu.serve.queue import QueueFull, RequestQueue
@@ -118,6 +119,11 @@ class Scheduler:
         migration_resend_after: int = 8,
     ):
         page = page or _default_page(engine.max_len)
+        # -- the host-span log (obs/spans.py): every round's phases,
+        # the worker's put/launch/wait and each request's lifecycle
+        # phases, always on. One a scheduler, like the registry;
+        # obs.spans.default_log() is the newest scheduler's
+        self.spans = new_default_log()
         self.pool = KVPool(engine, slots, page, max_pages=max_pages,
                            total_pages=total_pages)
         if chunk is None:
@@ -256,7 +262,8 @@ class Scheduler:
                 "window/ring_cap configure the resident mode — pass "
                 "resident=True (or 'auto')")
             self.worker = Worker(engine, self.pool, chunk,
-                                 per_pos=self.spec is not None)
+                                 per_pos=self.spec is not None,
+                                 spans=self.spans)
         # `queue or ...` would silently DISCARD a custom queue that is
         # currently empty (RequestQueue defines __len__, and an empty
         # queue is falsy) — the admission-control settings a caller
@@ -272,13 +279,18 @@ class Scheduler:
         self.max_active = max_active or slots
         self.detok = detokenizer
         self.active: dict = {}  # slot -> Request
+        # every request submitted, for metrics() and the ledger.
+        # Bounded like `history`: past the cap the oldest RETIRED
+        # request is dropped (counted); a live one never is
         self.requests: List[Request] = []
+        self.requests_cap = 8192
+        self.requests_dropped = 0
+        self._requests_lock = threading.Lock()
         self.quarantined: List[Request] = []
         self.max_step_retries = max_step_retries
         self.retry_backoff_s = retry_backoff_s
         self.n_step_retries = 0
         self._admit_seq = 0
-        self._spans: List[tuple] = []
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
         # -- always-on telemetry (docs/observability.md): the metrics
@@ -387,8 +399,21 @@ class Scheduler:
             self.obs.inc("serve_rejected", site="queue_full")
             raise
         self.obs.inc("serve_submitted")
-        self.requests.append(req)
+        self._keep_request(req)
         return req
+
+    def _keep_request(self, req: Request) -> None:
+        """Append to `requests`; past `requests_cap`, drop the oldest
+        retired one. Clients submit from threads of their own, and the
+        scan-then-delete must not interleave."""
+        with self._requests_lock:
+            self.requests.append(req)
+            if len(self.requests) > self.requests_cap:
+                for i, old in enumerate(self.requests):
+                    if old.done:
+                        del self.requests[i]
+                        self.requests_dropped += 1
+                        break
 
     def cancel(self, req: Request) -> None:
         """Cancel queued or active; the slot frees on the next step."""
@@ -408,24 +433,84 @@ class Scheduler:
         ONE device step, postprocess. Resident mode: admit by writing
         injection records, launch one device-resident WINDOW (up to
         `window` steps in a single dispatch), drain the output ring.
-        Returns False when there was nothing to do."""
+        Returns False when there was nothing to do.
+
+        A host-loop round is one `sched.step` span whose children name
+        what the host was doing (docs/observability.md "Span log"):
+        `sched.admit`, `sched.assemble` (with `sched.keys`),
+        `worker.step` (`worker.put` / `.launch` / `.wait`),
+        `sched.emit`, `sched.observe`."""
         if self.resident:
             return self._resident_pump()
-        self._reap_cancelled()
-        # prefill role: drain acks/nacks and drive the resend ladder
-        # BEFORE admitting — an ack frees a slot's pages this round
-        mig_busy = self._pump_migration()
-        self._admit()
-        if not self.active:
-            return mig_busy
+        if self._nothing_to_do():
+            return False
+        step_idx = self.worker.n_steps
+        span = self.spans.span
+        with span("sched.step", step=step_idx):
+            with span("sched.admit", step=step_idx):
+                self._reap_cancelled()
+                # prefill role: drain acks/nacks and drive the resend
+                # ladder BEFORE admitting — an ack frees a slot's pages
+                # this round
+                mig_busy = self._pump_migration()
+                self._admit()
+            if not self.active:
+                return mig_busy
 
+            with span("sched.assemble", step=step_idx):
+                tokens, n_valid, temps, keys, plans = \
+                    self._assemble(step_idx)
+
+            if not plans:
+                # every slot stalled on pages: evict the most-
+                # victimizable to guarantee progress (its pages feed
+                # the others)
+                victim = min(self.active.values(),
+                             key=self._victim_order)
+                self._evict(victim, site="progress")
+                with span("sched.observe", step=step_idx):
+                    self._observe_step()
+                return True
+
+            toks = self._run_step(tokens, n_valid, temps, keys, plans)
+            if toks is not None:
+                with span("sched.emit", step=step_idx):
+                    self._fold_step(step_idx, toks, n_valid, plans)
+            # toks None: the step failed beyond its retry budget; the
+            # poisoning request is quarantined — survivors rerun next
+            # step from unchanged pool state (Worker.step's failure
+            # contract)
+            with span("sched.observe", step=step_idx):
+                self._observe_step()
+            return True
+
+    def _nothing_to_do(self) -> bool:
+        """A host-loop round that could only return False: no slot
+        busy, nothing queued, no migration in flight or arrived. Such
+        a round leaves no span — an idle server's log holds one
+        `sched.idle` for the stretch (start()'s loop), not a record
+        every 2 ms. A decode-role slice polls its channel here, and
+        parks what it finds for the round's `_admit_migrated`."""
+        if (self.active or self._migrating
+                or self.queue.peek() is not None):
+            return False
+        if self.role == "decode" and not self._pending_migrations:
+            rec = self.admit_from.recv()
+            if rec is not None:
+                self._pending_migrations.append(rec)
+        return not self._pending_migrations
+
+    def _assemble(self, step_idx: int):
+        """The step's arguments from the active slots: (tokens,
+        n_valid, temps, keys, plans). A plan is
+        (slot, req, n, emits, drafts)."""
         spec_on = self.spec is not None
         K, C = self.pool.slots, self.chunk
         tokens = np.zeros((K, C), np.int32)
         n_valid = np.zeros((K,), np.int32)
         temps = np.zeros((K,), np.float32)
         keys = np.zeros((K, C, 2) if spec_on else (K, 2), np.uint32)
-        plans = []  # (slot, req, n, completes_chunk, drafts)
+        plans = []
 
         for slot in sorted(self.active):
             req = self.active.get(slot)
@@ -458,17 +543,6 @@ class Scheduler:
             n_valid[slot] = n
             if emits:
                 temps[slot] = req.temperature
-                if spec_on:
-                    # per-column keys: the verify row's column j emits
-                    # output index n_out + j (spec/verify.verify_keys'
-                    # derivation, inlined for the plan loop)
-                    base = n - 1 - len(drafts)
-                    for j in range(len(drafts) + 1):
-                        keys[slot, base + j] = self.worker.key_for(
-                            req.seed, len(req.out_tokens) + j)
-                else:
-                    keys[slot] = self.worker.key_for(
-                        req.seed, len(req.out_tokens))
             plans.append((slot, req, n, emits, drafts))
 
         # a later slot's page demand may have evicted an earlier,
@@ -480,26 +554,34 @@ class Scheduler:
                 n_valid[slot] = 0
                 tokens[slot] = 0
 
-        if not plans:
-            # every slot stalled on pages: evict the most-victimizable
-            # to guarantee progress (its pages feed the others)
-            victim = min(self.active.values(), key=self._victim_order)
-            self._evict(victim, site="progress")
-            self._observe_step()
-            return True
+        # one sampling key an emitted token, drawn for the rows that
+        # stayed in the step
+        with self.spans.span("sched.keys", step=step_idx):
+            for slot, req, n, emits, drafts in plans:
+                if not emits:
+                    continue
+                n_out = len(req.out_tokens)
+                if spec_on:
+                    # per-column keys: the verify row's column j emits
+                    # output index n_out + j (spec/verify.verify_keys'
+                    # derivation, inlined for the plan loop)
+                    base = n - 1 - len(drafts)
+                    for j in range(len(drafts) + 1):
+                        keys[slot, base + j] = self.worker.key_for(
+                            req.seed, n_out + j)
+                else:
+                    keys[slot] = self.worker.key_for(req.seed, n_out)
+        return tokens, n_valid, temps, keys, plans
 
-        step_idx = self.worker.n_steps
-        toks = self._run_step(tokens, n_valid, temps, keys, plans)
-        if toks is None:
-            # step failed beyond its retry budget; the poisoning
-            # request is quarantined — survivors rerun next step from
-            # unchanged pool state (Worker.step's failure contract)
-            self._observe_step()
-            return True
+    def _fold_step(self, step_idx: int, toks, n_valid, plans) -> None:
+        """A successful device step back into request state: the
+        history entry, the step's counters, accepted drafts, emitted
+        tokens, retirements."""
+        spec_on = self.spec is not None
         # history walls come from the SUCCESSFUL attempt only — retry
         # walls and backoff sleeps must not inflate the ledger's
         # device-time split (retries are separately visible as
-        # step/retryN spans + counters)
+        # step.retry spans + counters)
         t0, t1 = self._attempt_span
         self._record_history({
             "kind": "step", "step": step_idx, "t0": t0, "t1": t1,
@@ -531,6 +613,7 @@ class Scheduler:
                                      acc / len(drafts))
                     self._note_accept_rate(acc / len(drafts))
             self.worker.advance_lengths(advance)
+        self._count_step(plans)
 
         for slot, req, n, emits, drafts in plans:
             req.last_active_step = self.worker.n_steps
@@ -565,14 +648,27 @@ class Scheduler:
                     self._emit(req, int(t))
             else:
                 self._emit(req, int(toks[slot]))
-        self._observe_step()
-        return True
 
-    def _attempt_with_backoff(self, label, body, on_fault=None):
+    def _count_step(self, plans) -> None:
+        """What one device step worked on, as counters: integers that
+        repeat exactly for a seed. Called once the pool's lengths hold
+        the step's advance and before any of its requests retires."""
+        rows = {"prefill": 0, "decode": 0}
+        for _slot, req, n, _emits, _drafts in plans:
+            rows[req.state.value] += n
+        for state, n in rows.items():
+            self.obs.inc("serve_rows", n, state=state)
+        self.obs.inc("serve_kv_tokens_live",
+                     self.pool.live_tokens(p[0] for p in plans))
+        self.obs.inc("serve_kv_tokens_gathered",
+                     self.pool.dense_view_tokens())
+
+    def _attempt_with_backoff(self, retry_span, body, on_fault=None):
         """The shared half of the degradation ladder: run `body` with
         bounded exponential-backoff retries, streaming the retry
         bookkeeping (retry counters by fault class, guard-trip
-        counters by site, spans) every attempt. Returns
+        counters by site, one `retry_span` record a failed attempt)
+        every attempt. Returns
         (result, None) on success or (None, last_err) on exhaustion —
         what exhaustion MEANS (quarantine a victim, re-raise a ring
         trip) stays with the caller. Only FaultError is degradable — a
@@ -596,9 +692,8 @@ class Scheduler:
                 self.n_step_retries += 1
                 self.obs.inc("serve_retries", site=type(e).__name__)
                 self._count_guard_trips(e)
-                self._spans.append(
-                    (f"{label}/retry{attempt}", t0,
-                     time.perf_counter_ns()))
+                self.spans.add(retry_span, t0, time.perf_counter_ns(),
+                               step=self.worker.n_steps)
                 if attempt < self.max_step_retries:
                     time.sleep(delay)
                     delay = min(delay * 2, 0.25)
@@ -612,7 +707,7 @@ class Scheduler:
         body = (self.worker.step_spec if self.worker.per_pos
                 else self.worker.step)
         toks, err = self._attempt_with_backoff(
-            "step", lambda: body(tokens, n_valid, temps, keys))
+            "step.retry", lambda: body(tokens, n_valid, temps, keys))
         if err is None:
             return toks
         victim = max((req for _slot, req, _n, _e, _d in plans),
@@ -646,8 +741,8 @@ class Scheduler:
         self.obs.set_gauge("serve_ring_depth",
                            self.worker.pending_records())
         records = self._run_window()
-        self._spans.append(("resident/window", t0,
-                            time.perf_counter_ns()))
+        self.spans.add("resident.window", t0, time.perf_counter_ns(),
+                       step=steps0)
         if records is not None:
             self._drain_records(records)
         self.obs.inc("serve_resident_windows")
@@ -854,7 +949,7 @@ class Scheduler:
         recently admitted active request. Returns the drained records,
         or None when the round was abandoned."""
         records, err = self._attempt_with_backoff(
-            "window", self.worker.run_window,
+            "window.retry", self.worker.run_window,
             # a post-launch trip (starved ring) carries the window's
             # drained records — fold the emissions in before retrying
             # so a trip never eats completions
@@ -898,7 +993,8 @@ class Scheduler:
         `retire` is the mode-specific middle — host-loop retires the
         lane immediately, resident injects a device retirement."""
         now = time.perf_counter_ns()
-        self._spans.append((f"req{req.request_id}/quarantined", now, now))
+        self.spans.add("req.quarantined", now, now,
+                       step=self.worker.n_steps, request=req.request_id)
         self.quarantined.append(req)
         self.obs.inc("serve_quarantined")
         retire()
@@ -1043,7 +1139,11 @@ class Scheduler:
         self.error: Optional[BaseException] = None
 
         def loop():
+            # one `sched.idle` span a STRETCH of rounds with nothing to
+            # do, closed when the next round finds work (or at stop())
+            idle_t0 = None
             while not self._stop.is_set():
+                t_round = time.perf_counter_ns()
                 try:
                     idle = not self.step()
                 except BaseException as e:  # noqa: BLE001 — see docstring
@@ -1064,7 +1164,15 @@ class Scheduler:
                     self._fail_all(f"scheduler error: {e!r}")
                     return
                 if idle:
+                    if idle_t0 is None:
+                        idle_t0 = t_round
                     time.sleep(0.002)
+                elif idle_t0 is not None:
+                    self.spans.add("sched.idle", idle_t0, t_round)
+                    idle_t0 = None
+            if idle_t0 is not None:
+                self.spans.add("sched.idle", idle_t0,
+                               time.perf_counter_ns())
 
         self._thread = threading.Thread(target=loop, daemon=True)
         self._thread.start()
@@ -1217,13 +1325,14 @@ class Scheduler:
         return out
 
     def timeline(self):
-        """Per-request lifecycle spans as a trace.Timeline (host spans
-        only) — write_trace() exports it to Perfetto beside the
-        in-kernel traces."""
+        """The span log as a trace.Timeline (host spans only: request
+        lifecycle phases, each round's phases, retries, windows) —
+        write_trace() exports it to Perfetto beside the in-kernel
+        traces."""
         from triton_dist_tpu.trace.collect import Timeline
 
         return Timeline(events=[], spans=[], drops={},
-                        host_spans=list(self._spans), label="serve")
+                        host_spans=self.spans.triples(), label="serve")
 
     def ledger(self, tol: float = 0.05):
         """The per-request attribution ledger (ISSUE 13): TTFT/TPOT
@@ -1255,7 +1364,7 @@ class Scheduler:
                 "no traced resident windows — construct the Scheduler "
                 "inside trace.building() to trace the loop")
         return assemble(bufs, label="serve-resident",
-                        host_spans=list(self._spans))
+                        host_spans=self.spans.triples())
 
     # -- internals ------------------------------------------------------
 
@@ -1495,7 +1604,7 @@ class Scheduler:
                     seed=rec.meta["seed"], eos_id=rec.meta["eos_id"])
                 req.request_id = rec.request_id  # keep the origin id
                 req.t_submit = time.perf_counter_ns()
-                self.requests.append(req)
+                self._keep_request(req)
                 self._begin_phase(req, "admit")
             try:
                 self.pool.install(slot, kp, vp, rec.n_tokens)
@@ -1571,7 +1680,8 @@ class Scheduler:
         req.n_evictions += 1
         self.obs.inc("serve_evicted", site=site)
         now = time.perf_counter_ns()
-        self._spans.append((f"req{req.request_id}/evicted", now, now))
+        self.spans.add("req.evicted", now, now, step=self.worker.n_steps,
+                       request=req.request_id)
         self._phase(req, "queued")
         self.queue.requeue(req)
 
@@ -1630,7 +1740,8 @@ class Scheduler:
         if ph is not None:
             name, t0 = ph
             now = time.perf_counter_ns()
-            self._spans.append((f"req{req.request_id}/{name}", t0, now))
+            self.spans.add("req." + name, t0, now,
+                           request=req.request_id)
             # accumulate into the per-request phase ledger (ISSUE 13):
             # an evicted request re-accumulates queued/prefill, so the
             # sum over phases closes against submit->finish wall time

@@ -31,6 +31,7 @@ import numpy as np
 from triton_dist_tpu.faults import plan as _fplan
 from triton_dist_tpu.faults.errors import DeadlineExceeded
 from triton_dist_tpu.mega import ring as mring
+from triton_dist_tpu.obs.spans import SpanLog
 from triton_dist_tpu.serve.kv_pool import KVPool
 
 
@@ -47,7 +48,8 @@ def sampling_key(seed: int, token_index: int) -> np.ndarray:
 
 class Worker:
     def __init__(self, engine, pool: KVPool, chunk: int,
-                 per_pos: bool = False):
+                 per_pos: bool = False,
+                 spans: Optional[SpanLog] = None):
         self.engine = engine
         self.pool = pool
         self.chunk = chunk
@@ -56,6 +58,9 @@ class Worker:
                                           pool.max_pages,
                                           per_pos=per_pos)
         self.n_steps = 0
+        # the scheduler hands its log over; a worker driven alone
+        # keeps one of its own
+        self.spans = spans if spans is not None else SpanLog()
 
     key_for = staticmethod(sampling_key)
 
@@ -77,25 +82,13 @@ class Worker:
             "a per-position (spec) worker runs step_spec + "
             "advance_lengths — the scheduler owns the accepted-count "
             "advance")
-        plan = _fplan.active()
-        if plan is not None:
-            err = plan.step_fault(self.n_steps)
-            if err is not None:
-                raise err
-        pool = self.pool
-        tok, _logits, pool.k, pool.v = self._fn(
-            self.engine.params,
-            jnp.asarray(tokens, jnp.int32),
-            pool.k, pool.v,
-            jnp.asarray(pool.table),
-            jnp.asarray(pool.lengths),
-            jnp.asarray(n_valid, jnp.int32),
-            jnp.asarray(temps, jnp.float32),
-            jnp.asarray(keys, jnp.uint32),
-        )
-        pool.lengths = pool.lengths + np.asarray(n_valid, np.int32)
-        self.n_steps += 1
-        return np.asarray(tok)
+        step = self.n_steps
+        with self.spans.span("worker.step", step=step):
+            tok = self._dispatch(step, tokens, n_valid, temps, keys)
+            self.pool.lengths = self.pool.lengths + np.asarray(n_valid,
+                                                               np.int32)
+            self.n_steps += 1
+            return self._wait(step, tok)
 
     def step_spec(self, tokens: np.ndarray, n_valid: np.ndarray,
                   temps: np.ndarray, keys: np.ndarray) -> np.ndarray:
@@ -111,24 +104,39 @@ class Worker:
         retried step rebuilds the identical row — no double
         emission)."""
         assert self.per_pos, "built without per_pos=True"
+        step = self.n_steps
+        with self.spans.span("worker.step", step=step):
+            tok = self._dispatch(step, tokens, n_valid, temps, keys)
+            self.n_steps += 1
+            return self._wait(step, tok)
+
+    def _dispatch(self, step: int, tokens, n_valid, temps, keys):
+        """Both steps' device half: the injected fault, the six
+        host-to-device puts (`worker.put`) and the call of the compiled
+        step, which returns at enqueue (`worker.launch`)."""
         plan = _fplan.active()
         if plan is not None:
-            err = plan.step_fault(self.n_steps)
+            err = plan.step_fault(step)
             if err is not None:
                 raise err
         pool = self.pool
-        tok, _logits, pool.k, pool.v = self._fn(
-            self.engine.params,
-            jnp.asarray(tokens, jnp.int32),
-            pool.k, pool.v,
-            jnp.asarray(pool.table),
-            jnp.asarray(pool.lengths),
-            jnp.asarray(n_valid, jnp.int32),
-            jnp.asarray(temps, jnp.float32),
-            jnp.asarray(keys, jnp.uint32),
-        )
-        self.n_steps += 1
-        return np.asarray(tok)
+        with self.spans.span("worker.put", step=step):
+            tokens = jnp.asarray(tokens, jnp.int32)
+            table = jnp.asarray(pool.table)
+            lengths = jnp.asarray(pool.lengths)
+            n_valid = jnp.asarray(n_valid, jnp.int32)
+            temps = jnp.asarray(temps, jnp.float32)
+            keys = jnp.asarray(keys, jnp.uint32)
+        with self.spans.span("worker.launch", step=step):
+            tok, _logits, pool.k, pool.v = self._fn(
+                self.engine.params, tokens, pool.k, pool.v, table,
+                lengths, n_valid, temps, keys)
+        return tok
+
+    def _wait(self, step: int, tok) -> np.ndarray:
+        """The device's step and the readback (`worker.wait`)."""
+        with self.spans.span("worker.wait", step=step):
+            return np.asarray(tok)
 
     def advance_lengths(self, advance: np.ndarray) -> None:
         """Fold a step_spec's per-slot length advance into the pool

@@ -21,7 +21,8 @@ pipeline runs on real stamps once TraceCtx.stamp is wired to a cycle
 counter (events.py clock notes).
 
 Wall-clock anchoring: TraceSession.host_span records python-level
-perf_counter_ns spans around the traced calls; export.to_chrome_trace
+perf_counter_ns spans around the traced calls (into an obs.spans.SpanLog
+of the session's own); export.to_chrome_trace
 places device streams at their host anchors so the Perfetto view lines
 up with real time (per-region host timing — the documented compiled-
 mode reconstruction).
@@ -31,11 +32,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from triton_dist_tpu.obs.spans import SpanLog
 from triton_dist_tpu.trace import events as ev
 
 
@@ -210,20 +211,14 @@ class TraceSession:
 
     def __init__(self, label: str = "trace"):
         self.label = label
-        self.host_spans: List[Tuple[str, int, int]] = []
-        self._t0 = time.perf_counter_ns()
+        self.log = SpanLog()
 
-    @contextlib.contextmanager
     def host_span(self, name: str):
-        t0 = time.perf_counter_ns()
-        try:
-            yield
-        finally:
-            self.host_spans.append((name, t0, time.perf_counter_ns()))
+        return self.log.span(name)
 
     def assemble(self, buffers: Dict[str, np.ndarray]) -> Timeline:
         return assemble(buffers, label=self.label,
-                        host_spans=self.host_spans)
+                        host_spans=self.log.triples())
 
 
 @contextlib.contextmanager

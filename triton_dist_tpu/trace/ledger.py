@@ -281,14 +281,15 @@ def attribute_branch_time(ledger: dict, tl, branch_keys=None,
 
 def write_request_trace(sch, path: str) -> str:
     """Perfetto export with ONE PROCESS TRACK PER REQUEST: every
-    req<N>/<phase> span of the scheduler's host-span log lands in its
-    request's own track (instants — evictions, quarantines — as 'i'
-    events), with the scheduler-level spans (step retries, resident
-    windows) in a 'serve' track beside them. Loads at ui.perfetto.dev
-    next to the in-kernel traces (same format tag)."""
-    spans = list(sch._spans)
+    req<N>/<phase> span of the scheduler's span log (obs/spans.py)
+    lands in its request's own track (instants — evictions,
+    quarantines — as 'i' events), with the scheduler-level spans (each
+    round's phases, step retries, resident windows) in a 'serve' track
+    beside them. Loads at ui.perfetto.dev next to the in-kernel traces
+    (same format tag)."""
+    spans = sch.spans.triples()
     # a live export must not lose in-flight requests: each OPEN phase
-    # (req._phase — closed spans land in sch._spans only at phase end)
+    # (req._phase — a span reaches the log only when its phase closes)
     # is exported as a zero-length instant at its open stamp
     for req in sch.requests:
         ph = getattr(req, "_phase", None)
