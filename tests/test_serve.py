@@ -14,6 +14,7 @@ streaming, the megakernel paged-decode bridge, and the step roofline.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from triton_dist_tpu.models import Engine, ModelConfig
@@ -28,6 +29,12 @@ from triton_dist_tpu.serve import (
     RequestState,
     Scheduler,
     pages_for,
+)
+from triton_dist_tpu.serve.worker import (
+    Worker,
+    check_prng_impl,
+    sampling_key,
+    sampling_keys,
 )
 
 GEO = dict(slots=3, chunk=4, page=8)  # one compiled step for the module
@@ -194,6 +201,50 @@ def test_sampled_generation_scheduling_invariant(eng1, prompts):
     assert constrained == relaxed
     # distinct seeds actually diverge (the keys are per-request)
     assert len({tuple(t) for t in relaxed}) > 1
+
+
+# ---------- the host's key derivation (ISSUE 28) ----------
+
+KEY_SEEDS = (0, 1, 41, 12345, 2**31 - 1, -1, -5, 2**32 + 7)
+KEY_INDICES = (0, 1, 3, 255, 100000)
+
+
+def _fold_in_key(seed, index):
+    """The derivation `sampling_keys` must reproduce bit for bit."""
+    return np.asarray(jax.random.fold_in(jax.random.PRNGKey(seed), index))
+
+
+@pytest.mark.parametrize("index", KEY_INDICES)
+@pytest.mark.parametrize("seed", KEY_SEEDS)
+def test_sampling_key_is_fold_in_bitwise(seed, index):
+    key = sampling_key(seed, index)
+    assert key.dtype == np.uint32 and key.shape == (2,)
+    np.testing.assert_array_equal(key, _fold_in_key(seed, index))
+    np.testing.assert_array_equal(key,
+                                  sampling_keys([seed], [index])[0])
+
+
+@pytest.mark.parametrize("shape", [(8,), (4, 10)])
+def test_sampling_keys_shapes(shape):
+    n = int(np.prod(shape))
+    seeds = np.resize(np.array(KEY_SEEDS), n).reshape(shape)
+    idx = np.resize(np.array(KEY_INDICES), n).reshape(shape)
+    keys = sampling_keys(seeds, idx)
+    assert keys.dtype == np.uint32 and keys.shape == shape + (2,)
+    for at in np.ndindex(*shape):
+        np.testing.assert_array_equal(
+            keys[at], _fold_in_key(int(seeds[at]), int(idx[at])))
+
+
+@pytest.mark.parametrize("impl", ["rbg", "unsafe_rbg"])
+def test_worker_refuses_non_threefry_default(eng1, impl):
+    check_prng_impl()  # the default passes
+    pool = KVPool(eng1, slots=GEO["slots"], page=GEO["page"])
+    with jax.default_prng_impl(impl):
+        with pytest.raises(RuntimeError, match="threefry2x32"):
+            check_prng_impl()
+        with pytest.raises(RuntimeError, match="threefry2x32"):
+            Worker(eng1, pool, GEO["chunk"])
 
 
 def test_priority_preemption_and_completion(eng1, prompts):

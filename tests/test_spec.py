@@ -14,14 +14,16 @@ the spec scheduler adds exactly one per_pos executable and the
 resident-spec loop one spec_k executable.
 """
 
+import jax
 import numpy as np
 import pytest
 
 from triton_dist_tpu.models import Engine, ModelConfig
 from triton_dist_tpu.runtime import make_mesh
 from triton_dist_tpu.serve import Scheduler
+from triton_dist_tpu.serve import scheduler as scheduler_mod
 from triton_dist_tpu.spec import NgramDraft, SpecConfig, accept_tokens
-from triton_dist_tpu.spec.verify import draft_cap
+from triton_dist_tpu.spec.verify import draft_cap, verify_keys
 
 GEO = dict(slots=3, chunk=6, page=8)
 K = 4  # one spec width (= one per_pos/spec_k executable) per module
@@ -335,6 +337,100 @@ def test_prune_spec_ks_keeps_off_switch():
     assert 0 in live and len(live) <= 2
     hi = prune_spec_ks(accept_rate=0.9, top_n=3, **dims)
     assert 0 in hi and hi[0] > 0  # best-ranked first at high rates
+
+
+# ---------- the step's keys come from the host alone (ISSUE 28) ----------
+
+
+def _fold_in_key(seed, index):
+    return np.asarray(jax.random.fold_in(jax.random.PRNGKey(seed), index))
+
+
+def _per_row_keys(sch, plans):
+    """The step's keys as the host loop drew them before ISSUE 28: one
+    eager fold_in an emitted token."""
+    spec_on = sch.spec is not None
+    keys = np.zeros((sch.pool.slots, sch.chunk, 2) if spec_on
+                    else (sch.pool.slots, 2), np.uint32)
+    for slot, req, n, emits, drafts in plans:
+        if not emits:
+            continue
+        n_out = len(req.out_tokens)
+        if spec_on:
+            base = n - 1 - len(drafts)
+            for j in range(len(drafts) + 1):
+                keys[slot, base + j] = _fold_in_key(req.seed, n_out + j)
+        else:
+            keys[slot] = _fold_in_key(req.seed, n_out)
+    return keys
+
+
+def test_verify_keys_row_is_the_key_stream():
+    keys = verify_keys(seed=41, n_out=3, width=3, cols=GEO["chunk"])
+    assert keys.shape == (GEO["chunk"], 2) and keys.dtype == np.uint32
+    for j in range(3):
+        np.testing.assert_array_equal(keys[j], _fold_in_key(41, 3 + j))
+    assert not keys[3:].any()
+
+
+def _forbid_jax_keys(patch):
+    def called(*_a, **_k):
+        raise AssertionError("the host loop called JAX for a key")
+
+    patch.setattr(jax.random, "PRNGKey", called)
+    patch.setattr(jax.random, "fold_in", called)
+
+
+def _submit_mixed(sch, prompts):
+    """One greedy request (it self-loops, so verify rows carry drafts)
+    beside sampled ones: keys are drawn for both."""
+    return [sch.submit(p, max_new_tokens=GEN,
+                       temperature=0.0 if i == 0 else 0.9, seed=41 + i)
+            for i, p in enumerate(prompts)]
+
+
+@pytest.mark.parametrize("spec_on", [False, True], ids=["plain", "spec"])
+def test_assemble_draws_keys_without_jax(eng1, prompts, monkeypatch,
+                                         spec_on):
+    sch = Scheduler(eng1, spec=_spec() if spec_on else None, **GEO)
+    _submit_mixed(sch, prompts)
+    widest = 0
+    while sch.step():
+        if not sch.active:
+            continue
+        step_idx = sch.worker.n_steps
+        *_, want, plans = sch._assemble(step_idx)
+        with monkeypatch.context() as patch:
+            _forbid_jax_keys(patch)
+            *_, got, plans_again = sch._assemble(step_idx)
+        assert [p[:4] for p in plans_again] == [p[:4] for p in plans]
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got, _per_row_keys(sch, plans))
+        assert got.any()
+        widest = max([widest] + [len(p[4]) for p in plans])
+    assert (widest > 0) == spec_on, "no verify row carried a draft"
+
+
+@pytest.mark.parametrize("spec_on", [False, True], ids=["plain", "spec"])
+def test_streams_are_those_of_per_row_fold_in(eng1, prompts, monkeypatch,
+                                              spec_on):
+    def run():
+        sch = Scheduler(eng1, spec=_spec() if spec_on else None, **GEO)
+        reqs = _submit_mixed(sch, prompts)
+        sch.run()
+        return [r.out_tokens for r in reqs]
+
+    with monkeypatch.context() as patch:
+        _forbid_jax_keys(patch)
+        got = run()
+    with monkeypatch.context() as patch:
+        patch.setattr(
+            scheduler_mod, "sampling_keys",
+            lambda seeds, idx: np.stack(
+                [_fold_in_key(s, i) for s, i in zip(seeds, idx)]))
+        want = run()
+    assert got == want
+    assert len({tuple(t) for t in got}) > 1
 
 
 # ---------- wiring / guards ----------

@@ -488,7 +488,10 @@ prompts = [list(map(int, rng.integers(0, cfg.vocab_size, n)))
 ch = FileMigrationChannel(root)
 deadline = time.time() + 240
 if role == "prefill":
-    sch = Scheduler(eng, role="prefill", migrate_to=ch, **GEO)
+    # the resend ladder counts this loop's 10 ms rounds: patient enough
+    # (2 s a resend) for a decode process that comes up seconds later
+    sch = Scheduler(eng, role="prefill", migrate_to=ch,
+                    migration_resend_after=200, **GEO)
     reqs = [sch.submit(p, max_new_tokens=GEN) for p in prompts]
     while (sch._migrating or sch.queue.peek() is not None
            or sch.active):

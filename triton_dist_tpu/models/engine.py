@@ -298,9 +298,10 @@ class Engine:
 
         next_token is greedy argmax where temps<=0, else categorical on
         logits/temp under the slot's key — keys are derived host-side
-        from (request seed, token index), so sampled generations are
-        ALSO scheduling-invariant. Pool buffers are donated when the
-        engine was built with donate_cache=True.
+        from (request seed, token index) in numpy, with no device work
+        (serve.worker.sampling_keys: threefry2x32 key data), so sampled
+        generations are ALSO scheduling-invariant. Pool buffers are
+        donated when the engine was built with donate_cache=True.
 
         per_pos=True compiles the SPEC-VERIFY form of the same step
         (ISSUE 14, triton_dist_tpu.spec): keys become (K, C, 2) — one

@@ -77,7 +77,11 @@ from triton_dist_tpu.serve.request import (
     TokenStream,
     summarize,
 )
-from triton_dist_tpu.serve.worker import ResidentWorker, Worker
+from triton_dist_tpu.serve.worker import (
+    ResidentWorker,
+    Worker,
+    sampling_keys,
+)
 from triton_dist_tpu.spec.verify import accept_tokens, draft_cap
 
 
@@ -554,23 +558,27 @@ class Scheduler:
                 n_valid[slot] = 0
                 tokens[slot] = 0
 
-        # one sampling key an emitted token, drawn for the rows that
-        # stayed in the step
+        # one sampling key an emitted token, all of the step's in one
+        # host call, drawn for the rows that stayed in the step. With
+        # spec on a row's column base + j emits output index n_out + j
+        # (a prefill tail is the one column n - 1)
         with self.spans.span("sched.keys", step=step_idx):
+            rows, cols, seeds, idx = [], [], [], []
             for slot, req, n, emits, drafts in plans:
                 if not emits:
                     continue
+                width = len(drafts) + 1 if spec_on else 1
+                rows += [slot] * width
+                cols += range(n - width, n)
+                seeds += [req.seed] * width
                 n_out = len(req.out_tokens)
+                idx += range(n_out, n_out + width)
+            if rows:
+                drawn = sampling_keys(seeds, idx)
                 if spec_on:
-                    # per-column keys: the verify row's column j emits
-                    # output index n_out + j (spec/verify.verify_keys'
-                    # derivation, inlined for the plan loop)
-                    base = n - 1 - len(drafts)
-                    for j in range(len(drafts) + 1):
-                        keys[slot, base + j] = self.worker.key_for(
-                            req.seed, n_out + j)
+                    keys[rows, cols] = drawn
                 else:
-                    keys[slot] = self.worker.key_for(req.seed, n_out)
+                    keys[rows] = drawn
         return tokens, n_valid, temps, keys, plans
 
     def _fold_step(self, step_idx: int, toks, n_valid, plans) -> None:

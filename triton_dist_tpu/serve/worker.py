@@ -35,15 +35,67 @@ from triton_dist_tpu.obs.spans import SpanLog
 from triton_dist_tpu.serve.kv_pool import KVPool
 
 
+# Threefry-2x32 rotations (Salmon et al. 2011) as (left, right) shift
+# pairs, in the two alternating groups of four rounds jax._src.prng
+# applies them in
+_ROTATIONS = tuple(
+    tuple((np.uint32(r), np.uint32(32 - r)) for r in group)
+    for group in ((13, 15, 26, 6), (17, 29, 16, 24)))
+
+
+def check_prng_impl() -> None:
+    """`sampling_keys` and the step's `(K, 2) uint32` keys are
+    threefry2x32 key data: refuse to build a worker under any other
+    default PRNG implementation, where the host's keys and the traced
+    derivation (mega.ring) would part ways in silence."""
+    impl = jax.config.jax_default_prng_impl
+    if impl != "threefry2x32":
+        raise RuntimeError(
+            "serve sampling keys are threefry2x32 key data, but "
+            f"jax_default_prng_impl is {impl!r}")
+
+
+def sampling_keys(seeds, token_indices) -> np.ndarray:
+    """Per-(request, token) sampling keys, `(..., 2) uint32` for integer
+    arrays of any equal (or broadcastable) shape: derived from the
+    request seed and the OUTPUT TOKEN INDEX only, so sampled tokens —
+    like greedy ones — are invariant to scheduling and eviction. THE
+    single host derivation: host-loop Worker, ResidentWorker and
+    spec-verify all come through here, and the device key stream
+    (mega.ring) reproduces it traced.
+
+    Bitwise `jax.random.fold_in(jax.random.PRNGKey(seed), index)` under
+    threefry2x32 (`check_prng_impl`), computed in numpy with no JAX
+    call, no device dispatch and no readback: one threefry-2x32 block
+    with key words [0, seed mod 2**32] (PRNGKey of a 32-bit seed) and
+    counter words [0, index mod 2**32] (fold_in's threefry_seed)."""
+    k1 = np.asarray(seeds, np.int64).astype(np.uint32)  # the low 32 bits
+    ks = (np.uint32(0), k1, k1 ^ np.uint32(0x1BD11BDA))
+    x1 = np.asarray(token_indices, np.int64).astype(np.uint32) + k1
+    # The two words are views of the result, updated in place by few
+    # kinds of numpy call: the host comes to this cold after a device
+    # step, where the first call of each kind costs more than all the
+    # rounds of a step's handful of keys (PERF.md, PR 28).
+    out = np.empty(x1.shape + (2,), np.uint32)
+    out[..., 0] = 0  # counter word 0 + ks[0]
+    out[..., 1] = x1  # counter word 1 + ks[1]
+    x0, x1 = out[..., 0], out[..., 1]
+    for i in range(5):
+        for left, right in _ROTATIONS[i % 2]:
+            x0 += x1
+            high = x1 << left
+            x1 >>= right
+            x1 |= high
+            x1 ^= x0
+        x0 += ks[(i + 1) % 3]
+        x1 += ks[(i + 2) % 3]
+        x1 += np.uint32(i + 1)
+    return out
+
+
 def sampling_key(seed: int, token_index: int) -> np.ndarray:
-    """Per-(request, token) sampling key: derived from the request
-    seed and the OUTPUT TOKEN INDEX only, so sampled tokens — like
-    greedy ones — are invariant to scheduling and eviction. THE single
-    derivation: host-loop Worker, ResidentWorker, and the device key
-    stream (mega.ring) all reproduce this."""
-    return np.asarray(
-        jax.random.fold_in(jax.random.PRNGKey(seed), token_index)
-    )
+    """One key of `sampling_keys`, `(2,) uint32`."""
+    return sampling_keys(seed, token_index)
 
 
 class Worker:
@@ -54,6 +106,7 @@ class Worker:
         self.pool = pool
         self.chunk = chunk
         self.per_pos = per_pos
+        check_prng_impl()
         self._fn = engine.make_serve_step(pool.slots, chunk, pool.page,
                                           pool.max_pages,
                                           per_pos=per_pos)
@@ -171,6 +224,7 @@ class ResidentWorker:
         self.poll_budget = poll_budget
         self.max_stuck_windows = max_stuck_windows
         self.spec_k = spec_k
+        check_prng_impl()
         cap = ring_cap if ring_cap is not None else max(4 * pool.slots,
                                                         16)
         self.ring = mring.InjectionRing(cap, pool.max_pages, pool.t_max,

@@ -77,16 +77,18 @@ def draft_cap(k: int, chunk: int, history_len: int, n_out: int,
                       t_max - history_len))
 
 
-def verify_keys(key_for, seed: int, n_out: int, width: int,
+def verify_keys(seed: int, n_out: int, width: int,
                 cols: int) -> np.ndarray:
     """The verify row's per-column sampling keys (cols=chunk wide,
     first `width` columns populated): column j emits output-token index
     n_out + j, so its key is THE key stream's fold_in(PRNGKey(seed),
     n_out + j) — the same derivation sequential decode uses for that
-    token index (serve.worker.sampling_key)."""
+    token index (serve.worker.sampling_keys, one host call a row)."""
+    # serve.scheduler imports this module: import at call time
+    from triton_dist_tpu.serve.worker import sampling_keys
+
     keys = np.zeros((cols, 2), np.uint32)
-    for j in range(width):
-        keys[j] = key_for(seed, n_out + j)
+    keys[:width] = sampling_keys(seed, n_out + np.arange(width))
     return keys
 
 
