@@ -165,7 +165,7 @@ def serve_phase(eng, prompts, want_kernels):
     pool, w = sch.pool, sch.worker
     kernels, t_compile = compile_and_name(
         w._fn, eng.params, jnp.zeros((SLOTS, sch.chunk), jnp.int32),
-        pool.k, pool.v, jnp.asarray(pool.table), jnp.asarray(pool.lengths),
+        pool.state, jnp.asarray(pool.table), jnp.asarray(pool.lengths),
         jnp.zeros((SLOTS,), jnp.int32), jnp.zeros((SLOTS,), jnp.float32),
         jnp.zeros((SLOTS, 2), jnp.uint32))
     say(f"serve: Scheduler(slots={SLOTS}, chunk={sch.chunk}, "

@@ -156,12 +156,63 @@ def test_serve_step_one_chip(chip):
                sharding=NamedSharding(mesh, P(None, "tp")))
     compiled = eng.make_serve_step(slots, chunk, page, max_pages).lower(
         eng.params, SDS((slots, chunk), jnp.int32, sharding=rep),
-        pool, pool, SDS((slots, max_pages), jnp.int32, sharding=rep),
+        (pool, pool), SDS((slots, max_pages), jnp.int32, sharding=rep),
         SDS((slots,), jnp.int32, sharding=rep),
         SDS((slots,), jnp.int32, sharding=rep),
         SDS((slots,), jnp.float32, sharding=rep),
         SDS((slots, 2), jnp.uint32, sharding=rep)).compile()
     assert _kernels(compiled) == {"_fp_local_kernel": 1}
+
+
+def test_hybrid_serve_step_one_chip(chip):
+    """The hybrid family's step at the benchmark's geometry (8 slots x
+    the chooser's 128-token chunk, 64-token pages, 8,192 positions),
+    two periods deep: the gated attention blocks run `_fp_local_kernel`
+    at head size 256 with 16 q / 2 kv heads, and no layer's experts
+    are sliced out of the stack (a slice that feeds the grouped matmul
+    is a copy of every expert's weights every step: 3.2 GB of
+    temporaries a period where the whole step needs under 2.5)."""
+    from triton_dist_tpu.models import qwen3_next
+    from triton_dist_tpu.perf_model import choose_chunk_for
+
+    max_len, slots, page = 8192, 8, 64
+    cfg = ModelConfig.qwen3_next_80b(
+        num_layers=8, experts_held=128, vocab_size=37_984,
+        max_positions=max_len)
+    mesh = _mesh(chip, 1)
+    rep = NamedSharding(mesh, P())
+    params = {name: SDS(shape, BF16, sharding=rep)
+              for name, shape, _ in qwen3_next.leaves(cfg)}
+    eng = Engine(cfg, mesh, params=params, max_len=max_len)
+    chunk = choose_chunk_for(cfg, 1, slots, max_len, "flash")
+    assert chunk == 128
+    max_pages = max_len // page
+    pool = SDS((cfg.num_kv_layers, cfg.num_kv_heads,
+                1 + slots * max_pages, page, cfg.head_dim), BF16,
+               sharding=rep)
+    rec, conv = qwen3_next.state_shapes(cfg, slots)
+    cache = (pool, pool, SDS(rec, jnp.float32, sharding=rep),
+             SDS(conv, BF16, sharding=rep))
+    compiled = eng.make_serve_step(slots, chunk, page, max_pages).lower(
+        params, SDS((slots, chunk), jnp.int32, sharding=rep), cache,
+        SDS((slots, max_pages), jnp.int32, sharding=rep),
+        SDS((slots,), jnp.int32, sharding=rep),
+        SDS((slots,), jnp.int32, sharding=rep),
+        SDS((slots,), jnp.float32, sharding=rep),
+        SDS((slots, 2), jnp.uint32, sharding=rep)).compile()
+    assert _kernels(compiled) == {"_fp_local_kernel": 1}
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+def test_gated_attention_has_no_silent_route_on_the_chip(chip):
+    """A head shape flash-prefill does not take is an error when the
+    hybrid step is built, not a dense XLA chain in its place."""
+    from triton_dist_tpu.plan.planner import route_gated_attention
+
+    assert route_gated_attention(8, 128, 8192, 16, 2, 256,
+                                 "bfloat16") == "pallas"
+    with pytest.raises(NotImplementedError, match="no other route"):
+        route_gated_attention(8, 128, 8192, 16, 2, 96, "bfloat16")
 
 
 def test_mega_decode_step_one_chip(chip):

@@ -42,3 +42,17 @@ from triton_dist_tpu.layers.sp_flash_decode import (  # noqa: F401
     sp_cache_write,
     sp_decode_attn_fwd,
 )
+from triton_dist_tpu.layers.gated_delta_net import (  # noqa: F401
+    GDNParams,
+    GDNSpec,
+    gated_delta_net_fwd,
+)
+from triton_dist_tpu.layers.gated_attn import (  # noqa: F401
+    GatedAttnParams,
+    GatedAttnSpec,
+    gated_attn_fwd,
+)
+from triton_dist_tpu.layers.held_moe import (  # noqa: F401
+    HeldMoEParams,
+    held_moe_fwd,
+)

@@ -1,0 +1,104 @@
+"""Expert layer of a chip that HOLDS some of the experts whole.
+
+Expert parallelism as one chip sees it: the router scores all
+`n_experts`, each token takes its `top_k` with their weights divided
+by their sum, and this chip computes the part of the sum that falls on
+the `held` experts it stores, `offset` being the first one's id. A
+(token, choice) pair whose expert is absent adds nothing here: its
+term is the holder's to compute and the exchange's to bring, and no
+code stands in for either. A shared expert, under its sigmoid gate,
+is computed for every token on every chip. On one chip there is no
+exchange at all.
+
+The held experts' products are XLA's grouped matmul
+(`kernels.grouped_gemm`, `lax.ragged_dot`) over the pairs sorted by
+expert; absent pairs and padding rows sort behind every group and are
+multiplied by nothing.
+
+  w_router (H, E) · w_gate_up (held, H, 2 I) gate | up · w_down
+  (held, I, H) · ws_gate_up (H, 2 Is) · ws_down (Is, H) · w_sgate (H,)
+
+A model that scans its layers hands the expert stacks of ALL layers,
+(layers, held, ...), and `layer`, the traced index of this one: the
+grouped matmul then runs over layers x held groups of which only this
+layer's are not empty, so that no layer's experts are sliced out of
+the stack (a slice that feeds a grouped matmul is a copy of every
+expert's weights, every step).
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from triton_dist_tpu.kernels.grouped_gemm import grouped_gemm
+from triton_dist_tpu.kernels.moe_utils import (
+    silu_mul,
+    sort_by_expert,
+    topk_routing,
+)
+
+
+class HeldMoEParams(NamedTuple):
+    w_router: jax.Array
+    w_gate_up: jax.Array
+    w_down: jax.Array
+    ws_gate_up: jax.Array
+    ws_down: jax.Array
+    w_sgate: jax.Array
+
+
+def held_moe_fwd(x, valid, p: HeldMoEParams, top_k: int, offset: int,
+                 layer=None):
+    """x (M, H); valid (M,) bool, the rows that are real tokens.
+    Returns (y (M, H), pairs_here, pairs_absent): the held experts'
+    part plus the shared expert, and how many of the valid rows'
+    (token, choice) pairs fell on a held and on an absent expert.
+    With `layer`, the expert stacks are all layers' (module doc)."""
+    m, _ = x.shape
+    w_gate_up, w_down = p.w_gate_up, p.w_down
+    held = w_gate_up.shape[-3]
+    logits = jnp.dot(x.astype(jnp.float32), p.w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    weights, ids = topk_routing(logits, top_k)
+    local = ids - offset
+    held_here = (local >= 0) & (local < held)
+    here = held_here & valid[:, None]
+    # one group behind the held experts takes what is not computed here
+    sort = sort_by_expert(jnp.where(here, local, held), held + 1)
+    sizes = sort.group_sizes[:held]
+    n_here = jnp.sum(sizes)
+    if layer is not None:
+        layers = w_gate_up.shape[0]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((layers * held,), sizes.dtype), sizes,
+            (layer * held,))
+        w_gate_up = w_gate_up.reshape((-1,) + w_gate_up.shape[2:])
+        w_down = w_down.reshape((-1,) + w_down.shape[2:])
+    pairs = m * top_k
+    mine = jnp.where(here, weights, 0.0)
+
+    # the held experts' weighted sum; each token's terms are summed in
+    # the order of its choices, so a row's result is the same bit for
+    # bit whatever else rides the step
+    h = grouped_gemm(x[sort.token_idx], w_gate_up, sizes)
+    act = silu_mul(h).astype(x.dtype)
+    y = grouped_gemm(act, w_down, sizes)  # in the model's dtype
+    y = jnp.where((jnp.arange(pairs) < n_here)[:, None], y,
+                  jnp.zeros((), y.dtype))
+    out = jnp.einsum(
+        "mkh,mk->mh",
+        y[sort.unsort_idx].reshape(m, top_k, -1).astype(jnp.float32), mine)
+
+    sgate = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), p.w_sgate.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    sh = jnp.dot(x, p.ws_gate_up, preferred_element_type=jnp.float32)
+    shared = jnp.dot(silu_mul(sh).astype(x.dtype), p.ws_down,
+                     preferred_element_type=jnp.float32)
+    out = out + sgate[:, None] * shared
+    pairs_here = jnp.sum(here, dtype=jnp.int32)
+    pairs_absent = jnp.sum(valid[:, None] & ~held_here, dtype=jnp.int32)
+    return out.astype(x.dtype), pairs_here, pairs_absent

@@ -128,6 +128,15 @@ class PagedMegaKVCache(NamedTuple):
                                 jnp.asarray(used, jnp.int32))
 
 
+def _dense_only(cfg: ModelConfig) -> None:
+    if cfg.is_hybrid:
+        raise NotImplementedError(
+            "the megakernel's task graph is the dense decoder's: a "
+            "configuration with recurrent (gated-delta-net) layers has no "
+            "delta-rule, convolution or held-expert task, and no state "
+            "buffer beside the KV pages")
+
+
 def build_qwen3_graph(
     cfg: ModelConfig, batch: int, world: int, s_max: int,
     axis: str = TP_AXIS, page: int = 0,
@@ -139,6 +148,7 @@ def build_qwen3_graph(
       [0,L) input_ln · [L,2L) post_attn_ln · [2L] final_ln ·
       [2L+1,3L+1) q_norm · [3L+1,4L+1) k_norm
     """
+    _dense_only(cfg)
     n = world
     L = cfg.num_layers
     H = cfg.hidden_size
@@ -205,6 +215,7 @@ class MegaQwen3:
         page_size: Optional[int] = None,
         total_pages: Optional[int] = None,
     ):
+        _dense_only(cfg)
         assert not cfg.is_moe, "megakernel covers the dense decode graph"
         from triton_dist_tpu.lang.core import use_interpret
 

@@ -31,10 +31,44 @@ class ModelConfig:
     num_experts: int = 0
     num_experts_per_tok: int = 8
     moe_intermediate_size: int = 0
+    # Hybrid layer pattern (Qwen3-Next; models/qwen3_next.py). Block i is
+    # gated full attention where (i + 1) % full_attention_interval == 0
+    # and a gated delta net otherwise; 0 = every block the dense
+    # family's attention. The hybrid family also means: RMSNorm with
+    # gain (1 + w), an output gate on attention, rotary over
+    # `partial_rotary_factor` of the head, a router over all
+    # `num_experts` of which this chip holds `experts_held` from
+    # `expert_offset` on (0 held = all), and a shared expert.
+    full_attention_interval: int = 0
+    partial_rotary_factor: float = 1.0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    shared_expert_intermediate_size: int = 0
+    experts_held: int = 0
+    expert_offset: int = 0
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_hybrid(self) -> bool:
+        return self.full_attention_interval > 0
+
+    @property
+    def num_experts_held(self) -> int:
+        """Experts this chip stores (all of them unless told)."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def num_kv_layers(self) -> int:
+        """Blocks that keep keys and values (pages in the serve pool)."""
+        if not self.is_hybrid:
+            return self.num_layers
+        return self.num_layers // self.full_attention_interval
 
     # The published presets fix every WIDTH; depth (`num_layers`) is the
     # one cut a single chip may force, so it is the presets' only
@@ -69,6 +103,42 @@ class ModelConfig:
             head_dim=128, num_experts=128, num_experts_per_tok=8,
             moe_intermediate_size=768, **kw,
         )
+
+    @staticmethod
+    def qwen3_next_80b(num_layers: int = 48, **kw) -> "ModelConfig":
+        """Qwen3-Next-80B-A3B geometry: periods of three gated-delta-net
+        blocks and one gated-attention block, 512 experts of width 512
+        with 10 a token plus a shared one. What one chip of an
+        expert-parallel group holds is `experts_held` / `expert_offset`
+        and a `vocab_size` slice in **kw."""
+        defaults = dict(
+            vocab_size=151_936, hidden_size=2048, intermediate_size=5120,
+            num_q_heads=16, num_kv_heads=2, head_dim=256,
+            rope_theta=10_000_000.0, num_experts=512,
+            num_experts_per_tok=10, moe_intermediate_size=512,
+            full_attention_interval=4, partial_rotary_factor=0.25,
+            linear_num_key_heads=16, linear_num_value_heads=32,
+            linear_key_head_dim=128, linear_value_head_dim=128,
+            linear_conv_kernel_dim=4, shared_expert_intermediate_size=512,
+        )
+        defaults.update(kw)
+        return ModelConfig(num_layers=num_layers, **defaults)
+
+    @staticmethod
+    def tiny_next(**kw) -> "ModelConfig":
+        """Test-scale hybrid config: one period, 8 experts."""
+        defaults = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=128,
+            num_layers=4, num_q_heads=4, num_kv_heads=2, head_dim=32,
+            rope_theta=10_000_000.0, max_positions=64, dtype="float32",
+            num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
+            full_attention_interval=4, partial_rotary_factor=0.25,
+            linear_num_key_heads=2, linear_num_value_heads=4,
+            linear_key_head_dim=16, linear_value_head_dim=16,
+            linear_conv_kernel_dim=4, shared_expert_intermediate_size=32,
+        )
+        defaults.update(kw)
+        return ModelConfig(**defaults)
 
     @staticmethod
     def tiny_moe(**kw) -> "ModelConfig":

@@ -72,7 +72,17 @@ def _serve_step_math(cfg, mode, axis, slots, chunk, page, t_pool,
         cfg, params, tokens, cache, mode=mode, axis=axis,
         return_full_logits=True, plan=plan,
     )  # logits (K, C, V) f32, new_cache k/v (L, K, T, Hkv, D)
-    bidx = jnp.arange(slots)[:, None]
+    tok, last = _sample_step(logits, n_valid, temps, keys, per_pos)
+    pool_k, pool_v = KVCache.scatter_step(pool_k, pool_v, new_cache, table,
+                                          lengths, n_valid, chunk)
+    return tok, last, pool_k, pool_v
+
+
+def _sample_step(logits, n_valid, temps, keys, per_pos: bool):
+    """A step's tokens from its (K, C, V) logits: (tok, last) with
+    `last` the (K, V) logits at column n_valid - 1 (see
+    `_serve_step_math` for `per_pos`)."""
+    slots = logits.shape[0]
     last = logits[jnp.arange(slots),
                   jnp.maximum(n_valid - 1, 0)]  # (K, V)
     if per_pos:
@@ -89,22 +99,24 @@ def _serve_step_math(cfg, mode, axis, slots, chunk, page, t_pool,
             keys, last / temp
         ).astype(jnp.int32)
         tok = jnp.where(temps > 0.0, sampled, greedy)
+    return tok, last
 
-    # scatter this step's K/V rows back into the pool: valid
-    # columns land on their table pages; padding columns are
-    # routed to page 0, the pool's reserved null page (their
-    # positions may sit past the slot's allocated pages, whose
-    # table entries still map to live pages of OTHER slots)
-    pos = lengths[:, None] + jnp.arange(chunk)[None, :]  # (K, C)
-    posc = jnp.minimum(pos, t_pool - 1)
-    valid = jnp.arange(chunk)[None, :] < n_valid[:, None]
-    pg = jnp.where(valid, table[bidx, posc // page], 0)
-    off = posc % page
-    kn = jnp.moveaxis(new_cache.k[:, bidx, posc], 3, 1)
-    vn = jnp.moveaxis(new_cache.v[:, bidx, posc], 3, 1)
-    pool_k = pool_k.at[:, :, pg, off].set(kn.astype(pool_k.dtype))
-    pool_v = pool_v.at[:, :, pg, off].set(vn.astype(pool_v.dtype))
-    return tok, last, pool_k, pool_v
+
+def _hybrid_step_math(cfg, chunk, attn_impl, params, tokens, cache, table,
+                      lengths, n_valid, temps, keys):
+    """The serve step of the hybrid family (models/qwen3_next.py): the
+    same fixed-geometry forward, sampling and page scatter, with the
+    delta-net blocks' per-slot state carried beside the pages. Returns
+    (tok, last, cache, {counter: () int32})."""
+    from triton_dist_tpu.models import qwen3_next
+
+    logits, (k_new, v_new), rec, conv, stats = qwen3_next.forward_chunk(
+        cfg, params, tokens, cache, table, lengths, n_valid, attn_impl)
+    tok, last = _sample_step(logits, n_valid, temps, keys, False)
+    pool_k, pool_v = KVCache.scatter_step(
+        cache.k, cache.v, KVCache(k_new, v_new, lengths), table, lengths,
+        n_valid, chunk)
+    return tok, last, qwen3_next.Cache(pool_k, pool_v, rec, conv), stats
 
 
 class Engine:
@@ -135,12 +147,40 @@ class Engine:
         self.max_len = max_len or cfg.max_positions
         self.prefill_mode = prefill_mode
         self.decode_mode = decode_mode
+        n = int(mesh.shape[axis])
+        self._hkv_loc = cfg.num_kv_heads // n
+        self._donate_cache = donate_cache
+        # compiled generate() executables, keyed (steps, greedy). A
+        # per-instance dict, NOT lru_cache on the bound method: that keys
+        # a module-lifetime cache on self and pins every Engine (params +
+        # compiled shard_map executables) for the process lifetime.
+        # Bounded like the lru_cache it replaces — a server honoring
+        # per-request step counts must not accumulate executables forever.
+        self._gen_cache: dict = {}
+        self._gen_cache_max = 8
+        # compiled serve-step executables, keyed on the batch-of-
+        # sequence-states geometry (see make_serve_step) — bounded like
+        # _gen_cache, and shared between Engine.serve's stepwise path
+        # and the serve-plane Worker so both replay ONE executable.
+        self._serve_cache: dict = {}
+        if cfg.is_hybrid:
+            # the hybrid family (models/qwen3_next.py) is served through
+            # make_serve_step alone: its per-slot recurrent state lives
+            # in the serve plane's pool, so the KVCache entry points
+            # (prefill, decode_step, generate) refuse it
+            from triton_dist_tpu.models import qwen3_next
+
+            qwen3_next.check(cfg, n)
+            self.params = (
+                params if params is not None
+                else qwen3_next.init_params(cfg, mesh, seed, fast=fast_init)
+            )
+            self._wrap_specs = (P(), P(batch_axis), None)
+            return
         self.params = (
             params if params is not None
             else init_params(cfg, mesh, seed, axis, fast=fast_init)
         )
-        n = int(mesh.shape[axis])
-        self._hkv_loc = cfg.num_kv_heads // n
 
         p_specs = param_specs(axis, cfg.is_moe)
         c_specs = cache_specs(axis, batch_axis)
@@ -174,28 +214,28 @@ class Engine:
         self._decode = wrap(decode_fn)
         self._decode_fn = decode_fn
         self._wrap_specs = (p_specs, t_spec, c_specs)
-        self._donate_cache = donate_cache
-        # compiled generate() executables, keyed (steps, greedy). A
-        # per-instance dict, NOT lru_cache on the bound method: that keys
-        # a module-lifetime cache on self and pins every Engine (params +
-        # compiled shard_map executables) for the process lifetime.
-        # Bounded like the lru_cache it replaces — a server honoring
-        # per-request step counts must not accumulate executables forever.
-        self._gen_cache: dict = {}
-        self._gen_cache_max = 8
-        # compiled serve-step executables, keyed on the batch-of-
-        # sequence-states geometry (see make_serve_step) — bounded like
-        # _gen_cache, and shared between Engine.serve's stepwise path
-        # and the serve-plane Worker so both replay ONE executable.
-        self._serve_cache: dict = {}
+
+    def _refuse_hybrid(self, what: str) -> None:
+        if self.cfg.is_hybrid:
+            raise NotImplementedError(
+                f"{what} is not built for a configuration with recurrent "
+                "(gated-delta-net) layers: their per-slot state is carried "
+                "by the serve step alone (Engine.make_serve_step, "
+                "serve.Scheduler)")
 
     def plan_for(self, batch: int, seq: int, kind: str = "decode"):
         """The fusion plan (triton_dist_tpu.plan.Plan) this engine's
         forwards execute under at the given step geometry. Memoized in
         the planner, so this IS the same object `forward` resolves
         inside the compiled step — the serve Scheduler and
-        mega.schedule_graph consume it to provably agree on pairings."""
+        mega.schedule_graph consume it to provably agree on pairings.
+        None for the hybrid family: on its one chip there is no
+        collective to pair, and its one routing decision is
+        `plan.planner.route_gated_attention`."""
         from triton_dist_tpu.plan.planner import plan_dense_forward
+
+        if self.cfg.is_hybrid:
+            return None
 
         mode = self.prefill_mode if kind == "prefill" else self.decode_mode
         n = int(self.mesh.shape[self.axis])
@@ -255,6 +295,7 @@ class Engine:
         temperature<=0 (or no key), else categorical on logits/T with
         per-step key splits; temperature rides as a traced scalar so
         distinct values replay one executable."""
+        self._refuse_hybrid("Engine.generate")
         greedy = temperature <= 0.0 or key is None
         if key is None:
             key = jax.random.PRNGKey(0)
@@ -288,19 +329,29 @@ class Engine:
         eviction/re-prefill — the property tests/test_serve.py pins.
 
         Signature of the returned callable:
-          fn(params, tokens (K, C) i32, pool_k, pool_v
-             (L, Hkv, P, page, D) — megakernel pool layout, shared with
-             mega.qwen3.PagedMegaKVCache — table (K, MAXP) i32,
+          fn(params, tokens (K, C) i32, cache, table (K, MAXP) i32,
              lengths (K,) i32, n_valid (K,) i32, temps (K,) f32,
              keys (K, 2) u32)
-          -> (next_token (K,) i32, last_logits (K, V) f32,
-              pool_k, pool_v)
+          -> (next_token (K,) i32, last_logits (K, V) f32, cache,
+              stats)
+
+        `cache` is ONE pytree, everything a slot carries between steps
+        (`KVPool.state`): for the dense family (pool_k, pool_v), each
+        (L, Hkv, P, page, D) — megakernel pool layout, shared with
+        mega.qwen3.PagedMegaKVCache; for the hybrid family
+        `qwen3_next.Cache`, pages for the attention blocks only and the
+        delta-net blocks' per-slot recurrent and convolution state
+        (a padding column leaves both as they were; a slot whose
+        length is 0 starts from zero state inside the step). `stats`
+        is a dict of () int32 counts the step made on the device
+        (empty for the dense family; `moe_pairs_here` /
+        `moe_pairs_absent` for the hybrid one).
 
         next_token is greedy argmax where temps<=0, else categorical on
         logits/temp under the slot's key — keys are derived host-side
         from (request seed, token index) in numpy, with no device work
         (serve.worker.sampling_keys: threefry2x32 key data), so sampled
-        generations are ALSO scheduling-invariant. Pool buffers are
+        generations are ALSO scheduling-invariant. The cache is
         donated when the engine was built with donate_cache=True.
 
         per_pos=True compiles the SPEC-VERIFY form of the same step
@@ -332,23 +383,43 @@ class Engine:
         # serve Scheduler and mega builders hold — plan_for doc)
         plan = self.plan_for(slots, chunk, kind="decode")
 
-        def per_rank(params, tokens, pool_k, pool_v, table, lengths,
-                     n_valid, temps, keys):
-            return _serve_step_math(
-                cfg, mode, axis, slots, chunk, page, t_pool,
-                params, tokens, pool_k, pool_v, table, lengths,
-                n_valid, temps, keys, per_pos=per_pos, plan=plan)
+        if cfg.is_hybrid:
+            if per_pos:
+                self._refuse_hybrid("the per-position (spec-verify) step")
+            from triton_dist_tpu.models import qwen3_next
+            from triton_dist_tpu.plan.planner import route_gated_attention
 
-        pool_spec = P(None, self.axis)
+            attn_impl = route_gated_attention(
+                slots, chunk, t_pool, cfg.num_q_heads, cfg.num_kv_heads,
+                cfg.head_dim, cfg.dtype)
+
+            def per_rank(params, tokens, cache, table, lengths, n_valid,
+                         temps, keys):
+                return _hybrid_step_math(
+                    cfg, chunk, attn_impl, params, tokens,
+                    qwen3_next.Cache(*cache), table, lengths, n_valid,
+                    temps, keys)
+
+            cache_spec = P()
+        else:
+            def per_rank(params, tokens, cache, table, lengths, n_valid,
+                         temps, keys):
+                tok, last, pool_k, pool_v = _serve_step_math(
+                    cfg, mode, axis, slots, chunk, page, t_pool,
+                    params, tokens, cache[0], cache[1], table, lengths,
+                    n_valid, temps, keys, per_pos=per_pos, plan=plan)
+                return tok, last, (pool_k, pool_v), {}
+
+            cache_spec = P(None, self.axis)
         return jax.jit(
             jax.shard_map(
                 per_rank, mesh=self.mesh,
-                in_specs=((self._wrap_specs[0], P(), pool_spec, pool_spec)
+                in_specs=((self._wrap_specs[0], P(), cache_spec)
                           + (P(),) * 5),
-                out_specs=(P(), P(), pool_spec, pool_spec),
+                out_specs=(P(), P(), cache_spec, P()),
                 check_vma=False,
             ),
-            donate_argnums=(2, 3) if self._donate_cache else (),
+            donate_argnums=(2,) if self._donate_cache else (),
         )
 
     def _check_serve_geometry(self, slots: int, chunk: int, page: int,
@@ -429,6 +500,9 @@ class Engine:
         from triton_dist_tpu.obs import stats as _ost
         from triton_dist_tpu.trace import events as _tev
 
+        self._refuse_hybrid("the device-resident loop (its carry and "
+                            "mega.ring's slot plan hold keys and values "
+                            "only)")
         prompt_cap = prompt_cap if prompt_cap is not None \
             else max_pages * page
         # the build contexts are consulted when the loop is CONSTRUCTED
@@ -793,6 +867,7 @@ class Engine:
     # -- API ----------------------------------------------------------------
 
     def new_cache(self, batch: int) -> KVCache:
+        self._refuse_hybrid("Engine.new_cache / prefill / decode_step")
         shape = (self.cfg.num_layers, batch, self.max_len,
                  self._hkv_loc * int(self.mesh.shape[self.axis]),
                  self.cfg.head_dim)
@@ -809,6 +884,7 @@ class Engine:
 
     def prefill(self, input_ids, cache: Optional[KVCache] = None):
         """input_ids: (B, S) -> (last-token logits (B, V), cache)."""
+        self._refuse_hybrid("Engine.prefill")
         input_ids = jnp.asarray(input_ids, jnp.int32)
         if cache is None:
             cache = self.new_cache(input_ids.shape[0])
@@ -816,6 +892,7 @@ class Engine:
 
     def decode_step(self, tokens, cache: KVCache):
         """tokens: (B,) -> (logits (B, V), cache)."""
+        self._refuse_hybrid("Engine.decode_step")
         return self._decode(
             self.params, jnp.asarray(tokens, jnp.int32)[:, None], cache
         )

@@ -55,6 +55,29 @@ class KVCache(NamedTuple):
         return slots * maxp * page
 
     @staticmethod
+    def scatter_step(pool_k, pool_v, new: "KVCache", table, lengths,
+                     n_valid, chunk: int):
+        """A serve step's K/V rows back into the paged pool — the write
+        path beside `dense_view`: the rows at positions lengths ..
+        lengths + chunk of `new` (the dense view after the forward).
+        Valid columns land on their table pages; padding columns are
+        routed to page 0, the pool's reserved null page (their
+        positions may sit past the slot's allocated pages, whose table
+        entries still map to live pages of OTHER slots)."""
+        page = pool_k.shape[3]
+        slots, max_pages = table.shape
+        bidx = jnp.arange(slots)[:, None]
+        pos = lengths[:, None] + jnp.arange(chunk)[None, :]  # (K, C)
+        posc = jnp.minimum(pos, max_pages * page - 1)
+        valid = jnp.arange(chunk)[None, :] < n_valid[:, None]
+        pg = jnp.where(valid, table[bidx, posc // page], 0)
+        off = posc % page
+        kn = jnp.moveaxis(new.k[:, bidx, posc], 3, 1)
+        vn = jnp.moveaxis(new.v[:, bidx, posc], 3, 1)
+        return (pool_k.at[:, :, pg, off].set(kn.astype(pool_k.dtype)),
+                pool_v.at[:, :, pg, off].set(vn.astype(pool_v.dtype)))
+
+    @staticmethod
     def create(num_layers, batch, max_len, num_kv_heads, head_dim,
                dtype=jnp.bfloat16) -> "KVCache":
         shape = (num_layers, batch, max_len, num_kv_heads, head_dim)

@@ -1331,20 +1331,94 @@ def choose_prefill_chunk(
     estimate_serve_step_ms — the flash kernel's missing logits term is
     what lets the pick stay wide at long contexts). Returns at least
     candidates[0]."""
-    args = (num_layers, hidden, inter_loc, hq_loc, hkv_loc, head_dim,
-            vocab_loc)
-    base = estimate_serve_step_ms(*args, n_tokens=max(slots, 1),
-                                  kv_tokens=kv_tokens, dtype=dtype,
-                                  chip=chip, attn_impl=attn_impl)
+    def step_ms(n_tokens):
+        return estimate_serve_step_ms(
+            num_layers, hidden, inter_loc, hq_loc, hkv_loc, head_dim,
+            vocab_loc, n_tokens=n_tokens, kv_tokens=kv_tokens,
+            dtype=dtype, chip=chip, attn_impl=attn_impl)
+
+    return _largest_chunk_within(step_ms, slots, stall_budget, candidates)
+
+
+def _largest_chunk_within(step_ms, slots: int, stall_budget: float = 2.0,
+                          candidates=(1, 2, 4, 8, 16, 32, 64, 128)) -> int:
+    """The largest candidate whose mixed step (one slot prefilling a
+    chunk, the rest decoding) stays within `stall_budget` x the
+    decode-only step, under a family's `step_ms(n_tokens)`."""
+    base = step_ms(max(slots, 1))
     best = candidates[0]
     for c in sorted(candidates):
-        mixed = estimate_serve_step_ms(
-            *args, n_tokens=c + max(slots - 1, 0),
-            kv_tokens=kv_tokens, dtype=dtype, chip=chip,
-            attn_impl=attn_impl)
-        if mixed <= stall_budget * base:
+        if step_ms(c + max(slots - 1, 0)) <= stall_budget * base:
             best = c
     return best
+
+
+# the share of the HBM peak at which XLA's grouped matmul streams the
+# held experts' weights at a few rows an expert (v5e; PERF.md, PR 30)
+_RAGGED_DOT_HBM_SHARE = 0.2
+
+
+def estimate_hybrid_step_ms(cfg, n_tokens: int, kv_tokens: int = 0,
+                            chip: Optional[ChipSpec] = None) -> float:
+    """`estimate_serve_step_ms` for the hybrid family
+    (models/qwen3_next.py), from ITS sizes: the weight stream is every
+    expert the chip holds (each is read when any token takes it, and a
+    step's tokens take nearly all), the mixers by kind and the head;
+    the operations are those of the experts a token is routed to HERE
+    (top_k x held / experts), the shared expert, the router, the
+    mixers' projections and attention over the full-attention blocks
+    alone. A floor, not a forecast: against the v5e it was checked
+    once (PERF.md, PR 30: 73-81 ms found at 1,024 rows where this
+    gives 60, and 13 before the held experts' stream was priced at
+    the rate `lax.ragged_dot` was measured to reach, a fifth of the
+    HBM peak at 7 rows an expert). `choose_chunk_for` only compares
+    its values across chunks; the step is bound by the weights at
+    every candidate, so the largest wins."""
+    chip = chip or detect_chip()
+    b = _dtype_bytes(cfg.dtype)
+    L, h = cfg.num_layers, cfg.hidden_size
+    lf = cfg.num_kv_layers
+    ll = L - lf
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    hqd, kwd = cfg.num_q_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
+    held = cfg.num_experts_held
+    expert = 3 * h * cfg.moe_intermediate_size
+    shared = 3 * h * cfg.shared_expert_intermediate_size + h
+    gdn = h * (2 * hk * dk + 2 * hv * dv + 2 * hv) + hv * dv * h
+    attn = h * (2 * hqd + 2 * kwd) + hqd * h
+    router = h * cfg.num_experts
+    w_params = (L * (shared + router) + ll * gdn + lf * attn
+                + h * cfg.vocab_size)
+    state = ll * hv * dk * dv * 4 * 2  # read and written, every slot's
+    mem_ms = (L * held * expert * b / _RAGGED_DOT_HBM_SHARE + w_params * b
+              + 2 * lf * kwd * kv_tokens * b
+              + state) / (chip.hbm_gbps * 1e9) * 1e3
+    routed = cfg.num_experts_per_tok * held / cfg.num_experts * expert
+    per_token = (L * (routed + shared + router) + ll * gdn + lf * attn
+                 + h * cfg.vocab_size)
+    flops = 2.0 * n_tokens * per_token \
+        + 4.0 * n_tokens * kv_tokens * lf * hqd \
+        + 4.0 * n_tokens * ll * hv * dk * dv
+    compute_ms = flops / (
+        chip.bf16_tflops * 1e12 * 0.85
+        * mxu_efficiency(max(n_tokens, 1024), h, h)) * 1e3
+    return max(compute_ms, mem_ms)
+
+
+def choose_chunk_for(cfg, world: int, slots: int, kv_tokens: int,
+                     attn_impl: str = "flash") -> int:
+    """The Scheduler's default chunk for a model configuration, priced
+    from the sizes of ITS family: the dense formula for the dense
+    family, `estimate_hybrid_step_ms` for the hybrid one."""
+    if cfg.is_hybrid:
+        return _largest_chunk_within(
+            lambda t: estimate_hybrid_step_ms(cfg, t, kv_tokens), slots)
+    return choose_prefill_chunk(
+        cfg.num_layers, cfg.hidden_size, cfg.intermediate_size // world,
+        cfg.num_q_heads // world, cfg.num_kv_heads // world, cfg.head_dim,
+        cfg.vocab_size // world, slots=slots, kv_tokens=kv_tokens,
+        dtype=cfg.dtype, attn_impl=attn_impl)
 
 
 def estimate_ag_gemm_ms(

@@ -687,6 +687,38 @@ def plan_ep_chunks(m: int, hidden: int, inter: int, e_loc: int, n: int,
         payload_dtype=payload_dtype, chip=chip, overlap=overlap)
 
 
+def route_gated_attention(b: int, s: int, t: int, hq: int, hkv: int,
+                          d: int, dtype) -> str:
+    """The attention implementation of the hybrid family's full
+    attention blocks (models/qwen3_next.py), decided once when its
+    serve step is built and never left to fall through. On the chip
+    that is the Pallas flash-prefill kernel ("pallas") or an error: a
+    head shape the kernel does not take, or per-grid-step residents
+    over the VMEM ceiling, would otherwise compile a dense (S, T)
+    float32 logits chain in silence. Under the interpreter (the CPU
+    mesh of the tests) it is the XLA formulation, as for every auto
+    route (`flash_prefill_native_ok`)."""
+    from triton_dist_tpu.kernels.flash_prefill import (
+        flash_prefill_fits,
+        supports_flash_prefill,
+    )
+    from triton_dist_tpu.lang import use_interpret
+
+    if use_interpret():
+        return "xla"
+    if not supports_flash_prefill(hq, hkv, d):
+        raise NotImplementedError(
+            f"flash-prefill does not take {hq} q / {hkv} kv heads of size "
+            f"{d}, and the gated attention blocks have no other route on "
+            "the chip")
+    if not flash_prefill_fits(s, t, hq, hkv, d, dtype=dtype):
+        raise NotImplementedError(
+            f"flash-prefill's residents for {s} rows x {hq} heads of {d} "
+            f"over {t} positions pass the VMEM ceiling, and the gated "
+            "attention blocks have no other route on the chip")
+    return "pallas"
+
+
 def route_prefill_impl(b: int, s: int, t: int, hq: int, hkv: int,
                        d: int, dtype) -> str:
     """THE prefill-impl routing predicate ("pallas" | "xla"): native

@@ -33,6 +33,18 @@ null-page / leak / alias assertions: every page's refcount must equal
 its holder count (slot table occurrences + external holds), and the
 free list is exactly the refcount-0 pages.
 
+A second kind of state (the hybrid family, models/qwen3_next.py): pages
+hold the keys and values of the full-attention blocks only, and every
+delta-net block keeps PER-SLOT state beside them — `rec`
+(Ll, slots, Hv, dk, dv) float32 and `conv` (Ll, slots, K-1, channels).
+It needs no allocator: a slot's life covers it, because the serve step
+starts a slot whose length is 0 from zero state and leaves the state of
+a slot with no valid column as it was; admit, eviction with re-prefill
+and release therefore touch `lengths` alone. `state` is everything the
+step carries, as one pytree. What would have to copy or ship that
+state (prefix sharing, copy-on-write, page export / install, the
+megakernel bridge) refuses such a pool.
+
 Host/device split: page bookkeeping (free list, per-slot page lists,
 lengths, refcounts) is host-side numpy — the scheduler reads it every
 step — while k/v live on device and are donated through the step
@@ -89,13 +101,25 @@ class KVPool:
         n = int(engine.mesh.shape[engine.axis])
         hkv = cfg.num_kv_heads // n * n
         dt = jnp.dtype(cfg.dtype)
-        shape = (cfg.num_layers, hkv, 1 + self.capacity, page,
+        shape = (cfg.num_kv_layers, hkv, 1 + self.capacity, page,
                  cfg.head_dim)
         sharding = NamedSharding(engine.mesh,
                                  P(None, engine.axis, None, None, None))
         # zeros created IN the sharding: never whole on one device
         self.k = jnp.zeros(shape, dt, device=sharding)
         self.v = jnp.zeros(shape, dt, device=sharding)
+        # the delta-net blocks' per-slot state (module doc)
+        self.rec = self.conv = None
+        self.state_bytes_per_slot = 0
+        if cfg.is_hybrid:
+            from triton_dist_tpu.models import qwen3_next
+
+            rec, conv = qwen3_next.state_shapes(cfg, slots)
+            here = NamedSharding(engine.mesh, P())
+            self.rec = jnp.zeros(rec, jnp.float32, device=here)
+            self.conv = jnp.zeros(conv, dt, device=here)
+            self.state_bytes_per_slot = (
+                self.rec.nbytes + self.conv.nbytes) // slots
 
         self.table = np.zeros((slots, self.max_pages), np.int32)
         self.lengths = np.zeros((slots,), np.int32)
@@ -106,6 +130,28 @@ class KVPool:
         # external holds (the prefix cache); 0 <=> on the free list.
         self._refs = np.zeros((1 + self.capacity,), np.int32)
         self._ext: Dict[int, int] = {}  # page -> external hold count
+
+    @property
+    def state(self):
+        """Everything the serve step carries, as ONE pytree (the step's
+        `cache` argument and third result)."""
+        if self.rec is None:
+            return (self.k, self.v)
+        return (self.k, self.v, self.rec, self.conv)
+
+    @state.setter
+    def state(self, new) -> None:
+        if self.rec is None:
+            self.k, self.v = new
+        else:
+            self.k, self.v, self.rec, self.conv = new
+
+    def _pages_only(self, what: str) -> None:
+        if self.rec is not None:
+            raise NotImplementedError(
+                f"{what} moves pages, and this pool's slots also carry "
+                "recurrent (gated-delta-net) state that it has no way to "
+                "copy, share or ship")
 
     # -- queries --------------------------------------------------------
 
@@ -225,6 +271,7 @@ class KVPool:
         and the shared pages cover exactly [0, lengths) — a shared page
         is never written through this slot (cow() exists for callers
         that break that alignment)."""
+        self._pages_only("KVPool.share (the prefix cache)")
         assert self._pages[slot] is None, f"slot {slot} already in use"
         shared = [int(p) for p in shared]
         assert all(self._refs[p] >= 1 for p in shared), (
@@ -286,6 +333,7 @@ class KVPool:
         and drops this slot's hold on the shared original. Returns the
         (possibly new) page id; raises PoolExhausted when no page is
         free for the copy."""
+        self._pages_only("KVPool.cow")
         ps = self._pages[slot]
         assert ps is not None, f"slot {slot} is not admitted"
         assert 0 <= page_idx < len(ps)
@@ -347,6 +395,7 @@ class KVPool:
         (xslice/migrate.py). `n_tokens` trims to the pages covering the
         first n_tokens positions (default: all of the slot's pages).
         Pure gather; bitwise."""
+        self._pages_only("KVPool.export_pages (xslice migration)")
         ps = self._pages[slot]
         assert ps is not None, f"slot {slot} is not admitted"
         if n_tokens is not None:
@@ -364,6 +413,7 @@ class KVPool:
         handoff. Page COUNT must match the admit demand; lengths starts
         at n_tokens (the migrated history is live). All-or-nothing:
         raises PoolExhausted before touching device state."""
+        self._pages_only("KVPool.install (xslice migration)")
         need = max(pages_for(n_tokens, self.page), 1)
         assert k_pages.shape[2] == need and v_pages.shape[2] == need, (
             f"{n_tokens} tokens need {need} pages, image has "
@@ -392,6 +442,7 @@ class KVPool:
         allocator resumes at the pool high-water mark; note it will NOT
         see pages freed back to this pool's free list (export is a
         decode handoff, not shared ownership)."""
+        self._pages_only("KVPool.as_mega_cache (the megakernel bridge)")
         from triton_dist_tpu.mega.qwen3 import PagedMegaKVCache
 
         high = max((max(ps) for ps in self._pages if ps), default=0)

@@ -128,13 +128,35 @@ class Scheduler:
         # phases, always on. One a scheduler, like the registry;
         # obs.spans.default_log() is the newest scheduler's
         self.spans = new_default_log()
+        if engine.cfg.is_hybrid:
+            # what would have to copy, verify or ship a slot's
+            # recurrent state, and cannot yet (docs/serving.md)
+            for asked, what in (
+                    (prefix_cache, "prefix_cache: a cached prefix is "
+                     "pages, and a delta-net block's state after the "
+                     "prefix is kept nowhere"),
+                    (spec is not None and getattr(spec, "k", 0) > 0,
+                     "spec: a rejected draft would have to roll the "
+                     "recurrent state back, and the step keeps no "
+                     "per-column state"),
+                    (resident, "resident: the device-resident loop "
+                     "carries keys and values only"),
+                    (role != "both" or migrate_to is not None
+                     or admit_from is not None,
+                     "xslice migration (role / migrate_to / admit_from):"
+                     " the wire image holds pages only")):
+                if asked:
+                    raise NotImplementedError(
+                        f"a configuration with recurrent "
+                        f"(gated-delta-net) layers cannot be served with "
+                        f"{what}")
         self.pool = KVPool(engine, slots, page, max_pages=max_pages,
                            total_pages=total_pages)
         if chunk is None:
             from triton_dist_tpu.kernels.flash_prefill import (
                 flash_prefill_native_ok,
             )
-            from triton_dist_tpu.perf_model import choose_prefill_chunk
+            from triton_dist_tpu.perf_model import choose_chunk_for
 
             cfg = engine.cfg
             n = int(engine.mesh.shape[engine.axis])
@@ -145,14 +167,9 @@ class Scheduler:
                 "flash" if flash_prefill_native_ok(
                     cfg.num_q_heads // n, cfg.num_kv_heads // n,
                     cfg.head_dim) else "xla")
-            chunk = choose_prefill_chunk(
-                cfg.num_layers, cfg.hidden_size,
-                cfg.intermediate_size // n, cfg.num_q_heads // n,
-                cfg.num_kv_heads // n, cfg.head_dim,
-                cfg.vocab_size // n, slots=slots,
-                kv_tokens=self.pool.t_max, dtype=cfg.dtype,
-                attn_impl=attn_impl,
-            )
+            # from the family's own sizes (perf_model.choose_chunk_for)
+            chunk = choose_chunk_for(cfg, n, slots, self.pool.t_max,
+                                     attn_impl)
             chunk = max(1, min(chunk, self.pool.t_max))
         self.chunk = chunk
         # -- speculative decoding (ISSUE 14, triton_dist_tpu.spec): a
@@ -670,6 +687,24 @@ class Scheduler:
                      self.pool.live_tokens(p[0] for p in plans))
         self.obs.inc("serve_kv_tokens_gathered",
                      self.pool.dense_view_tokens())
+        per_slot = self.pool.state_bytes_per_slot
+        if per_slot:
+            # the hybrid family: the per-slot state beside the pages
+            # (what the step reads and writes is every slot's) and the
+            # expert layer's routing, counted by the step on the device
+            cfg = self.pool.engine.cfg
+            self.obs.inc("serve_state_bytes_live", per_slot * len(plans))
+            self.obs.inc("serve_state_bytes_moved",
+                         per_slot * self.pool.slots)
+            self.obs.inc("serve_state_resets", sum(
+                1 for slot, _r, n, _e, _d in plans
+                if int(self.pool.lengths[slot]) == n))
+            stats = self.worker.last_stats
+            self.obs.inc("moe_pairs", stats["moe_pairs_here"], held="here")
+            self.obs.inc("moe_pairs", stats["moe_pairs_absent"],
+                         held="absent")
+            self.obs.inc("moe_expert_steps",
+                         cfg.num_layers * cfg.num_experts_held)
 
     def _attempt_with_backoff(self, retry_span, body, on_fault=None):
         """The shared half of the degradation ladder: run `body` with

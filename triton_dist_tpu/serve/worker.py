@@ -111,6 +111,9 @@ class Worker:
                                           pool.max_pages,
                                           per_pos=per_pos)
         self.n_steps = 0
+        # what the newest step counted on the device (the step's
+        # `stats` result as host ints; {} for the dense family)
+        self.last_stats: dict = {}
         # the scheduler hands its log over; a worker driven alone
         # keeps one of its own
         self.spans = spans if spans is not None else SpanLog()
@@ -181,15 +184,17 @@ class Worker:
             temps = jnp.asarray(temps, jnp.float32)
             keys = jnp.asarray(keys, jnp.uint32)
         with self.spans.span("worker.launch", step=step):
-            tok, _logits, pool.k, pool.v = self._fn(
-                self.engine.params, tokens, pool.k, pool.v, table,
+            tok, _logits, pool.state, self._stats = self._fn(
+                self.engine.params, tokens, pool.state, table,
                 lengths, n_valid, temps, keys)
         return tok
 
     def _wait(self, step: int, tok) -> np.ndarray:
         """The device's step and the readback (`worker.wait`)."""
         with self.spans.span("worker.wait", step=step):
-            return np.asarray(tok)
+            tok = np.asarray(tok)
+            self.last_stats = {k: int(v) for k, v in self._stats.items()}
+            return tok
 
     def advance_lengths(self, advance: np.ndarray) -> None:
         """Fold a step_spec's per-slot length advance into the pool
