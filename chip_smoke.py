@@ -196,7 +196,10 @@ def serve_phase(eng, prompts, want_kernels):
     served = [list(r.out_tokens) for r in reqs]
 
     # finding, not a gate: docs/serving.md promises each request's
-    # tokens are bitwise those of the same Engine decoding it alone
+    # tokens are bitwise those of the same Engine decoding it alone AT
+    # THE SAME STEP WIDTHS; alone a request decodes through the narrow
+    # step, batched it may ride a wide one beside a prefilling slot,
+    # and then the streams are equal to rounding only
     del sch, pool, w, reqs
     alone_sch = Scheduler(eng, slots=SLOTS)
     alone = []

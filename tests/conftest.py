@@ -65,3 +65,16 @@ def mesh2d():
 @pytest.fixture(autouse=True)
 def _seed():
     np.random.seed(0)
+
+
+@pytest.fixture(params=["wide", "per-step"])
+def step_widths(request, monkeypatch):
+    """Run a serve test under both statements of its invariance
+    (tests/_widths.py): every scheduler held to the one wide step
+    (bitwise), and the program's own width a step (equal tokens on
+    float32 sizes, logits to a tolerance)."""
+    if request.param == "wide":
+        from _widths import hold_to_the_wide_step
+
+        hold_to_the_wide_step(monkeypatch)
+    return request.param

@@ -143,25 +143,55 @@ def test_engine_decode_one_chip(chip):
     assert _kernels(compiled) == {}
 
 
-def test_serve_step_one_chip(chip):
-    """The Scheduler's step at its default geometry for this model
-    (4 slots x the chooser's 128-token chunk, 64-token pages)."""
-    eng = _engine(chip, 1)
-    slots, chunk, page = 4, 128, 64
+def _serve_step_compiled(eng, slots, width, page=64):
+    """The dense family's serve step at (slots, width), compiled."""
     max_pages = MAX_LEN // page
     mesh, cfg = eng.mesh, eng.cfg
     rep = NamedSharding(mesh, P())
     pool = SDS((cfg.num_layers, cfg.num_kv_heads, 1 + slots * max_pages,
                 page, cfg.head_dim), BF16,
                sharding=NamedSharding(mesh, P(None, "tp")))
-    compiled = eng.make_serve_step(slots, chunk, page, max_pages).lower(
-        eng.params, SDS((slots, chunk), jnp.int32, sharding=rep),
+    return eng.make_serve_step(slots, width, page, max_pages).lower(
+        eng.params, SDS((slots, width), jnp.int32, sharding=rep),
         (pool, pool), SDS((slots, max_pages), jnp.int32, sharding=rep),
         SDS((slots,), jnp.int32, sharding=rep),
         SDS((slots,), jnp.int32, sharding=rep),
         SDS((slots,), jnp.float32, sharding=rep),
         SDS((slots, 2), jnp.uint32, sharding=rep)).compile()
+
+
+def test_serve_step_one_chip(chip):
+    """The Scheduler's step at its default geometry for this model
+    (4 slots x the chooser's 128-token chunk, 64-token pages)."""
+    compiled = _serve_step_compiled(_engine(chip, 1), 4, 128)
     assert _kernels(compiled) == {"_fp_local_kernel": 1}
+
+
+@pytest.mark.parametrize("tp, kernels, xla_collectives", [
+    (1, {}, {}),
+    (4, {"_one_shot_ar_kernel": 2}, {"all-gather": 1}),
+], ids=["qwen3-8b.1chip", "qwen3-8b.tp4"])
+def test_decode_only_serve_step(chip, tp, kernels, xla_collectives):
+    """The width-1 step of the benchmark's two dense configurations
+    (8 slots, 64-token pages; `Engine.serve_widths`): it compiles for
+    the chip, and what it holds is listed. One chip: XLA matmuls and
+    the dense attention chain over one query row, no kernel of ours
+    (the prefill routes are behind `s > 1`). tp=4: the `ar` lowering
+    at 8 rows is one `_one_shot_ar_kernel` behind each row-parallel
+    projection (two in the layer scan's body, 72 calls a step at depth
+    36) and XLA's all-gather of the logits — none of the wide step's
+    `_gemm_rs_kernel*` / `_ring_ag_kernel`."""
+    import re
+    from collections import Counter
+
+    eng = _engine(chip, tp)
+    assert eng.serve_widths(128) == (1, 128)
+    compiled = _serve_step_compiled(eng, 8, 1)
+    assert _kernels(compiled) == kernels
+    found = Counter(re.findall(
+        r"= \S+ (all-reduce|all-gather|reduce-scatter|collective-permute"
+        r"|all-to-all)[-\w]*\(", compiled.as_text()))
+    assert dict(found) == xla_collectives
 
 
 def test_hybrid_serve_step_one_chip(chip):
@@ -213,6 +243,22 @@ def test_gated_attention_has_no_silent_route_on_the_chip(chip):
                                  "bfloat16") == "pallas"
     with pytest.raises(NotImplementedError, match="no other route"):
         route_gated_attention(8, 128, 8192, 16, 2, 96, "bfloat16")
+
+
+def test_hybrid_family_keeps_the_chunk_s_width_on_the_chip(chip):
+    """One query row never reaches `_fp_local_kernel` (gqa_attention's
+    `s > 1` guard), so a width-1 step of the hybrid family would hold
+    the dense chain its route refuses: the route says so, and the
+    engine gives the family the one width."""
+    from triton_dist_tpu.plan.planner import route_gated_attention
+
+    with pytest.raises(NotImplementedError, match="one query row"):
+        route_gated_attention(8, 1, 8192, 16, 2, 256, "bfloat16")
+    cfg = ModelConfig.qwen3_next_80b(
+        num_layers=4, experts_held=128, vocab_size=37_984,
+        max_positions=8192)
+    eng = Engine(cfg, _mesh(chip, 1), params={}, max_len=8192)
+    assert eng.serve_widths(128) == (128,)
 
 
 def test_mega_decode_step_one_chip(chip):

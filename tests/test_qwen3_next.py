@@ -264,6 +264,23 @@ def test_the_pool_holds_pages_for_the_attention_blocks_only(eng, cfg):
     assert pool.conv.shape == (3, GEO["slots"], 3, 2 * 2 * 16 + 4 * 16)
 
 
+def test_the_family_keeps_the_chunk_s_width(eng, prompts):
+    """The dense family's worker holds a decode-only step beside the
+    wide one; this family's set is the chunk's width alone (its
+    attention route has no form for one query row on the chip:
+    Engine.serve_widths), so every step, decode rows alone too, is the
+    one `(slots, chunk)` program and counts as wide."""
+    assert eng.serve_widths(GEO["chunk"]) == (GEO["chunk"],)
+    sch = Scheduler(eng, **GEO)
+    assert sch.worker.widths == (GEO["chunk"],) and not sch.worker._narrow
+    sch.submit(prompts[0], max_new_tokens=3)
+    sch.run()
+    assert {h["width"] for h in sch.history} == {GEO["chunk"]}
+    c = sch.obs.snapshot()["counters"]
+    assert c["serve_steps{shape=wide}"] == sch.worker.n_steps
+    assert "serve_steps{shape=narrow}" not in c
+
+
 def test_chunk_is_priced_from_the_family_s_sizes(mesh1, monkeypatch):
     """The default chunk comes from `estimate_hybrid_step_ms` over the
     configuration itself, not from the dense formula at

@@ -1,14 +1,22 @@
 """Serving-plane tests: continuous batching over the serve step.
 
-The load-bearing property (ISSUE 6 acceptance): per-request outputs are
-BIT-IDENTICAL (temperature 0, and — via per-(seed, index) keys — at
-temperature > 0 too) between the continuous-batching scheduler and
-sequential `Engine.serve(..., slots=, chunk=)` runs of the same step
-geometry, including across an eviction/requeue. The serve step's fixed
-(slots, chunk) shape makes each row's numerics independent of batch
-composition, slot placement, and chunk alignment — these tests pin that
-end to end, plus the KVPool allocator invariants, queue policies,
-streaming, the megakernel paged-decode bridge, and the step roofline.
+The load-bearing property (ISSUE 6 acceptance, restated by ISSUE 31):
+per-request outputs are the same (temperature 0, and — via per-(seed,
+index) keys — at temperature > 0 too) between the continuous-batching
+scheduler and sequential `Engine.serve(..., slots=, chunk=)` runs,
+including across an eviction/requeue. At ONE step width a row's
+numerics are independent of batch composition, slot placement, and
+chunk alignment, so a scheduler held to the `(slots, chunk)` step is
+BIT-IDENTICAL, tokens and logits, to the sequential runs. With the
+worker's two widths (a step of decode rows alone runs the `(slots, 1)`
+program) a token is bitwise a function of its request's history and of
+the widths of the steps that computed it: across width sequences the
+tokens are equal on these float32 sizes and the logits agree to a
+tolerance (tests/_widths.py; the `step_widths` fixture runs a test
+under both statements). These tests pin that end to end, plus the
+choice of a step's width and its dispatch, the KVPool allocator
+invariants, queue policies, streaming, the megakernel paged-decode
+bridge, and the step roofline.
 """
 
 import numpy as np
@@ -36,6 +44,8 @@ from triton_dist_tpu.serve.worker import (
     sampling_key,
     sampling_keys,
 )
+
+from _widths import ACROSS_WIDTHS_ATOL, record_logits
 
 GEO = dict(slots=3, chunk=4, page=8)  # one compiled step for the module
 
@@ -163,7 +173,23 @@ def test_queue_cancel_and_requeue_order():
 # ---------- continuous batching: bit-identity ----------
 
 
-def test_batched_bit_identical_to_sequential(eng1, prompts):
+def _served_with_logits(eng, prompts, gen, together: bool):
+    """(tokens, logits) a request, in the prompts' order: through one
+    scheduler together, or each through a scheduler of its own (what
+    `_sequential` does through Engine.serve)."""
+    toks, logits = [], []
+    for group in ([prompts] if together else [[p] for p in prompts]):
+        sch = Scheduler(eng, **GEO)
+        logits_of = record_logits(sch)
+        reqs = [sch.submit(p, max_new_tokens=gen) for p in group]
+        sch.run()
+        got = logits_of({r.request_id: len(r.prompt) for r in reqs})
+        toks += [r.out_tokens for r in reqs]
+        logits += [got[r.request_id] for r in reqs]
+    return toks, logits
+
+
+def test_batched_bit_identical_to_sequential(eng1, prompts, step_widths):
     sch = Scheduler(eng1, **GEO)
     reqs = [sch.submit(p, max_new_tokens=6) for p in prompts]
     sch.run()
@@ -171,12 +197,69 @@ def test_batched_bit_identical_to_sequential(eng1, prompts):
     assert all(r.finish_reason == "length" for r in reqs)
     sch.pool.check()
     assert sch.pool.used_pages() == 0  # free-on-finish
+    shapes = sch.obs.snapshot()["counters"]
+    if step_widths == "wide":
+        assert "serve_steps{shape=narrow}" not in shapes
+    else:  # batched, decode rows rode both programs
+        assert shapes["serve_steps{shape=narrow}"] > 0
+        assert shapes["serve_steps{shape=wide}"] > 0
 
 
-def test_eviction_requeue_bit_identical(eng1, prompts):
+def test_batched_logits_against_sequential(eng1, prompts, step_widths):
+    """The logits behind the tokens: bitwise at one width sequence
+    (every step the wide one), to ACROSS_WIDTHS_ATOL where a request
+    alone decodes through the narrow step and, batched, beside a
+    prefilling slot through the wide one."""
+    toks, batched = _served_with_logits(eng1, prompts, 6, together=True)
+    toks_alone, alone = _served_with_logits(eng1, prompts, 6,
+                                            together=False)
+    assert toks == toks_alone
+    for b, a in zip(batched, alone):
+        assert b.shape == a.shape == (6, eng1.cfg.vocab_size)
+        if step_widths == "wide":
+            np.testing.assert_array_equal(b, a)
+        else:
+            np.testing.assert_allclose(b, a, atol=ACROSS_WIDTHS_ATOL,
+                                       rtol=0)
+
+
+def test_a_decode_step_agrees_across_widths(eng1, prompts):
+    """The same pool state and the same decode rows through both
+    compiled steps: the same first choice, logits within the
+    tolerance, and the pool's K/V rows written bit for bit alike (a
+    projection row does not depend on the other rows' count in
+    float32; the cell's bf16 reading is in docs/serving.md)."""
+    sch = Scheduler(eng1, **GEO)
+    reqs = [sch.submit(p, max_new_tokens=4) for p in prompts]
+    while any(r.state is not RequestState.DECODE for r in reqs):
+        sch.step()
+    tokens, n_valid, temps, keys, plans = sch._assemble(sch.worker.n_steps)
+    assert tokens.shape == (GEO["slots"], 1) and len(plans) == 3
+    w, pool = sch.worker, sch.pool
+    wide = np.zeros((GEO["slots"], GEO["chunk"]), np.int32)
+    wide[:, :1] = tokens
+    args = (jnp.asarray(pool.table), jnp.asarray(pool.lengths),
+            jnp.asarray(n_valid), jnp.asarray(temps), jnp.asarray(keys))
+    tok_n, last_n, state_n, _ = w._narrow[1](
+        eng1.params, jnp.asarray(tokens), pool.state, *args)
+    tok_w, last_w, state_w, _ = w._fn(
+        eng1.params, jnp.asarray(wide), pool.state, *args)
+    np.testing.assert_array_equal(np.asarray(tok_n), np.asarray(tok_w))
+    np.testing.assert_allclose(np.asarray(last_n), np.asarray(last_w),
+                               atol=ACROSS_WIDTHS_ATOL, rtol=0)
+    assert float(np.abs(np.asarray(last_w)).max()) > 0.1  # not all zero
+    # page 0 is the null page: the wide step's padding columns land there
+    for got, want in zip(state_n, state_w):
+        np.testing.assert_array_equal(np.asarray(got)[:, :, 1:],
+                                      np.asarray(want)[:, :, 1:])
+
+
+def test_eviction_requeue_bit_identical(eng1, prompts, step_widths):
     # 4 allocatable pages for three requests growing to 3 pages each:
     # mid-flight growth must evict younger slots, which requeue and
-    # re-prefill their full history
+    # re-prefill their full history (through the wide step, whatever
+    # width computed the token the first time: "wide" is bitwise,
+    # "per-step" equal tokens on these float32 sizes)
     sch = Scheduler(eng1, total_pages=4, **GEO)
     reqs = [sch.submit(p, max_new_tokens=12) for p in prompts]
     sch.run()
@@ -187,7 +270,8 @@ def test_eviction_requeue_bit_identical(eng1, prompts):
     sch.pool.check()
 
 
-def test_sampled_generation_scheduling_invariant(eng1, prompts):
+def test_sampled_generation_scheduling_invariant(eng1, prompts,
+                                                 step_widths):
     def run(total_pages):
         sch = Scheduler(eng1, total_pages=total_pages, **GEO)
         reqs = [sch.submit(p, max_new_tokens=8, temperature=0.9,
@@ -201,6 +285,146 @@ def test_sampled_generation_scheduling_invariant(eng1, prompts):
     assert constrained == relaxed
     # distinct seeds actually diverge (the keys are per-request)
     assert len({tuple(t) for t in relaxed}) > 1
+
+
+# ---------- a step's width: the choice and the dispatch (ISSUE 31) ----------
+
+
+def _decoding(eng, prompts, **kw):
+    """A scheduler whose requests have all reached DECODE."""
+    sch = Scheduler(eng, **{**GEO, **kw})
+    reqs = [sch.submit(p, max_new_tokens=8) for p in prompts]
+    while any(r.state is not RequestState.DECODE for r in reqs):
+        assert sch.step()
+    return sch, reqs
+
+
+def test_worker_holds_one_compiled_step_a_width(eng1):
+    sch = Scheduler(eng1, **GEO)
+    w = sch.worker
+    assert w.widths == eng1.serve_widths(GEO["chunk"]) == (1, GEO["chunk"])
+    max_pages = sch.pool.max_pages
+    assert w._fn is eng1.make_serve_step(3, 4, 8, max_pages)  # the widest
+    assert w._narrow == {1: eng1.make_serve_step(3, 1, 8, max_pages)}
+    assert eng1.serve_widths(1) == (1,)  # nothing narrower to hold
+
+
+def test_rows_of_one_token_or_none_take_the_narrow_step(eng1, prompts):
+    """Decode rows, an empty slot, and a one-token prefill tail."""
+    sch, reqs = _decoding(eng1, prompts[:2])
+    tokens, n_valid, *_ = sch._assemble(sch.worker.n_steps)
+    assert tokens.shape == (3, 1) and list(n_valid) == [1, 1, 0]
+    assert [int(t) for t in tokens[:2, 0]] == [r.out_tokens[-1]
+                                              for r in reqs]
+    # chunk + 1 tokens: the tail of the prompt is one row
+    tail = sch.submit(prompts[2][:GEO["chunk"] + 1], max_new_tokens=2)
+    sch.step()
+    assert tail.state is RequestState.PREFILL and tail.pos == GEO["chunk"]
+    tokens, n_valid, _t, _k, plans = sch._assemble(sch.worker.n_steps)
+    assert tokens.shape == (3, 1) and list(n_valid) == [1, 1, 1]
+    assert plans[2][1] is tail and plans[2][3]  # it emits
+    assert int(tokens[2, 0]) == tail.prompt[-1]
+
+
+def test_a_prefill_row_of_two_tokens_takes_the_wide_step(eng1, prompts):
+    sch, _reqs = _decoding(eng1, prompts[:2])
+    new = sch.submit(prompts[2][:2], max_new_tokens=2)
+    sch._admit()
+    tokens, n_valid, *_ = sch._assemble(sch.worker.n_steps)
+    assert tokens.shape == (3, GEO["chunk"]) and list(n_valid) == [1, 1, 2]
+    assert list(tokens[2]) == new.prompt + [0, 0]
+    assert not tokens[:2, 1:].any()
+
+
+def test_a_verify_row_takes_the_wide_step(eng1, prompts):
+    """Speculative decoding: a decode row with drafts is 1 + drafts
+    tokens; without a draft it is one row again."""
+    from triton_dist_tpu.spec import NgramDraft, SpecConfig
+
+    spec = SpecConfig(k=2, draft=NgramDraft())
+    sch, reqs = _decoding(eng1, prompts[:1], spec=spec)
+    assert sch.worker.widths == (1, GEO["chunk"])
+    req = reqs[0]
+    spec.draft.propose = lambda hist, cap: [7, 9][:cap]
+    tokens, n_valid, _t, keys, plans = sch._assemble(sch.worker.n_steps)
+    assert tokens.shape == (3, GEO["chunk"]) and n_valid[0] == 3
+    assert list(tokens[0, :3]) == [req.out_tokens[-1], 7, 9]
+    assert keys.shape == (3, GEO["chunk"], 2) and plans[0][4] == [7, 9]
+    spec.draft.propose = lambda hist, cap: []
+    tokens, n_valid, _t, keys, plans = sch._assemble(sch.worker.n_steps)
+    assert tokens.shape == (3, 1) and keys.shape == (3, 1, 2)
+    assert n_valid[0] == 1 and keys[0, 0].any()
+
+
+def test_an_evicted_row_is_scrubbed_before_the_width_is_taken(
+        eng1, prompts, monkeypatch):
+    """A later slot's page demand evicts an earlier slot whose prefill
+    chunk was already planned: the step that is left holds one decode
+    row, and runs narrow."""
+    sch = Scheduler(eng1, **GEO)
+    first = sch.submit(prompts[2][:2], max_new_tokens=1)  # frees slot 0
+    keeper = sch.submit(prompts[1], max_new_tokens=8)
+    while keeper.state is not RequestState.DECODE:
+        assert sch.step()
+    assert first.done
+    victim = sch.submit(prompts[0], max_new_tokens=4)
+    sch._admit()
+    assert sch.active[0] is victim and sch.active[1] is keeper
+    tokens, n_valid, *_ = sch._assemble(sch.worker.n_steps)
+    assert tokens.shape == (3, GEO["chunk"])
+    assert list(n_valid) == [GEO["chunk"], 1, 0]
+    room = sch._room
+
+    def evicting_room(slot, req, upto):
+        if req is keeper:
+            sch._evict(victim)
+        return room(slot, req, upto)
+
+    monkeypatch.setattr(sch, "_room", evicting_room)
+    tokens, n_valid, _t, _k, plans = sch._assemble(sch.worker.n_steps)
+    assert victim.n_evictions == 1 and [p[1] for p in plans] == [keeper]
+    assert tokens.shape == (3, 1) and list(n_valid) == [0, 1, 0]
+    assert int(tokens[1, 0]) == keeper.out_tokens[-1]
+
+
+def test_a_wrapper_on_fn_sees_the_first_wide_call(eng1, prompts):
+    """perfbench's StepListing contract: `Worker._fn` is the widest
+    step, looked up at call time, so a wrapper set on the attribute
+    (after construction) is what the first wide step calls; the narrow
+    steps do not pass through it."""
+    sch = Scheduler(eng1, **GEO)
+    fn, calls = sch.worker._fn, []
+
+    def first_call(*a):
+        sch.worker._fn = fn
+        calls.append(a[1].shape)
+        return fn(*a)
+
+    sch.worker._fn = first_call
+    req = sch.submit(prompts[0], max_new_tokens=4)
+    sch.run()
+    assert calls == [(GEO["slots"], GEO["chunk"])]
+    assert sch.worker._fn is fn
+    assert req.out_tokens == _sequential(eng1, prompts[:1], 4)[0]
+
+
+def test_the_step_counter_names_each_step_s_shape(eng1, prompts):
+    sch = Scheduler(eng1, **GEO)
+    reqs = [sch.submit(p, max_new_tokens=5) for p in prompts]
+    sch.run()
+    shapes = sch.obs.snapshot()["counters"]
+    narrow = shapes["serve_steps{shape=narrow}"]
+    wide = shapes["serve_steps{shape=wide}"]
+    assert narrow + wide == sch.worker.n_steps == len(sch.history)
+    widths = [h["width"] for h in sch.history]
+    assert widths.count(1) == narrow and widths.count(GEO["chunk"]) == wide
+    for h in sch.history:  # the narrowest width that holds the rows
+        longest = max(n for _rid, _state, n in h["slots"].values())
+        assert h["width"] == (1 if longest <= 1 else GEO["chunk"])
+    # prompts of 12, 10 and 9 tokens at chunk 4: three wide steps, then
+    # the 9's one-token tail beside two decode rows and decode alone
+    assert widths[:3] == [4, 4, 4] and set(widths[3:]) == {1}
+    assert all(len(r.out_tokens) == 5 for r in reqs)
 
 
 # ---------- the host's key derivation (ISSUE 28) ----------

@@ -2,11 +2,17 @@
 
 The load-bearing property: per-request tokens from the device-resident
 step loop (work injected through mega.ring, up to `window` steps per
-dispatch, decode self-fed on device) are BIT-IDENTICAL to the host-loop
-scheduler — greedy and sampled, across admissions and retirements that
-land mid-loop. Both paths compile the same `_serve_step_math`, and
-`mega.ring.slot_plan` reproduces the host scheduler's per-step inputs
-field for field; these tests pin that end to end, plus the ring's
+dispatch, decode self-fed on device) are BIT-IDENTICAL to a host-loop
+scheduler at the same step width — greedy and sampled, across
+admissions and retirements that land mid-loop. Both paths compile the
+same `_serve_step_math`, and `mega.ring.slot_plan` reproduces the host
+scheduler's per-step inputs field for field. The resident loop keeps
+its ONE `(slots, chunk)` geometry; the host loop picks a width a step
+(ISSUE 31). So the pin has two statements (the `step_widths` fixture,
+tests/_widths.py): against a host loop held to the wide step, bitwise;
+against the host loop's own choice, where decode rows alone run the
+`(slots, 1)` step, equal tokens on these float32 sizes. These tests
+pin that end to end, plus the ring's
 visibility/watchdog contract (an abandoned ring trips, never hangs,
 never eats tokens), the KVPool↔mega-cache bridge under allocator churn,
 and the resident perf model/bench schema.
@@ -137,7 +143,7 @@ def test_device_key_stream_matches_worker(eng1):
 
 
 def test_resident_bit_identical_greedy_with_midloop_retirement(
-        eng1, prompts):
+        eng1, prompts, step_widths):
     """3 staggered requests, one cancelled mid-loop: every request's
     tokens (including the cancelled one's emitted prefix) are bitwise
     the host-loop scheduler's."""
@@ -158,7 +164,7 @@ def test_resident_bit_identical_greedy_with_midloop_retirement(
     assert sch.pool.used_pages() == 0
 
 
-def test_resident_bit_identical_sampled(eng1, prompts):
+def test_resident_bit_identical_sampled(eng1, prompts, step_widths):
     host = _host_tokens(eng1, prompts, 6, temperature=0.9,
                         seed=[51, 52, 53])
     sch = Scheduler(eng1, resident=True, window=8, **GEO)
@@ -198,7 +204,7 @@ def test_resident_staggered_admission_inside_window(eng1, prompts):
     assert first_step[1] >= 6 > first_step[0]
 
 
-def test_resident_matches_engine_serve_oracle(eng1, prompts):
+def test_resident_matches_engine_serve_oracle(eng1, prompts, step_widths):
     """Transitivity spot-check against the ORIGINAL sequential oracle
     (Engine.serve stepwise), not just the host-loop scheduler."""
     sch = Scheduler(eng1, resident=True, window=8, **GEO)

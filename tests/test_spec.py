@@ -1,11 +1,17 @@
 """Speculative decoding on the serve plane (ISSUE 14).
 
 The load-bearing property: with spec ON, every request's token stream
-is BITWISE equal to the spec-OFF (and sequential) run — greedy and
+is equal to the spec-OFF (and sequential) run — greedy and
 sampled, host loop and resident — because the per-position verify step
 samples each column under the per-(seed, token-index) key the
 sequential path would use, so the longest-accepted-prefix rule only
-ever emits the model's own tokens. Around it: the n-gram draft units,
+ever emits the model's own tokens. BITWISE where both runs compute
+every token at one step width (the `step_widths` fixture's "wide": a
+verify row always takes the `(slots, chunk)` step, and the spec-off
+run is held to it too). With the worker's own choice a spec-off decode
+row runs the `(slots, 1)` step, so the two runs differ in the widths
+that computed a token: equal tokens on these float32 sizes, logits to
+a tolerance (ISSUE 31, tests/_widths.py). Around it: the n-gram draft units,
 the accept rule, the k chooser/pruner, the FailStep-during-verify
 chaos cell (no double emission), metrics, and the bench schema.
 
@@ -125,8 +131,9 @@ def test_accept_tokens_eos_and_budget_cuts():
 # ---------- bit-identity (the acceptance oracle) ----------
 
 
-def test_spec_bitwise_greedy_and_saves_steps(eng1, prompts, baseline):
-    base, base_steps = baseline
+def test_spec_bitwise_greedy_and_saves_steps(eng1, prompts, baseline,
+                                             step_widths):
+    base, base_steps = baseline  # its tokens are those of either width
     sch = Scheduler(eng1, spec=_spec(), **GEO)
     reqs = [sch.submit(p, max_new_tokens=GEN) for p in prompts]
     sch.run()
@@ -141,7 +148,7 @@ def test_spec_bitwise_greedy_and_saves_steps(eng1, prompts, baseline):
     sch.pool.check()
 
 
-def test_spec_bitwise_sampled(eng1, prompts):
+def test_spec_bitwise_sampled(eng1, prompts, step_widths):
     def run(spec):
         sch = Scheduler(eng1, spec=spec, **GEO)
         reqs = [sch.submit(p, max_new_tokens=GEN, temperature=0.9,
@@ -346,11 +353,12 @@ def _fold_in_key(seed, index):
     return np.asarray(jax.random.fold_in(jax.random.PRNGKey(seed), index))
 
 
-def _per_row_keys(sch, plans):
+def _per_row_keys(sch, plans, width):
     """The step's keys as the host loop drew them before ISSUE 28: one
-    eager fold_in an emitted token."""
+    eager fold_in an emitted token (with spec on, a key a column of the
+    step's own width)."""
     spec_on = sch.spec is not None
-    keys = np.zeros((sch.pool.slots, sch.chunk, 2) if spec_on
+    keys = np.zeros((sch.pool.slots, width, 2) if spec_on
                     else (sch.pool.slots, 2), np.uint32)
     for slot, req, n, emits, drafts in plans:
         if not emits:
@@ -399,13 +407,14 @@ def test_assemble_draws_keys_without_jax(eng1, prompts, monkeypatch,
         if not sch.active:
             continue
         step_idx = sch.worker.n_steps
-        *_, want, plans = sch._assemble(step_idx)
+        tokens, *_, want, plans = sch._assemble(step_idx)
         with monkeypatch.context() as patch:
             _forbid_jax_keys(patch)
             *_, got, plans_again = sch._assemble(step_idx)
         assert [p[:4] for p in plans_again] == [p[:4] for p in plans]
         np.testing.assert_array_equal(got, want)
-        np.testing.assert_array_equal(got, _per_row_keys(sch, plans))
+        np.testing.assert_array_equal(
+            got, _per_row_keys(sch, plans, tokens.shape[1]))
         assert got.any()
         widest = max([widest] + [len(p[4]) for p in plans])
     assert (widest > 0) == spec_on, "no verify row carried a draft"

@@ -49,12 +49,17 @@ def _serve_step_math(cfg, mode, axis, slots, chunk, page, t_pool,
                      n_valid, temps, keys, per_pos: bool = False,
                      plan=None):
     """THE per-rank serve-step computation (inside shard_map): one
-    fixed-geometry (slots, chunk) forward over the paged pool's dense
-    view, per-slot sampling, and the null-page-routed KV scatter.
-    Shared VERBATIM between `make_serve_step` (the host-loop replay)
-    and `make_resident_loop` (the device-resident window) — the serve
-    plane's bit-identity discipline extends to the resident loop
-    because both compile exactly this function on identical inputs
+    (slots, chunk) forward over the paged pool's dense view, per-slot
+    sampling, and the null-page-routed KV scatter. Generic in `chunk`:
+    the host loop compiles it once a width of `Engine.serve_widths`
+    (the decode-only step is `chunk == 1`: one query row a slot
+    through the dense attention chain, no prefill route). Shared
+    VERBATIM between `make_serve_step` (the host-loop replay) and
+    `make_resident_loop` (the device-resident window, which keeps the
+    one wide geometry) — the serve plane's bit-identity discipline
+    extends to the resident loop because both compile exactly this
+    function on identical inputs: the resident loop's tokens are
+    bitwise those of a host loop held to the wide step
     (tests/test_serve_resident.py pins the loop-vs-standalone bitwise
     equality end to end).
 
@@ -314,19 +319,33 @@ class Engine:
         the captured decode graph, mega_triton_kernel/test/models/
         model_server.py).
 
-        Geometry is FIXED at (slots, chunk): every step runs the model
-        over a (slots, chunk) token block in `decode_mode`, whatever
-        mixture of prefill chunks and single-token decode steps the
-        scheduler packed into it. A slot's row carries `n_valid` real
-        tokens (prefill: up to `chunk` prompt tokens; decode: 1;
-        inactive: 0) starting at its current sequence length; the rest
-        of the row is padding whose outputs are discarded and whose KV
-        writes are routed to the pool's reserved null page. Because the
-        geometry never changes and XLA's row numerics are independent
+        Geometry is FIXED at (slots, chunk) for ONE returned function:
+        every call runs the model over a (slots, chunk) token block in
+        `decode_mode`, whatever mixture of prefill chunks and
+        single-token decode steps the scheduler packed into it. A
+        slot's row carries `n_valid` real tokens (prefill: up to
+        `chunk` prompt tokens; decode: 1; inactive: 0) starting at its
+        current sequence length; the rest of the row is padding whose
+        outputs are discarded and whose KV writes are routed to the
+        pool's reserved null page. XLA's row numerics are independent
         of the CONTENT and COLUMN PLACEMENT of other rows (only of the
-        operand shapes), each request's tokens are bitwise invariant to
-        batch composition, slot placement, chunk alignment, and
-        eviction/re-prefill — the property tests/test_serve.py pins.
+        operand shapes), so AT ONE WIDTH each request's tokens are
+        bitwise invariant to batch composition, slot placement, chunk
+        alignment, and eviction/re-prefill — the property
+        tests/test_serve.py pins.
+
+        The serve plane holds one such function a width of
+        `serve_widths(chunk)` and picks a step's width from what the
+        step holds (serve.Worker, Scheduler._assemble). A token is
+        then bitwise a function of its request's history AND of the
+        width of the step that computed it, and which width that was
+        depends on what the other slots were doing. What holds: (a) at
+        a fixed sequence of widths, bitwise as before; (b) across
+        widths the same `forward` in the same precision (bf16 with
+        float32 logits), so the same logits to the tolerance that
+        separates two correct bf16 formulations (docs/serving.md gives
+        the number), and on float32 sizes the same tokens; (c) the
+        sampling keys are the same (request seed and output index).
 
         Signature of the returned callable:
           fn(params, tokens (K, C) i32, cache, table (K, MAXP) i32,
@@ -371,6 +390,29 @@ class Engine:
                 self._serve_cache.pop(next(iter(self._serve_cache)))
         self._serve_cache[key] = fn  # re-insert = LRU touch
         return fn
+
+    def serve_widths(self, chunk: int) -> tuple:
+        """The closed set of step widths the serve plane compiles for
+        a scheduler whose prefill width is `chunk`: ascending, the
+        last one `chunk` itself. `(1, chunk)` — a decode-only step
+        beside the mixed one — for every family whose step math is
+        generic in the width; a step takes the narrowest width that
+        holds its longest row (serve.Scheduler._assemble), so a step
+        of decode rows alone streams the weights for `slots` rows and
+        not for `slots x chunk`. Fixed when the worker is built, by
+        what this engine can compile: no option and no environment
+        variable reaches it.
+
+        The hybrid family keeps the one width `(chunk,)`: its full
+        attention blocks are `_fp_local_kernel` or an error
+        (`plan.planner.route_gated_attention`), and one query row
+        never reaches that kernel (`layers.attention.gqa_attention`
+        takes a single row through the dense chain), so a width-1 step
+        would compile, in silence, the route the family refuses
+        (docs/serving.md "The hybrid family")."""
+        if chunk <= 1 or self.cfg.is_hybrid:
+            return (chunk,)
+        return (1, chunk)
 
     def _build_serve_step(self, slots: int, chunk: int, page: int,
                           max_pages: int, per_pos: bool = False):

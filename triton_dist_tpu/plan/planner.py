@@ -693,11 +693,12 @@ def route_gated_attention(b: int, s: int, t: int, hq: int, hkv: int,
     attention blocks (models/qwen3_next.py), decided once when its
     serve step is built and never left to fall through. On the chip
     that is the Pallas flash-prefill kernel ("pallas") or an error: a
-    head shape the kernel does not take, or per-grid-step residents
-    over the VMEM ceiling, would otherwise compile a dense (S, T)
-    float32 logits chain in silence. Under the interpreter (the CPU
-    mesh of the tests) it is the XLA formulation, as for every auto
-    route (`flash_prefill_native_ok`)."""
+    head shape the kernel does not take, per-grid-step residents
+    over the VMEM ceiling, or a step of one query row (which
+    `gqa_attention` never hands to the kernel) would otherwise compile
+    a dense (S, T) float32 logits chain in silence. Under the
+    interpreter (the CPU mesh of the tests) it is the XLA formulation,
+    as for every auto route (`flash_prefill_native_ok`)."""
     from triton_dist_tpu.kernels.flash_prefill import (
         flash_prefill_fits,
         supports_flash_prefill,
@@ -706,6 +707,12 @@ def route_gated_attention(b: int, s: int, t: int, hq: int, hkv: int,
 
     if use_interpret():
         return "xla"
+    if s < 2:
+        raise NotImplementedError(
+            "one query row never reaches flash-prefill (gqa_attention "
+            "takes it through the dense chain), and the gated attention "
+            "blocks have no other route on the chip: the family's serve "
+            "step keeps the chunk's width (Engine.serve_widths)")
     if not supports_flash_prefill(hq, hkv, d):
         raise NotImplementedError(
             f"flash-prefill does not take {hq} q / {hkv} kv heads of size "

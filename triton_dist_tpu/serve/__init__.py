@@ -5,7 +5,8 @@ production shape of the reference's Engine.serve + socket model_server,
 ref: mega_triton_kernel/test/models/model_server.py): requests queue
 with priorities, a Scheduler assembles a heterogeneous batch each step
 — new requests' prefill chunks beside in-flight decode steps — and a
-Worker replays ONE jit'd step function (engine.make_serve_step) over a
+Worker replays a jit'd step function (engine.make_serve_step, one
+compiled step a width: a decode-only step beside the mixed one) over a
 shared paged-KV pool with admission, eviction + requeue, and streaming
 detokenized output.
 

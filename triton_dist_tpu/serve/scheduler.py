@@ -1,11 +1,14 @@
 """Scheduler — continuous (in-flight) batching over the serve step.
 
 Each step assembles a HETEROGENEOUS batch: new requests' prefill chunks
-ride next to in-flight requests' decode steps in the same fixed
-(slots, chunk) token block, so admission never waits for the running
+ride next to in-flight requests' decode steps in the same
+(slots, width) token block, so admission never waits for the running
 batch to drain (the reference serves one blocking request at a time
 over its socket — model_server.py:112-193; this is the production shape
-of that loop). Policies:
+of that loop). The width is the step's own: the narrowest of the
+worker's compiled widths (`Engine.serve_widths`: 1 and `chunk`) that
+holds the step's longest row, so a step of decode rows alone runs the
+(slots, 1) program. Policies:
 
   admission   — priority order off the RequestQueue; a new request
                 needs a free slot + pages for its history
@@ -19,7 +22,8 @@ of that loop). Policies:
                 victimizable is evicted to guarantee progress. Evicted
                 requests requeue with their original arrival order and
                 re-prefill their full history — bit-identical to an
-                uninterrupted run (engine.make_serve_step).
+                uninterrupted run at one step width, and equal to
+                rounding across widths (engine.make_serve_step).
   completion  — eos_id or max_new_tokens; the slot and its pages free
                 immediately (free-on-finish).
   degradation — a step failure (faults.FaultError: a guard watchdog's
@@ -496,7 +500,8 @@ class Scheduler:
             toks = self._run_step(tokens, n_valid, temps, keys, plans)
             if toks is not None:
                 with span("sched.emit", step=step_idx):
-                    self._fold_step(step_idx, toks, n_valid, plans)
+                    self._fold_step(step_idx, toks, n_valid, plans,
+                                    tokens.shape[1])
             # toks None: the step failed beyond its retry budget; the
             # poisoning request is quarantined — survivors rerun next
             # step from unchanged pool state (Worker.step's failure
@@ -524,14 +529,16 @@ class Scheduler:
     def _assemble(self, step_idx: int):
         """The step's arguments from the active slots: (tokens,
         n_valid, temps, keys, plans). A plan is
-        (slot, req, n, emits, drafts)."""
+        (slot, req, n, emits, drafts). `tokens` is (K, W), W the
+        narrowest of the worker's widths that holds the longest row
+        that stayed in the step: 1 when every row is a decode row, a
+        one-token prefill tail or empty; `chunk` when any slot
+        prefills more than one token or verifies drafts."""
         spec_on = self.spec is not None
         K, C = self.pool.slots, self.chunk
-        tokens = np.zeros((K, C), np.int32)
         n_valid = np.zeros((K,), np.int32)
         temps = np.zeros((K,), np.float32)
-        keys = np.zeros((K, C, 2) if spec_on else (K, 2), np.uint32)
-        plans = []
+        plans, rows = [], {}
 
         for slot in sorted(self.active):
             req = self.active.get(slot)
@@ -543,7 +550,7 @@ class Scheduler:
                 n = min(C, len(hist) - req.pos)
                 if not self._room(slot, req, req.pos + n):
                     continue  # stalled this step
-                tokens[slot, :n] = hist[req.pos:req.pos + n]
+                rows[slot] = hist[req.pos:req.pos + n]
                 emits = req.pos + n == len(hist)
             else:  # DECODE — possibly a spec-verify row (ISSUE 14)
                 if spec_on:
@@ -557,9 +564,7 @@ class Scheduler:
                 n = 1 + len(drafts)
                 if not self._room(slot, req, len(hist) + n):
                     continue
-                tokens[slot, 0] = hist[-1]
-                if drafts:
-                    tokens[slot, 1:n] = drafts
+                rows[slot] = [hist[-1]] + drafts
                 emits = True
             n_valid[slot] = n
             if emits:
@@ -573,7 +578,13 @@ class Scheduler:
         for slot in range(K):
             if slot not in live:
                 n_valid[slot] = 0
-                tokens[slot] = 0
+        # the narrowest compiled width that holds the rows that stayed
+        longest = int(n_valid.max())
+        W = next(w for w in self.worker.widths if w >= longest)
+        tokens = np.zeros((K, W), np.int32)
+        for slot in live:
+            tokens[slot, :n_valid[slot]] = rows[slot]
+        keys = np.zeros((K, W, 2) if spec_on else (K, 2), np.uint32)
 
         # one sampling key an emitted token, all of the step's in one
         # host call, drawn for the rows that stayed in the step. With
@@ -598,7 +609,8 @@ class Scheduler:
                     keys[rows] = drawn
         return tokens, n_valid, temps, keys, plans
 
-    def _fold_step(self, step_idx: int, toks, n_valid, plans) -> None:
+    def _fold_step(self, step_idx: int, toks, n_valid, plans,
+                   width: int) -> None:
         """A successful device step back into request state: the
         history entry, the step's counters, accepted drafts, emitted
         tokens, retirements."""
@@ -610,6 +622,7 @@ class Scheduler:
         t0, t1 = self._attempt_span
         self._record_history({
             "kind": "step", "step": step_idx, "t0": t0, "t1": t1,
+            "width": width,
             "slots": {s: (r.request_id, r.state.value, n)
                       for s, r, n, _e, _d in plans},
         })
@@ -638,7 +651,7 @@ class Scheduler:
                                      acc / len(drafts))
                     self._note_accept_rate(acc / len(drafts))
             self.worker.advance_lengths(advance)
-        self._count_step(plans)
+        self._count_step(plans, width)
 
         for slot, req, n, emits, drafts in plans:
             req.last_active_step = self.worker.n_steps
@@ -674,10 +687,14 @@ class Scheduler:
             else:
                 self._emit(req, int(toks[slot]))
 
-    def _count_step(self, plans) -> None:
+    def _count_step(self, plans, width: int) -> None:
         """What one device step worked on, as counters: integers that
         repeat exactly for a seed. Called once the pool's lengths hold
         the step's advance and before any of its requests retires."""
+        # which compiled step ran: `wide` is the chunk's, `narrow`
+        # any other of the worker's widths
+        self.obs.inc("serve_steps",
+                     shape="wide" if width == self.chunk else "narrow")
         rows = {"prefill": 0, "decode": 0}
         for _slot, req, n, _emits, _drafts in plans:
             rows[req.state.value] += n

@@ -1,12 +1,18 @@
-"""Worker — replays the engine's ONE jit'd serve step over the pool.
+"""Worker — replays the engine's jit'd serve steps over the pool, one
+compiled step a width.
 
 The scheduler/worker split of the Engine (ROADMAP item 1): the
 Scheduler decides WHAT runs each step (which slots, which tokens, how
-many are real); the Worker is the only component that touches the
-device — it materializes the step arguments, replays the single
-compiled executable `engine.make_serve_step` built for this geometry
-(the CUDA-graph-replay analog: same shapes every step, whatever the
-batch mixes), and folds the results back into the pool.
+many are real, and so how wide the step is); the Worker is the only
+component that touches the device — it materializes the step
+arguments, replays the compiled executable `engine.make_serve_step`
+built for the step's width (the CUDA-graph-replay analog: a closed set
+of shapes, `engine.serve_widths(chunk)`, fixed at construction; the
+same shapes every step of one width, whatever the batch mixes), and
+folds the results back into the pool. A request's tokens are bitwise
+a function of its history and of the widths of the steps that
+computed them; across widths the logits agree to the tolerance of two
+correct formulations in the model's precision (docs/serving.md).
 
 `ResidentWorker` is the megakernel-resident form (ISSUE 12): instead
 of one device dispatch per step, the scheduler's decisions travel as
@@ -107,9 +113,19 @@ class Worker:
         self.chunk = chunk
         self.per_pos = per_pos
         check_prng_impl()
-        self._fn = engine.make_serve_step(pool.slots, chunk, pool.page,
-                                          pool.max_pages,
-                                          per_pos=per_pos)
+        # one compiled step a width, all built here and first called
+        # in the caller's warm-up (nothing compiles lazily behind a
+        # step's shape). `_fn` is the WIDEST (`chunk`) and is looked
+        # up on the instance at call time, so a wrapper set on the
+        # attribute sees the wide calls (perfbench's StepListing)
+        self.widths = tuple(engine.serve_widths(chunk))
+        assert self.widths[-1] == chunk, (self.widths, chunk)
+        steps = {w: engine.make_serve_step(pool.slots, w, pool.page,
+                                           pool.max_pages,
+                                           per_pos=per_pos)
+                 for w in self.widths}
+        self._fn = steps.pop(chunk)
+        self._narrow = steps
         self.n_steps = 0
         # what the newest step counted on the device (the step's
         # `stats` result as host ints; {} for the dense family)
@@ -122,8 +138,9 @@ class Worker:
 
     def step(self, tokens: np.ndarray, n_valid: np.ndarray,
              temps: np.ndarray, keys: np.ndarray) -> np.ndarray:
-        """One serve step. tokens (K, C) i32 / n_valid (K,) i32 /
-        temps (K,) f32 / keys (K, 2) u32. Advances pool lengths by
+        """One serve step. tokens (K, W) i32, W one of `widths` /
+        n_valid (K,) i32 / temps (K,) f32 / keys (K, 2) u32. Advances
+        pool lengths by
         n_valid and returns the per-slot next token (K,) i32 — only
         slots whose chunk just completed (prefill tail or decode) carry
         a meaningful token; the scheduler knows which.
@@ -148,9 +165,10 @@ class Worker:
 
     def step_spec(self, tokens: np.ndarray, n_valid: np.ndarray,
                   temps: np.ndarray, keys: np.ndarray) -> np.ndarray:
-        """The per-position (spec-capable) step: keys (K, C, 2) — one
-        per column — and the return is the full (K, C) per-position
-        token matrix (ISSUE 14, spec/verify.py). Pool LENGTHS ARE NOT
+        """The per-position (spec-capable) step: keys (K, W, 2) — one
+        per column of a (K, W) token block — and the return is the
+        full (K, W) per-position token matrix (ISSUE 14,
+        spec/verify.py). Pool LENGTHS ARE NOT
         ADVANCED: a verify row's valid advance is its ACCEPTED count,
         which only the scheduler can compute from the returned matrix
         — it calls `advance_lengths` after applying the
@@ -168,8 +186,9 @@ class Worker:
 
     def _dispatch(self, step: int, tokens, n_valid, temps, keys):
         """Both steps' device half: the injected fault, the six
-        host-to-device puts (`worker.put`) and the call of the compiled
-        step, which returns at enqueue (`worker.launch`)."""
+        host-to-device puts (`worker.put`) and the call of the step
+        compiled for the token block's width, which returns at
+        enqueue (`worker.launch`)."""
         plan = _fplan.active()
         if plan is not None:
             err = plan.step_fault(step)
@@ -183,8 +202,10 @@ class Worker:
             n_valid = jnp.asarray(n_valid, jnp.int32)
             temps = jnp.asarray(temps, jnp.float32)
             keys = jnp.asarray(keys, jnp.uint32)
+        width = tokens.shape[1]
+        fn = self._fn if width == self.chunk else self._narrow[width]
         with self.spans.span("worker.launch", step=step):
-            tok, _logits, pool.state, self._stats = self._fn(
+            tok, _logits, pool.state, self._stats = fn(
                 self.engine.params, tokens, pool.state, table,
                 lengths, n_valid, temps, keys)
         return tok
