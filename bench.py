@@ -61,9 +61,8 @@ chain time - 1) HARD-ASSERTED < 0.03, plus `obs_stat_events` (the
 metered run's decoded event total, asserted > 0: a meter that records
 nothing is as broken as one that taxes the kernel). Request tagging
 (ISSUE 13) rides the same build flag with ZERO kernel surface — the
-per-request ledger is host bookkeeping and the resident-window rows
-are pure-jnp streams — so the gate's ceiling covers the whole
-always-on tier with tagging active.
+per-request ledger is host bookkeeping — so the gate's ceiling covers
+the whole always-on tier with tagging active.
 """
 
 import json
@@ -1122,128 +1121,6 @@ def bench_serving(mesh, qps_levels=(1.0, 4.0), n_requests=10,
     return out
 
 
-def bench_serve_resident(mesh, n_requests=8, prompt_len=96, gen_len=16,
-                         window=16, sat_windows=4, cfg=None, ctx=None):
-    """Megakernel-resident serving vs the host-loop scheduler at FIXED
-    slots (ISSUE 12): the same request batch through (a) the host-loop
-    Scheduler — one dispatch per step — and (b) the resident Scheduler
-    — work injected through the mega.ring, up to `window` steps per
-    dispatch. The per-request tokens are asserted BIT-IDENTICAL between
-    the arms before any number is reported (the serve plane's
-    acceptance oracle extends to the artifact chain), so
-    `serve_resident_vs_hostloop` can only ever price the dispatch tax,
-    never a numerics change.
-
-    Also runs the steady-state decode-only saturation arm: all slots
-    resident in DECODE, `sat_windows` windows timed wall-clock —
-    `serve_resident_saturation_tokens_per_s` is the device-side
-    tokens/s ceiling with zero admission traffic. Ring-depth stats
-    (max/mean records pending at each window launch) and the
-    per-window wall times (tail-stat raw dict) ride along; world
-    semantics match bench_serving (per-rank 8B shard, world=1 on this
-    rig). cfg/ctx are overridable for the reduced-geometry CPU rig
-    (see _main_cpu_rig); the defaults are the 8B-shard arm."""
-    from triton_dist_tpu.serve import Scheduler
-
-    cfg = cfg or _shard_cfg()
-    ctx = ctx or CTX
-    eng = Engine(cfg, mesh, decode_mode="ar", max_len=ctx,
-                 fast_init=True)
-    SLOTS, CHUNK, PAGE = 4, 64, 64
-    rng = np.random.default_rng(29)
-    prompts = [rng.integers(0, cfg.vocab_size, prompt_len).tolist()
-               for _ in range(n_requests)]
-
-    def submit_all(sch):
-        return [sch.submit(p, max_new_tokens=gen_len) for p in prompts]
-
-    import time as _time
-
-    # compile both executables OUTSIDE the timed arms (they are cached
-    # per-engine, so the throwaway runs below warm the real ones)
-    for warm_kw in ({}, {"resident": True, "window": window}):
-        warm = Scheduler(eng, slots=SLOTS, chunk=CHUNK, page=PAGE,
-                         **warm_kw)
-        warm.submit(prompts[0][:CHUNK], max_new_tokens=2)
-        warm.run()
-
-    # host-loop arm
-    hsch = Scheduler(eng, slots=SLOTS, chunk=CHUNK, page=PAGE)
-    hreqs = submit_all(hsch)
-    t0 = _time.perf_counter()
-    hsch.run()
-    host_s = _time.perf_counter() - t0
-    host_tokens = sum(len(r.out_tokens) for r in hreqs)
-    host_tps = host_tokens / max(host_s, 1e-9)
-
-    # resident arm (per-window wall times + ring depth at each launch)
-    rsch = Scheduler(eng, slots=SLOTS, chunk=CHUNK, page=PAGE,
-                     resident=True, window=window)
-    rreqs = submit_all(rsch)
-    depths = []
-    win_ms = []
-    t0 = _time.perf_counter()
-    while True:
-        w0 = _time.perf_counter()
-        if not rsch.step():
-            if rsch.queue.peek() is None:
-                break
-        else:
-            win_ms.append((_time.perf_counter() - w0) * 1e3)
-            # the scheduler gauges the ring depth AT window launch
-            # (after this round's admissions were injected)
-            depths.append(rsch.obs.snapshot()["gauges"]
-                          .get("serve_ring_depth", 0))
-    res_s = _time.perf_counter() - t0
-    res_tokens = sum(len(r.out_tokens) for r in rreqs)
-    res_tps = res_tokens / max(res_s, 1e-9)
-
-    assert [r.out_tokens for r in rreqs] == \
-        [r.out_tokens for r in hreqs], (
-        "resident loop diverged bitwise from the host-loop scheduler "
-        "— the dispatch-tax ratio below would be meaningless")
-
-    # decode-only saturation: all slots resident mid-decode, timed
-    # windows with zero admission traffic
-    ssch = Scheduler(eng, slots=SLOTS, chunk=CHUNK, page=PAGE,
-                     resident=True, window=window)
-    sreqs = [ssch.submit(p, max_new_tokens=ctx - prompt_len - 1)
-             for p in prompts[:SLOTS]]
-    ssch.step()  # admits + prefills inside the first window(s)
-    while any(r.state.name == "PREFILL" for r in ssch.active.values()):
-        ssch.step()
-    base = sum(len(r.out_tokens) for r in sreqs)
-    t0 = _time.perf_counter()
-    for _ in range(sat_windows):
-        ssch.step()
-    sat_s = _time.perf_counter() - t0
-    sat_tokens = sum(len(r.out_tokens) for r in sreqs) - base
-    for r in sreqs:
-        ssch.cancel(r)
-    ssch.run()
-
-    depths = depths or [0]
-    pos = [m for m in win_ms if m > 0] or [1e-9]
-    return {
-        "serve_resident_tokens_per_s": round(res_tps, 2),
-        "serve_resident_hostloop_tokens_per_s": round(host_tps, 2),
-        "serve_resident_vs_hostloop": round(
-            res_tps / max(host_tps, 1e-9), 4),
-        "serve_resident_saturation_tokens_per_s": round(
-            sat_tokens / max(sat_s, 1e-9), 2),
-        "serve_resident_window_steps": window,
-        "serve_resident_ring_depth_max": int(np.max(depths)),
-        "serve_resident_ring_depth_mean": round(
-            float(np.mean(depths)), 3),
-        "serve_resident_raw": {
-            "diffs_ms": [round(m, 4) for m in win_ms],
-            "k": (1, 1 + window),
-            "p25_ms": round(float(np.percentile(pos, 25)), 4),
-            "min_ms": round(float(np.min(pos)), 4),
-        },
-    }
-
-
 def bench_serve_spec(mesh, n_requests=8, prompt_len=48, gen_len=32,
                      qps_levels=(4.0, 32.0), spec_k=4, cfg=None,
                      ctx=None):
@@ -1254,8 +1131,8 @@ def bench_serve_spec(mesh, n_requests=8, prompt_len=48, gen_len=32,
     self-drafting n-gram head exists for. Before any timing, a
     submit-all pass asserts the spec arm's tokens BIT-IDENTICAL to the
     plain arm's (the serve plane's acceptance oracle extends to the
-    artifact chain, like bench_serve_resident), and doubles as the
-    compile warmup for both executables.
+    artifact chain), and doubles as the compile warmup for both
+    executables.
 
     `spec_vs_plain_tokens` is the headline throughput ratio at the hi
     QPS level; `spec_accept_rate` (accepted/proposed over the spec
@@ -1814,10 +1691,8 @@ _NUMERIC_KEYS = {
     # run's decoded event audit (must be > 0 — a meter recording
     # nothing is broken)
     "obs_overhead_frac", "obs_stat_events",
-    # megakernel-resident serving (ISSUE 12): the dispatch-tax recovery
-    # at fixed slots (resident vs host-loop, bit-identity asserted
-    # in-arm), the decode-only saturation ceiling, and the injection-
-    # ring pressure stats (keys travel together + raw tails)
+    # retired (PR 32): no arm emits these; BENCH_r06-r09.json hold them,
+    # and check_result and obs/trend still read those records
     "serve_resident_tokens_per_s",
     "serve_resident_hostloop_tokens_per_s",
     "serve_resident_vs_hostloop",
@@ -1903,17 +1778,6 @@ _OTHER_KEYS = {"raw", "mega_32b_raw", "prefill_raw", "prefill_s128_raw",
                "serve_levels", "sp_prefill_raw", "allreduce_wire_raw",
                "serve_resident_raw", "serve_spec_levels", "plan_raw",
                "tuned_raw"}
-# the resident-serving family travels together: the ratio without both
-# absolute arms, the saturation ceiling, or the ring-pressure stats
-# would be unfalsifiable from the artifact
-_SERVE_RESIDENT_KEYS = {
-    "serve_resident_tokens_per_s",
-    "serve_resident_hostloop_tokens_per_s",
-    "serve_resident_vs_hostloop",
-    "serve_resident_saturation_tokens_per_s",
-    "serve_resident_window_steps",
-    "serve_resident_ring_depth_max", "serve_resident_ring_depth_mean",
-}
 # the spec-decode family travels together: the ratio without both
 # absolute arms or the acceptance rate (which explains the ratio) is
 # unfalsifiable; the per-level breakdown rides in serve_spec_levels
@@ -2128,17 +1992,6 @@ def check_result(result: dict) -> list:
                 problems.append(
                     f"{k!r} must ride beside the plan_* keys (the "
                     "planner's pick is part of the artifact)")
-    srv_res_present = _SERVE_RESIDENT_KEYS & set(result)
-    if srv_res_present:
-        for k in _SERVE_RESIDENT_KEYS - set(result):
-            problems.append(
-                f"serve-resident keys travel together: {k!r} missing "
-                f"while {sorted(srv_res_present)[0]!r} is present")
-        raw = result.get("serve_resident_raw")
-        if not isinstance(raw, dict) or "diffs_ms" not in raw:
-            problems.append(
-                "serve_resident_raw (per-window tail-stat dict) must "
-                "ride beside the serve_resident_* keys")
     agw_present = _AG_WIRE_KEYS & set(result)
     if agw_present:
         for k in _AG_WIRE_KEYS - set(result):
@@ -2420,8 +2273,8 @@ def bench_tuned_vs_default(mesh, ks=(1, 9, 17), cache_path=None,
 def _main_cpu_rig(mesh):
     """The reduced-geometry CPU rig (no TPU attached): measures ONLY
     the keys whose claims are ratio-shaped or rig-local — the serving
-    plane (host-loop vs sequential, resident vs host-loop), the SP
-    flash-prefill fold, and the quantized-wire pairs — at geometries
+    plane (batched vs sequential), the SP flash-prefill fold, and the
+    quantized-wire pairs — at geometries
     the interpreter can run in minutes. The absolute TPU headline arms
     (mega decode, fused-kernel vs XLA) are deliberately NOT emitted:
     per key the newest artifact carrying it wins
@@ -2435,45 +2288,33 @@ def _main_cpu_rig(mesh):
     last_err = None
     for _ in range(3):  # same transient-measurement policy as main()
         try:
-            # gen_len 32 (vs the default arm's 16): a decode-heavy mix
-            # keeps the resident window amortization the dominant term
-            # over wave-tail raggedness, so the headline stays robustly
-            # above the host-loop arm run-to-run on this rig
-            res = bench_serve_resident(
-                mesh, n_requests=8, prompt_len=48, gen_len=32,
-                window=16, sat_windows=4, cfg=cfg, ctx=_RIG_CTX)
+            # saturating QPS at the hi level: the rig's steps are
+            # millisecond-scale, so arrivals must outpace service for
+            # the batched/sequential ratio to read batching (not idle
+            # time)
+            res = bench_serving(
+                mesh, qps_levels=(4.0, 32.0), n_requests=12,
+                prompt_len=48, gen_len=32, cfg=cfg, ctx=_RIG_CTX,
+                k_hi=6, pairs=3)
             break
         except RuntimeError as e:
             last_err = e
     else:
         _emit({
-            "metric": "serve_resident_vs_hostloop", "value": -1.0,
+            "metric": "serve_vs_seq_tokens", "value": -1.0,
             "unit": "ratio", "vs_baseline": -1.0, "rig": "cpu-world1",
             "error": str(last_err)[:200],
         })
         return
 
     result = {
-        "metric": "serve_resident_vs_hostloop",
-        "value": res["serve_resident_vs_hostloop"],
+        "metric": "serve_vs_seq_tokens",
+        "value": res["serve_vs_seq_tokens"],
         "unit": "ratio",
-        "vs_baseline": res["serve_resident_vs_hostloop"],
+        "vs_baseline": res["serve_vs_seq_tokens"],
         "rig": "cpu-world1",
     }
     result.update(res)
-    try:
-        # saturating QPS at the hi level: the rig's steps are
-        # millisecond-scale, so arrivals must outpace service for the
-        # batched/sequential ratio to read batching (not idle time).
-        # prompt/gen MATCH the resident arm above — per-request length
-        # sets the KV page depth and with it the per-step compute, so
-        # unmatched geometry would make the resident-vs-serving
-        # tokens/s comparison read page depth, not scheduling
-        result.update(bench_serving(
-            mesh, qps_levels=(4.0, 32.0), n_requests=12, prompt_len=48,
-            gen_len=32, cfg=cfg, ctx=_RIG_CTX, k_hi=6, pairs=3))
-    except Exception as e:
-        result["serve_error"] = str(e)[:200]
     try:
         # spec + prefix arms (ISSUE 14): the same rig shard and
         # matched per-request geometry as the serving arms above, so
@@ -2700,13 +2541,6 @@ def main():
         result.update(bench_serving(mesh))
     except Exception as e:
         result["serve_error"] = str(e)[:200]
-    try:
-        # megakernel-resident serving (ISSUE 12): the dispatch-tax
-        # recovery at fixed slots + the decode-only saturation ceiling
-        # (bit-identity between the arms asserted inside the bench).
-        result.update(bench_serve_resident(mesh))
-    except Exception as e:
-        result["serve_resident_error"] = str(e)[:200]
 
     if "--faults" in sys.argv:
         # opt-in guarded-execution smoke arm (never on the driver's

@@ -201,8 +201,7 @@ def report_requests(path: str) -> None:
 
     doc = load_ledger(path)
     print(f"== {path} (request ledger: {len(doc['requests'])} "
-          f"request(s), mode={doc.get('mode', '?')}, "
-          f"chunk={doc.get('chunk', '?')}) ==")
+          f"request(s), chunk={doc.get('chunk', '?')}) ==")
     print(format_requests_table(doc))
     problems = check_close(doc)
     for p in problems:

@@ -18,6 +18,7 @@ cross-device-blocking kernel deadlocks (this was round-1 VERDICT weak #1/#2).
 Spare virtual devices = spare pool threads = guaranteed progress.
 """
 
+import functools
 import os
 
 # tier-1 is hermetic against the committed autotune cache: a bench round
@@ -78,3 +79,36 @@ def step_widths(request, monkeypatch):
 
         hold_to_the_wide_step(monkeypatch)
     return request.param
+
+
+@functools.cache
+def _interpreter_lowers_semaphore_read() -> bool:
+    """Does the installed Pallas TPU interpreter lower
+    `pl.semaphore_read`? Every watchdog of `faults.guard` reads its
+    semaphore through it, so where it does not, a kernel built under
+    `faults.guard.building()` cannot run on the CPU mesh at all."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def body(o_ref, sem):
+        o_ref[0] = pl.semaphore_read(sem)
+
+    try:
+        jax.block_until_ready(pl.pallas_call(
+            body, out_shape=jax.ShapeDtypeStruct((1,), jnp.int32),
+            out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+            scratch_shapes=[pltpu.SemaphoreType.REGULAR],
+            interpret=pltpu.InterpretParams())())
+    except NotImplementedError:
+        return False
+    return True
+
+
+def pytest_runtest_setup(item):
+    # probed by the first marked test a worker runs, not at collection
+    if (item.get_closest_marker("needs_semaphore_read") is not None
+            and not _interpreter_lowers_semaphore_read()):
+        pytest.skip("the installed Pallas TPU interpreter has no "
+                    "lowering rule for pl.semaphore_read, which every "
+                    "faults.guard watchdog reads its semaphore through")

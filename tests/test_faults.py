@@ -164,6 +164,7 @@ def test_guards_off_bit_identity_and_call_count(mesh4):
     np.testing.assert_array_equal(np.asarray(again), np.asarray(ref))
 
 
+@pytest.mark.needs_semaphore_read
 def test_guards_on_clean_is_bit_identical(mesh4):
     x = _make((4, 16, 128), seed=2)
     ref = _run_ar(mesh4, x, guarded=False)
@@ -175,6 +176,7 @@ def test_guards_on_clean_is_bit_identical(mesh4):
 # ---------- watchdog trips on the kernel families ----------
 
 
+@pytest.mark.needs_semaphore_read
 def test_ar_dropped_credit_trips_watchdog(mesh4):
     x = _make((4, 16, 128), seed=3)
     plan = faults.FaultPlan(faults.DroppedSignal(2, label="credit"))
@@ -188,6 +190,7 @@ def test_ar_dropped_credit_trips_watchdog(mesh4):
         faults.check(np.asarray(g), context="two_shot_ar")
 
 
+@pytest.mark.needs_semaphore_read
 def test_ar_dropped_barrier_trips_all_ranks(mesh4):
     x = _make((4, 16, 128), seed=4)
     plan = faults.FaultPlan(faults.DroppedSignal(2, label="barrier"))
@@ -200,6 +203,7 @@ def test_ar_dropped_barrier_trips_all_ranks(mesh4):
     assert all(t.observed == t.expected - 1 for t in trips)
 
 
+@pytest.mark.needs_semaphore_read
 def test_ar_delay_and_stall_recover_bitwise(mesh4):
     x = _make((4, 16, 128), seed=5)
     ref = _run_ar(mesh4, x, guarded=False)
@@ -229,6 +233,7 @@ def _run_ll(mesh4, guarded, plan=None, fmt=None, n=4):
         return fn(x)
 
 
+@pytest.mark.needs_semaphore_read
 def test_ll_ag_dropped_barrier_trips(mesh4):
     plan = faults.FaultPlan(faults.DroppedSignal(1, label="barrier"))
     res = _run_ll(mesh4, guarded=True, plan=plan)
@@ -240,6 +245,7 @@ def test_ll_ag_dropped_barrier_trips(mesh4):
                for t in trips)
 
 
+@pytest.mark.needs_semaphore_read
 def test_ll_ag_wire_corruption_detected(mesh4):
     fmt = wire.WireFormat("fp8", checksum=True)
     clean = _run_ll(mesh4, guarded=True, fmt=fmt)
@@ -254,6 +260,7 @@ def test_ll_ag_wire_corruption_detected(mesh4):
         faults.check(g)
 
 
+@pytest.mark.needs_semaphore_read
 def test_sp_flash_prefill_dropped_barrier_trips(mesh4):
     from triton_dist_tpu.kernels.flash_prefill import sp_flash_prefill
 
@@ -273,6 +280,7 @@ def test_sp_flash_prefill_dropped_barrier_trips(mesh4):
     assert all(t.site_label == "barrier" for t in trips)
 
 
+@pytest.mark.needs_semaphore_read
 def test_a2a_chunked_guarded_clean_and_dropped(mesh4):
     from triton_dist_tpu.kernels.all_to_all import all_to_all_chunked
 
@@ -302,6 +310,7 @@ def test_a2a_chunked_guarded_clean_and_dropped(mesh4):
 # ---------- degradation: guard-tripped fallback="xla" ----------
 
 
+@pytest.mark.needs_semaphore_read
 def test_ll_op_fallback_degrades_and_completes(mesh4):
     from triton_dist_tpu.runtime.symm_mem import SymmetricWorkspace
 
@@ -322,6 +331,7 @@ def test_ll_op_fallback_degrades_and_completes(mesh4):
     np.testing.assert_array_equal(np.asarray(out2), ref)
 
 
+@pytest.mark.needs_semaphore_read
 def test_ll_op_without_fallback_raises(mesh4):
     from triton_dist_tpu.runtime.symm_mem import SymmetricWorkspace
 
@@ -334,6 +344,7 @@ def test_ll_op_without_fallback_raises(mesh4):
     assert not faults.is_degraded("low_latency_allgather")
 
 
+@pytest.mark.needs_semaphore_read
 def test_ar_op_fallback_degrades(mesh4):
     x = _make((4, 16, 128), seed=14)
     ref = np.asarray(all_reduce_op(x, mesh4))
@@ -465,12 +476,14 @@ def test_run_faulted_drop_delivery_detected():
 # ---------- guard-polarity mutant (red/green corpus) ----------
 
 
+@pytest.mark.needs_semaphore_read
 def test_watchdog_mutant_polarity():
     assert chaos.watchdog_mutant_findings(2, impl="shipped") == []
     fs = chaos.watchdog_mutant_findings(2, impl="reset_poll")
     assert len(fs) == 1 and fs[0].klass == "guard-no-trip"
 
 
+@pytest.mark.needs_semaphore_read
 def test_guard_mutant_registered_in_corpus():
     import importlib.util
     import os

@@ -337,3 +337,48 @@ def test_mega_paged_decode_matches_engine(tiny_cfg, world):
         tok = jnp.argmax(lm, -1).astype(jnp.int32)
     # exactly one page per sequence was allocated at the boundary
     assert int(np.asarray(pcache.next_free)) == B * (S // page) + B
+
+
+# ---------- decode_resident (the megakernel's own multi-step decode) ----------
+
+
+@pytest.fixture(scope="module")
+def eng1():
+    cfg = ModelConfig.tiny(num_q_heads=4, num_kv_heads=2,
+                           max_positions=64)
+    return Engine(cfg, _mesh(1), decode_mode="ar", max_len=64,
+                  donate_cache=False)
+
+
+@pytest.fixture(scope="module")
+def prompts(eng1):
+    rng = np.random.default_rng(7)
+    v = eng1.cfg.vocab_size
+    return [list(map(int, rng.integers(0, v, n))) for n in (12, 10, 9)]
+
+
+def test_mega_decode_resident_bitwise_over_pool_export(eng1, prompts):
+    from triton_dist_tpu.serve import Scheduler
+
+    cfg = eng1.cfg
+    sch = Scheduler(eng1, slots=2, chunk=4, page=8)
+    reqs = [sch.submit(p, max_new_tokens=20) for p in prompts[:2]]
+    for _ in range(6):
+        sch.step()
+    assert all(r.state.name == "DECODE" for r in reqs)
+    mega = MegaQwen3(cfg, eng1.mesh, batch=2, s_max=sch.pool.t_max,
+                     params=eng1.params, donate_cache=False, paged=True,
+                     page_size=sch.pool.page,
+                     total_pages=1 + sch.pool.capacity)
+    tok = jnp.asarray([r.out_tokens[-1] for r in reqs], jnp.int32)
+    cache = sch.pool.as_mega_cache()
+    seq_t, c = [], cache
+    t = tok
+    for _ in range(3):
+        lg, c = mega.decode_step(t, c)
+        t = jnp.argmax(lg, -1).astype(jnp.int32)
+        seq_t.append(np.asarray(t))
+    out, c2 = mega.decode_resident(tok, sch.pool.as_mega_cache(),
+                                   steps=3)
+    np.testing.assert_array_equal(np.asarray(out), np.stack(seq_t, 1))
+    np.testing.assert_array_equal(np.asarray(c.k), np.asarray(c2.k))

@@ -335,6 +335,7 @@ def test_metered_ll_allgather_op(mesh4):
                        fmt="native") == 4 * 3 * 8 * 128 * 4
 
 
+@pytest.mark.needs_semaphore_read
 def test_guard_trips_land_in_stat_rows(mesh4):
     """Guard + obs coexistence: a tripped watchdog bumps the stat row's
     trip counter (through GuardCtx.octx / the ambient meter)."""
@@ -348,6 +349,7 @@ def test_guard_trips_land_in_stat_rows(mesh4):
     assert reg.counter("obs_guard_trips", kernel="allreduce") > 0
 
 
+@pytest.mark.needs_semaphore_read
 def test_sp_flash_decode_ll_under_guard_and_obs_builds(mesh4):
     """Composite-caller build safety: sp_flash_decode's LL-AG partial
     exchange must strip BOTH trailing buffers (guard row under
@@ -408,6 +410,7 @@ def test_flight_ring_bounds_and_roundtrip(tmp_path):
         obs.load_dump(str(bad))
 
 
+@pytest.mark.needs_semaphore_read
 def test_guard_trip_cell_dumps_with_decoded_row(mesh4, tmp_path):
     """Acceptance criterion: a guard-trip chaos cell produces a
     flight-recorder dump whose LAST snapshot contains the decoded

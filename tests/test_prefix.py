@@ -1,8 +1,8 @@
 """Radix prefix cache + KVPool refcount/COW plane (ISSUE 14).
 
 The load-bearing property: a prefix-HIT request's token stream is
-BITWISE equal to its cold run — greedy and sampled, host loop and
-resident — because the serve step's row numerics are placement/
+BITWISE equal to its cold run — greedy and sampled — because the
+serve step's row numerics are placement/
 chunk-alignment independent (the tier-1-pinned eviction property), so
 a donor's cached KV pages are bitwise the pages the hit request's own
 prefill would have written. Around it: the KVPool refcount/share/cow
@@ -11,8 +11,7 @@ LRU reclaim with the shared-page refusal, pool-pressure integration,
 and the ledger's prefill collapse on hits.
 
 Wall budget: ONE engine geometry for the whole module (module-scoped
-fixtures, GEO shared with tests/test_serve.py's shapes); the resident
-variants reuse the same compiled loop geometry.
+fixtures, GEO shared with tests/test_serve.py's shapes).
 """
 
 import numpy as np
@@ -260,39 +259,6 @@ def test_prefix_hot_cold_bitwise_host_sampled(eng1, prompts):
     hot = batch()
     assert hot == cold
     assert sch.metrics()["prefix_hits"] >= len(prompts)
-    sch.pool.check()
-
-
-def test_prefix_hot_cold_bitwise_resident(eng1, prompts):
-    cold = _cold(eng1, prompts, 6)
-    sch = Scheduler(eng1, resident=True, window=4, prefix_cache=True,
-                    prefix_block=BLOCK, **GEO)
-    first = [sch.submit(p, max_new_tokens=6) for p in prompts]
-    sch.run()
-    hot = [sch.submit(p, max_new_tokens=6) for p in prompts]
-    sch.run()
-    assert [r.out_tokens for r in first] == cold
-    assert [r.out_tokens for r in hot] == cold
-    assert all(r.prefix_len >= BLOCK for r in hot)
-    assert sch.metrics()["prefix_hits"] >= len(prompts)
-    sch.pool.check()
-    sch.prefix.check()
-
-
-@pytest.mark.slow  # duplicates the host sampled + resident greedy
-# pins above (the sampled key stream and the IR_PREFIX admission are
-# each already covered); kept for the full matrix on deep runs
-def test_prefix_hot_cold_bitwise_resident_sampled(eng1, prompts):
-    sch = Scheduler(eng1, resident=True, window=4, prefix_cache=True,
-                    prefix_block=BLOCK, **GEO)
-
-    def batch():
-        reqs = [sch.submit(p, max_new_tokens=6, temperature=0.9,
-                           seed=60 + i) for i, p in enumerate(prompts)]
-        sch.run()
-        return [r.out_tokens for r in reqs]
-
-    assert batch() == batch()
     sch.pool.check()
 
 

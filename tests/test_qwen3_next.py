@@ -312,8 +312,6 @@ def test_chunk_is_priced_from_the_family_s_sizes(mesh1, monkeypatch):
 
 @pytest.mark.parametrize("kw, names", [
     (dict(prefix_cache=True), "prefix_cache"),
-    (dict(resident=True), "resident"),
-    (dict(resident="auto"), "resident"),
     (dict(role="prefill", migrate_to=object()), "xslice"),
     (dict(spec="k2"), "spec"),
 ])
@@ -340,7 +338,6 @@ def test_pool_engine_and_megakernel_refuse_too(eng, cfg, mesh1):
     for call in (lambda: eng.prefill(np.zeros((1, 4), np.int32)),
                  lambda: eng.decode_step(np.zeros((1,), np.int32), None),
                  lambda: eng.generate(np.zeros((1,), np.int32), None, 2),
-                 lambda: eng.make_resident_loop(2, 4, 8, 8, window=4),
                  lambda: eng.make_serve_step(2, 4, 8, 8, per_pos=True)):
         with pytest.raises(NotImplementedError, match="recurrent"):
             call()

@@ -2,13 +2,9 @@
 
 The load-bearing properties: (1) the per-request ledger CLOSES — each
 retired request's decomposed phase times sum to its submit->finish
-wall time within the documented tolerance, on a traced+metered
-resident run; (2) the PR-11 agreement pin extends to the new
-resident-window stat rows (counters == the serve.* trace stream's
-record counts, per slot lane); (3) request tagging is zero-cost-off on
-both serve paths — bit-identical tokens, unchanged pallas_call_count;
-(4) the ledger/report tooling is strict (malformed input is loud) and
-the window chooser drives `Scheduler(resident=True, window=None)`.
+wall time within the documented tolerance; (2) request tagging is
+zero-cost-off — bit-identical tokens, unchanged pallas_call_count;
+(3) the ledger/report tooling is strict (malformed input is loud).
 """
 
 import importlib.util
@@ -17,10 +13,9 @@ import os
 import numpy as np
 import pytest
 
-from triton_dist_tpu import obs, trace
+from triton_dist_tpu import obs
 from triton_dist_tpu.lang.core import pallas_call_count
 from triton_dist_tpu.models import Engine, ModelConfig
-from triton_dist_tpu.obs import stats as ost
 from triton_dist_tpu.runtime import make_mesh
 from triton_dist_tpu.serve import Scheduler
 from triton_dist_tpu.trace import events as tev
@@ -36,7 +31,6 @@ from triton_dist_tpu.trace.ledger import (
 )
 
 GEO = dict(slots=3, chunk=4, page=8)
-WINDOW = 4  # one compiled resident geometry per module
 
 
 @pytest.fixture(scope="module")
@@ -65,86 +59,7 @@ def _run(sch, prompts, gen=5):
     return reqs
 
 
-@pytest.fixture(scope="module")
-def bare_tokens(eng1, prompts):
-    """Bare (untelemetered) resident run + the pallas-call count of its
-    fresh compile — the zero-cost-off reference the traced+metered run
-    is pinned against (one compile, shared by every test here)."""
-    eng1._serve_cache.clear()
-    c0 = pallas_call_count()
-    sch = Scheduler(eng1, resident=True, window=WINDOW, **GEO)
-    toks = [r.out_tokens for r in _run(sch, prompts)]
-    assert sch.worker.last_window_stats is None
-    assert sch.worker.last_window_trace is None
-    return toks, pallas_call_count() - c0
-
-
-# ---------- the close pin (acceptance criterion) ----------
-
-
-def test_ledger_closes_on_traced_metered_resident_run(
-        eng1, prompts, bare_tokens, tmp_path):
-    """THE acceptance pin: a resident run whose loop was built under
-    BOTH trace.building() and obs.stats.building() — tokens bitwise
-    the bare run's with ZERO added pallas calls (request tagging and
-    the window telemetry are host bookkeeping + pure-jnp streams),
-    every retired request's phase decomposition closes against wall
-    time, and the window stat rows agree with the serve.* trace stream
-    record for record."""
-    ref_tokens, plain_calls = bare_tokens
-    eng1._serve_cache.clear()
-    with trace.building(cap=256), ost.building():
-        sch = Scheduler(eng1, resident=True, window=WINDOW, **GEO)
-    # run OUTSIDE the builds (the construction-time discipline decides
-    # the loop's telemetry; the inner kernels compile bare either way)
-    c0 = pallas_call_count()
-    reqs = _run(sch, prompts)
-    assert pallas_call_count() - c0 == plain_calls, (
-        "resident telemetry must not add pallas calls")
-    assert [r.out_tokens for r in reqs] == ref_tokens
-
-    led = sch.ledger()
-    assert check_close(led) == [], format_requests_table(led)
-    rows = {r["request_id"]: r for r in led["requests"]}
-    for req in reqs:
-        row = rows[req.request_id]
-        assert row["state"] == "finished"
-        assert row["tokens_out"] == 5
-        # resident decode: one device step per emitted token past the
-        # prefill tail; prefill chunks = ceil(prompt / chunk)
-        chunks = -(-len(req.prompt) // sch.chunk)
-        assert row["prefill_chunks"] == chunks
-        assert row["decode_steps"] == 5 - 1
-        assert row["windows"] >= 1
-        assert row["device_share_us"] > 0
-        assert row["inject_wait_us"] >= 0
-
-    # the agreement pin, resident-window form (PR-11 extended)
-    wins = [e for e in sch.history if e["kind"] == "window"]
-    assert wins and all(e["stats"] is not None for e in wins)
-    assert all(e["trace"] is not None for e in wins)
-    for e in wins:
-        tl = trace.assemble({"w": np.asarray(e["trace"]).reshape(
-            1, -1, tev.RECORD_WORDS)})
-        ost.window_agree_with_trace(e["stats"], tl, "w")
-        assert e["stats"].steps == e["executed"]
-
-    # loop-level counters landed in the registry and metrics()
-    m = sch.metrics()
-    assert m["ring_polls"] > 0
-    assert m["ring_polls"] == sum(e["stats"].ring_polls for e in wins)
-    assert m["idle_polls"] == sum(e["stats"].idle_polls for e in wins)
-
-    # the window timeline assembles every traced window
-    tlw = sch.window_timeline()
-    assert len(tlw.streams()) == len(wins)
-
-    # and the document round-trips through the strict loader
-    path = write_ledger(led, str(tmp_path / "ledger.json"))
-    assert load_ledger(path)["requests"] == led["requests"]
-
-
-def test_ledger_closes_on_host_loop_run(eng1, prompts):
+def test_ledger_closes_on_host_loop_run(eng1, prompts, tmp_path):
     sch = Scheduler(eng1, **GEO)
     reqs = _run(sch, prompts)
     led = sch.ledger()
@@ -152,27 +67,27 @@ def test_ledger_closes_on_host_loop_run(eng1, prompts):
     rows = {r["request_id"]: r for r in led["requests"]}
     for req in reqs:
         row = rows[req.request_id]
+        assert row["state"] == "finished"
+        assert row["tokens_out"] == 5
         chunks = -(-len(req.prompt) // sch.chunk)
-        # host loop counts plan rows exactly: chunk steps + decodes
+        # the loop counts plan rows exactly: chunk steps + decodes
         assert row["prefill_chunks"] == chunks
+        assert row["decode_steps"] == 5 - 1
         assert row["device_steps"] == chunks + 4
-        assert row["windows"] == 0 and row["inject_wait_us"] == 0
         assert row["device_share_us"] > 0
     # step history carries the slot->request map the ledger folded
     steps = [e for e in sch.history if e["kind"] == "step"]
     assert steps and all(e["slots"] for e in steps)
+    # and the document round-trips through the strict loader
+    path = write_ledger(led, str(tmp_path / "ledger.json"))
+    assert load_ledger(path)["requests"] == led["requests"]
 
 
-# ---------- zero-cost-off (both paths) ----------
-#
-# The resident-path pin lives INSIDE the close test above: the bare
-# run (bare_tokens fixture) and the telemetered run compile the same
-# pallas calls and emit bitwise-identical tokens — one compile each,
-# no third build (the tier-1 wall budget is part of the contract).
+# ---------- zero-cost-off ----------
 
 
 def test_request_tagging_zero_cost_off_host_loop(eng1, prompts):
-    """Host-loop tagging (history + phase accumulation) never touches
+    """Tagging (history + phase accumulation) never touches
     the device: two tagged runs replay the same executable with zero
     new pallas calls after the first, and tokens are bitwise."""
     sch = Scheduler(eng1, **GEO)
@@ -183,26 +98,6 @@ def test_request_tagging_zero_cost_off_host_loop(eng1, prompts):
     assert pallas_call_count() == c0
     assert again == ref
     assert len(sch2.history) > 0  # tagging was on the whole time
-
-
-# ---------- window-row decode strictness ----------
-
-
-def test_window_rows_decode_strictness():
-    buf = np.zeros((3, 1, ost.STAT_WORDS), np.int32)
-    with pytest.raises(ValueError, match="magic"):
-        ost.decode_window_rows(buf)
-    buf[:, 0, ost.RW_MAGIC] = ost.WMAGIC
-    with pytest.raises(ValueError, match="loop lane"):
-        ost.decode_window_rows(buf)  # lane 0 must be -1
-    buf[0, 0, ost.RW_LANE] = -1
-    buf[0, 0, ost.RW_STEPS] = 4
-    buf[1, 0, ost.RW_LANE] = 0
-    buf[2, 0, ost.RW_LANE] = 1
-    buf[2, 0, ost.RW_STEPS] = 3
-    ws = ost.decode_window_rows(buf)
-    assert ws.steps == 4 and len(ws.slots) == 2
-    assert ws.slots[1].slot == 1 and ws.slots[1].steps == 3
 
 
 # ---------- ledger tooling strictness + render modes ----------
@@ -290,38 +185,6 @@ def test_branch_time_attribution_splits_proportionally():
     assert out[9]["attn"] == pytest.approx(10.0 * 1 / 4)
     # shares reassemble the bucket totals
     assert sum(d["mm"] for d in out.values()) == pytest.approx(20.0)
-
-
-# ---------- the window chooser (ROADMAP item 2 follow-up) ----------
-
-
-def test_choose_resident_window_monotone_in_step_time():
-    from triton_dist_tpu.perf_model import (
-        RESIDENT_WINDOW_MAX,
-        RESIDENT_WINDOW_MIN,
-        choose_resident_window,
-    )
-
-    tiny = choose_resident_window(4, 256, 128, 4, 2, 64, 1024, slots=4)
-    big = choose_resident_window(128, 16384, 53248, 64, 8, 128, 152064,
-                                 slots=4, kv_tokens=131072)
-    # fast steps need deep windows; giant steps drown the dispatch
-    assert tiny > big
-    assert RESIDENT_WINDOW_MIN <= big <= tiny <= RESIDENT_WINDOW_MAX
-    assert big == RESIDENT_WINDOW_MIN
-
-
-def test_scheduler_window_none_uses_chooser(eng1):
-    from triton_dist_tpu.perf_model import choose_resident_window
-
-    sch = Scheduler(eng1, resident=True, **GEO)  # window=None
-    cfg = eng1.cfg
-    want = choose_resident_window(
-        cfg.num_layers, cfg.hidden_size, cfg.intermediate_size,
-        cfg.num_q_heads, cfg.num_kv_heads, cfg.head_dim,
-        cfg.vocab_size, slots=GEO["slots"],
-        kv_tokens=sch.pool.t_max, dtype=cfg.dtype)
-    assert sch.worker.window == want != 16
 
 
 # ---------- decomposition histograms on the always-on plane ----------

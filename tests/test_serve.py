@@ -287,6 +287,77 @@ def test_sampled_generation_scheduling_invariant(eng1, prompts,
     assert len({tuple(t) for t in relaxed}) > 1
 
 
+def test_a_request_admitted_mid_decode_reads_what_it_reads_alone(
+        eng1, prompts, step_widths):
+    """Admission time is scheduling, never numerics: a request that
+    arrives while the others decode prefills beside their decode rows
+    and still reads what a run of its own reads."""
+    sch = Scheduler(eng1, **GEO)
+    early = [sch.submit(p, max_new_tokens=10) for p in prompts[:2]]
+    while any(r.state is not RequestState.DECODE for r in early):
+        assert sch.step()
+    for _ in range(2):
+        assert sch.step()
+    arrived_at = sch.worker.n_steps
+    late = sch.submit(prompts[2], max_new_tokens=6)
+    sch.run()
+    assert [r.out_tokens for r in early] == _sequential(eng1, prompts[:2],
+                                                        10)
+    assert late.out_tokens == _sequential(eng1, prompts[2:], 6)[0]
+    # its prefill chunks rode steps in which the others decoded
+    beside = [h for h in sch.history if h["step"] >= arrived_at
+              and {state for _rid, state, _n in h["slots"].values()}
+              == {"prefill", "decode"}]
+    assert len(beside) >= 2
+    sch.pool.check()
+
+
+def test_slots_reused_many_times_read_the_sequential_run(eng1,
+                                                         step_widths):
+    """More requests than slots: a long prompt prefills chunk by chunk
+    while short requests turn the other slots over, every slot serves
+    three requests or more, and each request reads what it reads
+    alone (a reused slot's pages and lengths carry nothing over)."""
+    rng = np.random.default_rng(23)
+    v = eng1.cfg.vocab_size
+    ps = [list(map(int, rng.integers(0, v, 40)))] + [
+        list(map(int, rng.integers(0, v, 5))) for _ in range(10)]
+    sch = Scheduler(eng1, **GEO)
+    reqs = [sch.submit(p, max_new_tokens=3) for p in ps]
+    sch.run()
+    assert [r.out_tokens for r in reqs] == _sequential(eng1, ps, 3)
+    served = {}
+    for h in sch.history:
+        for slot, (rid, _state, _n) in h["slots"].items():
+            served.setdefault(slot, set()).add(rid)
+    assert sorted(served) == list(range(GEO["slots"]))
+    assert all(len(rids) >= 3 for rids in served.values()), served
+    sch.pool.check()
+    assert sch.pool.used_pages() == 0
+
+
+def test_the_scheduler_has_one_loop(eng1, prompts):
+    """One `step()`, one worker, one kind of `history` entry: there is
+    no device-resident window beside the host loop (docs/serving.md
+    "Why there is no device-resident loop")."""
+    import inspect
+
+    import triton_dist_tpu.serve as serve
+
+    params = inspect.signature(Scheduler).parameters
+    assert not {"resident", "window", "ring_cap"} & set(params)
+    assert not hasattr(serve, "ResidentWorker")
+    assert not hasattr(Engine, "make_resident_loop")
+    sch = Scheduler(eng1, **GEO)
+    assert type(sch.worker) is Worker
+    for p in prompts:
+        sch.submit(p, max_new_tokens=4)
+    sch.run()
+    assert sch.history
+    for h in sch.history:
+        assert h["kind"] == "step" and h["width"] in sch.worker.widths
+
+
 # ---------- a step's width: the choice and the dispatch (ISSUE 31) ----------
 
 
@@ -608,7 +679,7 @@ def test_serve_step_executable_shared_and_bounded(eng1):
     assert len(eng1._serve_cache) <= eng1._gen_cache_max
 
 
-def test_moe_engine_serves_stepwise(mesh1):
+def test_moe_engine_serves_stepwise(mesh1, step_widths):
     cfg = ModelConfig.tiny_moe(num_q_heads=4, num_kv_heads=2,
                                num_experts=4)
     eng = Engine(cfg, mesh1, decode_mode="ar", max_len=64,
@@ -756,7 +827,7 @@ def eng8(mesh8):
                   donate_cache=False)
 
 
-def test_distributed_serve_bit_identical(eng8):
+def test_distributed_serve_bit_identical(eng8, step_widths):
     rng = np.random.default_rng(2)
     ps = [list(map(int, rng.integers(0, eng8.cfg.vocab_size, n)))
           for n in (6, 9)]
@@ -800,6 +871,61 @@ def test_mega_paged_decode_runs_over_pool_export(eng8):
     lg_ref, _ = mega.decode_step(tok, pc_ref)
     np.testing.assert_array_equal(np.asarray(lg_pool),
                                   np.asarray(lg_ref))
+
+
+def _dense_from_mega(pc, lengths):
+    """Reconstruct each sequence's valid prefix from a
+    PagedMegaKVCache through ITS page table (numpy gather)."""
+    k = np.asarray(pc.k)
+    tbl = np.asarray(pc.table)
+    page = k.shape[3]
+    out = []
+    for b, ln in enumerate(lengths):
+        rows = [k[:, :, tbl[b, i // page], i % page] for i in range(ln)]
+        out.append(np.stack(rows, axis=2) if rows
+                   else np.zeros(k.shape[:2] + (0, k.shape[-1]),
+                                 k.dtype))
+    return out
+
+
+def test_pool_mega_export_bitwise_under_churn(eng1, prompts):
+    """Allocate/grow/evict/re-admit churn: at every checkpoint the
+    pool's as_mega_cache export reconstructs (through its own table)
+    bitwise the same sequences as paged_cache_from_dense of the dense
+    view, and unallocated table entries stay on the null page 0."""
+    sch = Scheduler(eng1, total_pages=4, **GEO)  # tight: forces churn
+    reqs = [sch.submit(p, max_new_tokens=12) for p in prompts]
+    checked = 0
+    for _ in range(40):
+        if not sch.step() and sch.queue.peek() is None:
+            break
+        if not sch.active:
+            continue
+        sch.pool.check()
+        pc = sch.pool.as_mega_cache()
+        lens = [int(x) for x in np.asarray(pc.length)]
+        # null-page discipline: no allocated position maps to page 0,
+        # and unallocated table entries are exactly 0
+        from triton_dist_tpu.mega.qwen3 import PagedMegaKVCache
+        from triton_dist_tpu.serve import pages_for
+
+        tbl = np.asarray(pc.table)
+        for s, ln in enumerate(lens):
+            held = sch.pool.used_pages(s)  # may run AHEAD of length
+            # (ensure() allocates the next chunk before the step runs)
+            assert held >= (pages_for(ln, sch.pool.page) if ln else 0)
+            assert (tbl[s, :held] > 0).all()
+            assert (tbl[s, held:] == 0).all()
+        pc_ref = PagedMegaKVCache.from_dense(
+            sch.pool.to_dense(), sch.pool.page, 1 + sch.pool.capacity,
+            sch.pool.max_pages)
+        got = _dense_from_mega(pc, lens)
+        want = _dense_from_mega(pc_ref, lens)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+        checked += 1
+    assert sum(r.n_evictions for r in reqs) > 0, "churn never evicted"
+    assert checked >= 5
 
 
 # ---------- failure paths (ISSUE 10 satellites) ----------

@@ -108,12 +108,12 @@ def test_triples_put_the_request_s_id_back_into_the_name():
     log.add("req.prefill", 1, 2, request=4)
     log.add("req.evicted", 2, 2, step=9, request=4)
     log.add("step.retry", 3, 4, step=9)
-    log.add("resident.window", 9, 10, step=12)
+    log.add("sched.idle", 9, 10, step=12)
     with log.span("sched.step", step=12):
         pass
     names = [n for n, _t0, _t1 in log.triples()]
     assert names == ["req4/prefill", "req4/evicted", "step.retry",
-                     "resident.window", "sched.step"]
+                     "sched.idle", "sched.step"]
     assert log.triples()[0][1:] == (1, 2)
 
 

@@ -2,7 +2,7 @@
 
 The load-bearing property: with spec ON, every request's token stream
 is equal to the spec-OFF (and sequential) run — greedy and
-sampled, host loop and resident — because the per-position verify step
+sampled — because the per-position verify step
 samples each column under the per-(seed, token-index) key the
 sequential path would use, so the longest-accepted-prefix rule only
 ever emits the model's own tokens. BITWISE where both runs compute
@@ -16,8 +16,7 @@ the accept rule, the k chooser/pruner, the FailStep-during-verify
 chaos cell (no double emission), metrics, and the bench schema.
 
 Wall budget: ONE engine geometry per module (module-scoped fixtures);
-the spec scheduler adds exactly one per_pos executable and the
-resident-spec loop one spec_k executable.
+the spec scheduler adds exactly one per_pos executable.
 """
 
 import jax
@@ -159,45 +158,18 @@ def test_spec_bitwise_sampled(eng1, prompts, step_widths):
     assert run(_spec()) == run(None)
 
 
-def test_spec_bitwise_resident(eng1, prompts, baseline):
-    base, _ = baseline
-    sch = Scheduler(eng1, resident=True, window=4, spec=_spec(), **GEO)
-    reqs = [sch.submit(p, max_new_tokens=GEN) for p in prompts]
-    sch.run()
-    assert [r.out_tokens for r in reqs] == base
-    m = sch.metrics()
-    assert m["spec_proposed"] > 0 and m["spec_accepted"] > 0
-    sch.pool.check()
-
-
-@pytest.mark.slow  # duplicates the host sampled + resident greedy
-# pins above (the key stream and the KIND_VERIFY path are each already
-# covered); kept for the full matrix on deep runs
-def test_spec_bitwise_resident_sampled(eng1, prompts):
-    def run(spec):
-        sch = Scheduler(eng1, resident=True, window=4, spec=spec,
-                        **GEO)
-        reqs = [sch.submit(p, max_new_tokens=GEN, temperature=0.9,
-                           seed=71 + i) for i, p in enumerate(prompts)]
-        sch.run()
-        return [r.out_tokens for r in reqs]
-
-    assert run(_spec()) == run(None)
-
-
 def test_spec_eos_mid_verify(eng1, prompts, baseline):
     """An eos landing INSIDE an accepted prefix truncates exactly
-    where sequential decode would stop (host + resident)."""
+    where sequential decode would stop."""
     base, _ = baseline
     eos = base[0][8]
     idx = base[0].index(eos)
-    for kw in ({}, {"resident": True, "window": 4}):
-        sch = Scheduler(eng1, spec=_spec(), **GEO, **kw)
-        req = sch.submit(prompts[0], max_new_tokens=GEN, eos_id=eos)
-        sch.run()
-        assert req.out_tokens == base[0][:idx + 1], kw
-        assert req.finish_reason == "eos"
-        sch.pool.check()
+    sch = Scheduler(eng1, spec=_spec(), **GEO)
+    req = sch.submit(prompts[0], max_new_tokens=GEN, eos_id=eos)
+    sch.run()
+    assert req.out_tokens == base[0][:idx + 1]
+    assert req.finish_reason == "eos"
+    sch.pool.check()
 
 
 def test_spec_with_eviction_bitwise(eng1, prompts, baseline):

@@ -296,6 +296,7 @@ def test_shipped_registry_covers_the_kernel_families():
             "broadcast", "low_latency_allgather"} <= names
 
 
+@pytest.mark.needs_semaphore_read
 def test_every_mutant_flagged_with_expected_class():
     import _mutants  # noqa: F401  (registers on import)
 
@@ -530,6 +531,7 @@ def test_task_hb_graph_matches_after_vectors_predicate():
 # ---------- CLI + lint gates (tier-1) ----------
 
 
+@pytest.mark.needs_semaphore_read
 def test_verify_kernels_cli_exit_codes():
     script = os.path.join(REPO, "scripts", "verify_kernels.py")
     for args in ([], ["--mutants"], ["--list"]):

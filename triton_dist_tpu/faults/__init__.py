@@ -54,7 +54,6 @@ from triton_dist_tpu.faults.guard import (  # noqa: F401
     active_build as active_guard_build,
 )
 from triton_dist_tpu.faults.plan import (  # noqa: F401
-    AbandonedRing,
     BitFlipPayload,
     BitFlipScale,
     DelayedSend,

@@ -55,7 +55,7 @@ from triton_dist_tpu.faults.plan import (
 
 PROTOCOLS = ("two_shot_all_reduce", "all_to_all_chunked",
              "low_latency_allgather", "flash_prefill", "serve_step",
-             "serve_resident", "serve_spec", "serve_disagg")
+             "serve_spec", "serve_disagg")
 FAULTS = ("none", "delayed_send", "stalled_rank", "dropped_signal",
           "bitflip_payload", "bitflip_scale")
 OK_OUTCOMES = ("detected", "recovered", "n/a")
@@ -454,90 +454,6 @@ def _shared_page_polarity(engine) -> bool:
     return ok and all(r.done for r in (a, b, c))
 
 
-def _run_serve_resident(mesh, fault: str, engine=None) -> CellResult:
-    """The megakernel-resident serving cell (ISSUE 12). Fault mapping:
-    transient classes (delayed_send / bitflips) land as a one-window
-    FailStep — the retry ladder must absorb them; a persistent stall
-    (stalled_rank) exhausts the ladder and quarantines the poisoner;
-    dropped_signal maps to AbandonedRing — the host published a record
-    whose commit store never landed, the device's bounded ring poll
-    must exit starved and the host must raise a structured
-    DeadlineExceeded ("inject" site), never hang, never drop the
-    tokens already emitted (the oracle below re-checks every token
-    that DID stream against the fault-free host-loop reference)."""
-    from triton_dist_tpu.faults.plan import AbandonedRing
-    from triton_dist_tpu.serve import Scheduler
-
-    if engine is None:
-        return CellResult("serve_resident", fault, "n/a",
-                          "no engine provided")
-    rng = np.random.default_rng(12)
-    prompts = [rng.integers(0, engine.cfg.vocab_size, k).tolist()
-               for k in (5, 7)]
-    geo = dict(slots=2, chunk=4, page=8)
-
-    # fault-free host-loop reference (the bit-identity oracle)
-    ref = Scheduler(engine, **geo)
-    ref_reqs = [ref.submit(p, max_new_tokens=4) for p in prompts]
-    ref.run()
-
-    persistent = fault == "stalled_rank"
-    if fault == "none":
-        plan = None
-    elif fault == "dropped_signal":
-        plan = FaultPlan(AbandonedRing(at_window=1))
-    else:
-        err = "integrity" if fault.startswith("bitflip") else "deadline"
-        times = 4 if persistent else 1
-        plan = FaultPlan(FailStep(at_step=1, times=times, error=err))
-
-    sch = Scheduler(engine, resident=True, window=3,
-                    max_step_retries=2, retry_backoff_s=0.0005, **geo)
-    reqs = [sch.submit(p, max_new_tokens=4) for p in prompts]
-    raised = None
-    with (contextlib.nullcontext() if plan is None
-          else _fplan.injecting(plan)):
-        try:
-            sch.run()
-        except FaultError as e:
-            raised = e
-    m = sch.metrics()
-    # the silent-wrong check: every token that DID stream must match
-    # the fault-free reference prefix, whatever else happened
-    for r, rr in zip(reqs, ref_reqs):
-        if r.out_tokens != rr.out_tokens[:len(r.out_tokens)]:
-            return CellResult("serve_resident", fault, "silent-wrong",
-                              f"req{r.request_id} tokens diverged")
-    if fault == "none":
-        ok = (raised is None and m["quarantined"] == 0
-              and m["step_retries"] == 0
-              and all(r.done for r in reqs))
-        return CellResult("serve_resident", fault,
-                          "recovered" if ok else "silent-wrong",
-                          "clean run")
-    if fault == "dropped_signal":
-        trips = getattr(raised, "trips", None) or []
-        ok = (raised is not None
-              and any(t.site_label == "inject" for t in trips))
-        return CellResult(
-            "serve_resident", fault,
-            "detected" if ok else "silent-wrong",
-            f"raised={type(raised).__name__ if raised else None} "
-            f"retries={m['step_retries']}")
-    if persistent:
-        ok = m["quarantined"] == 1 and m["step_retries"] >= 3
-        return CellResult(
-            "serve_resident", fault,
-            "detected" if ok else "silent-wrong",
-            f"quarantined={m['quarantined']} "
-            f"retries={m['step_retries']}")
-    ok = (raised is None and m["quarantined"] == 0
-          and m["step_retries"] >= 1 and all(r.done for r in reqs))
-    return CellResult(
-        "serve_resident", fault, "recovered" if ok else "silent-wrong",
-        f"retries={m['step_retries']}")
-
-
 def _run_serve_disagg(mesh, fault: str, engine=None) -> CellResult:
     """The DCN-hop cell (ISSUE 18): the chaos vector is the MIGRATION
     CHANNEL between a prefill slice and a decode slice — dropped
@@ -643,8 +559,6 @@ def run_matrix(mesh, axis: str = "tp", protocols=None, faults=None,
         "flash_prefill": lambda f: _run_flash_prefill(mesh, axis, f),
         "serve_step": lambda f: _run_serve_step(mesh, f,
                                                 engine=serve_engine),
-        "serve_resident": lambda f: _run_serve_resident(
-            mesh, f, engine=serve_engine),
         "serve_spec": lambda f: _run_serve_spec(
             mesh, f, engine=serve_engine),
         "serve_disagg": lambda f: _run_serve_disagg(

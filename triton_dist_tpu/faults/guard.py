@@ -63,7 +63,6 @@ SITES = {
     "segment": 6,   # flash-prefill per-segment delivery wait
     "collect": 7,   # full-mesh collect slot wait
     "wire": 8,      # wire-image integrity failure at a consume edge
-    "inject": 9,    # work-injection ring poll (resident serve window)
 }
 _SITE_NAMES = {v: k for k, v in SITES.items()}
 

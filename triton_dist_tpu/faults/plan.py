@@ -113,24 +113,8 @@ class FailStep:
             f"injected step deadline at serve step {self.at_step}")
 
 
-@dataclasses.dataclass(frozen=True)
-class AbandonedRing:
-    """Megakernel-resident serving fault (ISSUE 12): before launching
-    resident window `at_window`, the producer PUBLISHES one injection
-    record without ever committing its seq field — the torn-write /
-    crashed-producer shape. The device's bounded ring poll must exit
-    the window starved (a structured "inject"-site DeadlineExceeded
-    from ResidentWorker), never spin on the hole and never consume the
-    garbage row. One abandonment poisons the ring permanently (the
-    hole sits ahead of every later record), so the scheduler's retry
-    ladder exhausts and surfaces the trip — exactly the
-    host-stops-feeding chaos cell."""
-
-    at_window: int
-
-
 FAULT_CLASSES = (DelayedSend, StalledRank, DroppedSignal, BitFlipPayload,
-                 BitFlipScale, FailStep, AbandonedRing)
+                 BitFlipScale, FailStep)
 
 
 class FaultPlan:
@@ -200,17 +184,6 @@ class FaultPlan:
                     self._step_fired[id(f)] = fired + 1
                     return f.exception()
         return None
-
-    def ring_abandons(self, window_index: int) -> bool:
-        """Should the injection-ring producer abandon (publish without
-        committing) one record before resident window `window_index`?
-        Fires once per AbandonedRing spec."""
-        for f in self.faults:
-            if isinstance(f, AbandonedRing) and f.at_window == window_index:
-                if not self._step_fired.get(("ring", id(f)), False):
-                    self._step_fired[("ring", id(f))] = True
-                    return True
-        return False
 
 
 def scheduled_straggler(protocol: str, given=None):

@@ -553,7 +553,7 @@ class MegaQwen3:
         tokens (B,) -> (generated ids (B, steps), cache). Greedy only
         (argmax — the self-feeding loop's fixed point); bitwise equal
         to `steps` repeated decode_step/argmax calls, test-pinned
-        (tests/test_serve_resident.py)."""
+        (tests/test_mega_model.py)."""
         assert steps >= 1
         assert self._trace_build is None, (
             "decode_resident does not thread per-step trace buffers; "

@@ -113,7 +113,7 @@ class Schedule:
     stall: Any = None
     prefetch: Optional[PrefetchPlan] = None
     # fusion-plan provenance: schedule_graph(plan=...) stamps the
-    # triton_dist_tpu.plan.Plan id so resident serving and one-shot
+    # triton_dist_tpu.plan.Plan id so serving and one-shot
     # forwards can be checked to agree on pairings
     plan_id: Optional[str] = None
 

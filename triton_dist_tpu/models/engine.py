@@ -51,17 +51,9 @@ def _serve_step_math(cfg, mode, axis, slots, chunk, page, t_pool,
     """THE per-rank serve-step computation (inside shard_map): one
     (slots, chunk) forward over the paged pool's dense view, per-slot
     sampling, and the null-page-routed KV scatter. Generic in `chunk`:
-    the host loop compiles it once a width of `Engine.serve_widths`
+    `make_serve_step` compiles it once a width of `Engine.serve_widths`
     (the decode-only step is `chunk == 1`: one query row a slot
-    through the dense attention chain, no prefill route). Shared
-    VERBATIM between `make_serve_step` (the host-loop replay) and
-    `make_resident_loop` (the device-resident window, which keeps the
-    one wide geometry) — the serve plane's bit-identity discipline
-    extends to the resident loop because both compile exactly this
-    function on identical inputs: the resident loop's tokens are
-    bitwise those of a host loop held to the wide step
-    (tests/test_serve_resident.py pins the loop-vs-standalone bitwise
-    equality end to end).
+    through the dense attention chain, no prefill route).
 
     per_pos=False: keys (K, 2) u32, the returned token is sampled at
     column n_valid-1 only — the classic one-emission step. per_pos=True
@@ -479,432 +471,6 @@ class Engine:
                 f"sequence-sharded mode {self.decode_mode!r} needs "
                 f"slots*chunk ({slots}*{chunk}) divisible by tp={n}"
             )
-
-    # -- resident step loop (megakernel-resident serving, ISSUE 12) ---------
-
-    def make_resident_loop(self, slots: int, chunk: int, page: int,
-                           max_pages: int, window: int,
-                           ring_cap: int = 64,
-                           prompt_cap: Optional[int] = None,
-                           poll_budget: int = 8, spec_k: int = 0):
-        """Compile the DEVICE-RESIDENT serve loop: up to `window` serve
-        steps inside one executable — consume work-injection records at
-        each step boundary, run the SAME per-rank step math as
-        `make_serve_step`, self-feed decode tokens, and stream
-        completions (emitted tokens + retirement flags) into a mirrored
-        output ring — so a window of W steps costs ONE dispatch instead
-        of W (the r05 `engine_decode_ms` vs `mega_decode_*` gap is pure
-        per-step dispatch tax; this loop is how the serve plane stops
-        paying it per token).
-
-        Contract (docs/serving.md "Device-resident serving"):
-
-          fn(params, ring (cap, RW) i32, published () i32,
-             consumed () i32, step0 () i32, slot_state (K, SS) i32,
-             table (K, MAXP) i32, lengths (K,) i32, pool_k, pool_v)
-          -> (consumed, executed, slot_state, table, lengths,
-              pool_k, pool_v, out_ring (out_cap, OW) i32,
-              out_count, starved)
-
-        All loop state round-trips through the call, so successive
-        windows chain seamlessly; pool buffers are donated like the
-        host-loop step. The loop exits when `window` steps executed OR
-        nothing is active and the pending-record poll budget is
-        exhausted; `starved` is set when a published head record never
-        became visible (abandoned ring — the host raises a structured
-        DeadlineExceeded from it, see serve.worker.ResidentWorker).
-
-        Per-request tokens are BITWISE what the host-loop scheduler
-        emits: both paths compile `_serve_step_math` and the device
-        plan assembly (`mega.ring.slot_plan`) reproduces the host
-        scheduler's per-step inputs field for field, including the
-        fold_in(PRNGKey(seed), n_out) sampling-key stream.
-
-        Telemetry (ISSUE 13, docs/observability.md "Request-scoped
-        attribution"): a loop constructed under `trace.building()`
-        returns one extra trailing output — a pure-jnp mark stream of
-        serve.step spans (payload=device step, aux=active-slot mask)
-        plus serve.poll / serve.idle instants; under
-        `obs.stats.building()` one more — the (1 + slots, 1,
-        STAT_WORDS) resident-window stat rows (obs.stats.WMAGIC: loop
-        lane + one lane per slot), OUTERMOST last (the stats-then-trace
-        strip order). Both are data-independent integer streams: tokens
-        stay bitwise identical with telemetry on, and the bare loop's
-        program is untouched (zero-cost-off, tier-1-pinned).
-
-        spec_k > 0 compiles the SPEC-CAPABLE loop (ISSUE 14,
-        triton_dist_tpu.spec): KIND_VERIFY injection records stage up
-        to spec_k draft tokens on a decoding slot, the next step runs
-        the per-position verify row, and the longest accepted prefix
-        streams out as FLAG_SPEC output records (up to spec_k + 1 per
-        slot per step — out_cap scales accordingly). spec_k=0 keeps
-        today's program exactly (the branch is trace-time)."""
-        from triton_dist_tpu.obs import stats as _ost
-        from triton_dist_tpu.trace import events as _tev
-
-        self._refuse_hybrid("the device-resident loop (its carry and "
-                            "mega.ring's slot plan hold keys and values "
-                            "only)")
-        prompt_cap = prompt_cap if prompt_cap is not None \
-            else max_pages * page
-        # the build contexts are consulted when the loop is CONSTRUCTED
-        # (the trace/obs discipline) — a loop built under
-        # trace.building()/obs.stats.building() returns extra trailing
-        # telemetry outputs, so it must never share an executable with
-        # the bare loop
-        _tb = _tev.active_build()
-        _ob = _ost.active_build()
-        key = ("resident", slots, chunk, page, max_pages, window,
-               ring_cap, prompt_cap, poll_budget, spec_k,
-               _tb.cap if _tb is not None else -1, _ob is not None)
-        fn = self._serve_cache.pop(key, None)
-        if fn is None:
-            fn = self._build_resident_loop(slots, chunk, page, max_pages,
-                                           window, ring_cap, prompt_cap,
-                                           poll_budget, spec_k)
-            while len(self._serve_cache) >= self._gen_cache_max:
-                self._serve_cache.pop(next(iter(self._serve_cache)))
-        self._serve_cache[key] = fn  # re-insert = LRU touch
-        return fn
-
-    def _build_resident_loop(self, slots: int, chunk: int, page: int,
-                             max_pages: int, window: int, ring_cap: int,
-                             prompt_cap: int, poll_budget: int,
-                             spec_k: int = 0):
-        from triton_dist_tpu.mega import ring as mring
-        from triton_dist_tpu.obs import stats as _ost
-        from triton_dist_tpu.trace import events as _tev
-
-        cfg = self.cfg
-        mode = self.decode_mode
-        axis = self.axis
-        t_pool = max_pages * page
-        self._check_serve_geometry(slots, chunk, page, max_pages)
-        # same memoized Plan object as make_serve_step's — the resident
-        # loop and the host-loop replay agree on pairings by identity
-        plan = self.plan_for(slots, chunk, kind="decode")
-        assert window >= 1 and ring_cap >= 2 and poll_budget >= 1
-        tb_build = _tev.active_build()
-        ob_build = _ost.active_build()
-        # serve.step aux carries the active-slot BITMASK, so traced
-        # builds need every slot lane to fit an i32
-        assert tb_build is None or slots <= 30, (
-            f"traced resident loop supports <= 30 slots (got {slots}): "
-            "the serve.step active mask is one i32")
-        # worst case: every step emits on every slot — up to 1 + spec_k
-        # tokens each on a spec-verify step — plus one token-less
-        # retirement record per injection-ring retire
-        out_cap = window * slots * (1 + spec_k) + ring_cap
-
-        def scatter_out(out_ring, out_count, step, rows_mask, slot_ids,
-                        toks, flags, reasons, reqids, spares=None):
-            """Append one output record per set slot of rows_mask, in
-            slot order; non-writers scatter to the trash row out_cap."""
-            offs = jnp.cumsum(rows_mask) - rows_mask
-            rows = jnp.where(rows_mask > 0, out_count + offs, out_cap)
-            rec = jnp.stack([
-                out_count + offs + 1, slot_ids,
-                jnp.full_like(slot_ids, step), toks, flags, reasons,
-                reqids,
-                jnp.zeros_like(slot_ids) if spares is None else spares,
-            ], axis=-1)
-            return (out_ring.at[rows].set(rec),
-                    out_count + jnp.sum(rows_mask))
-
-        def per_rank(params, ring, published, consumed0, step0,
-                     slot_state, table, lengths, pool_k, pool_v):
-            out_ring0 = jnp.zeros((out_cap + 1, mring.OR_WIDTH),
-                                  jnp.int32)
-            slot_ids = jnp.arange(slots, dtype=jnp.int32)
-            # telemetry carried through the loop — trace-time gated, so
-            # the bare build's carry (and program) is exactly the
-            # untelemetered one. All entries are data-independent
-            # integer streams: they never feed the step math.
-            aux0 = {}
-            if tb_build is not None:
-                aux0["t"] = _tev.new_stream(tb_build, stream=0, rank=0)
-            if ob_build is not None:
-                zk = jnp.zeros((slots,), jnp.int32)
-                aux0.update(polls=jnp.int32(0), idlep=jnp.int32(0),
-                            s_steps=zk, s_idle=zk, s_emits=zk)
-
-            def boundary(executed, consumed, ss, tb, ln, out, n_out,
-                         aux):
-                """Step boundary: drain visible injection records and
-                report host-forced retirements out."""
-                step = step0 + executed
-                consumed2, ss, tb, ln, retired = mring.device_consume(
-                    ring, published, consumed, step, ss, tb, ln)
-                out, n_out = scatter_out(
-                    out, n_out, step, retired, slot_ids,
-                    jnp.full((slots,), -1, jnp.int32),
-                    jnp.full((slots,), mring.FLAG_RETIRED, jnp.int32),
-                    jnp.full((slots,), mring.REASON_HOST, jnp.int32),
-                    ss[:, mring.SS_REQID])
-                if tb_build is not None:
-                    aux = dict(aux, t=_tev.mark(
-                        aux["t"], _tev.REGIONS["serve.poll"],
-                        payload=consumed2 - consumed,
-                        aux=published - consumed2))
-                if ob_build is not None:
-                    aux = dict(aux, polls=aux["polls"] + 1)
-                return consumed2, ss, tb, ln, out, n_out, aux
-
-            def cond(carry):
-                (executed, consumed, idle, ss, tb, ln, pk, pv, out,
-                 n_out, aux) = carry
-                any_active = jnp.any(ss[:, mring.SS_ACTIVE] > 0)
-                pending = consumed < published
-                return (executed < window) & (
-                    any_active | (pending & (idle < poll_budget)))
-
-            def body(carry):
-                (executed, consumed, idle, ss, tb, ln, pk, pv, out,
-                 n_out, aux) = carry
-                consumed2, ss, tb, ln, out, n_out, aux = boundary(
-                    executed, consumed, ss, tb, ln, out, n_out, aux)
-                any_active = jnp.any(ss[:, mring.SS_ACTIVE] > 0)
-
-                def run_step_spec(ss, tb, ln, pk, pv, out, n_out, aux):
-                    """The spec-capable step (ISSUE 14, compiled only
-                    when spec_k > 0 — the plain loop's program is
-                    untouched): a decoding slot with a fresh KIND_VERIFY
-                    record runs a [last, d_1..d_kd] verify row through
-                    the per-position step math; the longest accepted
-                    prefix (plus the bonus token) is emitted — one
-                    output record per token, FLAG_SPEC-tagged, the
-                    first carrying kd — and the slot length advances by
-                    the EMITTED count (rejected positions hold masked
-                    garbage the next step overwrites, exactly the
-                    post-eviction stale-page class). Every emitted
-                    token is bitwise the sequential emission for its
-                    output index (per-column fold_in keys)."""
-                    step = step0 + executed
-                    active = ss[:, mring.SS_ACTIVE] > 0
-                    if tb_build is not None:
-                        mask = jnp.sum(jnp.where(
-                            active, jnp.int32(1) << slot_ids, 0))
-                        aux = dict(aux, t=_tev.mark(
-                            aux["t"], _tev.REGIONS["serve.step"],
-                            _tev.KIND_BEGIN, payload=step, aux=mask))
-                    tokens, n_valid, temps, keys, emits, kdv = \
-                        mring.slot_plan_spec(ring, ss, chunk,
-                                             max_pages, spec_k)
-                    tok_all, _last, pk, pv = _serve_step_math(
-                        cfg, mode, axis, slots, chunk, page, t_pool,
-                        params, tokens, pk, pv, tb, ln,
-                        n_valid, temps, keys, per_pos=True, plan=plan)
-                    prefill = ss[:, mring.SS_PHASE] == 0
-                    base = jnp.maximum(n_valid - 1 - kdv, 0)
-                    span = jnp.arange(spec_k + 1, dtype=jnp.int32)
-                    colsm = jnp.clip(base[:, None] + span[None, :],
-                                     0, chunk - 1)
-                    o = jnp.take_along_axis(tok_all, colsm, axis=1)
-                    d = jnp.take_along_axis(
-                        tokens, jnp.clip(colsm + 1, 0, chunk - 1),
-                        axis=1)
-                    accept = ((o == d)
-                              & (span[None, :] < kdv[:, None])
-                              ).astype(jnp.int32)
-                    acc = jnp.sum(jnp.cumprod(accept, axis=1), axis=1)
-                    e = jnp.where(emits, acc + 1, 0)
-                    eos = ss[:, mring.SS_EOS]
-                    hits = (eos[:, None] > 0) & (o == eos[:, None] - 1)
-                    hit_in = hits & (span[None, :] < e[:, None])
-                    e = jnp.where(jnp.any(hit_in, axis=1),
-                                  jnp.argmax(hit_in, axis=1) + 1, e)
-                    rem = jnp.maximum(
-                        ss[:, mring.SS_MAX_NEW] - ss[:, mring.SS_N_OUT],
-                        0)
-                    e = jnp.minimum(e, rem)
-                    hit_eos = jnp.any(
-                        hits & (span[None, :] < e[:, None]), axis=1)
-                    n_out_new = ss[:, mring.SS_N_OUT] + e
-                    hit_len = (emits & (e > 0) & (~hit_eos)
-                               & (n_out_new >= ss[:, mring.SS_MAX_NEW]))
-                    finished = hit_eos | hit_len
-                    advance = jnp.where(prefill, n_valid, e)
-                    ln = ln + advance
-                    last_tok = jnp.take_along_axis(
-                        o, jnp.maximum(e - 1, 0)[:, None], axis=1)[:, 0]
-                    new_pos = ss[:, mring.SS_POS] + jnp.where(
-                        prefill, n_valid, 0)
-                    completing = (prefill
-                                  & (new_pos
-                                     >= ss[:, mring.SS_PROMPT_LEN])
-                                  & (ss[:, mring.SS_ACTIVE] > 0))
-                    ss = (ss
-                          .at[:, mring.SS_POS].set(new_pos)
-                          .at[:, mring.SS_PHASE].set(jnp.where(
-                              completing, 1, ss[:, mring.SS_PHASE]))
-                          .at[:, mring.SS_N_OUT].set(n_out_new)
-                          .at[:, mring.SS_LAST_TOK].set(jnp.where(
-                              e > 0, last_tok,
-                              ss[:, mring.SS_LAST_TOK]))
-                          .at[:, mring.SS_ACTIVE].set(jnp.where(
-                              finished, 0, ss[:, mring.SS_ACTIVE]))
-                          # staged verify records are one-shot
-                          .at[:, mring.SS_SPEC_K].set(0))
-                    spec_row = (kdv > 0).astype(jnp.int32)
-                    for j in range(spec_k + 1):
-                        m_j = (e > j).astype(jnp.int32)
-                        is_last = jnp.equal(e - 1, j)
-                        flags = (m_j * mring.FLAG_EMIT
-                                 + (is_last & finished).astype(jnp.int32)
-                                 * mring.FLAG_RETIRED
-                                 + m_j * spec_row * mring.FLAG_SPEC)
-                        reasons = jnp.where(
-                            is_last & hit_eos, mring.REASON_EOS,
-                            jnp.where(is_last & hit_len,
-                                      mring.REASON_LENGTH, 0))
-                        spare = spec_row * (
-                            kdv if j == 0 else jnp.zeros_like(kdv))
-                        out, n_out = scatter_out(
-                            out, n_out, step, m_j, slot_ids, o[:, j],
-                            flags, reasons, ss[:, mring.SS_REQID],
-                            spares=spare)
-                    if tb_build is not None:
-                        aux = dict(aux, t=_tev.mark(
-                            aux["t"], _tev.REGIONS["serve.step"],
-                            _tev.KIND_END, payload=step, aux=mask))
-                    if ob_build is not None:
-                        active_i = active.astype(jnp.int32)
-                        aux = dict(
-                            aux,
-                            s_steps=aux["s_steps"] + active_i,
-                            s_idle=aux["s_idle"] + 1 - active_i,
-                            s_emits=aux["s_emits"] + e)
-                    return 1, ss, tb, ln, pk, pv, out, n_out, aux
-
-                def run_step(ss, tb, ln, pk, pv, out, n_out, aux):
-                    step = step0 + executed
-                    active = ss[:, mring.SS_ACTIVE] > 0
-                    if tb_build is not None:
-                        mask = jnp.sum(jnp.where(
-                            active, jnp.int32(1) << slot_ids, 0))
-                        aux = dict(aux, t=_tev.mark(
-                            aux["t"], _tev.REGIONS["serve.step"],
-                            _tev.KIND_BEGIN, payload=step, aux=mask))
-                    tokens, n_valid, temps, keys, emits = \
-                        mring.slot_plan(ring, ss, chunk, max_pages)
-                    tok, _last, pk, pv = _serve_step_math(
-                        cfg, mode, axis, slots, chunk, page, t_pool,
-                        params, tokens, pk, pv, tb, ln,
-                        n_valid, temps, keys, plan=plan)
-                    ln = ln + n_valid
-                    # post-step slot-state advance (mirrors the host
-                    # scheduler's per-plan bookkeeping field for field)
-                    prefill = ss[:, mring.SS_PHASE] == 0
-                    new_pos = ss[:, mring.SS_POS] + jnp.where(
-                        prefill, n_valid, 0)
-                    completing = (prefill
-                                  & (new_pos >= ss[:, mring.SS_PROMPT_LEN])
-                                  & (ss[:, mring.SS_ACTIVE] > 0))
-                    emits_i = emits.astype(jnp.int32)
-                    n_out_new = ss[:, mring.SS_N_OUT] + emits_i
-                    eos = ss[:, mring.SS_EOS]
-                    hit_eos = emits & (eos > 0) & (tok == eos - 1)
-                    hit_len = emits & (n_out_new
-                                       >= ss[:, mring.SS_MAX_NEW])
-                    finished = hit_eos | hit_len
-                    ss = (ss
-                          .at[:, mring.SS_POS].set(new_pos)
-                          .at[:, mring.SS_PHASE].set(jnp.where(
-                              completing, 1, ss[:, mring.SS_PHASE]))
-                          .at[:, mring.SS_N_OUT].set(n_out_new)
-                          .at[:, mring.SS_LAST_TOK].set(jnp.where(
-                              emits, tok, ss[:, mring.SS_LAST_TOK]))
-                          .at[:, mring.SS_ACTIVE].set(jnp.where(
-                              finished, 0, ss[:, mring.SS_ACTIVE])))
-                    flags = (emits_i * mring.FLAG_EMIT
-                             + finished.astype(jnp.int32)
-                             * mring.FLAG_RETIRED)
-                    reasons = jnp.where(
-                        hit_eos, mring.REASON_EOS,
-                        jnp.where(hit_len, mring.REASON_LENGTH, 0))
-                    out, n_out = scatter_out(
-                        out, n_out, step, emits_i, slot_ids, tok,
-                        flags, reasons, ss[:, mring.SS_REQID])
-                    if tb_build is not None:
-                        aux = dict(aux, t=_tev.mark(
-                            aux["t"], _tev.REGIONS["serve.step"],
-                            _tev.KIND_END, payload=step, aux=mask))
-                    if ob_build is not None:
-                        active_i = active.astype(jnp.int32)
-                        aux = dict(
-                            aux,
-                            s_steps=aux["s_steps"] + active_i,
-                            s_idle=aux["s_idle"] + 1 - active_i,
-                            s_emits=aux["s_emits"] + emits_i)
-                    return 1, ss, tb, ln, pk, pv, out, n_out, aux
-
-                def idle_step(ss, tb, ln, pk, pv, out, n_out, aux):
-                    if tb_build is not None:
-                        aux = dict(aux, t=_tev.mark(
-                            aux["t"], _tev.REGIONS["serve.idle"],
-                            payload=step0 + executed))
-                    return 0, ss, tb, ln, pk, pv, out, n_out, aux
-
-                (stepped, ss, tb, ln, pk, pv, out, n_out,
-                 aux) = jax.lax.cond(
-                    any_active,
-                    run_step_spec if spec_k else run_step, idle_step,
-                    ss, tb, ln, pk, pv, out, n_out, aux)
-                if ob_build is not None:
-                    aux = dict(aux, idlep=aux["idlep"] + 1 - stepped)
-                progressed = (stepped > 0) | (consumed2 > consumed)
-                idle = jnp.where(progressed, 0, idle + 1)
-                return (executed + stepped, consumed2, idle, ss, tb,
-                        ln, pk, pv, out, n_out, aux)
-
-            carry = (jnp.int32(0), consumed0, jnp.int32(0), slot_state,
-                     table, lengths, pool_k, pool_v, out_ring0,
-                     jnp.int32(0), aux0)
-            (executed, consumed, _idle, ss, tb, ln, pk, pv, out,
-             n_out, aux) = jax.lax.while_loop(cond, body, carry)
-            # a final boundary drain: records whose at_step gate opened
-            # on the LAST executed step (e.g. a retire targeted at the
-            # window's end) must not wait a whole extra window
-            consumed, ss, tb, ln, out, n_out, aux = boundary(
-                executed, consumed, ss, tb, ln, out, n_out, aux)
-            starved = mring.head_abandoned(
-                ring, published, consumed).astype(jnp.int32)
-            extras = ()
-            if tb_build is not None:
-                extras += (aux["t"],)
-            if ob_build is not None:
-                # the resident-window stat rows (obs/stats.py WMAGIC
-                # layout): loop lane first, then one lane per slot
-                i32 = jnp.int32
-                loop_row = jnp.stack([
-                    i32(_ost.WMAGIC), i32(-1), executed, aux["polls"],
-                    aux["idlep"], consumed - consumed0, starved,
-                    i32(0)])
-                slot_rows = jnp.stack([
-                    jnp.full((slots,), _ost.WMAGIC, jnp.int32),
-                    slot_ids, aux["s_steps"], aux["s_idle"],
-                    aux["s_emits"], ss[:, mring.SS_REQID],
-                    jnp.zeros((slots,), jnp.int32),
-                    jnp.zeros((slots,), jnp.int32)], axis=-1)
-                wrow = jnp.concatenate(
-                    [loop_row[None], slot_rows], 0)[:, None, :]
-                extras += (wrow,)
-            return (consumed, executed, ss, tb, ln, pk, pv,
-                    out[:out_cap], n_out, starved) + extras
-
-        n_extras = (tb_build is not None) + (ob_build is not None)
-        pool_spec = P(None, self.axis)
-        return jax.jit(
-            jax.shard_map(
-                per_rank, mesh=self.mesh,
-                in_specs=((self._wrap_specs[0],) + (P(),) * 7
-                          + (pool_spec, pool_spec)),
-                out_specs=((P(),) * 5 + (pool_spec, pool_spec)
-                           + (P(),) * (3 + n_extras)),
-                check_vma=False,
-            ),
-            donate_argnums=(8, 9) if self._donate_cache else (),
-        )
 
     # -- API ----------------------------------------------------------------
 

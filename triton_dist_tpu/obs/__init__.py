@@ -51,11 +51,8 @@ from triton_dist_tpu.obs import stats  # noqa: F401
 from triton_dist_tpu.obs.stats import (  # noqa: F401
     KernelStats,
     STAT_WORDS,
-    WindowStats,
-    decode_window_rows,
     metered,
     record_stats,
-    window_agree_with_trace,
 )
 from triton_dist_tpu.obs import trend  # noqa: F401
 from triton_dist_tpu.obs.recorder import (  # noqa: F401

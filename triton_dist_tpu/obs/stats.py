@@ -449,140 +449,6 @@ def consume_rows(buf, kernel: str) -> None:
         kernel=kernel)
 
 
-# -- resident-window stat rows (the serve plane's lane set, ISSUE 13) --------
-#
-# The resident serve loop (models/engine.make_resident_loop) is pure
-# XLA — it has no semaphores or DMA queues of its own, so its wait
-# classification is the loop-level analog of sem_wait/dma_wait: RING POLLS
-# (boundary drains of the injection ring) and IDLE POLLS (poll-budget
-# burn while nothing is active). Under obs.stats.building() the loop
-# returns one trailing (1 + slots, 1, STAT_WORDS) i32 output — the
-# PR-11 trailing-row idiom with one LANE PER SLOT plus a loop lane —
-# so every word of device time in a window is attributable to a slot
-# (and through the scheduler's slot history, to a REQUEST):
-#
-#   lane 0 (the loop lane, RW_LANE = -1):
-#     [WMAGIC, -1, steps, ring_polls, idle_polls, consumed, starved, 0]
-#   lane 1+s (slot lane s):
-#     [WMAGIC, s, steps_active, steps_idle, emits, last_req_id, 0, 0]
-#
-# The agreement contract (the PR-11 pin extended): a loop built under
-# BOTH trace.building() and stats.building() emits serve.step spans /
-# serve.poll / serve.idle instants whose counts (and per-slot
-# active-mask popcounts) equal these counters exactly —
-# `window_agree_with_trace` below, tier-1-pinned.
-
-WMAGIC = 0x5D7B  # resident-window row tag (OMAGIC family)
-
-# loop-lane words
-RW_MAGIC, RW_LANE, RW_STEPS, RW_POLLS, RW_IDLE, RW_CONSUMED, \
-    RW_STARVED = range(7)
-# slot-lane words (RW_MAGIC/RW_LANE/RW_STEPS shared)
-RW_SLOT_IDLE, RW_EMITS, RW_REQID = 3, 4, 5
-
-
-@dataclasses.dataclass(frozen=True)
-class WindowSlotStats:
-    """One decoded slot lane of a resident-window row set."""
-
-    slot: int
-    steps: int       # device steps this slot ran a plan row in
-    idle_steps: int  # executed steps the slot sat inactive through
-    emits: int       # tokens emitted (prefill-tail + decode)
-    req_id: int      # the lane's occupant at window end (-0 when idle)
-
-
-@dataclasses.dataclass(frozen=True)
-class WindowStats:
-    """One decoded resident-window row set (loop lane + slot lanes)."""
-
-    steps: int       # executed device steps this window
-    ring_polls: int  # injection-ring boundary drains
-    idle_polls: int  # loop iterations that executed no step
-    consumed: int    # injection records consumed this window
-    starved: int     # abandoned-ring flag at window exit
-    slots: List[WindowSlotStats] = dataclasses.field(
-        default_factory=list)
-
-
-def decode_window_rows(buf) -> WindowStats:
-    """Decode one (1 + slots, 1, STAT_WORDS) resident-window output.
-    A row without the window magic is malformed."""
-    import numpy as np
-
-    a = np.asarray(buf).reshape(-1, STAT_WORDS)
-    if a.shape[0] < 1:
-        raise ValueError(f"empty window-row buffer {a.shape}")
-    for r in a:
-        if int(r[RW_MAGIC]) != WMAGIC:
-            raise ValueError(
-                f"window row magic {int(r[RW_MAGIC]):#x} != {WMAGIC:#x} "
-                "(uninitialized or clobbered)")
-    loop = a[0]
-    if int(loop[RW_LANE]) != -1:
-        raise ValueError(
-            f"window row 0 lane {int(loop[RW_LANE])} != -1 (loop lane "
-            "must lead)")
-    slots = [
-        WindowSlotStats(
-            slot=int(r[RW_LANE]), steps=int(r[RW_STEPS]),
-            idle_steps=int(r[RW_SLOT_IDLE]), emits=int(r[RW_EMITS]),
-            req_id=int(r[RW_REQID]))
-        for r in a[1:]
-    ]
-    return WindowStats(
-        steps=int(loop[RW_STEPS]), ring_polls=int(loop[RW_POLLS]),
-        idle_polls=int(loop[RW_IDLE]), consumed=int(loop[RW_CONSUMED]),
-        starved=int(loop[RW_STARVED]), slots=slots)
-
-
-def record_window_stats(registry, ws: WindowStats) -> None:
-    """Fold one window's counters into a metrics Registry — the serve
-    plane's record_stats analog: serve_resident_ring_polls /
-    serve_resident_idle_polls counters beside the existing
-    serve_resident_windows/steps family."""
-    registry.inc("serve_resident_ring_polls", ws.ring_polls)
-    registry.inc("serve_resident_idle_polls", ws.idle_polls)
-
-
-def window_agree_with_trace(ws: WindowStats, tl, stream: str) -> None:
-    """THE agreement pin, resident-window form: on a loop built under
-    BOTH trace.building() and stats.building(), the window row's
-    counters must equal the trace stream's serve.* record counts —
-    steps == serve.step spans, ring_polls == serve.poll instants,
-    idle_polls == serve.idle instants, consumed == the summed
-    serve.poll payloads — and each slot lane's steps must equal the
-    popcount of its bit across the serve.step active masks (aux).
-    Raises AssertionError with the diff."""
-    from triton_dist_tpu.trace import events as ev
-
-    steps = tl.spans_of(stream, region="serve.step")
-    polls = [e for e in tl.events
-             if e.stream == stream and e.kind == ev.KIND_INSTANT
-             and e.region == ev.REGIONS["serve.poll"]]
-    idles = [e for e in tl.events
-             if e.stream == stream and e.kind == ev.KIND_INSTANT
-             and e.region == ev.REGIONS["serve.idle"]]
-    assert ws.steps == len(steps), (
-        f"window row steps {ws.steps} != {len(steps)} serve.step spans")
-    assert ws.ring_polls == len(polls), (
-        f"window row ring_polls {ws.ring_polls} != {len(polls)} "
-        "serve.poll instants")
-    assert ws.idle_polls == len(idles), (
-        f"window row idle_polls {ws.idle_polls} != {len(idles)} "
-        "serve.idle instants")
-    consumed = sum(e.payload for e in polls)
-    assert ws.consumed == consumed, (
-        f"window row consumed {ws.consumed} != {consumed} summed "
-        "serve.poll payloads")
-    for lane in ws.slots:
-        mask_steps = sum(1 for s in steps
-                         if (s.aux >> lane.slot) & 1)
-        assert lane.steps == mask_steps, (
-            f"slot lane {lane.slot}: {lane.steps} steps != {mask_steps} "
-            "serve.step active-mask bits")
-
-
 def agree_with_trace(stats: List[KernelStats], tl, stream: str) -> None:
     """THE agreement pin: on a run whose kernel was built under BOTH
     trace.building() and stats.building(), every rank's stat-row

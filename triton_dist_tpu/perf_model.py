@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 from typing import Optional
 
 import jax.numpy as jnp
@@ -989,7 +988,7 @@ def estimate_serve_step_ms(
     """Roofline of ONE mixed prefill+decode serve step
     (models/engine.make_serve_step) processing `n_tokens` real tokens
     (prefill-chunk columns + decode slots combined) against `kv_tokens`
-    of resident context across the batch.
+    of live context across the batch.
 
     The term structure is what makes continuous batching pay: the
     per-step WEIGHT stream (the whole per-rank shard — the decode
@@ -1039,129 +1038,12 @@ def estimate_serve_step_ms(
     return max(compute_ms, mem_ms)
 
 
-
 # Per-step host dispatch tax of the host-loop serve path: one python
 # step assembly + jit re-entry + host->device arg staging. A
 # conservative constant for the LOCAL dispatch floor, set from a
 # round-5 reading whose record is deleted (PR 24); not measured on
 # today's code.
 SERVE_DISPATCH_US = 250.0
-# Per-step cost of the resident loop's ring poll + slot-plan assembly
-# (a handful of SMEM-class reads and a (K, SS) state update — tiny next
-# to the step itself).
-RESIDENT_POLL_US = 5.0
-
-
-def estimate_resident_step_ms(
-    num_layers: int,
-    hidden: int,
-    inter_loc: int,
-    hq_loc: int,
-    hkv_loc: int,
-    head_dim: int,
-    vocab_loc: int,
-    n_tokens: int,
-    kv_tokens: int = 0,
-    dtype=jnp.bfloat16,
-    chip: Optional[ChipSpec] = None,
-    attn_impl: str = "flash",
-    window: int = 16,
-) -> float:
-    """Per-step cost of the megakernel-RESIDENT serve loop
-    (models/engine.make_resident_loop): the same mixed-step roofline as
-    `estimate_serve_step_ms`, plus the in-loop ring poll, plus the
-    host dispatch tax amortized over the `window` steps one launch
-    covers — the saved dispatch is the whole point (ISSUE 12: the r05
-    engine-vs-mega decode gap is pure per-step dispatch). At window=1
-    this degenerates to the host-loop step cost; the chooser walks the
-    crossover."""
-    base = estimate_serve_step_ms(
-        num_layers, hidden, inter_loc, hq_loc, hkv_loc, head_dim,
-        vocab_loc, n_tokens, kv_tokens=kv_tokens, dtype=dtype,
-        chip=chip, attn_impl=attn_impl)
-    return (base + RESIDENT_POLL_US * 1e-3
-            + SERVE_DISPATCH_US * 1e-3 / max(window, 1))
-
-
-# resident-window auto-sizing targets: the amortized dispatch tax the
-# chooser drives under (2% of the modeled step), and the window bounds
-# — at least 4 steps (below that the mode barely amortizes anything)
-# and at most 128 (the host must regain control for admission/cancel
-# latency within a bounded horizon)
-RESIDENT_WINDOW_TAX = 0.02
-RESIDENT_WINDOW_MIN = 4
-RESIDENT_WINDOW_MAX = 128
-
-
-def choose_resident_window(
-    num_layers: int,
-    hidden: int,
-    inter_loc: int,
-    hq_loc: int,
-    hkv_loc: int,
-    head_dim: int,
-    vocab_loc: int,
-    slots: int = 4,
-    kv_tokens: int = 0,
-    dtype=jnp.bfloat16,
-    chip: Optional[ChipSpec] = None,
-    attn_impl: str = "flash",
-) -> int:
-    """Model-driven resident window (ROADMAP item 2 follow-up: drive
-    the window from `estimate_resident_step_ms` instead of a fixed 16):
-    the SMALLEST window whose amortized per-step dispatch tax
-    (SERVE_DISPATCH_US / window) is within RESIDENT_WINDOW_TAX of the
-    modeled step time. Small/fast steps (tiny shards, or a link whose
-    round trip prices in as dispatch) need deep windows; steps that
-    drown the dispatch keep the window shallow so admissions and
-    cancellations reach the device sooner — the same step-time axis
-    `choose_serve_mode` flips the MODE on, driving the DEPTH. Clamped
-    to [RESIDENT_WINDOW_MIN, RESIDENT_WINDOW_MAX]; monotone
-    non-increasing in the modeled step time (tests/test_serve_resident
-    pins both)."""
-    base_ms = estimate_serve_step_ms(
-        num_layers, hidden, inter_loc, hq_loc, hkv_loc, head_dim,
-        vocab_loc, n_tokens=max(slots, 1), kv_tokens=kv_tokens,
-        dtype=dtype, chip=chip, attn_impl=attn_impl)
-    want = int(math.ceil(
-        SERVE_DISPATCH_US * 1e-3 / (RESIDENT_WINDOW_TAX * base_ms)))
-    return max(RESIDENT_WINDOW_MIN, min(RESIDENT_WINDOW_MAX, want))
-
-
-def choose_serve_mode(
-    num_layers: int,
-    hidden: int,
-    inter_loc: int,
-    hq_loc: int,
-    hkv_loc: int,
-    head_dim: int,
-    vocab_loc: int,
-    slots: int = 4,
-    kv_tokens: int = 0,
-    dtype=jnp.bfloat16,
-    chip: Optional[ChipSpec] = None,
-    attn_impl: str = "flash",
-    window: int = 16,
-) -> str:
-    """"resident" | "host" for the serve Scheduler (resident="auto").
-
-    Resident wins when the amortized dispatch saving beats the poll
-    overhead — which it does for any window >= ~2 at realistic shapes,
-    BUT the resident mode also gives up mid-flight eviction (full-
-    lifetime page allocation), so the chooser only flips when the
-    dispatch tax is a MATERIAL fraction of the step (>= 2% of the
-    modeled step time): on a step long enough to drown the dispatch,
-    the host loop's flexibility is worth keeping."""
-    args = (num_layers, hidden, inter_loc, hq_loc, hkv_loc, head_dim,
-            vocab_loc)
-    host_ms = estimate_serve_step_ms(
-        *args, n_tokens=max(slots, 1), kv_tokens=kv_tokens, dtype=dtype,
-        chip=chip, attn_impl=attn_impl) + SERVE_DISPATCH_US * 1e-3
-    res_ms = estimate_resident_step_ms(
-        *args, n_tokens=max(slots, 1), kv_tokens=kv_tokens, dtype=dtype,
-        chip=chip, attn_impl=attn_impl, window=window)
-    saved = host_ms - res_ms
-    return "resident" if saved >= 0.02 * host_ms else "host"
 
 
 def expected_spec_tokens(accept_rate: float, k: int) -> float:

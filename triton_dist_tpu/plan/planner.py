@@ -41,7 +41,7 @@ one decision per matched producer -> collective -> consumer triple:
 `plan_dense_forward` memoizes on the hashable (cfg, geometry, mode)
 tuple, so the model forward, `models/engine.Engine`, the serve
 `Scheduler`, and `mega.schedule_graph` all hold the SAME Plan object
-for the same step shape — resident serving and one-shot forwards agree
+for the same step shape — serving and one-shot forwards agree
 on pairings by construction.
 """
 

@@ -44,7 +44,4 @@ from triton_dist_tpu.serve.request import (  # noqa: F401
     summarize,
 )
 from triton_dist_tpu.serve.scheduler import Scheduler  # noqa: F401
-from triton_dist_tpu.serve.worker import (  # noqa: F401
-    ResidentWorker,
-    Worker,
-)
+from triton_dist_tpu.serve.worker import Worker  # noqa: F401

@@ -108,18 +108,13 @@ class Request:
     phase_ns: dict = dataclasses.field(default_factory=dict)
     n_device_steps: int = 0    # serve steps this request rode
     n_prefill_chunks: int = 0  # prefill chunk steps among them
-    n_windows: int = 0         # resident windows it was live in
-    inject_wait_ns: int = 0    # admit -> first window that consumed the
-    # request's injection record (resident mode; 0 on the host loop)
     # -- prefix + spec planes (ISSUE 14) --------------------------------
     prefix_len: int = 0        # prompt tokens served from the prefix
     # cache at the LAST admission (prefill skipped straight past them)
     n_spec_steps: int = 0      # device steps that ran a spec-verify row
     spec_verify_ns: int = 0    # wall share of those steps — a
     # SUB-BUCKET of the decode phase (trace/ledger.py), never added to
-    # the close sum (host loop; resident windows are step-unresolved)
-    _last_spec_step: int = -1  # drain bookkeeping: dedupe multi-token
-    # verify emissions into ONE device step (resident record drain)
+    # the close sum
 
     def history(self) -> List[int]:
         return self.prompt + self.out_tokens

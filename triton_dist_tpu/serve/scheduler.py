@@ -81,11 +81,7 @@ from triton_dist_tpu.serve.request import (
     TokenStream,
     summarize,
 )
-from triton_dist_tpu.serve.worker import (
-    ResidentWorker,
-    Worker,
-    sampling_keys,
-)
+from triton_dist_tpu.serve.worker import Worker, sampling_keys
 from triton_dist_tpu.spec.verify import accept_tokens, draft_cap
 
 
@@ -113,9 +109,6 @@ class Scheduler:
         registry: Optional[Registry] = None,
         recorder: Optional[FlightRecorder] = None,
         slo: Optional[SLOMonitor] = None,
-        resident=False,
-        window: Optional[int] = None,
-        ring_cap: Optional[int] = None,
         spec=None,
         prefix_cache=False,
         prefix_block: Optional[int] = None,
@@ -143,8 +136,6 @@ class Scheduler:
                      "spec: a rejected draft would have to roll the "
                      "recurrent state back, and the step keeps no "
                      "per-column state"),
-                    (resident, "resident: the device-resident loop "
-                     "carries keys and values only"),
                     (role != "both" or migrate_to is not None
                      or admit_from is not None,
                      "xslice migration (role / migrate_to / admit_from):"
@@ -177,9 +168,8 @@ class Scheduler:
             chunk = max(1, min(chunk, self.pool.t_max))
         self.chunk = chunk
         # -- speculative decoding (ISSUE 14, triton_dist_tpu.spec): a
-        # SpecConfig turns decoding slots into k-token verify rows —
-        # host loop via the per-position serve step, resident via
-        # KIND_VERIFY ring records. k=0 (or spec=None) is OFF.
+        # SpecConfig turns decoding slots into k-token verify rows of
+        # the per-position serve step. k=0 (or spec=None) is OFF.
         self.spec = spec if (spec is not None
                              and getattr(spec, "k", 0) > 0) else None
         if self.spec is not None:
@@ -190,11 +180,11 @@ class Scheduler:
         # observed per-step acceptance rate, folded back through
         # perf_model.choose_spec_k so the LIVE draft width decays to 0
         # on non-self-similar traffic and recovers when acceptance
-        # does. spec.k stays the hard cap (the k+1 <= chunk assert and
-        # the resident ring's verify records are sized for it, so
-        # adaptation may only narrow rows). Emitted tokens are bitwise
-        # unchanged — k widens/narrows what is PROPOSED, and every
-        # accepted token is the model's own emission.
+        # does. spec.k stays the hard cap (the k+1 <= chunk assert is
+        # made for it, so adaptation may only narrow rows). Emitted
+        # tokens are bitwise unchanged — k widens/narrows what is
+        # PROPOSED, and every accepted token is the model's own
+        # emission.
         self._spec_ewma: Optional[float] = None
         self._spec_k_live: Optional[int] = None
         self._spec_geom: Optional[dict] = None
@@ -237,58 +227,9 @@ class Scheduler:
                     page=page, t_max=self.pool.t_max,
                     dtype=cfg.dtype)
             self.prefix = PrefixCache(self.pool, block=prefix_block)
-        # -- execution mode: the host loop (one dispatch per step) or
-        # the megakernel-resident window (ISSUE 12: one dispatch per
-        # `window` steps, work injected through mega.ring). "auto"
-        # consults the perf model's dispatch-tax chooser.
-        auto = resident == "auto"
-        if auto:
-            from triton_dist_tpu.perf_model import choose_serve_mode
-
-            cfg = engine.cfg
-            n = int(engine.mesh.shape[engine.axis])
-            resident = choose_serve_mode(
-                cfg.num_layers, cfg.hidden_size,
-                cfg.intermediate_size // n, cfg.num_q_heads // n,
-                cfg.num_kv_heads // n, cfg.head_dim,
-                cfg.vocab_size // n, slots=slots,
-                kv_tokens=self.pool.t_max, dtype=cfg.dtype,
-                window=window or 16,
-            ) == "resident"
-        self.resident = bool(resident)
-        if self.resident:
-            if window is None:
-                # chooser-backed auto-sizing (ROADMAP item 2 follow-up):
-                # the window comes from the resident step model — small
-                # steps need a deep window to amortize the dispatch tax,
-                # steps that drown it keep the window short so the host
-                # regains control (admission/cancel latency) sooner
-                from triton_dist_tpu.perf_model import (
-                    choose_resident_window,
-                )
-
-                cfg = engine.cfg
-                n = int(engine.mesh.shape[engine.axis])
-                window = choose_resident_window(
-                    cfg.num_layers, cfg.hidden_size,
-                    cfg.intermediate_size // n, cfg.num_q_heads // n,
-                    cfg.num_kv_heads // n, cfg.head_dim,
-                    cfg.vocab_size // n, slots=slots,
-                    kv_tokens=self.pool.t_max, dtype=cfg.dtype)
-            self.worker = ResidentWorker(
-                engine, self.pool, chunk, window=window,
-                ring_cap=ring_cap,
-                spec_k=self.spec.k if self.spec is not None else 0)
-        else:
-            # under "auto" the chooser may legitimately pick the host
-            # loop: the caller's window/ring_cap are then simply moot,
-            # not a usage error
-            assert auto or (window is None and ring_cap is None), (
-                "window/ring_cap configure the resident mode — pass "
-                "resident=True (or 'auto')")
-            self.worker = Worker(engine, self.pool, chunk,
-                                 per_pos=self.spec is not None,
-                                 spans=self.spans)
+        self.worker = Worker(engine, self.pool, chunk,
+                             per_pos=self.spec is not None,
+                             spans=self.spans)
         # `queue or ...` would silently DISCARD a custom queue that is
         # currently empty (RequestQueue defines __len__, and an empty
         # queue is falsy) — the admission-control settings a caller
@@ -340,10 +281,6 @@ class Scheduler:
         # reference the disaggregated pair is measured against.
         assert role in ("both", "prefill", "decode"), role
         self.role = role
-        if role != "both":
-            assert not self.resident, (
-                "disaggregated roles run the host loop (the resident "
-                "window has no migration hook yet — ROADMAP)")
         assert role != "prefill" or migrate_to is not None, (
             "role='prefill' needs a migrate_to channel")
         assert role != "decode" or admit_from is not None, (
@@ -367,17 +304,13 @@ class Scheduler:
         # per verify step, accepted/proposed in [0, 1] (a 0.0 lands in
         # the first bucket — the ladder's lo is the resolution floor)
         self.obs.declare_histogram("spec_accept_rate", 0.01, 1.0, 1.25)
-        # -- request-scoped attribution (ISSUE 13): per-step / per-
-        # window slot->request history, the substrate trace/ledger.py
+        # -- request-scoped attribution (ISSUE 13): per-step
+        # slot->request history, the substrate trace/ledger.py
         # folds device time through. Bounded: a long-running server
         # drops the oldest entries (counted) rather than growing
         self.history: List[dict] = []
         self.history_cap = 8192
         self.history_dropped = 0
-        # requests whose injection record the device has not consumed
-        # yet (req_id -> Request) — the inject-wait stamp's worklist,
-        # kept tiny so _observe_window never scans self.requests
-        self._pending_inject: dict = {}
         self.recorder = recorder if recorder is not None \
             else FlightRecorder(cap=64)
         self.slo = slo
@@ -454,19 +387,14 @@ class Scheduler:
     # -- the step -------------------------------------------------------
 
     def step(self) -> bool:
-        """One scheduling round. Host-loop mode: admit, assemble, run
-        ONE device step, postprocess. Resident mode: admit by writing
-        injection records, launch one device-resident WINDOW (up to
-        `window` steps in a single dispatch), drain the output ring.
-        Returns False when there was nothing to do.
+        """One scheduling round: admit, assemble, run ONE device step,
+        postprocess. Returns False when there was nothing to do.
 
-        A host-loop round is one `sched.step` span whose children name
+        A round is one `sched.step` span whose children name
         what the host was doing (docs/observability.md "Span log"):
         `sched.admit`, `sched.assemble` (with `sched.keys`),
         `worker.step` (`worker.put` / `.launch` / `.wait`),
         `sched.emit`, `sched.observe`."""
-        if self.resident:
-            return self._resident_pump()
         if self._nothing_to_do():
             return False
         step_idx = self.worker.n_steps
@@ -511,7 +439,7 @@ class Scheduler:
             return True
 
     def _nothing_to_do(self) -> bool:
-        """A host-loop round that could only return False: no slot
+        """A round that could only return False: no slot
         busy, nothing queued, no migration in flight or arrived. Such
         a round leaves no span — an idle server's log holds one
         `sched.idle` for the stretch (start()'s loop), not a record
@@ -723,16 +651,16 @@ class Scheduler:
             self.obs.inc("moe_expert_steps",
                          cfg.num_layers * cfg.num_experts_held)
 
-    def _attempt_with_backoff(self, retry_span, body, on_fault=None):
-        """The shared half of the degradation ladder: run `body` with
+    def _attempt_with_backoff(self, retry_span, body):
+        """The retrying half of the degradation ladder: run `body` with
         bounded exponential-backoff retries, streaming the retry
         bookkeeping (retry counters by fault class, guard-trip
         counters by site, one `retry_span` record a failed attempt)
         every attempt. Returns
         (result, None) on success or (None, last_err) on exhaustion —
-        what exhaustion MEANS (quarantine a victim, re-raise a ring
-        trip) stays with the caller. Only FaultError is degradable — a
-        programming error stays loud."""
+        what exhaustion MEANS (quarantine a victim) stays with the
+        caller. Only FaultError is degradable — a programming error
+        stays loud."""
         delay = self.retry_backoff_s
         last_err = None
         for attempt in range(self.max_step_retries + 1):
@@ -747,8 +675,6 @@ class Scheduler:
             except FaultError as e:
                 self._attempt_span = (t0, time.perf_counter_ns())
                 last_err = e
-                if on_fault is not None:
-                    on_fault(e)
                 self.n_step_retries += 1
                 self.obs.inc("serve_retries", site=type(e).__name__)
                 self._count_guard_trips(e)
@@ -775,187 +701,17 @@ class Scheduler:
         self._quarantine(victim, err)
         return None
 
-    # -- resident mode (megakernel-resident serving, ISSUE 12) ----------
-
-    def _resident_pump(self) -> bool:
-        """One resident round: inject admissions/retirements, launch a
-        window, drain completions. The scheduler never assembles a
-        step — its decisions travel as ring records and the device
-        self-feeds decode between boundaries (docs/serving.md
-        "Device-resident serving")."""
-        self._reap_cancelled_resident()
-        self._admit_resident()
-        if self.spec is not None:
-            self._inject_spec_resident()
-        if not self.active and self.worker.pending_records() == 0:
-            return False
-        t0 = time.perf_counter_ns()
-        steps0 = self.worker.n_steps
-        window_idx = self.worker.n_windows
-        consumed0 = self.worker.ring.consumed
-        # slot occupants at window LAUNCH — the attribution snapshot
-        # (a slot that turns over mid-window is attributed to its
-        # launch occupant; docs/observability.md documents the
-        # tolerance)
-        slots_at_launch = dict(self.active)
-        self.obs.set_gauge("serve_ring_depth",
-                           self.worker.pending_records())
-        records = self._run_window()
-        self.spans.add("resident.window", t0, time.perf_counter_ns(),
-                       step=steps0)
-        if records is not None:
-            self._drain_records(records)
-        self.obs.inc("serve_resident_windows")
-        executed = self.worker.n_steps - steps0
-        if executed:
-            self.obs.inc("serve_resident_steps", executed)
-        # the history entry's wall is the LAST launch attempt only (the
-        # span above keeps the full pump incl. retries/backoff — the
-        # two answer different questions)
-        w0, w1 = self._attempt_span
-        self._observe_window(window_idx, steps0, executed, w0, w1,
-                             consumed0, slots_at_launch)
-        self.obs.set_gauge("serve_ring_depth_post",
-                           self.worker.pending_records())
-        self._observe_step()
-        return True
-
-    def _observe_window(self, window_idx, step0, executed, t0, t1,
-                        consumed0, slots_at_launch) -> None:
-        """Window-level attribution bookkeeping: the history entry the
-        request ledger folds device time through, the decoded
-        resident-window stat rows (when the loop was built metered),
-        and the per-request window/inject-wait counters. O(slots +
-        pending admissions) per window — never a scan of the full
-        request log."""
-        from triton_dist_tpu.obs import stats as ostats
-
-        consumed1 = self.worker.ring.consumed
-        wstats = None
-        if self.worker.last_window_stats is not None:
-            wstats = ostats.decode_window_rows(
-                self.worker.last_window_stats)
-            ostats.record_window_stats(self.obs, wstats)
-        self._record_history({
-            "kind": "window", "window": window_idx, "step0": step0,
-            "executed": executed, "t0": t0, "t1": t1,
-            "consumed0": consumed0, "consumed1": consumed1,
-            "slots": {s: r.request_id
-                      for s, r in slots_at_launch.items()},
-            "stats": wstats,
-            "trace": self.worker.last_window_trace,
-        })
-        if executed:
-            for req in slots_at_launch.values():
-                req.n_windows += 1
-        for rid, req in list(self._pending_inject.items()):
-            if consumed1 >= req._admit_rec_seq:
-                # the device picked the admission up somewhere in this
-                # window: inject wait = admit -> this window's end (the
-                # per-window resolution the ring contract gives us)
-                req.inject_wait_ns = max(
-                    0, t1 - getattr(req, "_t_admit_ns", t1))
-                del self._pending_inject[rid]
-
     def _record_history(self, entry: dict) -> None:
         self.history.append(entry)
         if len(self.history) > self.history_cap:
             del self.history[0]
             self.history_dropped += 1
 
-    def _admit_resident(self) -> None:
-        """Admission, resident form: a request needs a free slot and
-        its WHOLE lifetime of pages up front (prompt + max_new_tokens
-        — the device never grows an allocation mid-loop, so page
-        exhaustion can never stall a resident window). The admission
-        travels as a ring record carrying the page-table row and the
-        prompt; no preemption/eviction — a resident batch runs to
-        retirement (the mode trades eviction flexibility for dispatch
-        amortization; docs/serving.md)."""
-        while len(self.active) < self.max_active:
-            req = self.queue.peek()
-            if req is None:
-                return
-            if not self.worker.can_inject():
-                # ring backpressure: every reclaimable row is pending
-                # or pinned by an in-flight prefill — the admission
-                # waits a round rather than overwriting a row the
-                # device still streams from
-                return
-            slot = self.pool.free_slot()
-            total = len(req.history()) + req.max_new_tokens
-            if slot is None:
-                return
-            # the prefix match + cache pressure valve; no eviction in
-            # resident mode, so the cache is the ONLY valve
-            m, mpages, need = self._reclaim_and_rematch(req, total)
-            if self.pool.free_pages() < need:
-                return
-            self.queue.pop()
-            try:
-                if m > 0:
-                    self.pool.share(slot, mpages, total)
-                else:
-                    self.pool.admit(slot, len(req.history()))
-                    ok = self.pool.ensure(slot, total)
-                    assert ok, "free_pages said yes, ensure said no"
-            except PoolExhausted:
-                self.queue.requeue(req)
-                return
-            req.slot = slot
-            req.pos = m
-            req.prefix_len = m
-            req.state = RequestState.PREFILL
-            req.admit_seq = self._admit_seq
-            self._admit_seq += 1
-            self.active[slot] = req
-            self.obs.inc("serve_admitted")
-            self._note_prefix(m, mpages)
-            self._phase(req, "prefill")
-            self.worker.admit(
-                slot, req.history(), req.max_new_tokens,
-                req.temperature, req.seed, req.eos_id, req.request_id,
-                prefix=m)
-            # inject-wait bookkeeping (ISSUE 13): the record's seq, so
-            # _observe_window can stamp the admit -> device-pickup wait
-            req._t_admit_ns = time.perf_counter_ns()
-            req._admit_rec_seq = self.worker.ring.published
-            self._pending_inject[req.request_id] = req
-
-    def _inject_spec_resident(self) -> None:
-        """Spec-verify injection, resident form (ISSUE 14): one
-        KIND_VERIFY record per decoding slot per window, drafted from
-        the tokens drained so far. The device verifies it at the
-        window's FIRST step (its state still matches the record's
-        n_out there) and plain-decodes the rest of the window — the
-        per-window cadence is the resolution the ring contract gives
-        the host; every accepted token is still bitwise the sequential
-        emission (the per-column key stream travels with the step, not
-        the record)."""
-        for slot, req in self.active.items():
-            if req.done or req.state is not RequestState.DECODE:
-                continue
-            if not self.worker.can_inject():
-                return
-            hist = req.history()
-            cap = draft_cap(self._live_spec_k(), self.chunk, len(hist),
-                            len(req.out_tokens), req.max_new_tokens,
-                            self.pool.t_max)
-            if cap <= 0:
-                continue
-            drafts = [int(t) for t in
-                      self.spec.draft.propose(hist, cap)][:cap]
-            if drafts:
-                self.worker.inject_verify(
-                    slot, req.request_id, len(req.out_tokens), drafts)
-
     # -- adaptive spec-k (ISSUE 17 satellite) ---------------------------
 
     def _note_accept_rate(self, rate: float) -> None:
-        """Fold one verify step's acceptance into the adaptive-k EWMA
-        (a no-op unless SpecConfig.adaptive). Both spec planes report
-        here: the host plan loop after each verify row, and
-        _drain_records per drained resident verify record."""
+        """Fold one verify row's acceptance into the adaptive-k EWMA
+        (a no-op unless SpecConfig.adaptive)."""
         if self._spec_geom is None:
             return
         a = self.spec.ewma_alpha
@@ -967,8 +723,8 @@ class Scheduler:
     def _live_spec_k(self) -> int:
         """The draft width the NEXT verify row may carry: spec.k until
         the EWMA has evidence, then choose_spec_k(accept_rate=ewma)
-        capped at spec.k (the chunk assert and the resident ring's
-        verify records are sized for spec.k — adaptation only narrows).
+        capped at spec.k (the chunk assert is made for spec.k —
+        adaptation only narrows).
         choose_spec_k is monotone in accept_rate, so sustained
         non-self-similar traffic decays the live k to 0 (spec
         effectively OFF) and self-similar traffic restores it."""
@@ -981,182 +737,6 @@ class Scheduler:
                 accept_rate=self._spec_ewma, k_max=self.spec.k,
                 **self._spec_geom))
         return self._spec_k_live
-
-    def _reap_cancelled_resident(self) -> None:
-        """Cancellation, resident form: the retirement travels as a
-        ring record; the slot and its pages free when the DEVICE's
-        retirement record comes back (the device may still be writing
-        the slot's KV until the record is consumed — freeing earlier
-        could alias a live page onto a new admission). Also retries
-        retirements an earlier round deferred under ring backpressure
-        (a quarantined request whose retire could not be injected)."""
-        for slot in list(self.active):
-            req = self.active[slot]
-            wants_retire = (req.finish_reason == "cancel_requested"
-                            or req.state is RequestState.FAILED)
-            if wants_retire and not getattr(req, "_retire_sent", False):
-                if not self.worker.can_inject():
-                    return  # ring full: retried next round
-                req._retire_sent = True
-                self.worker.retire(slot, req.request_id)
-
-    def _run_window(self):
-        """The degradation ladder around the resident window (mirror
-        of _run_step): bounded exponential-backoff retries; on
-        exhaustion, a ring-watchdog trip ("inject" site: the host side
-        of the ring is broken — there is no poisoning request) is
-        re-raised, while a device/step fault quarantines the most
-        recently admitted active request. Returns the drained records,
-        or None when the round was abandoned."""
-        records, err = self._attempt_with_backoff(
-            "window.retry", self.worker.run_window,
-            # a post-launch trip (starved ring) carries the window's
-            # drained records — fold the emissions in before retrying
-            # so a trip never eats completions
-            on_fault=lambda e: self._drain_records(
-                getattr(e, "out_records", [])))
-        if err is None:
-            return records
-        last_err = err
-        trips = getattr(last_err, "trips", None) or []
-        ring_trip = trips and all(t.site_label == "inject"
-                                  for t in trips)
-        live = [r for r in self.active.values() if not r.done]
-        if ring_trip or not live:
-            raise last_err
-        victim = max(live, key=lambda r: r.admit_seq)
-        self._quarantine_resident(victim, last_err)
-        return None
-
-    def _quarantine_resident(self, req: Request, err) -> None:
-        """Quarantine, resident form: the client unblocks NOW (stream
-        closes, state FAILED) but the slot and pages stay held until
-        the device confirms the injected retirement — the device may
-        touch the slot's pages until its record is consumed."""
-
-        def retire():
-            self._end_phase(req)
-            req._finish(f"quarantined: {err!r}", RequestState.FAILED)
-            if self.worker.can_inject():
-                req._retire_sent = True
-                self.worker.retire(req.slot, req.request_id)
-            else:
-                # ring full right now — _reap_cancelled_resident
-                # retries (the FAILED state marks the lane as wanting
-                # retirement)
-                req._retire_sent = False
-
-        self._do_quarantine(req, err, retire)
-
-    def _do_quarantine(self, req: Request, err, retire) -> None:
-        """Shared quarantine bookkeeping (span, counter, flight dump);
-        `retire` is the mode-specific middle — host-loop retires the
-        lane immediately, resident injects a device retirement."""
-        now = time.perf_counter_ns()
-        self.spans.add("req.quarantined", now, now,
-                       step=self.worker.n_steps, request=req.request_id)
-        self.quarantined.append(req)
-        self.obs.inc("serve_quarantined")
-        retire()
-        self.recorder.record(registry=self.obs,
-                             scheduler_state=self._state_summary(),
-                             error=err, step=self.worker.n_steps)
-        try:
-            self.last_flight_dump = self.recorder.dump(
-                reason=f"quarantine req{req.request_id}: {err!r}"[:200])
-        except OSError:
-            pass  # an unwritable dump dir must not kill the batch
-
-    def _drain_records(self, records) -> None:
-        """Fold the window's output records back into request state, in
-        device seq order — emissions stream through the detokenizer
-        exactly like host-loop emissions; retirements release the slot
-        and its pages. The device's eos/length decision is cross-
-        checked against the host recomputation (drift between the two
-        would be a contract break, not a policy choice)."""
-        from triton_dist_tpu.mega.ring import (
-            REASON_EOS,
-            REASON_LENGTH,
-        )
-
-        # spec-verify roll-up (ISSUE 14): FLAG_SPEC records group by
-        # (slot, step) — the first carries the proposed count, every
-        # further one is an accepted draft riding the same step
-        spec_groups: dict = {}
-        for rec in records:
-            if rec.emitted or rec.retired:
-                # first emission = prefill done (the device no longer
-                # streams from the admission row); retirement likewise
-                # — either way the pinned ring row is reclaimable
-                self.worker.unpin(rec.req_id)
-            if rec.spec and rec.emitted:
-                g = spec_groups.setdefault((rec.slot, rec.step),
-                                           [0, -1])
-                g[1] += 1
-                if rec.spec_k:
-                    g[0] = rec.spec_k
-            req = self.active.get(rec.slot)
-            if req is None or req.request_id != rec.req_id:
-                continue  # stale record for a slot already turned over
-            if rec.emitted and not req.done:
-                # a done request (quarantined/cancelled with the retire
-                # record still pending) may keep stepping on-device for
-                # a window; its stream is closed — dropping the stale
-                # emission here keeps the TokenStream end-of-stream
-                # sentinel terminal
-                if req.state is RequestState.PREFILL:
-                    if self.prefix is not None:
-                        self._prefix_insert(req, rec.slot)
-                    self._phase(req, "decode")
-                    req.state = RequestState.DECODE
-                    # the full prefill ran on device: credit its chunk
-                    # steps now (resident mode never evicts, so what
-                    # was staged is history minus the prefix-cache hit)
-                    chunks = -(-(len(req.history()) - req.prefix_len)
-                               // self.chunk)
-                    req.n_prefill_chunks += chunks
-                    req.n_device_steps += chunks
-                elif rec.spec and req.out_tokens \
-                        and rec.step == req._last_spec_step:
-                    pass  # same verify step: one device step, n tokens
-                else:
-                    req.n_device_steps += 1
-                    if rec.spec:
-                        req.n_spec_steps += 1
-                        req._last_spec_step = rec.step
-                req.last_active_step = self.worker.n_steps
-                piece = (self.detok.piece(rec.token)
-                         if self.detok else None)
-                req._emit(rec.token, piece)
-                self.obs.inc("serve_tokens_out")
-                would_retire = (
-                    (req.eos_id is not None and rec.token == req.eos_id)
-                    or len(req.out_tokens) >= req.max_new_tokens)
-                assert would_retire == rec.retired, (
-                    f"device retirement decision diverged from host "
-                    f"policy on req{req.request_id}: {rec}")
-            if rec.retired:
-                if req.done:
-                    # quarantined/cancel-finished earlier: the record
-                    # is the device's confirmation — free the lane
-                    self.pool.release(rec.slot)
-                    del self.active[rec.slot]
-                    req.slot = -1
-                    continue
-                if rec.reason == REASON_EOS:
-                    self._retire(req, "eos", RequestState.FINISHED)
-                elif rec.reason == REASON_LENGTH:
-                    self._retire(req, "length", RequestState.FINISHED)
-                else:  # REASON_HOST: an injected cancel came back
-                    self._retire(req, "cancelled",
-                                 RequestState.CANCELLED)
-        for (_slot, _step), (kd, extra) in spec_groups.items():
-            if kd > 0:
-                acc = max(extra, 0)
-                self.obs.inc("spec_proposed", kd)
-                self.obs.inc("spec_accepted", acc)
-                self.obs.observe("spec_accept_rate", acc / kd)
-                self._note_accept_rate(acc / kd)
 
     def _count_guard_trips(self, err) -> None:
         """Guard-trip counters by wait site (the decoded rows a
@@ -1177,10 +757,20 @@ class Scheduler:
         scheduler state, and the decoded guard rows of the fatal error
         — so the trip arrives with its context (docs/observability.md
         "Flight recorder")."""
-        self._do_quarantine(
-            req, err,
-            lambda: self._retire(req, f"quarantined: {err!r}",
-                                 RequestState.FAILED))
+        now = time.perf_counter_ns()
+        self.spans.add("req.quarantined", now, now,
+                       step=self.worker.n_steps, request=req.request_id)
+        self.quarantined.append(req)
+        self.obs.inc("serve_quarantined")
+        self._retire(req, f"quarantined: {err!r}", RequestState.FAILED)
+        self.recorder.record(registry=self.obs,
+                             scheduler_state=self._state_summary(),
+                             error=err, step=self.worker.n_steps)
+        try:
+            self.last_flight_dump = self.recorder.dump(
+                reason=f"quarantine req{req.request_id}: {err!r}"[:200])
+        except OSError:
+            pass  # an unwritable dump dir must not kill the batch
 
     def run(self, max_steps: int = 100_000) -> None:
         """Drive steps until queue and slots drain."""
@@ -1371,22 +961,13 @@ class Scheduler:
             # tune-cache winners riding this plan (site -> config via
             # Plan.applied_configs); 0 = every kernel on default tiles
             out["plan_applied_configs"] = len(self.plan.applied_configs())
-        if self.resident:
-            out["resident_windows"] = snap.get(
-                "serve_resident_windows", 0)
-            out["resident_steps"] = snap.get("serve_resident_steps", 0)
-            out["ring_depth"] = self.worker.pending_records()
-            # metered loops (obs.stats.building at construction) fold
-            # the window rows' poll classification in; 0 when unmetered
-            out["ring_polls"] = snap.get("serve_resident_ring_polls", 0)
-            out["idle_polls"] = snap.get("serve_resident_idle_polls", 0)
         if self.slo is not None and self.slo.last is not None:
             out["health"] = self.slo.last.to_dict()
         return out
 
     def timeline(self):
         """The span log as a trace.Timeline (host spans only: request
-        lifecycle phases, each round's phases, retries, windows) —
+        lifecycle phases, each round's phases, retries) —
         write_trace() exports it to Perfetto beside the in-kernel
         traces."""
         from triton_dist_tpu.trace.collect import Timeline
@@ -1396,35 +977,14 @@ class Scheduler:
 
     def ledger(self, tol: float = 0.05):
         """The per-request attribution ledger (ISSUE 13): TTFT/TPOT
-        decomposed per retired request — queued / inject wait / prefill
-        / decode wall, device-step share, window counters — built from
+        decomposed per retired request — queued / prefill / decode
+        wall, device-step share — built from
         the phase accumulators plus the slot history. See
         trace/ledger.py for the close contract (phase sums vs wall
         within `tol`)."""
         from triton_dist_tpu.trace.ledger import build_ledger
 
         return build_ledger(self, tol=tol)
-
-    def window_timeline(self):
-        """Assemble the resident windows' serve.* mark streams (loops
-        constructed under trace.building()) into one Timeline — one
-        stream per window, named serve.w<N>. Raises when no window
-        carried a trace (the loop was built untraced)."""
-        from triton_dist_tpu.trace import events as tev
-        from triton_dist_tpu.trace.collect import assemble
-
-        bufs = {
-            f"serve.w{e['window']}": np.asarray(e["trace"]).reshape(
-                1, -1, tev.RECORD_WORDS)
-            for e in self.history
-            if e.get("kind") == "window" and e.get("trace") is not None
-        }
-        if not bufs:
-            raise ValueError(
-                "no traced resident windows — construct the Scheduler "
-                "inside trace.building() to trace the loop")
-        return assemble(bufs, label="serve-resident",
-                        host_spans=self.spans.triples())
 
     # -- internals ------------------------------------------------------
 
@@ -1455,9 +1015,9 @@ class Scheduler:
 
     def _prefix_insert(self, req: Request, slot: int) -> None:
         """Index a freshly completed prefill's prompt blocks (the
-        PREFILL -> DECODE transition, host loop and resident drain
-        alike): the trie increfs the slot's pages — no copy — so the
-        next templated prompt admission shares them."""
+        PREFILL -> DECODE transition): the trie increfs the slot's
+        pages — no copy — so the next templated prompt admission
+        shares them."""
         self.prefix.insert(req.prompt, self.pool.table[slot])
 
     @staticmethod
@@ -1489,12 +1049,12 @@ class Scheduler:
         return self.prefix.match(req.history())
 
     def _reclaim_and_rematch(self, req: Request, total: int):
-        """The prefix-cache pressure valve shared by BOTH admission
-        paths: match, and if the fresh-page need outruns the free
-        list, reclaim the DEFICIT from unshared cached blocks and
-        RE-match — the reclaim may have dropped nodes on the matched
-        path itself (an unshared hit is a valid LRU victim), and stale
-        mpages would share freed pages. Returns (m, mpages,
+        """The prefix-cache pressure valve of admission: match, and if
+        the fresh-page need outruns the free list, reclaim the DEFICIT
+        from unshared cached blocks and RE-match — the reclaim may
+        have dropped nodes on the matched path itself (an unshared hit
+        is a valid LRU victim), and stale mpages would share freed
+        pages. Returns (m, mpages,
         fresh_need) for a `total`-token allocation."""
         m, mpages = self._match_prefix(req)
         need = max(pages_for(total, self.pool.page), 1) - len(mpages)

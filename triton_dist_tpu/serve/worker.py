@@ -13,30 +13,17 @@ folds the results back into the pool. A request's tokens are bitwise
 a function of its history and of the widths of the steps that
 computed them; across widths the logits agree to the tolerance of two
 correct formulations in the model's precision (docs/serving.md).
-
-`ResidentWorker` is the megakernel-resident form (ISSUE 12): instead
-of one device dispatch per step, the scheduler's decisions travel as
-work-injection ring records (mega.ring) and the Worker launches the
-device-RESIDENT window `engine.make_resident_loop` compiled — up to W
-steps per dispatch, decode self-fed on device, completions drained
-from the mirrored output ring afterwards. The Worker is the ring
-producer (admit/retire records) AND the output-ring consumer; every
-window launch is a bounded watchdog wait — an abandoned ring (starved
-window) or a windows-long stretch with zero progress raises a
-structured `DeadlineExceeded` guard trip, never a hang.
 """
 
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from triton_dist_tpu.faults import plan as _fplan
-from triton_dist_tpu.faults.errors import DeadlineExceeded
-from triton_dist_tpu.mega import ring as mring
 from triton_dist_tpu.obs.spans import SpanLog
 from triton_dist_tpu.serve.kv_pool import KVPool
 
@@ -52,8 +39,8 @@ _ROTATIONS = tuple(
 def check_prng_impl() -> None:
     """`sampling_keys` and the step's `(K, 2) uint32` keys are
     threefry2x32 key data: refuse to build a worker under any other
-    default PRNG implementation, where the host's keys and the traced
-    derivation (mega.ring) would part ways in silence."""
+    default PRNG implementation, where the host's keys and
+    `jax.random.fold_in` would part ways in silence."""
     impl = jax.config.jax_default_prng_impl
     if impl != "threefry2x32":
         raise RuntimeError(
@@ -66,9 +53,8 @@ def sampling_keys(seeds, token_indices) -> np.ndarray:
     arrays of any equal (or broadcastable) shape: derived from the
     request seed and the OUTPUT TOKEN INDEX only, so sampled tokens —
     like greedy ones — are invariant to scheduling and eviction. THE
-    single host derivation: host-loop Worker, ResidentWorker and
-    spec-verify all come through here, and the device key stream
-    (mega.ring) reproduces it traced.
+    single derivation: plain and spec-verify steps both come through
+    here.
 
     Bitwise `jax.random.fold_in(jax.random.PRNGKey(seed), index)` under
     threefry2x32 (`check_prng_impl`), computed in numpy with no JAX
@@ -224,229 +210,3 @@ class Worker:
         self.pool.lengths = self.pool.lengths + np.asarray(advance,
                                                            np.int32)
 
-
-class ResidentWorker:
-    """Ring producer / output consumer around the device-resident
-    window (`engine.make_resident_loop`). Device loop state —
-    slot_state, page table, lengths, the ring's consumed cursor —
-    round-trips through each window launch, so windows chain without
-    the host ever reassembling a step.
-
-    Failure contract (mirrors Worker.step): `run_window` raises BEFORE
-    advancing any host-visible state — an injected FailStep fires
-    before the launch, and a starved window's outputs are folded in
-    (the device DID run those steps) before the DeadlineExceeded is
-    raised, so a retry resumes from truth. `guard_trip_site` for every
-    ring watchdog trip is "inject" (faults.guard.SITES)."""
-
-    def __init__(self, engine, pool: KVPool, chunk: int,
-                 window: int = 16, ring_cap: Optional[int] = None,
-                 poll_budget: int = 8, max_stuck_windows: int = 3,
-                 spec_k: int = 0):
-        self.engine = engine
-        self.pool = pool
-        self.chunk = chunk
-        self.window = window
-        self.poll_budget = poll_budget
-        self.max_stuck_windows = max_stuck_windows
-        self.spec_k = spec_k
-        check_prng_impl()
-        cap = ring_cap if ring_cap is not None else max(4 * pool.slots,
-                                                        16)
-        self.ring = mring.InjectionRing(cap, pool.max_pages, pool.t_max,
-                                        chunk)
-        self._spec_pins: List[object] = []
-        # the build contexts active NOW decide the loop's trailing
-        # telemetry outputs (the trace/obs construction-time
-        # discipline, ISSUE 13): a trace build adds the serve.* mark
-        # stream, an obs build the resident-window stat rows
-        from triton_dist_tpu.obs import stats as _ost
-        from triton_dist_tpu.trace import events as _tev
-
-        self._traced = _tev.active_build() is not None
-        self._metered = _ost.active_build() is not None
-        self._fn = engine.make_resident_loop(
-            pool.slots, chunk, pool.page, pool.max_pages, window,
-            ring_cap=cap, prompt_cap=pool.t_max,
-            poll_budget=poll_budget, spec_k=spec_k)
-        # newest window's telemetry (None until a window ran / when the
-        # matching build was off at construction)
-        self.last_window_stats = None
-        self.last_window_trace = None
-        self.slot_state = np.zeros((pool.slots, mring.SS_WIDTH),
-                                   np.int32)
-        # the DEVICE's page-table/length view, installed by record
-        # consumption — kept apart from pool.table/pool.lengths (the
-        # host allocator's view, which may already carry rows for
-        # admissions whose records the device has not consumed yet)
-        self._table = np.zeros_like(pool.table)
-        self._lengths = np.zeros((pool.slots,), np.int32)
-        self.n_steps = 0    # executed device steps (all windows)
-        self.n_windows = 0  # successful window launches
-        self._stuck = 0     # consecutive zero-progress windows
-        self._ring_dev = None       # cached device copy of ring.buf
-        self._ring_dev_version = -1  # ring.version it mirrors
-
-    # -- ring producer (the scheduler's injection API) -------------------
-
-    key_for = staticmethod(sampling_key)
-
-    def admit(self, slot: int, prompt, max_new: int, temperature: float,
-              seed: int, eos_id, req_id: int, at_step: int = 0,
-              prefix: int = 0) -> None:
-        """Write the admission record: the slot's FULL page-table row
-        (the resident mode allocates a request's whole lifetime at
-        admission — the device never grows an allocation mid-loop) plus
-        the prompt the device streams prefill chunks from. `prefix` is
-        the prefix-cache hit length (serve/prefix.py): the device
-        starts prefill and the slot length there — the table row's
-        leading pages already carry that KV (KVPool.share)."""
-        self.ring.admit(slot, prompt, max_new, temperature, seed,
-                        eos_id, req_id,
-                        self.pool.table[slot, :self.pool.max_pages],
-                        at_step=at_step, prefix=prefix)
-
-    def retire(self, slot: int, req_id: int, at_step: int = 0) -> None:
-        self.ring.retire(slot, req_id, at_step=at_step)
-
-    def inject_verify(self, slot: int, req_id: int, n_out: int,
-                      drafts, at_step: int = 0) -> None:
-        """Stage a KIND_VERIFY record (ISSUE 14): `drafts` proposed at
-        exactly `n_out` emitted tokens. The record's row is pinned
-        until the window that rode it returns (the device reads the
-        draft tokens from the row at its verify step)."""
-        assert self.spec_k > 0, "loop built without spec_k"
-        assert 1 <= len(drafts) <= self.spec_k, (len(drafts),
-                                                 self.spec_k)
-        self._spec_pins.append(
-            self.ring.verify(slot, req_id, n_out, drafts,
-                             at_step=at_step))
-
-    def can_inject(self) -> bool:
-        """Room in the ring for one more record (see
-        InjectionRing.can_claim) — the scheduler's backpressure probe:
-        admissions and retirements defer to a later round instead of
-        overflowing."""
-        return self.ring.can_claim()
-
-    def unpin(self, req_id: int) -> None:
-        """Release a request's admission row (prefill complete or
-        retired — the device no longer streams from it)."""
-        self.ring.unpin(req_id)
-
-    def pending_records(self) -> int:
-        return self.ring.pending()
-
-    # -- the window ------------------------------------------------------
-
-    def run_window(self) -> List[mring.OutRecord]:
-        """Launch one resident window; returns the drained output
-        records in seq order. Raises DeadlineExceeded (with a
-        structured "inject"-site guard trip) on a starved ring or
-        after `max_stuck_windows` consecutive windows with zero
-        progress (no step executed, no record consumed) while work is
-        pending — the host-side bound on the device's ring poll."""
-        # reset the telemetry slots BEFORE any fault can fire: a window
-        # that raises pre-launch must not leave the PREVIOUS window's
-        # stats behind for the scheduler to re-fold (double-counted
-        # ring polls — the stale-stats class)
-        self.last_window_stats = None
-        self.last_window_trace = None
-        plan = _fplan.active()
-        if plan is not None:
-            err = plan.step_fault(self.n_windows)
-            if err is not None:
-                raise err
-            if plan.ring_abandons(self.n_windows):
-                self.ring.abandon()
-        pool = self.pool
-        consumed0 = self.ring.consumed
-        # upload the ring buffer only when the producer mutated it —
-        # steady-state decode windows (no records) re-use the cached
-        # device copy instead of paying a cap x width host->device
-        # transfer on the exact dispatch path the mode exists to shave
-        if self._ring_dev is None \
-                or self._ring_dev_version != self.ring.version:
-            self._ring_dev = jnp.asarray(self.ring.buf)
-            self._ring_dev_version = self.ring.version
-        res = self._fn(
-            self.engine.params,
-            self._ring_dev,
-            jnp.asarray(self.ring.published, jnp.int32),
-            jnp.asarray(consumed0, jnp.int32),
-            jnp.asarray(self.n_steps, jnp.int32),
-            jnp.asarray(self.slot_state),
-            jnp.asarray(self._table),
-            jnp.asarray(self._lengths),
-            pool.k, pool.v,
-        )
-        # the device call returned: any verify rows staged for this
-        # window are no longer read — release their pins (a pre-launch
-        # fault above left them pinned for the retry, which relaunches
-        # with the records still pending)
-        for pin in self._spec_pins:
-            self.ring.unpin(pin)
-        self._spec_pins.clear()
-        # strip the trailing telemetry outputs, stats outermost (the
-        # documented strip order): primary, trace mark stream, window
-        # stat rows
-        if self._metered:
-            self.last_window_stats = np.asarray(res[-1])
-            res = res[:-1]
-        if self._traced:
-            self.last_window_trace = np.asarray(res[-1])
-            res = res[:-1]
-        (consumed, executed, ss, table, lengths, pool.k, pool.v,
-         out_ring, out_count, starved) = res
-        # fold the window's truth back in BEFORE any raise: the device
-        # really ran `executed` steps — a retry must not replay them
-        consumed = int(consumed)
-        executed = int(executed)
-        self.slot_state = np.asarray(ss)
-        self._table = np.asarray(table)
-        self._lengths = np.asarray(lengths)
-        # mirror device lengths into the pool so mid-flight exports
-        # (to_dense / as_mega_cache) read the device truth; retired
-        # slots read 0 (their device row is stale until re-admission)
-        pool.lengths = np.where(
-            self.slot_state[:, mring.SS_ACTIVE] > 0,
-            self._lengths, 0).astype(np.int32)
-        self.ring.ack(consumed)
-        self.n_steps += executed
-        self.n_windows += 1
-        records = mring.decode_out_ring(out_ring, int(out_count))
-        progressed = executed > 0 or consumed > consumed0
-        self._stuck = 0 if progressed else self._stuck + 1
-        if int(starved):
-            self._trip(consumed, "abandoned ring: head record "
-                       f"{consumed + 1} published but never committed",
-                       records)
-        if (not progressed and self.ring.pending() > 0
-                and self._stuck >= self.max_stuck_windows):
-            self._trip(consumed, f"{self._stuck} consecutive windows "
-                       "with pending records and zero progress",
-                       records)
-        return records
-
-    def _trip(self, consumed: int, detail: str, records=None):
-        from triton_dist_tpu.faults import guard
-
-        trip = guard.GuardTrip(
-            rank=0, site=guard.SITES["inject"],
-            slot=consumed % self.ring.cap, progress=consumed,
-            expected=consumed + 1,
-            observed=int(self.ring.buf[consumed % self.ring.cap,
-                                       mring.IR_SEQ]),
-            seq=self.n_windows)
-        err = DeadlineExceeded(
-            f"resident window watchdog: {detail} ({trip})",
-            trips=[trip])
-        # the window DID run before the watchdog fired: its drained
-        # output records ride the exception so the scheduler folds the
-        # emitted tokens in before handling the trip — a trip must
-        # never eat completions (that would be the silent-wrong class)
-        err.out_records = records or []
-        raise err
-
-    def active_slots(self) -> np.ndarray:
-        return np.flatnonzero(self.slot_state[:, mring.SS_ACTIVE])

@@ -21,9 +21,7 @@ the whole plane off (`perf_model.choose_spec_k` picks k from the
 observed acceptance rate).
 
 Wired through `serve.Scheduler(spec=SpecConfig(...))`: verify slots mix
-with prefill/decode slots in the heterogeneous step (host loop), and in
-resident mode the proposals travel as KIND_VERIFY work-injection
-records (mega.ring) the device loop verifies at window-start steps.
+with prefill/decode slots in the heterogeneous step.
 """
 
 from triton_dist_tpu.spec.draft import Draft, NgramDraft  # noqa: F401

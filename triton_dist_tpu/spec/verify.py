@@ -48,8 +48,8 @@ class SpecConfig:
              perf_model.choose_spec_k, so the draft width decays to 0
              on non-self-similar traffic and recovers when acceptance
              returns (ROADMAP item 4 follow-up). `k` stays the hard
-             cap (the resident ring's verify records are sized for
-             it); adaptation only narrows rows. Emitted tokens are
+             cap (the k+1 <= chunk check is made for it);
+             adaptation only narrows rows. Emitted tokens are
              bitwise unaffected — k changes what is PROPOSED, and
              every accepted token is the model's own emission.
     ewma_alpha  weight of the newest verify step in the EWMA.

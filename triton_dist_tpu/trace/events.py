@@ -93,12 +93,9 @@ REGIONS = {
     "fault.inject": 22,  # host-side fault-injection instant (chaos
     # plane / scheduler quarantine markers ride host spans; this region
     # tags in-band injection points)
-    "serve.step": 23,    # resident-loop serve step (payload=device step,
-    # aux=active-slot bitmask — the slot lanes of the step, ISSUE 13)
-    "serve.poll": 24,    # resident-loop ring boundary drain (payload=
-    # records consumed at this boundary, aux=records still pending)
-    "serve.idle": 25,    # resident-loop idle poll (nothing active, ring
-    # pending but gated — payload=device step)
+    # 23-25 were the regions of a loop that is gone (PR 32): the ids
+    # stay unused, so a trace exported before then still names no other
+    # region by them
 }
 _REGION_NAMES = {v: k for k, v in REGIONS.items()}
 
@@ -118,7 +115,6 @@ REGION_CLASS = {
     "ep.ffn_chunk": "compute",
     "fp.wait": "sem_wait",
     "fp.fold": "compute",
-    "serve.step": "compute",
 }
 
 # ep.phase payload codes

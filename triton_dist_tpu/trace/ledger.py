@@ -5,7 +5,7 @@ attribution").
 Every earlier observability surface is kernel- or step-scoped: trace
 spans name a region, obs stat rows name a kernel, the scheduler's
 metrics name the fleet. This module folds them along the REQUEST axis —
-the unit users experience latency in — using three sources the serve
+the unit users experience latency in — using two sources the serve
 plane already records:
 
   phase accumulators   serve.Request.phase_ns: wall time per lifecycle
@@ -17,20 +17,10 @@ plane already records:
                        |close_frac - 1| <= tol (default 0.05; the slack
                        is the handful of bookkeeping instructions
                        between a phase close and the next open).
-  slot history         scheduler.history: per-step (host loop) /
-                       per-window (resident) entries carrying wall
-                       time, the slot->request map, and — when the
-                       resident loop was built under
-                       obs.stats.building() — the decoded
-                       resident-window stat rows (obs.stats.WMAGIC
-                       slot lanes). Device wall time splits across a
-                       step's occupants equally; across a window's by
-                       the slot lanes' per-slot step counts (launch-
-                       occupant attribution — a slot that turns over
-                       mid-window credits its launch occupant; that is
-                       the documented resolution of the ring contract).
-  output-ring metadata mega.ring.summarize_records: per-request
-                       emits / step bounds / retirement reason.
+  slot history         scheduler.history: one entry a device step,
+                       carrying wall time and the slot->request map.
+                       Device wall time splits across a step's
+                       occupants equally.
 
 Products: a JSON-able ledger document (magic "tdt-req-ledger",
 rendered by `scripts/trace_report.py --requests`), a per-request
@@ -89,7 +79,6 @@ def build_ledger(sch, tol: float = 0.05) -> dict:
             "tpot_us": (round(req.tpot_us(), 2)
                         if req.tpot_us() is not None else None),
             "queued_us": _us(phases.get("queued", 0)),
-            "inject_wait_us": _us(req.inject_wait_ns),
             "prefill_us": _us(phases.get("prefill", 0)),
             "migrate_us": _us(phases.get("migrate", 0)),
             "admit_us": _us(phases.get("admit", 0)),
@@ -98,9 +87,7 @@ def build_ledger(sch, tol: float = 0.05) -> dict:
             # wall share of decode steps that ran a verify row. It is
             # NOT added to the close sum — the decode phase already
             # contains it, so the close-against-wall contract (and its
-            # tol) is untouched. 0 on unspecced runs and in resident
-            # mode (windows are step-unresolved; the counters still
-            # land in spec_steps).
+            # tol) is untouched. 0 on unspecced runs.
             "spec_verify_us": _us(req.spec_verify_ns),
             "spec_steps": req.n_spec_steps,
             # a prefix-cache hit skips [0, prefix_hit_tokens) of
@@ -115,14 +102,12 @@ def build_ledger(sch, tol: float = 0.05) -> dict:
             "decode_steps": max(
                 0, req.n_device_steps - req.n_prefill_chunks),
             "device_steps": req.n_device_steps,
-            "windows": req.n_windows,
             "evictions": req.n_evictions,
             "device_share_us": round(
                 device_us.get(req.request_id, 0.0), 2),
         })
     return {
         "magic": LEDGER_MAGIC,
-        "mode": "resident" if sch.resident else "host",
         "chunk": sch.chunk,
         "tol": tol,
         "history_dropped": sch.history_dropped,
@@ -131,34 +116,17 @@ def build_ledger(sch, tol: float = 0.05) -> dict:
 
 
 def _device_time_by_request(sch) -> Dict[int, float]:
-    """Device wall time (us) per request from the slot history: step
-    entries split equally across occupants; window entries split by
-    the stat lanes' per-slot step counts when the loop was metered,
-    else equally across the launch occupants."""
+    """Device wall time (us) per request from the slot history: each
+    step's wall split equally across its occupants."""
     out: Dict[int, float] = {}
     for e in sch.history:
         dur_us = (e["t1"] - e["t0"]) / 1e3
         slots = e.get("slots") or {}
         if not slots:
             continue
-        if e["kind"] == "step":
-            share = dur_us / len(slots)
-            for rid, _phase, _n in slots.values():
-                out[rid] = out.get(rid, 0.0) + share
-            continue
-        # window entry
-        weights: Dict[int, float] = {}
-        ws = e.get("stats")
-        if ws is not None:
-            lane_steps = {lane.slot: lane.steps for lane in ws.slots}
-            for slot, rid in slots.items():
-                weights[rid] = weights.get(rid, 0.0) + lane_steps.get(
-                    slot, 0)
-        if not weights or not any(weights.values()):
-            weights = {rid: 1.0 for rid in slots.values()}
-        total = sum(weights.values())
-        for rid, w in weights.items():
-            out[rid] = out.get(rid, 0.0) + dur_us * w / total
+        share = dur_us / len(slots)
+        for rid, _phase, _n in slots.values():
+            out[rid] = out.get(rid, 0.0) + share
     return out
 
 
@@ -166,7 +134,7 @@ def check_close(ledger: dict, states=("finished",)) -> List[str]:
     """The ledger close contract: for every request in one of `states`,
     |close_frac - 1| <= tol — the decomposed phase times sum to the
     submit->finish wall time. Returns problem strings (empty = closed);
-    the tier-1 pin asserts empty on a traced+metered resident run."""
+    the tier-1 pin asserts empty on a served run."""
     tol = float(ledger.get("tol", 0.05))
     problems = []
     for row in ledger["requests"]:
@@ -226,9 +194,9 @@ def format_requests_table(ledger: dict) -> str:
     """The per-request table `scripts/trace_report.py --requests`
     prints: one row per request, decomposition columns in ms."""
     cols = (f"{'req':>5} {'state':<10} {'wall_ms':>9} {'queued':>8} "
-            f"{'inject':>8} {'prefill':>8} {'migrate':>8} "
+            f"{'prefill':>8} {'migrate':>8} "
             f"{'admit':>8} {'decode':>9} {'close':>6} "
-            f"{'ttft_ms':>8} {'tok':>4} {'steps':>6} {'win':>4} "
+            f"{'ttft_ms':>8} {'tok':>4} {'steps':>6} "
             f"{'dev_ms':>8}")
     lines = [cols]
 
@@ -240,14 +208,13 @@ def format_requests_table(ledger: dict) -> str:
         lines.append(
             f"{row['request_id']:>5} {row['state']:<10} "
             f"{ms(row.get('wall_us')):>9} {ms(row['queued_us']):>8} "
-            f"{ms(row.get('inject_wait_us', 0)):>8} "
             f"{ms(row['prefill_us']):>8} "
             f"{ms(row.get('migrate_us', 0)):>8} "
             f"{ms(row.get('admit_us', 0)):>8} "
             f"{ms(row['decode_us']):>9} "
             f"{'-' if close is None else format(close, '.3f'):>6} "
             f"{ms(row.get('ttft_us')):>8} {row.get('tokens_out', 0):>4} "
-            f"{row['device_steps']:>6} {row.get('windows', 0):>4} "
+            f"{row['device_steps']:>6} "
             f"{ms(row.get('device_share_us', 0)):>8}")
     if ledger.get("history_dropped"):
         lines.append(f"(history truncated: {ledger['history_dropped']} "
@@ -284,7 +251,7 @@ def write_request_trace(sch, path: str) -> str:
     req<N>/<phase> span of the scheduler's span log (obs/spans.py)
     lands in its request's own track (instants — evictions,
     quarantines — as 'i' events), with the scheduler-level spans (each
-    round's phases, step retries, resident windows) in a 'serve' track
+    round's phases, step retries) in a 'serve' track
     beside them. Loads at ui.perfetto.dev next to the in-kernel traces
     (same format tag)."""
     spans = sch.spans.triples()
