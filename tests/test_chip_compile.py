@@ -148,9 +148,9 @@ def _serve_step_compiled(eng, slots, width, page=64):
     max_pages = MAX_LEN // page
     mesh, cfg = eng.mesh, eng.cfg
     rep = NamedSharding(mesh, P())
-    pool = SDS((cfg.num_layers, cfg.num_kv_heads, 1 + slots * max_pages,
-                page, cfg.head_dim), BF16,
-               sharding=NamedSharding(mesh, P(None, "tp")))
+    pool = SDS((cfg.num_layers, 1 + slots * max_pages, page,
+                cfg.num_kv_heads, cfg.head_dim), BF16,
+               sharding=NamedSharding(mesh, P(None, None, None, "tp")))
     return eng.make_serve_step(slots, width, page, max_pages).lower(
         eng.params, SDS((slots, width), jnp.int32, sharding=rep),
         (pool, pool), SDS((slots, max_pages), jnp.int32, sharding=rep),
@@ -194,6 +194,55 @@ def test_decode_only_serve_step(chip, tp, kernels, xla_collectives):
     assert dict(found) == xla_collectives
 
 
+def _pool_moves_once(text, depth, slots, max_len, page, hkv, d):
+    """The pool's round trip in a compiled serve program: the donated
+    pool is updated IN PLACE — an array of the pool's shape is a
+    parameter, a loop's element, or a `dynamic-update-slice` (alone or
+    the root of a fusion), never a `copy` or a `transpose` — and no
+    array of the whole view's shape is built, in any of the orders a
+    gather or a restack of the layer scan would give it: each layer
+    reads its own (slots, max_len, Hkv, D) view through the table."""
+    import re
+
+    maxp = max_len // page
+    pool = f"bf16[{depth},{1 + slots * maxp},{page},{hkv},{d}]"
+    made = re.findall(
+        r"%(\S+) = " + re.escape(pool) + r"\S* ([a-z-]+)\(", text)
+    assert made, "the pool's shape is not in the program"
+    in_place = {"parameter", "get-tuple-element", "bitcast",
+                "dynamic-update-slice", "fusion"}
+    assert {op for _, op in made} <= in_place, made
+    assert all("dynamic-update-slice" in name
+               for name, op in made if op == "fusion"), made
+    assert sum("dynamic-update-slice" in name + op
+               for name, op in made) >= 2  # K and V are written
+    tail = f"{hkv},{d}]"
+    assert not [v for v in (f"[{depth},{slots},{max_len},{tail}",
+                            f"[{depth},{slots},{maxp},{page},{tail}",
+                            f"[{slots * maxp},{depth},{page},{tail}",
+                            f"[{slots},{maxp},{depth},{page},{tail}")
+                if v in text]
+
+
+@pytest.mark.parametrize("width", [1, 128])
+@pytest.mark.parametrize("tp", [1, 4], ids=["qwen3-8b.1chip", "qwen3-8b.tp4"])
+def test_serve_step_moves_the_pool_s_bytes_once(chip, tp, width):
+    """Both serve programs of both dense configurations (8 slots,
+    64-token pages) hold no copy of the pool and no whole view
+    (`_pool_moves_once`), and the decode-only one keeps nothing of a
+    view's size alive from layer to layer."""
+    eng = _engine(chip, tp)
+    cfg, slots, page = eng.cfg, 8, 64
+    compiled = _serve_step_compiled(eng, slots, width, page)
+    hkv = cfg.num_kv_heads // tp
+    _pool_moves_once(compiled.as_text(), cfg.num_layers, slots, MAX_LEN,
+                     page, hkv, cfg.head_dim)
+    if width == 1:
+        one_layer_s_view = 2 * slots * MAX_LEN * hkv * cfg.head_dim * 2
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < one_layer_s_view)
+
+
 def test_hybrid_serve_step_one_chip(chip):
     """The hybrid family's step at the benchmark's geometry (8 slots x
     the chooser's 128-token chunk, 64-token pages, 8,192 positions),
@@ -217,9 +266,8 @@ def test_hybrid_serve_step_one_chip(chip):
     chunk = choose_chunk_for(cfg, 1, slots, max_len, "flash")
     assert chunk == 128
     max_pages = max_len // page
-    pool = SDS((cfg.num_kv_layers, cfg.num_kv_heads,
-                1 + slots * max_pages, page, cfg.head_dim), BF16,
-               sharding=rep)
+    pool = SDS((cfg.num_kv_layers, 1 + slots * max_pages, page,
+                cfg.num_kv_heads, cfg.head_dim), BF16, sharding=rep)
     rec, conv = qwen3_next.state_shapes(cfg, slots)
     cache = (pool, pool, SDS(rec, jnp.float32, sharding=rep),
              SDS(conv, BF16, sharding=rep))
@@ -232,6 +280,8 @@ def test_hybrid_serve_step_one_chip(chip):
         SDS((slots, 2), jnp.uint32, sharding=rep)).compile()
     assert _kernels(compiled) == {"_fp_local_kernel": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    _pool_moves_once(compiled.as_text(), cfg.num_kv_layers, slots, max_len,
+                     page, cfg.num_kv_heads, cfg.head_dim)
 
 
 def test_gated_attention_has_no_silent_route_on_the_chip(chip):

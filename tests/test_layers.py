@@ -304,10 +304,14 @@ def test_tp_attn_decode_with_cache_matches_prefill(mesh8):
         kc = jnp.zeros((b, t_max, spec.num_kv_heads, d), xp.dtype)
         vc = jnp.zeros_like(kc)
         pos = jnp.tile(jnp.arange(s)[None], (b, 1))
-        _, (kc, vc) = tp_attn_fwd(
+        # the layer hands back the step's rows (B, S, Hkv, D); where
+        # they are kept is the caller's (models/dense.py `forward`)
+        _, (k_rows, v_rows) = tp_attn_fwd(
             xp, params, spec, cos, sin, pos, b, mode="ar",
             kv_cache=(kc, vc), kv_len=jnp.full((b,), s),
         )
+        assert k_rows.shape == v_rows.shape == (b, s, spec.num_kv_heads, d)
+        kc, vc = kc.at[:, :s].set(k_rows), vc.at[:, :s].set(v_rows)
         # decode 1 token at position s
         pos_d = jnp.full((b, 1), s)
         y, _ = tp_attn_fwd(

@@ -110,14 +110,16 @@ def test_pool_cow_copies_shared_page(eng1):
     pool = KVPool(eng1, slots=2, page=8, total_pages=4)
     pool.admit(0, 8)
     (pg,) = pool._pages[0]
-    pool.k = pool.k.at[:, :, pg].set(jnp.ones_like(pool.k[:, :, pg]))
+    # token-major pages: (L, P, page, Hkv, D)
+    pool.k = pool.k.at[:, pg].set(jnp.ones_like(pool.k[:, pg]))
     assert pool.cow(0, 0) == pg  # exclusive: no-op
     pool.ref_pages([pg])
     new = pool.cow(0, 0)
     assert new != pg and pool.table[0, 0] == new
     assert pool.refcount(pg) == 1 and pool.refcount(new) == 1
-    np.testing.assert_array_equal(np.asarray(pool.k[:, :, new]),
-                                  np.asarray(pool.k[:, :, pg]))
+    np.testing.assert_array_equal(np.asarray(pool.k[:, new]),
+                                  np.asarray(pool.k[:, pg]))
+    assert float(np.asarray(pool.k[:, new], np.float32).min()) == 1.0
     pool.check()
     pool.release(0)
     pool.unref_pages([pg])

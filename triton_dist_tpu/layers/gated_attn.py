@@ -53,8 +53,9 @@ def gated_attn_fwd(x, p: GatedAttnParams, spec: GatedAttnSpec, cos, sin,
                    positions, kv_cache, kv_len, attn_impl: str,
                    eps: float = 1e-6):
     """x (B, C, H); kv_cache (k, v) each (B, T, Hkv, D); positions
-    (B, C) absolute; kv_len (B,). Returns (y (B, C, H), (k, v) with the
-    chunk's rows written at `positions`)."""
+    (B, C) absolute; kv_len (B,). Returns (y (B, C, H), (k, v): the
+    chunk's rows (B, C, Hkv, D) in the cache's dtype, as they were laid
+    into this call's own copy of the view at `positions`)."""
     b, c, _ = x.shape
     hq, hkv, d = spec.num_q_heads, spec.num_kv_heads, spec.head_dim
     qg = jnp.dot(x, p.w_q, preferred_element_type=jnp.float32).astype(
@@ -69,13 +70,13 @@ def gated_attn_fwd(x, p: GatedAttnParams, spec: GatedAttnSpec, cos, sin,
     q = _partial_rope(q, cos, sin, positions, spec.rotary_dim)
     k = _partial_rope(k, cos, sin, positions, spec.rotary_dim)
     k_cache, v_cache = kv_cache
-    k_cache = _scatter_kv(k_cache, k, positions)
-    v_cache = _scatter_kv(v_cache, v, positions)
-    out = gqa_attention(q, k_cache, v_cache, causal=True,
+    k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+    out = gqa_attention(q, _scatter_kv(k_cache, k, positions),
+                        _scatter_kv(v_cache, v, positions), causal=True,
                         q_positions=positions, kv_len=kv_len,
                         prefill_impl=attn_impl)
     out = out.astype(jnp.float32) * jax.nn.sigmoid(
         gate.astype(jnp.float32))
     y = jnp.dot(out.reshape(b, c, hq * d).astype(x.dtype), p.w_o,
                 preferred_element_type=jnp.float32).astype(x.dtype)
-    return y, (k_cache, v_cache)
+    return y, (k, v)

@@ -83,33 +83,42 @@ def _attn_core(qkv, params, spec, batch, cos, sin, positions, kv_cache,
     auto; kernels/flash_prefill.py). attn_block: forwarded to
     gqa_attention's prefill_block — the planner's tune-cache KV page
     height (None keeps the default block, i.e. the legacy program).
-    Returns (attn_out (M, Hq*D), new_kv_cache)."""
+    Returns (attn_out (M, Hq*D), (k, v)): the step's NEW ROWS
+    (B, S, Hkv, D), rope applied — with a cache, in its dtype, the rows
+    this call laid into its own copy of the layer's view before
+    attending; the caller owns where they are kept (models/dense.py
+    `forward`, KVCache.scatter_step)."""
     q, k, v = _split_qkv(qkv, spec, batch)
     q, k = _qk_norm_rope(q, k, params, cos, sin, positions)
     if kv_cache is None:
         out = gqa_attention(q, k, v, causal=True,
                             prefill_impl=attn_impl,
                             prefill_block=attn_block)
-        new_cache = (k, v)
     else:
         assert kv_len is not None, (
             "kv_cache without kv_len would attend over the uninitialized "
             "cache tail"
         )
         k_cache, v_cache = kv_cache
+        # the rows have two readers, this layer's view and the caller;
+        # behind the barrier they are ONE materialised value, and what
+        # XLA fuses into the norm and rope that produce them does not
+        # depend on who else reads them (without it the tp=4 wide step
+        # re-fuses the k norm's sum: logits 0.2 from the same program
+        # without the second reader; PERF.md, PR 33)
+        k, v = jax.lax.optimization_barrier(
+            (k.astype(k_cache.dtype), v.astype(v_cache.dtype)))
         # Write this step's K/V into the cache at `positions`, then attend
         # causally by absolute position — one code path for 1-token decode
         # and multi-token prefill-into-cache.
-        k_cache = _scatter_kv(k_cache, k, positions)
-        v_cache = _scatter_kv(v_cache, v, positions)
         out = gqa_attention(
-            q, k_cache, v_cache, causal=True, q_positions=positions,
-            kv_len=kv_len, prefill_impl=attn_impl,
+            q, _scatter_kv(k_cache, k, positions),
+            _scatter_kv(v_cache, v, positions), causal=True,
+            q_positions=positions, kv_len=kv_len, prefill_impl=attn_impl,
             prefill_block=attn_block,
         )
-        new_cache = (k_cache, v_cache)
     m = out.shape[0] * out.shape[1]
-    return out.reshape(m, spec.num_q_heads * spec.head_dim), new_cache
+    return out.reshape(m, spec.num_q_heads * spec.head_dim), (k, v)
 
 
 def _scatter_kv(cache, kv, positions):
@@ -126,14 +135,14 @@ def tp_attn_xla_fwd(x_shard, params: TPAttnParams, spec: TPAttnSpec,
     x_full = jax.lax.all_gather(x_shard, axis, tiled=True)
     qkv = jnp.dot(x_full, params.w_qkv,
                   preferred_element_type=jnp.float32).astype(x_shard.dtype)
-    out, new_cache = _attn_core(qkv, params, spec, batch, cos, sin,
+    out, rows = _attn_core(qkv, params, spec, batch, cos, sin,
                                 positions, kv_cache, kv_len, attn_impl,
                                 attn_block)
     partial = jnp.dot(out, params.w_o, preferred_element_type=jnp.float32)
     y = jax.lax.psum_scatter(
         partial.astype(x_shard.dtype), axis, tiled=True
     )
-    return y, new_cache
+    return y, rows
 
 
 def tp_attn_dist_fwd(x_shard, params: TPAttnParams, spec: TPAttnSpec,
@@ -144,18 +153,18 @@ def tp_attn_dist_fwd(x_shard, params: TPAttnParams, spec: TPAttnSpec,
                      rs_config: Optional[GemmRsConfig] = None):
     """Fused path (ref dist_triton_fwd, tp_attn.py:215): overlapped
     AG+GEMM QKV projection, attention, overlapped GEMM+RS O projection.
-    x_shard: (M/n, hidden) -> ((M/n, hidden), new_kv_cache)."""
+    x_shard: (M/n, hidden) -> ((M/n, hidden), the step's (k, v) rows)."""
     from triton_dist_tpu.trace.events import primary
 
     # primary(): build-safe under trace.building() (buffers dropped; see
     # tp_mlp.dist_fwd)
     qkv = primary(ag_gemm(x_shard, params.w_qkv, axis=axis,
                           config=ag_config))
-    out, new_cache = _attn_core(qkv, params, spec, batch, cos, sin,
+    out, rows = _attn_core(qkv, params, spec, batch, cos, sin,
                                 positions, kv_cache, kv_len, attn_impl,
                                 attn_block)
     y = primary(gemm_rs(out, params.w_o, axis=axis, config=rs_config))
-    return y, new_cache
+    return y, rows
 
 
 def tp_attn_ar_fwd(x_full, params: TPAttnParams, spec: TPAttnSpec,
@@ -167,11 +176,11 @@ def tp_attn_ar_fwd(x_full, params: TPAttnParams, spec: TPAttnSpec,
     local QKV gemm, attention, fused gemm+allreduce O projection."""
     qkv = jnp.dot(x_full, params.w_qkv,
                   preferred_element_type=jnp.float32).astype(x_full.dtype)
-    out, new_cache = _attn_core(qkv, params, spec, batch, cos, sin,
+    out, rows = _attn_core(qkv, params, spec, batch, cos, sin,
                                 positions, kv_cache, kv_len, attn_impl,
                                 attn_block)
     y = gemm_ar(out, params.w_o, axis=axis, config=rs_config)
-    return y, new_cache
+    return y, rows
 
 
 MODES = {

@@ -548,7 +548,8 @@ class MegaQwen3:
         per-step dispatch tax the r05 engine-vs-mega gap prices).
         Works over both cache forms; with a PagedMegaKVCache the loop
         iterates directly over the shared page pool — a serve-plane
-        `KVPool.as_mega_cache()` export decodes in place.
+        `KVPool.as_mega_cache()` export (the pool's pages copied into
+        this module's page order) decodes in place.
 
         tokens (B,) -> (generated ids (B, steps), cache). Greedy only
         (argmax — the self-feeding loop's fixed point); bitwise equal

@@ -278,14 +278,15 @@ def _layer_fwd(cfg: ModelConfig, spec: TPAttnSpec, cos, sin, positions,
                lp: DenseLayerParams, kv):
     """One transformer block (ref DenseLLMLayer.fwd, dense.py:101-114).
     All mode/impl routing lives in the Plan (triton_dist_tpu.plan):
-    this function only states the block structure."""
+    this function only states the block structure. Returns (x, the
+    step's (k, v) rows (B, S, Hkv, D))."""
     attn_params = TPAttnParams(
         w_qkv=lp.w_qkv, w_o=lp.w_o,
         q_norm=lp.q_norm if cfg.use_qk_norm else None,
         k_norm=lp.k_norm if cfg.use_qk_norm else None,
     )
     h = rms_norm(x, lp.input_ln, cfg.rms_eps)
-    attn_out, kv = plan_exec.attn_fwd(
+    attn_out, rows = plan_exec.attn_fwd(
         plan, h, attn_params, spec, cos, sin, positions, batch,
         axis, kv, kv_len,
     )
@@ -300,10 +301,10 @@ def _layer_fwd(cfg: ModelConfig, spec: TPAttnSpec, cos, sin, positions,
     mlp_out = plan_exec.ffn_fwd(plan, h, ffn_params, axis,
                                 top_k=cfg.num_experts_per_tok)
     x = x + mlp_out
-    return x, kv
+    return x, rows
 
 
-def forward(
+def forward_rows(
     cfg: ModelConfig,
     params: DenseLLMParams,
     tokens: jax.Array,  # (B, S) int32, replicated
@@ -314,12 +315,16 @@ def forward(
     attn_impl: Optional[str] = None,
     plan: Optional[Plan] = None,
 ):
-    """Per-device forward (inside shard_map). Returns (logits, new_cache);
-    logits (B, V) for the last position (or (B, S, V) if
-    return_full_logits). attn_impl: prefill attention implementation
-    override ("xla" | "pallas"; None = auto — the flash-prefill switch,
-    plan.route_prefill_impl). Mirrors the reference inference entry
-    (ref: models/dense.py:221-241 `inference`).
+    """Per-device forward (inside shard_map) over a cache it only
+    READS — dense or paged, each layer through `cache.layer_view`.
+    Returns (logits, (k, v)): logits (B, V) for the last position (or
+    (B, S, V) if return_full_logits), and the step's new K/V rows
+    (L, B, S, Hkv, D) for positions cache.length .. + S — what leaves
+    the layer scan is the rows, never the (B, T, Hkv, D) views they
+    were laid into: `forward` lays them into a KVCache, the serve step
+    into its pages (KVCache.scatter_step). attn_impl: prefill
+    attention implementation override ("xla" | "pallas"; None = auto —
+    the flash-prefill switch, plan.route_prefill_impl).
 
     Routing is the fusion planner's (triton_dist_tpu.plan): a legacy
     `mode` string is honored bit-for-bit as a plan constraint,
@@ -352,20 +357,17 @@ def forward(
     x = plan_exec.shard_tokens(x, axis, plan)
 
     def step(x, xs):
-        lp, k_l, v_l = xs
-        x, kv = _layer_fwd(cfg, spec, cos, sin, positions, kv_len, b,
-                           axis, plan, x, lp, (k_l, v_l))
-        return x, kv
+        i, lp = xs
+        return _layer_fwd(cfg, spec, cos, sin, positions, kv_len, b,
+                          axis, plan, x, lp, cache.layer_view(i))
 
     # strip the n-axis dim (shard_map gives size-1 shards on that dim)
     lp_local = jax.tree.map(
         lambda a, sp: a[:, 0] if sp == P(None, axis) else a,
         params.layers, param_specs(axis, cfg.is_moe).layers,
     )
-    x, (k_new, v_new) = jax.lax.scan(
-        step, x, (lp_local, cache.k, cache.v)
-    )
-    new_cache = KVCache(k=k_new, v=v_new, length=kv_len)
+    x, rows = jax.lax.scan(
+        step, x, (jnp.arange(cfg.num_layers), lp_local))
 
     x = plan_exec.gather_tokens(x, axis, plan)  # (M, H) when sharded
     x = rms_norm(x, params.final_ln, cfg.rms_eps)
@@ -381,4 +383,20 @@ def forward(
     logits = jax.lax.all_gather(logits, axis, axis=2, tiled=True)  # (B,S,V)
     if not return_full_logits:
         logits = logits[:, 0]
-    return logits, new_cache
+    return logits, rows
+
+
+def forward(cfg: ModelConfig, params: DenseLLMParams, tokens: jax.Array,
+            cache: Optional[KVCache], **kw):
+    """`forward_rows` (and its keywords) with the step's rows laid into
+    the cache: returns (logits, new_cache), new_cache the (donated)
+    cache with the rows at positions cache.length .. + S and its length
+    advanced. Mirrors the reference inference entry (ref:
+    models/dense.py:221-241 `inference`)."""
+    logits, (k, v) = forward_rows(cfg, params, tokens, cache, **kw)
+    s = tokens.shape[1]
+    bidx = jnp.arange(tokens.shape[0])[:, None]
+    positions = cache.length[:, None] + jnp.arange(s)[None, :]
+    return logits, KVCache(k=cache.k.at[:, bidx, positions].set(k),
+                           v=cache.v.at[:, bidx, positions].set(v),
+                           length=cache.length + s)

@@ -27,6 +27,7 @@ from triton_dist_tpu.models.dense import (
     DenseLLMParams,
     cache_specs,
     forward,
+    forward_rows,
     init_params,
     param_specs,
 )
@@ -44,16 +45,16 @@ def sample_token(logits, key=None, temperature: float = 0.0):
     )
 
 
-def _serve_step_math(cfg, mode, axis, slots, chunk, page, t_pool,
-                     params, tokens, pool_k, pool_v, table, lengths,
-                     n_valid, temps, keys, per_pos: bool = False,
-                     plan=None):
+def _serve_step_math(cfg, mode, axis, params, tokens, pool_k, pool_v,
+                     table, lengths, n_valid, temps, keys,
+                     per_pos: bool = False, plan=None):
     """THE per-rank serve-step computation (inside shard_map): one
     (slots, chunk) forward over the paged pool's dense view, per-slot
-    sampling, and the null-page-routed KV scatter. Generic in `chunk`:
-    `make_serve_step` compiles it once a width of `Engine.serve_widths`
-    (the decode-only step is `chunk == 1`: one query row a slot
-    through the dense attention chain, no prefill route).
+    sampling, and the step's K/V rows written into their pages in
+    place. Generic in the width of `tokens`: `make_serve_step` compiles
+    it once a width of `Engine.serve_widths` (the decode-only step is
+    one column: one query row a slot through the dense attention chain,
+    no prefill route).
 
     per_pos=False: keys (K, 2) u32, the returned token is sampled at
     column n_valid-1 only — the classic one-emission step. per_pos=True
@@ -64,14 +65,14 @@ def _serve_step_math(cfg, mode, axis, slots, chunk, page, t_pool,
     makes that literal, greedy AND sampled), which is exactly the
     bit-identity oracle the longest-accepted-prefix rule needs
     (triton_dist_tpu.spec.verify)."""
-    cache = KVCache.dense_view(pool_k, pool_v, table, lengths)
-    logits, new_cache = forward(
+    cache = KVCache(pool_k, pool_v, lengths, table)  # paged: read in place
+    logits, (k_rows, v_rows) = forward_rows(
         cfg, params, tokens, cache, mode=mode, axis=axis,
         return_full_logits=True, plan=plan,
-    )  # logits (K, C, V) f32, new_cache k/v (L, K, T, Hkv, D)
+    )  # logits (K, C, V) f32, rows (L, K, C, Hkv, D)
     tok, last = _sample_step(logits, n_valid, temps, keys, per_pos)
-    pool_k, pool_v = KVCache.scatter_step(pool_k, pool_v, new_cache, table,
-                                          lengths, n_valid, chunk)
+    pool_k, pool_v = KVCache.scatter_step(pool_k, pool_v, k_rows, v_rows,
+                                          table, lengths, n_valid)
     return tok, last, pool_k, pool_v
 
 
@@ -99,7 +100,7 @@ def _sample_step(logits, n_valid, temps, keys, per_pos: bool):
     return tok, last
 
 
-def _hybrid_step_math(cfg, chunk, attn_impl, params, tokens, cache, table,
+def _hybrid_step_math(cfg, attn_impl, params, tokens, cache, table,
                       lengths, n_valid, temps, keys):
     """The serve step of the hybrid family (models/qwen3_next.py): the
     same fixed-geometry forward, sampling and page scatter, with the
@@ -107,12 +108,11 @@ def _hybrid_step_math(cfg, chunk, attn_impl, params, tokens, cache, table,
     (tok, last, cache, {counter: () int32})."""
     from triton_dist_tpu.models import qwen3_next
 
-    logits, (k_new, v_new), rec, conv, stats = qwen3_next.forward_chunk(
+    logits, (k_rows, v_rows), rec, conv, stats = qwen3_next.forward_chunk(
         cfg, params, tokens, cache, table, lengths, n_valid, attn_impl)
     tok, last = _sample_step(logits, n_valid, temps, keys, False)
     pool_k, pool_v = KVCache.scatter_step(
-        cache.k, cache.v, KVCache(k_new, v_new, lengths), table, lengths,
-        n_valid, chunk)
+        cache.k, cache.v, k_rows, v_rows, table, lengths, n_valid)
     return tok, last, qwen3_next.Cache(pool_k, pool_v, rec, conv), stats
 
 
@@ -348,8 +348,8 @@ class Engine:
 
         `cache` is ONE pytree, everything a slot carries between steps
         (`KVPool.state`): for the dense family (pool_k, pool_v), each
-        (L, Hkv, P, page, D) — megakernel pool layout, shared with
-        mega.qwen3.PagedMegaKVCache; for the hybrid family
+        (L, P, page, Hkv, D) — token-major pages, the kv-head axis
+        (3) the tensor-parallel one (serve/kv_pool.py); for the hybrid family
         `qwen3_next.Cache`, pages for the attention blocks only and the
         delta-net blocks' per-slot recurrent and convolution state
         (a padding column leaves both as they were; a slot whose
@@ -430,7 +430,7 @@ class Engine:
             def per_rank(params, tokens, cache, table, lengths, n_valid,
                          temps, keys):
                 return _hybrid_step_math(
-                    cfg, chunk, attn_impl, params, tokens,
+                    cfg, attn_impl, params, tokens,
                     qwen3_next.Cache(*cache), table, lengths, n_valid,
                     temps, keys)
 
@@ -439,12 +439,12 @@ class Engine:
             def per_rank(params, tokens, cache, table, lengths, n_valid,
                          temps, keys):
                 tok, last, pool_k, pool_v = _serve_step_math(
-                    cfg, mode, axis, slots, chunk, page, t_pool,
-                    params, tokens, cache[0], cache[1], table, lengths,
-                    n_valid, temps, keys, per_pos=per_pos, plan=plan)
+                    cfg, mode, axis, params, tokens, cache[0], cache[1],
+                    table, lengths, n_valid, temps, keys, per_pos=per_pos,
+                    plan=plan)
                 return tok, last, (pool_k, pool_v), {}
 
-            cache_spec = P(None, self.axis)
+            cache_spec = P(None, None, None, self.axis)
         return jax.jit(
             jax.shard_map(
                 per_rank, mesh=self.mesh,

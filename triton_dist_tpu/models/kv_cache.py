@@ -9,91 +9,125 @@ mutation under jit).
 
 Shapes (per tp rank): k/v (L, B, T_max, Hkv_loc, D). Inside shard_map the
 head axis is the tp-sharded one.
+
+The serve plane's cache is PAGED (serve/kv_pool.KVPool): k/v are page
+pools, token-major (L, P, page, Hkv, D), and a `table` maps each
+sequence's page grid onto them. A page is `page` whole token rows of a
+layer's dense (B, T, Hkv, D) view, so the step's round trip moves each
+byte once: every layer reads its own view through the table
+(`layer_view`), and the step's new rows go back as page slabs in place
+(`scatter_step`). No whole-model view is built in between.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 
 class KVCache(NamedTuple):
-    k: jax.Array  # (L, B, T_max, Hkv, D)
-    v: jax.Array  # (L, B, T_max, Hkv, D)
+    k: jax.Array  # (L, B, T_max, Hkv, D); paged: (L, P, page, Hkv, D)
+    v: jax.Array
     length: jax.Array  # (B,) valid entries per sequence
+    # a PAGED cache (the serve plane's): k/v are page pools and `table`
+    # (B, MAXP) maps each sequence's page grid onto pool pages
+    table: Optional[jax.Array] = None
+
+    def layer_view(self, i):
+        """Layer i's dense (k, v), each (B, T, Hkv, D) — what the layer
+        lays its rows into and attends over (models/dense.py's layer
+        scan calls this in its body). Of a paged cache it is the
+        layer's pages gathered through the table, `pool[i, table]` with
+        the two page axes read as one: each page is moved once, as one
+        contiguous piece, into a buffer that is the layer's own — no
+        transposition, and no copy of a whole-model view to slice it
+        from. A pure copy, so values round-trip bitwise: paging is an
+        allocation policy, never a numeric one. Unallocated table
+        entries point at page 0 (the pool's reserved null page); what
+        they gather sits beyond each sequence's length and is masked
+        by attention's kv_len/causal bounds."""
+        if self.table is None:
+            return self.k[i], self.v[i]
+        b, maxp = self.table.shape
+        _, _, page, hkv, d = self.k.shape
+        return tuple(pool[i, self.table].reshape(b, maxp * page, hkv, d)
+                     for pool in (self.k, self.v))
 
     @staticmethod
     def dense_view(pool_k, pool_v, table, lengths) -> "KVCache":
-        """Dense (L, B, T, Hkv, D) view of a PAGED pool — the serve
-        plane's read path (serve/kv_pool.KVPool): pool_k/pool_v are
-        shared page pools in megakernel pool layout (L, Hkv, P, page, D)
-        and `table` (B, MAXP) maps each sequence's page grid onto pool
-        pages. The gather is a pure copy, so values round-trip bitwise —
-        paging is an allocation policy, never a numeric one. Unallocated
-        table entries point at page 0 (the pool's reserved null page);
-        the garbage they gather sits beyond each sequence's `lengths`
-        and is masked by attention's kv_len/causal bounds."""
-        L, Hkv, _, page, D = pool_k.shape
+        """Every layer's `layer_view` of a paged pool at once, as a
+        dense (L, B, T, Hkv, D) KVCache: `pool[:, table]` with the two
+        page axes read as one. The SNAPSHOT form (KVPool.to_dense, the
+        megakernel bridge's reference, tests); the serve step never
+        builds it — its layers each read their own view."""
+        L, _, page, Hkv, D = pool_k.shape
         B = table.shape[0]
         t = KVCache.dense_view_tokens(table.shape, page) // B
-        k = jnp.moveaxis(pool_k[:, :, table].reshape(L, Hkv, B, t, D),
-                         1, 3)
-        v = jnp.moveaxis(pool_v[:, :, table].reshape(L, Hkv, B, t, D),
-                         1, 3)
-        return KVCache(k, v, lengths)
+        return KVCache(pool_k[:, table].reshape(L, B, t, Hkv, D),
+                       pool_v[:, table].reshape(L, B, t, Hkv, D), lengths)
 
     @staticmethod
     def dense_view_tokens(table_shape, page: int) -> int:
-        """Token positions one `dense_view` of a `table_shape` table
-        gathers, a layer and kv head: every entry's whole page, live or
-        null. The gather's own size — the serve plane's
-        `serve_kv_tokens_gathered` counts this, so the two change
-        together."""
+        """Token positions a step's `layer_view`s of a `table_shape`
+        table gather, a layer and kv head: every entry's whole page,
+        live or null, each moved once. The gather's own size — the
+        serve plane's `serve_kv_tokens_gathered` counts this, so the
+        two change together."""
         slots, maxp = table_shape
         return slots * maxp * page
 
     @staticmethod
-    def scatter_step(pool_k, pool_v, new: "KVCache", table, lengths,
-                     n_valid, chunk: int):
+    def scatter_step(pool_k, pool_v, rows_k, rows_v, table, lengths,
+                     n_valid):
         """A serve step's K/V rows back into the paged pool — the write
-        path beside `dense_view`: the rows at positions lengths ..
-        lengths + chunk of `new` (the dense view after the forward).
-        Valid columns land on their table pages; padding columns are
-        routed to page 0, the pool's reserved null page (their
-        positions may sit past the slot's allocated pages, whose table
-        entries still map to live pages of OTHER slots)."""
-        page = pool_k.shape[3]
+        path beside `layer_view`. rows_k/rows_v (L, B, C, Hkv, D) are
+        the rows the layers computed for the step's C columns; slot
+        s's columns [0, n_valid[s]) belong at positions lengths[s] ..
+        of its pages, the rest are padding and are written nowhere.
+
+        A slot's rows touch at most `(C + page - 2) // page + 1` pages,
+        each a slab (L, 1, page, Hkv, D) of the pool, so the write is a
+        loop over the slots of read the slabs, lay the rows over them,
+        keep the pool's own row wherever the step has no valid one, and
+        put the slabs back with `dynamic_update_slice`: the donated pool
+        is updated in place, at every C alike. A slab with no valid row
+        is the null page's (page 0), so an index past a slot's pages
+        never reaches a live page."""
+        L, _, page, Hkv, D = pool_k.shape
         slots, max_pages = table.shape
-        bidx = jnp.arange(slots)[:, None]
-        pos = lengths[:, None] + jnp.arange(chunk)[None, :]  # (K, C)
-        posc = jnp.minimum(pos, max_pages * page - 1)
-        valid = jnp.arange(chunk)[None, :] < n_valid[:, None]
-        pg = jnp.where(valid, table[bidx, posc // page], 0)
-        off = posc % page
-        kn = jnp.moveaxis(new.k[:, bidx, posc], 3, 1)
-        vn = jnp.moveaxis(new.v[:, bidx, posc], 3, 1)
-        if chunk == 1:
-            # the decode-only step: one row a slot, written in place a
-            # slot at a time. At one column XLA wraps the scatter below
-            # in four transposed copies of the whole pool (compile, PR
-            # 31: 27 M of the entry computation's 63 M estimated
-            # cycles); unrolled, the same updates keep 1.5 GB more of
-            # temporaries alive than the loop does
-            def rows_in_place(pool, rows):
-                rows = rows.astype(pool.dtype)  # (L, Hkv, K, 1, D)
+        chunk = rows_k.shape[2]
+        touch = (chunk + page - 2) // page + 1
+        slab = (L, 1, page, Hkv, D)
 
-                def one_slot(s, pool):
-                    row = jax.lax.dynamic_slice_in_dim(rows, s, 1, axis=2)
-                    return jax.lax.dynamic_update_slice(
-                        pool, row, (0, 0, pg[s, 0], off[s, 0], 0))
+        def one_slot(s, pools):
+            first, at = lengths[s] // page, lengths[s] % page
+            col = jnp.arange(touch * page) - at  # window row -> column
+            mine = (col >= 0) & (col < n_valid[s])
+            logical = jnp.minimum(first + jnp.arange(touch), max_pages - 1)
+            pages = jnp.where(mine.reshape(touch, page).any(axis=1),
+                              table[s, logical], 0)
 
-                return jax.lax.fori_loop(0, slots, one_slot, pool)
+            def into(pool, rows):
+                old = jnp.concatenate(
+                    [jax.lax.dynamic_slice(pool, (0, pages[i], 0, 0, 0),
+                                           slab) for i in range(touch)],
+                    axis=2)
+                row = jax.lax.dynamic_slice(
+                    rows, (0, s, 0, 0, 0), (L, 1, chunk, Hkv, D))
+                new = jax.lax.dynamic_update_slice(
+                    old, row.astype(pool.dtype), (0, 0, at, 0, 0))
+                new = jnp.where(mine[None, None, :, None, None], new, old)
+                for i in range(touch):
+                    pool = jax.lax.dynamic_update_slice(
+                        pool, new[:, :, i * page:(i + 1) * page],
+                        (0, pages[i], 0, 0, 0))
+                return pool
 
-            return rows_in_place(pool_k, kn), rows_in_place(pool_v, vn)
-        return (pool_k.at[:, :, pg, off].set(kn.astype(pool_k.dtype)),
-                pool_v.at[:, :, pg, off].set(vn.astype(pool_v.dtype)))
+            return into(pools[0], rows_k), into(pools[1], rows_v)
+
+        return jax.lax.fori_loop(0, slots, one_slot, (pool_k, pool_v))
 
     @staticmethod
     def create(num_layers, batch, max_len, num_kv_heads, head_dim,
@@ -103,16 +137,6 @@ class KVCache(NamedTuple):
             k=jnp.zeros(shape, dtype),
             v=jnp.zeros(shape, dtype),
             length=jnp.zeros((batch,), jnp.int32),
-        )
-
-    def layer(self, i):
-        """(k, v) views for layer i (used as tp_attn_fwd's kv_cache)."""
-        return self.k[i], self.v[i]
-
-    def with_layer(self, i, kv) -> "KVCache":
-        k_l, v_l = kv
-        return self._replace(
-            k=self.k.at[i].set(k_l), v=self.v.at[i].set(v_l)
         )
 
     def advanced(self, n: int) -> "KVCache":

@@ -59,7 +59,7 @@ from triton_dist_tpu.models.kv_cache import KVCache
 class Cache(NamedTuple):
     """The serve step's cache pytree for this family."""
 
-    k: jax.Array  # (Lf, Hkv, P, page, D)
+    k: jax.Array  # (Lf, P, page, Hkv, D)
     v: jax.Array
     rec: jax.Array  # (Ll, slots, Hv, dk, dv) float32
     conv: jax.Array  # (Ll, slots, K - 1, channels)
@@ -170,8 +170,8 @@ def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                   table, lengths, n_valid, attn_impl: str):
     """One (slots, chunk) block through the model. Slot s holds
     `lengths[s]` cached positions and `n_valid[s]` real columns.
-    Returns (logits (K, C, V) float32, (k, v) of the attention blocks
-    in the dense view with the chunk's rows written, rec, conv,
+    Returns (logits (K, C, V) float32, (k, v): the chunk's new rows of
+    the attention blocks (Lf, K, C, Hkv, D), rec, conv,
     {counter: () int32})."""
     period = cfg.full_attention_interval
     slots, chunk = tokens.shape
@@ -182,7 +182,7 @@ def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
     kv_len = lengths + chunk
     valid = (jnp.arange(chunk)[None, :] < n_valid[:, None]).reshape(-1)
     fresh = lengths == 0
-    view = KVCache.dense_view(cache.k, cache.v, table, lengths)
+    pages = KVCache(cache.k, cache.v, lengths, table)
 
     def normed(x, gain):
         return rms_norm(x, gain, eps, zero_centred=True)
@@ -200,7 +200,7 @@ def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
         return x + y.reshape(x.shape), here, absent
 
     def one_period(x, xs):
-        i, blk, lin, att, rec, conv, k_l, v_l = xs
+        i, blk, lin, att, rec, conv = xs
         here = absent = jnp.int32(0)
         recs, convs = [], []
         for j in range(period - 1):
@@ -214,11 +214,11 @@ def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
             here, absent = here + h_j, absent + a_j
         j = period - 1
         hid = normed(x, blk["input_ln"][j])
-        y, kv = gated_attn_fwd(
+        y, rows = gated_attn_fwd(
             hid, GatedAttnParams(*(att[n] for n in _ATTN)), a, cos, sin,
-            positions, (k_l, v_l), kv_len, attn_impl, eps)
+            positions, pages.layer_view(i), kv_len, attn_impl, eps)
         x, h_j, a_j = moe(x + y, blk, j, i * period + j)
-        return x, (jnp.stack(recs), jnp.stack(convs), kv[0], kv[1],
+        return x, (jnp.stack(recs), jnp.stack(convs), rows[0], rows[1],
                    here + h_j, absent + a_j)
 
     per = period - 1
@@ -227,16 +227,15 @@ def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
           _by_period(params, _BLOCK, period), _by_period(params, _GDN, per),
           {n: params[n] for n in _ATTN},
           cache.rec.reshape((-1, per) + cache.rec.shape[1:]),
-          cache.conv.reshape((-1, per) + cache.conv.shape[1:]),
-          view.k, view.v)
-    x, (rec, conv, k_new, v_new, here, absent) = jax.lax.scan(
+          cache.conv.reshape((-1, per) + cache.conv.shape[1:]))
+    x, (rec, conv, k_rows, v_rows, here, absent) = jax.lax.scan(
         one_period, x, xs)
     x = normed(x, params["final_ln"])
     logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)
     stats = {"moe_pairs_here": jnp.sum(here),
              "moe_pairs_absent": jnp.sum(absent)}
-    return (logits, (k_new, v_new), rec.reshape(cache.rec.shape),
+    return (logits, (k_rows, v_rows), rec.reshape(cache.rec.shape),
             conv.reshape(cache.conv.shape), stats)
 
 

@@ -2,15 +2,25 @@
 
 The pool generalizes `mega.qwen3.PagedMegaKVCache` from a per-model
 snapshot into a SERVING resource (ref: mega_triton_kernel/models/
-paged_kv_cache.py): k/v are shared page pools in the megakernel pool
-layout (L, Hkv, P, page, D) — so a pool slice exports straight into the
-megakernel's paged decode path (`as_mega_cache`) — and the page table
-maps SLOTS (bounded concurrency lanes of the fixed-geometry serve step)
-onto pool pages. Where the megakernel cache bump-allocates and never
-frees, the pool runs a full allocator lifecycle: allocate-on-admit,
+paged_kv_cache.py): k/v are shared page pools and the page table maps
+SLOTS (bounded concurrency lanes of the fixed-geometry serve step) onto
+pool pages. Where the megakernel cache bump-allocates and never frees,
+the pool runs a full allocator lifecycle: allocate-on-admit,
 grow-per-chunk, free-on-finish, and eviction (reclaim a victim's pages
 so a higher-priority request can run; the victim requeues and
 re-prefills bit-identically — engine.make_serve_step).
+
+Layout: TOKEN-MAJOR pages, k/v (L, P, page, Hkv, D). A page is `page`
+whole token rows of the step's dense (L, B, T, Hkv, D) view, so the
+step's round trip moves each byte once: the read is `pool[:, table]`
+with no transposition (KVCache.dense_view), and the write puts a
+step's rows back as page slabs in place (KVCache.scatter_step). The kv
+head axis (3) is the tensor-parallel one. This module and
+models/kv_cache.py are the only places that know the order; the two
+boundaries that speak another convert there: the migration image
+(`export_pages` / `install`, whose wire order (L, Hkv, n_pages, page,
+D) is xslice/migrate.py's) and the megakernel bridge (`as_mega_cache`,
+the megakernel's own (L, Hkv, P, page, D)).
 
 Page 0 is RESERVED (the null page): unallocated table entries point at
 it, and the serve step routes padding-column KV writes to it, so a
@@ -101,10 +111,10 @@ class KVPool:
         n = int(engine.mesh.shape[engine.axis])
         hkv = cfg.num_kv_heads // n * n
         dt = jnp.dtype(cfg.dtype)
-        shape = (cfg.num_kv_layers, hkv, 1 + self.capacity, page,
+        shape = (cfg.num_kv_layers, 1 + self.capacity, page, hkv,
                  cfg.head_dim)
         sharding = NamedSharding(engine.mesh,
-                                 P(None, engine.axis, None, None, None))
+                                 P(None, None, None, engine.axis, None))
         # zeros created IN the sharding: never whole on one device
         self.k = jnp.zeros(shape, dt, device=sharding)
         self.v = jnp.zeros(shape, dt, device=sharding)
@@ -343,8 +353,8 @@ class KVPool:
         if not self._free:
             raise PoolExhausted("no free page for the COW copy")
         (new,) = self._alloc(1)
-        self.k = self.k.at[:, :, new].set(self.k[:, :, old])
-        self.v = self.v.at[:, :, new].set(self.v[:, :, old])
+        self.k = self.k.at[:, new].set(self.k[:, old])
+        self.v = self.v.at[:, new].set(self.v[:, old])
         ps[page_idx] = new
         self.table[slot, page_idx] = new
         self._refs[old] -= 1
@@ -390,25 +400,26 @@ class KVPool:
     # -- export ---------------------------------------------------------
 
     def export_pages(self, slot: int, n_tokens: Optional[int] = None):
-        """Snapshot `slot`'s live KV pages as host numpy
-        (L, Hkv, n_pages, page, D) — the migration image source
-        (xslice/migrate.py). `n_tokens` trims to the pages covering the
-        first n_tokens positions (default: all of the slot's pages).
-        Pure gather; bitwise."""
+        """Snapshot `slot`'s live KV pages as host numpy in the
+        migration image's order (L, Hkv, n_pages, page, D) — a wire
+        format (xslice/migrate.py), so the pool's own order is turned
+        into it here, on the host. `n_tokens` trims to the pages
+        covering the first n_tokens positions (default: all of the
+        slot's pages). Pure gather; bitwise."""
         self._pages_only("KVPool.export_pages (xslice migration)")
         ps = self._pages[slot]
         assert ps is not None, f"slot {slot} is not admitted"
         if n_tokens is not None:
             ps = ps[:max(pages_for(n_tokens, self.page), 1)]
         idx = jnp.asarray(ps, jnp.int32)
-        k = np.asarray(jnp.take(self.k, idx, axis=2))
-        v = np.asarray(jnp.take(self.v, idx, axis=2))
-        return k, v
+        return tuple(
+            np.ascontiguousarray(np.asarray(pool[:, idx]).transpose(
+                0, 3, 1, 2, 4)) for pool in (self.k, self.v))
 
     def install(self, slot: int, k_pages, v_pages,
                 n_tokens: int) -> None:
         """Admit `slot` and install migrated KV pages
-        ((L, Hkv, n_pages, page, D), the export_pages layout) covering
+        ((L, Hkv, n_pages, page, D), the image's order) covering
         an n_tokens prefix — the destination half of the KV migration
         handoff. Page COUNT must match the admit demand; lengths starts
         at n_tokens (the migrated history is live). All-or-nothing:
@@ -420,11 +431,13 @@ class KVPool:
             f"{k_pages.shape[2]}/{v_pages.shape[2]}"
         )
         self.admit(slot, n_tokens)
-        kp = jnp.asarray(k_pages, self.k.dtype)
-        vp = jnp.asarray(v_pages, self.v.dtype)
-        for i, pg in enumerate(self._pages[slot]):
-            self.k = self.k.at[:, :, pg].set(kp[:, :, i])
-            self.v = self.v.at[:, :, pg].set(vp[:, :, i])
+        idx = jnp.asarray(self._pages[slot], jnp.int32)
+
+        def put(pool, image):  # the image's order into the pool's
+            return pool.at[:, idx].set(jnp.asarray(
+                np.asarray(image).transpose(0, 2, 3, 1, 4), pool.dtype))
+
+        self.k, self.v = put(self.k, k_pages), put(self.v, v_pages)
         self.lengths[slot] = n_tokens
 
     def to_dense(self):
@@ -435,19 +448,19 @@ class KVPool:
                                   jnp.asarray(self.lengths))
 
     def as_mega_cache(self):
-        """Snapshot the pool as a mega.qwen3.PagedMegaKVCache — the
-        layouts are IDENTICAL (that was the point of adopting the
-        megakernel pool layout), so the megakernel's paged decode path
-        runs directly over serve-plane state. The megakernel's bump
-        allocator resumes at the pool high-water mark; note it will NOT
-        see pages freed back to this pool's free list (export is a
-        decode handoff, not shared ownership)."""
+        """Snapshot the pool as a mega.qwen3.PagedMegaKVCache, page ids
+        and table as they are, so the megakernel's paged decode path
+        runs over serve-plane state. The megakernel keeps its own page
+        order (L, Hkv, P, page, D): the bridge hands it a transposed
+        COPY of the pool (a decode handoff, not shared ownership). Its
+        bump allocator resumes at the pool high-water mark; note it
+        will NOT see pages freed back to this pool's free list."""
         self._pages_only("KVPool.as_mega_cache (the megakernel bridge)")
         from triton_dist_tpu.mega.qwen3 import PagedMegaKVCache
 
         high = max((max(ps) for ps in self._pages if ps), default=0)
         return PagedMegaKVCache(
-            k=self.k, v=self.v,
+            k=jnp.moveaxis(self.k, 3, 1), v=jnp.moveaxis(self.v, 3, 1),
             table=jnp.asarray(self.table),
             length=jnp.asarray(self.lengths),
             next_free=jnp.asarray(high + 1, jnp.int32),
