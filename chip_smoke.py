@@ -9,7 +9,11 @@ head; weights random from --seed):
   one chip (default)  `models.Engine` + `serve.Scheduler` answer four
       requests (prompts of 128/256/512/512 tokens, 32 new tokens each,
       greedy, max_len 2048), then one `mega.MegaQwen3` decode step.
-      The only cut is DEPTH, to what one 16 GB chip holds.
+      The only cut is DEPTH, to what one 16 GB chip holds. Before
+      them, the hybrid family's latent attention alone at
+      Kimi-Linear's widths: 8 slots x 128 columns over 8,192 cached
+      rows of 640 on the planner's route, against the expanded form
+      in float32, with cached rows broken on purpose (`latent_phase`).
   --chips 4           the cross-chip path and nothing else: full depth
       (36 layers), tp=4 over the four chips, the same four requests.
 
@@ -389,6 +393,137 @@ def mega_phase(eng, first_decode, tol: float) -> None:
                            f"by {diff:.4f} >= {tol:.4f}")
 
 
+# Latent attention on the chip: 8 slots x 128 columns over views of
+# 8,192 rows of 640, the benchmark's geometry for the hybrid family's
+# latent member. Lengths and valid columns a slot: a full view, odd
+# lengths that end inside a page of the kernel, decode rows of one
+# valid column, a fresh slot.
+LATENT_LENS = (8192, 6001, 4097, 2049, 1025, 513, 200, 128)
+LATENT_VALID = (128, 128, 1, 128, 1, 128, 72, 128)
+# Largest difference from the expanded float32 form, as a share of its
+# largest output: what bf16 leaves (the absorbed query, the folded
+# output and the two projections round once each) against what a
+# cached row broken on purpose gives (latent_phase prints both).
+LATENT_TOL = 0.04
+
+
+def latent_phase(seed: int, max_len: int = 8192) -> None:
+    """`layers.latent_attn.latent_attn_fwd` on the route the planner
+    names for the chip, at Kimi-Linear's widths, against the EXPANDED
+    form in float32 at `highest` written out here (every head's keys
+    and values out of the cached rows, one slot at a time): what the
+    benchmark's `correct` cannot see under random weights, where a
+    near-uniform softmax over thousands of rows averages the values
+    away. The hidden states are wide enough that a row attends few
+    keys, so one page of cached rows with its value columns zeroed, and
+    every cached row so, both have to read over the tolerance."""
+    import jax
+    import jax.numpy as jnp
+
+    from triton_dist_tpu.kernels import flash_prefill
+    from triton_dist_tpu.layers.latent_attn import (
+        LatentAttnParams,
+        LatentAttnSpec,
+        latent_attn_fwd,
+    )
+    from triton_dist_tpu.models import ModelConfig
+    from triton_dist_tpu.plan.planner import route_hybrid_attention
+
+    cfg = ModelConfig.kimi_linear_48b(max_positions=max_len)
+    (_, width), = cfg.page_arrays
+    spec = LatentAttnSpec(cfg.num_q_heads, cfg.kv_lora_rank,
+                          cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+    hq, r, dn, dr, dv = spec
+    hidden, slots, cols = cfg.hidden_size, len(LATENT_LENS), 128
+    lens = np.minimum(LATENT_LENS, max_len)
+    valid = np.minimum(LATENT_VALID, lens)
+    impl = route_hybrid_attention(cfg, slots, cols, max_len)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    shapes = ((hidden, hq * (dn + dr)), (hidden, spec.row),
+              (r, hq * (dn + dv)), (hq * dv, hidden))
+    w_q, w_a, w_b, w_o = (
+        (0.02 * jax.random.normal(k, s, f32)).astype(bf16)
+        for k, s in zip(keys, shapes))
+    p = LatentAttnParams(w_q, w_a, jnp.ones((r,), bf16), w_b, w_o)
+    # a normed hidden state three times over: scores a few units wide
+    x = (3.0 * jax.random.normal(keys[4], (slots, cols, hidden),
+                                 f32)).astype(bf16)
+    cached = jax.random.normal(keys[5], (slots, max_len, 1, spec.row), f32)
+    cached = cached.at[..., r:].multiply(3.0)  # k_r is not normed
+    start = jnp.asarray(lens - valid, jnp.int32)
+    here = jnp.arange(max_len)[None, :] < start[:, None]
+    # past a slot's cached rows lies what another request left there
+    view = jnp.pad(jnp.where(here[..., None, None], cached, 50.0),
+                   ((0, 0),) * 3 + ((0, width - spec.row),)).astype(bf16)
+    pos = start[:, None] + jnp.arange(cols)[None, :]
+    kv_len, n_valid = (jnp.asarray(a, jnp.int32) for a in (lens, valid))
+
+    # every array an argument: a closed-over one is folded at compile
+    fwd = jax.jit(lambda x, p, view: latent_attn_fwd(
+        x, p, spec, pos, view, kv_len, n_valid, impl, cfg.rms_eps)[0])
+    kernels, secs = compile_and_name(fwd, x, p, view)
+    launch = flash_prefill.last_launch()
+    say(f"latent attention: route {impl!r}, kernels {kernels or 'none'}, "
+        f"launch {launch}, compiled in {secs:.1f}s")
+    if impl == "pallas":
+        require(kernels, ["_fp_local_kernel"], "latent attention")
+
+    hi = jax.lax.Precision.HIGHEST
+
+    @jax.jit
+    def expanded(x_i, rows_i, start_i, w):
+        """One slot: x_i (cols, H), rows_i (max_len, row) as cached."""
+        w_q, w_a, w_b, w_o = w
+        xs = x_i.astype(f32)
+        q = jnp.dot(xs, w_q.astype(f32), precision=hi).reshape(
+            cols, hq, dn + dr)
+        a = jnp.dot(xs, w_a.astype(f32), precision=hi)
+        c = a[:, :r] * jax.lax.rsqrt(
+            jnp.mean(a[:, :r] ** 2, -1, keepdims=True) + cfg.rms_eps)
+        new = jnp.concatenate([c, a[:, r:]], axis=-1)
+        rows = jax.lax.dynamic_update_slice(
+            rows_i.astype(f32), new, (start_i, 0))
+        kv = jnp.dot(rows[:, :r], w_b.astype(f32), precision=hi).reshape(
+            max_len, hq, dn + dv)
+        att = (jnp.einsum("shd,thd->hst", q[..., :dn], kv[..., :dn],
+                          precision=hi)
+               + jnp.einsum("shd,td->hst", q[..., dn:], rows[:, r:],
+                            precision=hi)) * (dn + dr) ** -0.5
+        seen = (jnp.arange(max_len)[None, :]
+                <= (start_i + jnp.arange(cols))[:, None])
+        prob = jax.nn.softmax(jnp.where(seen[None], att, -jnp.inf), -1)
+        o = jnp.einsum("hst,thd->shd", prob, kv[..., dn:], precision=hi)
+        return jnp.dot(o.reshape(cols, hq * dv), w_o.astype(f32),
+                       precision=hi)
+
+    weights = (w_q, w_a, w_b, w_o)
+    wants = [np.asarray(expanded(x[i], view[i, :, 0, :spec.row], start[i],
+                                 weights))[:n]
+             for i, n in enumerate(valid)]
+
+    def worst(view) -> float:
+        """Over the slots' valid columns (the others are discarded)."""
+        got = np.asarray(fwd(x, p, view).astype(f32))
+        return max(float(np.abs(got[i, :len(want)] - want).max()
+                         / np.abs(want).max())
+                   for i, want in enumerate(wants))
+
+    own = worst(view)
+    one_page = worst(view.at[:, 64:128, :, :r].set(0))
+    every = worst(jnp.where(here[..., None, None], view.at[..., :r].set(0),
+                            view))
+    say(f"latent attention against the expanded form, largest "
+        f"difference over largest output: {own:.4f} (tolerance "
+        f"{LATENT_TOL}); with the value columns of cached rows 64-127 "
+        f"zeroed {one_page:.4f}, of every cached row {every:.4f}")
+    if not own < LATENT_TOL < min(one_page, every):
+        raise RuntimeError(
+            f"latent attention: {own:.4f} has to lie under {LATENT_TOL} "
+            f"and the broken caches' {one_page:.4f}, {every:.4f} over it")
+
+
 def run(cfg, mesh, seed: int, prompts, cross_chip: bool) -> None:
     """Every phase on `mesh`. cross_chip: the tp>1 contract (kernels of
     the overlapped collectives by name, no megakernel phase)."""
@@ -474,6 +609,8 @@ def main() -> int:
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
                for n in PROMPT_LENS]
 
+    if args.chips == 1:
+        latent_phase(args.seed)
     run(cfg, make_mesh((args.chips,), ("tp",)), args.seed, prompts,
         cross_chip=args.chips > 1)
 

@@ -243,6 +243,74 @@ def test_serve_step_moves_the_pool_s_bytes_once(chip, tp, width):
                 < one_layer_s_view)
 
 
+def _hybrid_step_compiled(chip, cfg, slots, page, max_len):
+    """A hybrid configuration's serve step at the chooser's chunk,
+    compiled for the described chip from shapes alone."""
+    from triton_dist_tpu.models import hybrid
+    from triton_dist_tpu.perf_model import choose_chunk_for
+
+    mesh = _mesh(chip, 1)
+    rep = NamedSharding(mesh, P())
+    params = {name: SDS(shape, BF16, sharding=rep)
+              for name, shape, _ in hybrid.leaves(cfg)}
+    eng = Engine(cfg, mesh, params=params, max_len=max_len)
+    chunk = choose_chunk_for(cfg, 1, slots, max_len, "flash")
+    assert chunk == 128
+    max_pages = max_len // page
+    pools = tuple(SDS((cfg.num_kv_layers, 1 + slots * max_pages, page,
+                       heads, width), BF16, sharding=rep)
+                  for heads, width in cfg.page_arrays)
+    rec, conv = hybrid.state_shapes(cfg, slots)
+    cache = pools + (SDS(rec, jnp.float32, sharding=rep),
+                     SDS(conv, BF16, sharding=rep))
+    return eng.make_serve_step(slots, chunk, page, max_pages).lower(
+        params, SDS((slots, chunk), jnp.int32, sharding=rep), cache,
+        SDS((slots, max_pages), jnp.int32, sharding=rep),
+        SDS((slots,), jnp.int32, sharding=rep),
+        SDS((slots,), jnp.int32, sharding=rep),
+        SDS((slots,), jnp.float32, sharding=rep),
+        SDS((slots, 2), jnp.uint32, sharding=rep)).compile()
+
+
+def test_latent_serve_step_one_chip(chip):
+    """The Kimi-Linear pattern at the benchmark's widths and geometry,
+    cut to its first two periods and the short last one (11 blocks:
+    the leading dense one, three runs of periods): the latent blocks
+    run `_fp_local_kernel` with the value a column prefix of the key
+    page, once a run, over ONE pool of 640-wide rows (Mosaic refuses a
+    576-wide slice of a page: the row is padded to whole lanes), and
+    the route says so by name."""
+    from triton_dist_tpu.kernels import flash_prefill
+    from triton_dist_tpu.plan.planner import (
+        route_gated_attention,
+        route_hybrid_attention,
+    )
+
+    max_len, slots, page = 8192, 8, 64
+    full = (4, 8, 11)
+    cfg = ModelConfig.kimi_linear_48b(
+        num_layers=11, full_attn_layers=full,
+        kda_layers=tuple(i for i in range(1, 12) if i not in full),
+        experts_held=16, max_positions=max_len)
+    assert cfg.page_arrays == ((1, 640),)
+    assert route_hybrid_attention(cfg, slots, 128, max_len) == "pallas"
+    with pytest.raises(NotImplementedError, match="no other route"):
+        route_gated_attention(slots, 128, max_len, 32, 1, 576, "bfloat16",
+                              v_prefix=512)
+    with pytest.raises(NotImplementedError, match="one query row"):
+        route_hybrid_attention(cfg, slots, 1, max_len)
+    compiled = _hybrid_step_compiled(chip, cfg, slots, page, max_len)
+    assert _kernels(compiled) == {"_fp_local_kernel": 3}
+    launch = flash_prefill.last_launch()
+    # 32 heads stacked into the rows: 4,096 rows in tiles of 512, one
+    # stream of pages, keys 640 wide and values their first 512
+    assert launch["grid"] == (slots, 8) and launch["q_rows"] == 512
+    assert launch["widths"] == (640, 512) and launch["streams"] == 1
+    text = compiled.as_text()
+    assert "bf16[3,1025,64,1,640]" in text  # the one latent pool
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
 def test_hybrid_serve_step_one_chip(chip):
     """The hybrid family's step at the benchmark's geometry (8 slots x
     the chooser's 128-token chunk, 64-token pages, 8,192 positions),
@@ -251,33 +319,11 @@ def test_hybrid_serve_step_one_chip(chip):
     are sliced out of the stack (a slice that feeds the grouped matmul
     is a copy of every expert's weights every step: 3.2 GB of
     temporaries a period where the whole step needs under 2.5)."""
-    from triton_dist_tpu.models import qwen3_next
-    from triton_dist_tpu.perf_model import choose_chunk_for
-
     max_len, slots, page = 8192, 8, 64
     cfg = ModelConfig.qwen3_next_80b(
         num_layers=8, experts_held=128, vocab_size=37_984,
         max_positions=max_len)
-    mesh = _mesh(chip, 1)
-    rep = NamedSharding(mesh, P())
-    params = {name: SDS(shape, BF16, sharding=rep)
-              for name, shape, _ in qwen3_next.leaves(cfg)}
-    eng = Engine(cfg, mesh, params=params, max_len=max_len)
-    chunk = choose_chunk_for(cfg, 1, slots, max_len, "flash")
-    assert chunk == 128
-    max_pages = max_len // page
-    pool = SDS((cfg.num_kv_layers, 1 + slots * max_pages, page,
-                cfg.num_kv_heads, cfg.head_dim), BF16, sharding=rep)
-    rec, conv = qwen3_next.state_shapes(cfg, slots)
-    cache = (pool, pool, SDS(rec, jnp.float32, sharding=rep),
-             SDS(conv, BF16, sharding=rep))
-    compiled = eng.make_serve_step(slots, chunk, page, max_pages).lower(
-        params, SDS((slots, chunk), jnp.int32, sharding=rep), cache,
-        SDS((slots, max_pages), jnp.int32, sharding=rep),
-        SDS((slots,), jnp.int32, sharding=rep),
-        SDS((slots,), jnp.int32, sharding=rep),
-        SDS((slots,), jnp.float32, sharding=rep),
-        SDS((slots, 2), jnp.uint32, sharding=rep)).compile()
+    compiled = _hybrid_step_compiled(chip, cfg, slots, page, max_len)
     assert _kernels(compiled) == {"_fp_local_kernel": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
     _pool_moves_once(compiled.as_text(), cfg.num_kv_layers, slots, max_len,

@@ -80,7 +80,7 @@ def _step(mesh, pool, rows_k, rows_v, n_valid):
         k_view = jnp.stack([kv[0] for kv in views])
         v_view = jnp.stack([kv[1] for kv in views])
         new_k, new_v = KVCache.scatter_step(
-            pool_k, pool_v, rows_k, rows_v, table, lengths, n_valid)
+            (pool_k, pool_v), (rows_k, rows_v), table, lengths, n_valid)
         return k_view, v_view, new_k, new_v
 
     heads = P(None, None, None, "tp")  # rows and views: (L, B, *, Hkv, D)

@@ -1,4 +1,4 @@
-"""The hybrid family (models/qwen3_next.py) at a small size: one
+"""The hybrid family (models/hybrid.py) at a small size: one
 period of three gated-delta-net blocks and one gated-attention block,
 8 experts of which this chip holds some, float32, on the CPU.
 
@@ -27,7 +27,6 @@ from triton_dist_tpu.layers.held_moe import (
     held_moe_fwd,
 )
 from triton_dist_tpu.models import Engine, ModelConfig
-from triton_dist_tpu.models import qwen3_next
 from triton_dist_tpu.runtime import make_mesh
 from triton_dist_tpu.serve import RequestState, Scheduler
 

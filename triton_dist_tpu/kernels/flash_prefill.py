@@ -92,11 +92,19 @@ class FlashPrefillConfig:
     block: int = 512  # KV page rows streamed per fold step
 
 
-def supports_flash_prefill(hq: int, hkv: int, d: int) -> bool:
+def supports_flash_prefill(hq: int, hkv: int, d: int,
+                           v_prefix: Optional[int] = None) -> bool:
     """Shapes the native kernel accepts: lane-aligned head_dim (the
     per-head column slices of the (rows, Hkv*D) pages must be
-    lane-aligned for Mosaic) and an integral GQA group. Interpret mode
+    lane-aligned for Mosaic) and an integral GQA group. With
+    `v_prefix` (the values are the first `v_prefix` columns of the key
+    page: latent attention) there is ONE kv head and the prefix is
+    lane-aligned too (Mosaic refuses a page row of 576: "Slice shape
+    along dimension 2 must be aligned to tiling (128)"). Interpret mode
     accepts anything; callers' auto paths gate on this for native."""
+    if v_prefix is not None and not (
+            hkv == 1 and v_prefix % 128 == 0 and v_prefix <= d):
+        return False
     return d % 128 == 0 and hq % hkv == 0
 
 
@@ -144,10 +152,21 @@ def fit_q_rows(s: int, hq: int, d: int) -> int:
     return cands[-1] if cands else s
 
 
+def shared_head_rows(s: int, hq: int, v_prefix: Optional[int]):
+    """(query rows, query heads) as the LOCAL kernel sees them. Under
+    `v_prefix` every query head attends the same one kv head, so the
+    heads are stacked into the rows (row s * Hq + h) and each page
+    meets ONE (rows, d) slab in one matmul, not Hq slabs of a few rows
+    each. The one place that knows: `flash_prefill_local` launches on
+    it and the VMEM gate sizes by it."""
+    return (s * hq, 1) if v_prefix is not None else (s, hq)
+
+
 def flash_prefill_vmem_bytes(s_q: int, hq: int, hkv: int, d: int,
                              block: int, dtype=jnp.bfloat16,
                              batch: int = 1,
-                             q_rows: Optional[int] = None) -> int:
+                             q_rows: Optional[int] = None,
+                             v_prefix: Optional[int] = None) -> int:
     """Per-grid-step resident VMEM of the flash-prefill kernels — THE
     accounting behind the launch's vmem_limit_bytes, the routing gate
     (flash_prefill_fits) and the autotuner's pruner.
@@ -163,17 +182,24 @@ def flash_prefill_vmem_bytes(s_q: int, hq: int, hkv: int, d: int,
 
     q_rows: rows resident at once — default the local kernel's tile
     (fit_q_rows); the SP kernel keeps all s_q rows of all `batch` rows
-    live across its segment sweep and passes q_rows=s_q, batch=B."""
+    live across its segment sweep and passes q_rows=s_q, batch=B.
+    v_prefix: one stream of pages, not two, and the heads stacked into
+    the rows (`shared_head_rows`); a (rows, block) float32 logits and
+    probabilities pair is then as large as a slab and is counted."""
     isz = jnp.dtype(dtype).itemsize
+    s_q, hq = shared_head_rows(s_q, hq, v_prefix)
     rows = fit_q_rows(s_q, hq, d) if q_rows is None else q_rows
-    kv = 4 * block * hkv * d * isz + 2 * block * hkv * d * 4
-    return kv + batch * 10 * rows * hq * max(d, 128) * 4
+    streams = 1 if v_prefix is not None else 2
+    kv = streams * (2 * block * hkv * d * isz + block * hkv * d * 4)
+    logits = 2 * rows * block * 4 if v_prefix is not None else 0
+    return kv + logits + batch * 10 * rows * hq * max(d, 128) * 4
 
 
 def flash_prefill_fits(s_q: int, t: int, hq: int, hkv: int, d: int,
                        block: Optional[int] = None,
                        dtype=jnp.bfloat16, batch: int = 1,
-                       q_rows: Optional[int] = None) -> bool:
+                       q_rows: Optional[int] = None,
+                       v_prefix: Optional[int] = None) -> bool:
     """Memory-feasibility gate for auto routing: the per-grid-step
     residents plus VMEM_MARGIN must fit the forced-kernel VMEM ceiling
     — exactly what the launch will ask Mosaic for. What does not fit
@@ -184,7 +210,8 @@ def flash_prefill_fits(s_q: int, t: int, hq: int, hkv: int, d: int,
     from triton_dist_tpu.perf_model import kernel_vmem_ceiling
 
     need = flash_prefill_vmem_bytes(s_q, hq, hkv, d, fit_block(t, block),
-                                    dtype, batch=batch, q_rows=q_rows)
+                                    dtype, batch=batch, q_rows=q_rows,
+                                    v_prefix=v_prefix)
     return need + VMEM_MARGIN <= kernel_vmem_ceiling()
 
 
@@ -225,13 +252,16 @@ def _head_update(q_hg, k_blk, v_blk, live, state):
     return (m_new, l_new, acc * alpha + pv)
 
 
-def _fold_block_heads(q_slabs, kpage, vpage, live, states, hkv, g, d):
+def _fold_block_heads(q_slabs, kpage, vpage, live, states, hkv, g, d,
+                      dv=None):
     """One KV page folded into every (h, g) head state. kpage/vpage:
-    (blk, Hkv*D) f32; q_slabs[hg]: (S, D) f32 pre-scaled."""
+    (blk, Hkv*D) f32; q_slabs[hg]: (S, D) f32 pre-scaled. With `dv`
+    the values are the first dv columns of the ONE kv head's key page
+    (vpage is then the key page itself)."""
     out = []
     for h in range(hkv):
         k_h = kpage[:, h * d:(h + 1) * d]
-        v_h = vpage[:, h * d:(h + 1) * d]
+        v_h = vpage[:, h * d:(h + 1) * d] if dv is None else vpage[:, :dv]
         for gg in range(g):
             hg = h * g + gg
             out.append(_head_update(q_slabs[hg], k_h, v_h, live,
@@ -265,9 +295,8 @@ def _q_slabs(qf, hq: int, d: int, scale: float):
 # -- local kernel (n = 1 core; serves blockwise prefill + serve chunks) ------
 
 
-def _fp_local_kernel(hq, hkv, d, s, t, blk, causal, scale,
-                     len_ref, q_ref, qpos_ref, k_ref, v_ref, o_ref,
-                     vkv, sems):
+def _fp_local_kernel(hq, hkv, d, s, t, blk, causal, scale, dv,
+                     len_ref, q_ref, qpos_ref, *refs):
     """One grid step = `s` query rows (one fit_q_rows tile) of one batch
     row: stream (blk, Hkv*D) KV pages double-buffered from HBM and fold
     each into the per-head online-softmax states (the prefill
@@ -276,21 +305,24 @@ def _fp_local_kernel(hq, hkv, d, s, t, blk, causal, scale,
     operand — prefill is MXU-bound, so the decode kernel's Hkv-times
     FLOP inflation is not free here). Rows are independent, so tiling
     them changes no bit of any row's fold; a causal tile also stops at
-    ITS last row's page, not the chunk's."""
+    ITS last row's page, not the chunk's. With `dv` (the values a
+    column prefix of the key page, `flash_prefill_local`'s `v_prefix`)
+    there is no v operand: one stream of pages, each read once."""
+    *pages, o_ref, vkv, sems = refs  # (k_ref, v_ref), or (k_ref,)
     b = pl.program_id(0)
     g = hq // hkv
     nblk = t // blk
     valid = len_ref[b]
 
     def kv_start(ci, slot):
-        for which, ref in ((0, k_ref), (1, v_ref)):
+        for which, ref in enumerate(pages):
             pltpu.make_async_copy(
                 ref.at[b, pl.ds(ci * blk, blk)], vkv.at[slot, which],
                 sems.at[slot],
             ).start()
 
     def kv_wait(slot):
-        for which, ref in ((0, k_ref), (1, v_ref)):
+        for which, ref in enumerate(pages):
             pltpu.make_async_copy(
                 ref.at[0, pl.ds(0, blk)], vkv.at[slot, which],
                 sems.at[slot],
@@ -312,17 +344,17 @@ def _fp_local_kernel(hq, hkv, d, s, t, blk, causal, scale,
             kv_start(ci + 1, (ci + 1) % 2)
 
         kv_wait(ci % 2)
-        kv = vkv[ci % 2].astype(jnp.float32)  # (2, blk, W)
+        kv = vkv[ci % 2].astype(jnp.float32)  # (streams, blk, W)
         live = _block_live(s, blk, ci * blk, qp_col, valid, causal)
-        return tuple(_fold_block_heads(slabs, kv[0], kv[1], live,
-                                       list(states), hkv, g, d))
+        return tuple(_fold_block_heads(slabs, kv[0], kv[len(pages) - 1], live,
+                                       list(states), hkv, g, d, dv))
 
     @pl.when(n_act > 0)
     def _first():
         kv_start(0, 0)
 
     states = jax.lax.fori_loop(0, n_act, loop_body,
-                               tuple(_init_states(hq, s, d)))
+                               tuple(_init_states(hq, s, dv or d)))
     o_ref[0] = _finalize(list(states)).astype(o_ref.dtype)
 
 
@@ -340,20 +372,38 @@ def last_launch():
 def flash_prefill_local(
     q: jax.Array,  # (B, S, Hq, D)
     k: jax.Array,  # (B, T, Hkv, D)
-    v: jax.Array,
+    v: Optional[jax.Array],
     q_positions: Optional[jax.Array] = None,  # (B, S) absolute positions
     q_offset=0,
     kv_len: Optional[jax.Array] = None,  # (B,) valid KV prefix
     causal: bool = True,
     scale: Optional[float] = None,
     block: Optional[int] = None,
+    v_prefix: Optional[int] = None,
 ) -> jax.Array:
     """Pallas blockwise (flash) GQA prefill over local KV: same contract
     as layers.attention.gqa_attention_blockwise, but KV streams through
     double-buffered (block, Hkv*D) pages so the (S, T) logits tensor
     never exists — peak memory O(S*block). Returns (B, S, Hq, D) in
-    q.dtype."""
+    q.dtype.
+
+    `v_prefix` (with v None): the values are the first `v_prefix`
+    columns of the ONE kv head's keys (latent attention in its
+    absorbed form). The pages are then read once, not once as keys and
+    once as values; the query heads, which all meet the same page, are
+    stacked into the rows (`shared_head_rows`); the result is
+    (B, S, Hq, v_prefix)."""
     global _last_launch
+    out_heads = q.shape[2:3] + (v_prefix or q.shape[3],)
+    if v_prefix is not None:
+        assert v is None and k.shape[2] == 1, (
+            "v_prefix: one kv head whose values are a prefix of its keys")
+        b, s, hq, d = q.shape
+        if q_positions is None:
+            q_positions = jnp.broadcast_to(
+                jnp.arange(s)[None, :] + q_offset, (b, s))
+        q = q.reshape((b,) + shared_head_rows(s, hq, v_prefix) + (d,))
+        q_positions = jnp.repeat(q_positions, hq, axis=1)
     b, s, hq, d = q.shape
     _, t, hkv, _ = k.shape
     w = hkv * d
@@ -362,13 +412,16 @@ def flash_prefill_local(
     tq = fit_q_rows(s, hq, d)
     grid = (b, s // tq)
     _last_launch = {"kernel": "flash_prefill", "path": "local",
-                    "block": blk, "grid": grid,
+                    "block": blk, "grid": grid, "q_rows": tq,
+                    "widths": (d, v_prefix or d),
+                    "streams": 1 if v_prefix is not None else 2,
                     "overridden": block is not None}
+    pages = (k,) if v_prefix is not None else (k, v)
     t_valid = t
     if t % blk:
         pad = blk - t % blk
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        pages = tuple(jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for x in pages)
         t += pad
     len_arr = (jnp.full((b,), t_valid, jnp.int32) if kv_len is None
                else jnp.minimum(jnp.reshape(kv_len, (-1,)),
@@ -380,37 +433,37 @@ def flash_prefill_local(
     # (no in-kernel minor-dim reshape for Mosaic to lower)
     qpos = q_positions.astype(jnp.int32).reshape(b, s, 1)
     itemsize = jnp.dtype(k.dtype).itemsize
+    dv = v_prefix or d
     out = tpu_call(
         functools.partial(_fp_local_kernel, hq, hkv, d, tq, t, blk,
-                          causal, scale),
+                          causal, scale, v_prefix),
         grid=grid,
-        out_shape=jax.ShapeDtypeStruct((b, s, hq * d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, s, hq * dv), q.dtype),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, tq, hq * d), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, tq, 1), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, tq, hq * d), lambda i, j: (i, j, 0),
+        ] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pages),
+        out_specs=pl.BlockSpec((1, tq, hq * dv), lambda i, j: (i, j, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((2, 2, blk, w), k.dtype),
+            pltpu.VMEM((2, len(pages), blk, w), k.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         compiler_params=compiler_params(
             vmem_limit_bytes=flash_prefill_vmem_bytes(
-                s, hq, hkv, d, blk, k.dtype, q_rows=tq) + VMEM_MARGIN,
+                s, hq, hkv, d, blk, k.dtype, q_rows=tq,
+                v_prefix=v_prefix) + VMEM_MARGIN,
         ),
         cost_estimate=cost_estimate(
-            flops=4 * b * s * hq * t * d,
-            bytes_accessed=2 * b * t * w * itemsize,
+            flops=2 * b * s * hq * t * (d + dv),
+            bytes_accessed=len(pages) * b * t * w * itemsize,
         ),
     )(len_arr, q.reshape(b, s, hq * d), qpos,
-      k.reshape(b, t, w), v.reshape(b, t, w))
-    return out.reshape(b, s, hq, d)
+      *(x.reshape(b, t, w) for x in pages))
+    return out.reshape((b, -1) + out_heads)
 
 
 # -- SP kernel: per-segment-semaphore ring ingest + in-kernel consumer -------

@@ -12,7 +12,7 @@ bincount — static shapes, no atomics, fully fused by XLA.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,14 +22,29 @@ def topk_routing(
     router_logits: jax.Array,  # (M, E) f32
     k: int,
     normalize: bool = True,
+    score: str = "softmax",
+    bias: Optional[jax.Array] = None,  # (E,)
+    scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Softmax-then-topk router (Qwen3MoE's norm_topk_prob convention,
-    ref: models/qwen_moe.py:50-206). Returns (weights (M, k) f32,
-    ids (M, k) int32)."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    weights, ids = jax.lax.top_k(probs, k)
+    """Score-then-topk router. The default is softmax-then-topk
+    (Qwen3MoE's norm_topk_prob convention, ref: models/qwen_moe.py:
+    50-206); `score="sigmoid"` scores each expert on its own. A `bias`
+    is added for the CHOICE alone: the weights are the scores of the
+    chosen, divided by their sum under `normalize` and multiplied by
+    `scale`. Returns (weights (M, k) f32, ids (M, k) int32)."""
+    logits = router_logits.astype(jnp.float32)
+    assert score in ("softmax", "sigmoid"), score
+    probs = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+             else jax.nn.sigmoid(logits))
+    if bias is None:
+        weights, ids = jax.lax.top_k(probs, k)
+    else:
+        _, ids = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(probs, ids, axis=-1)
     if normalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, ids.astype(jnp.int32)
 
 

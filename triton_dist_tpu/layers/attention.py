@@ -69,7 +69,12 @@ def gqa_attention_blockwise(
     materializing the (S, T) logits (ref: the flashinfer prefill call,
     tp_attn.py:180-253; xla core shared with ring_attention's
     _block_update). impl: "xla" | "pallas" | "auto" (the module-doc
-    switch; perf_model.choose_prefill_impl)."""
+    switch; perf_model.choose_prefill_impl).
+
+    v may be narrower than k (latent attention, layers/latent_attn.py:
+    one shared head whose values are the first columns of its keys);
+    the result then has v's width. The kernel is told so and reads
+    each key page once, taking the values out of it."""
     from triton_dist_tpu.kernels.sp_attention import _block_update
 
     if impl == "auto":
@@ -83,14 +88,17 @@ def gqa_attention_blockwise(
 
         # `chunk` IS the kernel's KV page height — the tuning knob of
         # the shared contract must steer both implementations
+        prefix = v.shape[-1] if v.shape[-1] != k.shape[-1] else None
         return flash_prefill_local(
-            q, k, v, q_positions=q_positions, q_offset=q_offset,
-            kv_len=kv_len, causal=causal, scale=scale, block=chunk,
+            q, k, None if prefix else v, q_positions=q_positions,
+            q_offset=q_offset, kv_len=kv_len, causal=causal, scale=scale,
+            block=chunk, v_prefix=prefix,
         )
     assert impl == "xla", f"unknown blockwise impl {impl!r}"
 
     b, s, hq, d = q.shape
     _, t, hkv, _ = k.shape
+    dv = v.shape[-1]
     g = hq // hkv
     scale = scale if scale is not None else d ** -0.5
     if t % chunk:
@@ -113,7 +121,7 @@ def gqa_attention_blockwise(
         q_pos = q_positions
 
     kc = jnp.moveaxis(k.reshape(b, nc, chunk, hkv, d), 1, 0)
-    vc = jnp.moveaxis(v.reshape(b, nc, chunk, hkv, d), 1, 0)
+    vc = jnp.moveaxis(v.reshape(b, nc, chunk, hkv, dv), 1, 0)
 
     def body(state, xs):
         acc, m, l = state
@@ -126,14 +134,14 @@ def gqa_attention_blockwise(
         return (acc, m, l), None
 
     state0 = (
-        jnp.zeros((b, hkv, g, s, d), jnp.float32),
+        jnp.zeros((b, hkv, g, s, dv), jnp.float32),
         jnp.full((b, hkv, g, s, 1), NEG_INF, jnp.float32),
         jnp.zeros((b, hkv, g, s, 1), jnp.float32),
     )
     (acc, m, l), _ = jax.lax.scan(body, state0,
                                   (jnp.arange(nc), kc, vc))
     out = acc / jnp.maximum(l, 1e-30)
-    out = jnp.einsum("bkgsd->bskgd", out).reshape(b, s, hq, d)
+    out = jnp.einsum("bkgsd->bskgd", out).reshape(b, s, hq, dv)
     return out.astype(q.dtype)
 
 

@@ -6,9 +6,15 @@ by their sum, and this chip computes the part of the sum that falls on
 the `held` experts it stores, `offset` being the first one's id. A
 (token, choice) pair whose expert is absent adds nothing here: its
 term is the holder's to compute and the exchange's to bring, and no
-code stands in for either. A shared expert, under its sigmoid gate,
-is computed for every token on every chip. On one chip there is no
-exchange at all.
+code stands in for either. A shared expert is computed for every token
+on every chip. On one chip there is no exchange at all.
+
+The router's FORM is the configuration's (`RouterForm`): scores by
+softmax over the experts or by sigmoid, each on its own; a bias
+(`router_bias`, a parameter) added for the choice and not for the
+weight; the chosen weights multiplied by a scale after the division;
+the shared expert under a sigmoid gate (`w_sgate`) or, with no such
+parameter, under none.
 
 The held experts' products are XLA's grouped matmul
 (`kernels.grouped_gemm`, `lax.ragged_dot`) over the pairs sorted by
@@ -17,6 +23,7 @@ multiplied by nothing.
 
   w_router (H, E) · w_gate_up (held, H, 2 I) gate | up · w_down
   (held, I, H) · ws_gate_up (H, 2 Is) · ws_down (Is, H) · w_sgate (H,)
+  or None · router_bias (E,) or None
 
 A model that scans its layers hands the expert stacks of ALL layers,
 (layers, held, ...), and `layer`, the traced index of this one: the
@@ -28,7 +35,7 @@ expert's weights, every step).
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -47,11 +54,25 @@ class HeldMoEParams(NamedTuple):
     w_down: jax.Array
     ws_gate_up: jax.Array
     ws_down: jax.Array
-    w_sgate: jax.Array
+    w_sgate: Optional[jax.Array] = None
+    router_bias: Optional[jax.Array] = None
+
+
+class RouterForm(NamedTuple):
+    score: str = "softmax"  # or "sigmoid"
+    scale: float = 1.0
+
+
+def swiglu_fwd(x, w_gate_up, w_down):
+    """down(silu(gate) * up) of one SwiGLU MLP, float32: the shared
+    expert here, and a hybrid model's dense block."""
+    h = jnp.dot(x, w_gate_up, preferred_element_type=jnp.float32)
+    return jnp.dot(silu_mul(h).astype(x.dtype), w_down,
+                   preferred_element_type=jnp.float32)
 
 
 def held_moe_fwd(x, valid, p: HeldMoEParams, top_k: int, offset: int,
-                 layer=None):
+                 layer=None, router: RouterForm = RouterForm()):
     """x (M, H); valid (M,) bool, the rows that are real tokens.
     Returns (y (M, H), pairs_here, pairs_absent): the held experts'
     part plus the shared expert, and how many of the valid rows'
@@ -62,7 +83,8 @@ def held_moe_fwd(x, valid, p: HeldMoEParams, top_k: int, offset: int,
     held = w_gate_up.shape[-3]
     logits = jnp.dot(x.astype(jnp.float32), p.w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    weights, ids = topk_routing(logits, top_k)
+    weights, ids = topk_routing(logits, top_k, score=router.score,
+                                bias=p.router_bias, scale=router.scale)
     local = ids - offset
     held_here = (local >= 0) & (local < held)
     here = held_here & valid[:, None]
@@ -92,13 +114,12 @@ def held_moe_fwd(x, valid, p: HeldMoEParams, top_k: int, offset: int,
         "mkh,mk->mh",
         y[sort.unsort_idx].reshape(m, top_k, -1).astype(jnp.float32), mine)
 
-    sgate = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), p.w_sgate.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    sh = jnp.dot(x, p.ws_gate_up, preferred_element_type=jnp.float32)
-    shared = jnp.dot(silu_mul(sh).astype(x.dtype), p.ws_down,
-                     preferred_element_type=jnp.float32)
-    out = out + sgate[:, None] * shared
+    shared = swiglu_fwd(x, p.ws_gate_up, p.ws_down)
+    if p.w_sgate is not None:
+        shared = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), p.w_sgate.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))[:, None] * shared
+    out = out + shared
     pairs_here = jnp.sum(here, dtype=jnp.int32)
     pairs_absent = jnp.sum(valid[:, None] & ~held_here, dtype=jnp.int32)
     return out.astype(x.dtype), pairs_here, pairs_absent

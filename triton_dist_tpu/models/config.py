@@ -4,6 +4,15 @@ TPU-native analog of the reference's ModelConfig
 (ref: python/triton_dist/models/config.py:31). Carries the Qwen3-dense
 geometry plus TPU partitioning knobs. Presets mirror the models the
 reference benchmarks (Qwen3-8B/32B, e2e_dense.md).
+
+A configuration is one of two families. The dense family (every block
+grouped-query attention and an MLP, or experts sliced across TP:
+models/dense.py) is every configuration that states no layer pattern.
+The hybrid family (models/hybrid.py) is one whose blocks are data: a
+mixer kind and an FFN kind a block, read off `mixer_kinds` /
+`ffn_kinds`, stated as an interval (`qwen3_next_80b`, `tiny_next`) or
+as two lists (`kimi_linear_48b`, `tiny_kimi`); what a page of its
+cache keeps of a token is `page_arrays`.
 """
 
 from __future__ import annotations
@@ -31,14 +40,19 @@ class ModelConfig:
     num_experts: int = 0
     num_experts_per_tok: int = 8
     moe_intermediate_size: int = 0
-    # Hybrid layer pattern (Qwen3-Next; models/qwen3_next.py). Block i is
-    # gated full attention where (i + 1) % full_attention_interval == 0
-    # and a gated delta net otherwise; 0 = every block the dense
-    # family's attention. The hybrid family also means: RMSNorm with
-    # gain (1 + w), an output gate on attention, rotary over
-    # `partial_rotary_factor` of the head, a router over all
-    # `num_experts` of which this chip holds `experts_held` from
-    # `expert_offset` on (0 held = all), and a shared expert.
+    # The hybrid family (models/hybrid.py): every block is a MIXER kind
+    # and an FFN kind, and the pattern is data. Two ways to state it:
+    # an interval (Qwen3-Next: block i is gated full attention where
+    # (i + 1) % full_attention_interval == 0 and a scalar-gated delta
+    # net otherwise), or two lists that count from 1 as the source does
+    # (Kimi-Linear: `kda_layers` channel-gated delta nets,
+    # `full_attn_layers` latent attention without rotary); the first
+    # `first_k_dense` blocks have a dense MLP of `intermediate_size`,
+    # the others experts. 0 / () = every block the dense family's.
+    # The family also means: a router over all `num_experts` of which
+    # this chip holds `experts_held` from `expert_offset` on (0 held =
+    # all), and a shared expert. What differs between its members is
+    # stated below, each at the interval form's value.
     full_attention_interval: int = 0
     partial_rotary_factor: float = 1.0
     linear_num_key_heads: int = 0
@@ -49,6 +63,27 @@ class ModelConfig:
     shared_expert_intermediate_size: int = 0
     experts_held: int = 0
     expert_offset: int = 0
+    kda_layers: tuple = ()
+    full_attn_layers: tuple = ()
+    first_k_dense: int = 0
+    # latent attention: the cache holds kv_lora_rank + qk_rope_head_dim
+    # values a token, from which every head's keys and values come
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the channel-gated delta net's two low-rank gates
+    linear_gate_rank: int = 0
+    # RMSNorm's gain is (1 + w) (True) or w
+    norm_zero_centred: bool = True
+    # the router's form (layers/held_moe.py): scores by softmax or by
+    # sigmoid, a bias added for the CHOICE alone, the chosen weights
+    # divided by their sum and multiplied by the scale; the shared
+    # expert under a sigmoid gate or under none
+    router_score: str = "softmax"
+    router_bias: bool = False
+    routed_scaling_factor: float = 1.0
+    shared_expert_gate: bool = True
 
     @property
     def is_moe(self) -> bool:
@@ -56,7 +91,54 @@ class ModelConfig:
 
     @property
     def is_hybrid(self) -> bool:
-        return self.full_attention_interval > 0
+        return self.full_attention_interval > 0 or bool(self.kda_layers)
+
+    @property
+    def mixer_kinds(self) -> tuple:
+        """The hybrid family's mixer of each block, in order: "gdn"
+        (scalar-gated delta net), "kda" (channel-gated), "gated_attn",
+        "mla" (latent attention)."""
+        if self.kda_layers:
+            kda, full = set(self.kda_layers), set(self.full_attn_layers)
+            assert not kda & full and kda | full == set(
+                range(1, self.num_layers + 1)), (
+                "kda_layers and full_attn_layers must name every block "
+                "once, counting from 1")
+            return tuple("kda" if i + 1 in kda else "mla"
+                         for i in range(self.num_layers))
+        per = self.full_attention_interval
+        return tuple("gated_attn" if (i + 1) % per == 0 else "gdn"
+                     for i in range(self.num_layers))
+
+    @property
+    def ffn_kinds(self) -> tuple:
+        """ "dense" for the leading `first_k_dense` blocks, "moe" after."""
+        return tuple("dense" if i < self.first_k_dense else "moe"
+                     for i in range(self.num_layers))
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
+
+    @property
+    def page_arrays(self) -> tuple:
+        """(heads, width) of each array a page layer keeps a token in:
+        keys and values a kv head, or ONE latent row (kv_lora_rank |
+        qk_rope_head_dim, padded with zeros to whole 128-value lanes:
+        the chip's kernels take no narrower slice of a page) from which
+        both come."""
+        if self.kv_lora_rank:
+            row = self.kv_lora_rank + self.qk_rope_head_dim
+            return ((1, -(-row // 128) * 128),)
+        return ((self.num_kv_heads, self.head_dim),) * 2
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """Pool bytes of one position in one page layer."""
+        import numpy as np
+
+        return sum(h * w for h, w in self.page_arrays) \
+            * np.dtype(self.dtype).itemsize
 
     @property
     def num_experts_held(self) -> int:
@@ -68,7 +150,7 @@ class ModelConfig:
         """Blocks that keep keys and values (pages in the serve pool)."""
         if not self.is_hybrid:
             return self.num_layers
-        return self.num_layers // self.full_attention_interval
+        return sum(k in ("gated_attn", "mla") for k in self.mixer_kinds)
 
     # The published presets fix every WIDTH; depth (`num_layers`) is the
     # one cut a single chip may force, so it is the presets' only
@@ -136,6 +218,59 @@ class ModelConfig:
             linear_num_key_heads=2, linear_num_value_heads=4,
             linear_key_head_dim=16, linear_value_head_dim=16,
             linear_conv_kernel_dim=4, shared_expert_intermediate_size=32,
+        )
+        defaults.update(kw)
+        return ModelConfig(**defaults)
+
+    @staticmethod
+    def kimi_linear_48b(**kw) -> "ModelConfig":
+        """Kimi-Linear-48B-A3B geometry: 27 blocks, `K K K M` six times
+        and `K K M` (K a channel-gated delta net of 32 heads x 128, M
+        latent attention without rotary: 32 heads over a 512 + 64
+        latent row), block 1 a dense MLP of 9,216, the others 256
+        experts of width 1,024 with 8 a token under a sigmoid router
+        with a selection bias, scaled by 2.446, plus an ungated shared
+        expert. What one chip of an expert-parallel group holds is
+        `experts_held` / `expert_offset` in **kw."""
+        full = (4, 8, 12, 16, 20, 24, 27)
+        defaults = dict(
+            vocab_size=163_840, hidden_size=2304, intermediate_size=9216,
+            num_layers=27, num_q_heads=32, num_kv_heads=32, head_dim=72,
+            rope_theta=10_000.0, rms_eps=1e-5, num_experts=256,
+            num_experts_per_tok=8, moe_intermediate_size=1024,
+            shared_expert_intermediate_size=1024,
+            kda_layers=tuple(i for i in range(1, 28) if i not in full),
+            full_attn_layers=full, first_k_dense=1, kv_lora_rank=512,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            linear_num_key_heads=32, linear_num_value_heads=32,
+            linear_key_head_dim=128, linear_value_head_dim=128,
+            linear_conv_kernel_dim=4, linear_gate_rank=128,
+            norm_zero_centred=False, router_score="sigmoid",
+            router_bias=True, routed_scaling_factor=2.446,
+            shared_expert_gate=False,
+        )
+        defaults.update(kw)
+        return ModelConfig(**defaults)
+
+    @staticmethod
+    def tiny_kimi(**kw) -> "ModelConfig":
+        """Test-scale Kimi-Linear pattern: a leading dense block, one
+        whole period and a short last one (`K K K M K M`), 8 experts."""
+        defaults = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=96,
+            num_layers=6, num_q_heads=4, num_kv_heads=4, head_dim=16,
+            rms_eps=1e-5, max_positions=64, dtype="float32",
+            num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
+            shared_expert_intermediate_size=32,
+            kda_layers=(1, 2, 3, 5), full_attn_layers=(4, 6),
+            first_k_dense=1, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16,
+            linear_num_key_heads=4, linear_num_value_heads=4,
+            linear_key_head_dim=16, linear_value_head_dim=16,
+            linear_conv_kernel_dim=4, linear_gate_rank=8,
+            norm_zero_centred=False, router_score="sigmoid",
+            router_bias=True, routed_scaling_factor=2.446,
+            shared_expert_gate=False,
         )
         defaults.update(kw)
         return ModelConfig(**defaults)

@@ -71,8 +71,8 @@ def _serve_step_math(cfg, mode, axis, params, tokens, pool_k, pool_v,
         return_full_logits=True, plan=plan,
     )  # logits (K, C, V) f32, rows (L, K, C, Hkv, D)
     tok, last = _sample_step(logits, n_valid, temps, keys, per_pos)
-    pool_k, pool_v = KVCache.scatter_step(pool_k, pool_v, k_rows, v_rows,
-                                          table, lengths, n_valid)
+    pool_k, pool_v = KVCache.scatter_step(
+        (pool_k, pool_v), (k_rows, v_rows), table, lengths, n_valid)
     return tok, last, pool_k, pool_v
 
 
@@ -102,18 +102,21 @@ def _sample_step(logits, n_valid, temps, keys, per_pos: bool):
 
 def _hybrid_step_math(cfg, attn_impl, params, tokens, cache, table,
                       lengths, n_valid, temps, keys):
-    """The serve step of the hybrid family (models/qwen3_next.py): the
+    """The serve step of the hybrid family (models/hybrid.py): the
     same fixed-geometry forward, sampling and page scatter, with the
-    delta-net blocks' per-slot state carried beside the pages. Returns
+    delta-net blocks' per-slot state carried beside the pages (the
+    family's own: key-value pools or one latent pool). `cache` is
+    `KVPool.state`, (*pages, rec, conv). Returns
     (tok, last, cache, {counter: () int32})."""
-    from triton_dist_tpu.models import qwen3_next
+    from triton_dist_tpu.models import hybrid
 
-    logits, (k_rows, v_rows), rec, conv, stats = qwen3_next.forward_chunk(
-        cfg, params, tokens, cache, table, lengths, n_valid, attn_impl)
+    *pages, rec, conv = cache
+    logits, rows, rec, conv, stats = hybrid.forward_chunk(
+        cfg, params, tokens, hybrid.Cache(tuple(pages), rec, conv), table,
+        lengths, n_valid, attn_impl)
     tok, last = _sample_step(logits, n_valid, temps, keys, False)
-    pool_k, pool_v = KVCache.scatter_step(
-        cache.k, cache.v, k_rows, v_rows, table, lengths, n_valid)
-    return tok, last, qwen3_next.Cache(pool_k, pool_v, rec, conv), stats
+    pages = KVCache.scatter_step(pages, rows, table, lengths, n_valid)
+    return tok, last, (*pages, rec, conv), stats
 
 
 class Engine:
@@ -161,16 +164,16 @@ class Engine:
         # and the serve-plane Worker so both replay ONE executable.
         self._serve_cache: dict = {}
         if cfg.is_hybrid:
-            # the hybrid family (models/qwen3_next.py) is served through
+            # the hybrid family (models/hybrid.py) is served through
             # make_serve_step alone: its per-slot recurrent state lives
             # in the serve plane's pool, so the KVCache entry points
             # (prefill, decode_step, generate) refuse it
-            from triton_dist_tpu.models import qwen3_next
+            from triton_dist_tpu.models import hybrid
 
-            qwen3_next.check(cfg, n)
+            hybrid.check(cfg, n)
             self.params = (
                 params if params is not None
-                else qwen3_next.init_params(cfg, mesh, seed, fast=fast_init)
+                else hybrid.init_params(cfg, mesh, seed, fast=fast_init)
             )
             self._wrap_specs = (P(), P(batch_axis), None)
             return
@@ -228,7 +231,7 @@ class Engine:
         mega.schedule_graph consume it to provably agree on pairings.
         None for the hybrid family: on its one chip there is no
         collective to pair, and its one routing decision is
-        `plan.planner.route_gated_attention`."""
+        `plan.planner.route_hybrid_attention`."""
         from triton_dist_tpu.plan.planner import plan_dense_forward
 
         if self.cfg.is_hybrid:
@@ -350,7 +353,8 @@ class Engine:
         (`KVPool.state`): for the dense family (pool_k, pool_v), each
         (L, P, page, Hkv, D) — token-major pages, the kv-head axis
         (3) the tensor-parallel one (serve/kv_pool.py); for the hybrid family
-        `qwen3_next.Cache`, pages for the attention blocks only and the
+        (*pages, rec, conv): pages for the attention blocks only (two
+        pools, or ONE of latent rows) and the
         delta-net blocks' per-slot recurrent and convolution state
         (a padding column leaves both as they were; a slot whose
         length is 0 starts from zero state inside the step). `stats`
@@ -395,9 +399,10 @@ class Engine:
         what this engine can compile: no option and no environment
         variable reaches it.
 
-        The hybrid family keeps the one width `(chunk,)`: its full
-        attention blocks are `_fp_local_kernel` or an error
-        (`plan.planner.route_gated_attention`), and one query row
+        The hybrid family keeps the one width `(chunk,)`: its
+        attention blocks take the route the planner names
+        (`plan.planner.route_hybrid_attention`: `_fp_local_kernel` or
+        an error for the gated blocks), and one query row
         never reaches that kernel (`layers.attention.gqa_attention`
         takes a single row through the dense chain), so a width-1 step
         would compile, in silence, the route the family refuses
@@ -420,19 +425,15 @@ class Engine:
         if cfg.is_hybrid:
             if per_pos:
                 self._refuse_hybrid("the per-position (spec-verify) step")
-            from triton_dist_tpu.models import qwen3_next
-            from triton_dist_tpu.plan.planner import route_gated_attention
+            from triton_dist_tpu.plan.planner import route_hybrid_attention
 
-            attn_impl = route_gated_attention(
-                slots, chunk, t_pool, cfg.num_q_heads, cfg.num_kv_heads,
-                cfg.head_dim, cfg.dtype)
+            attn_impl = route_hybrid_attention(cfg, slots, chunk, t_pool)
 
             def per_rank(params, tokens, cache, table, lengths, n_valid,
                          temps, keys):
                 return _hybrid_step_math(
-                    cfg, attn_impl, params, tokens,
-                    qwen3_next.Cache(*cache), table, lengths, n_valid,
-                    temps, keys)
+                    cfg, attn_impl, params, tokens, cache, table, lengths,
+                    n_valid, temps, keys)
 
             cache_spec = P()
         else:

@@ -1,0 +1,426 @@
+"""The hybrid family — decoders whose blocks are DATA, served through
+the fixed-geometry step.
+
+Block i is `x += Mixer_i(norm(x)); x += FFN_i(norm(x))`, and what the
+mixer and the FFN are is read off the configuration
+(`ModelConfig.mixer_kinds`, `ffn_kinds`):
+
+  mixer  "gdn"         scalar-gated delta net    layers/gated_delta_net.py
+         "kda"         channel-gated delta net   layers/gated_delta_net.py
+         "gated_attn"  gated full attention      layers/gated_attn.py
+         "mla"         latent attention, no rotary  layers/latent_attn.py
+  FFN    "moe"         a router over all experts, the ones this chip
+                       holds, a shared expert    layers/held_moe.py
+         "dense"       a SwiGLU MLP of `intermediate_size`
+
+Two published members: Qwen3-Next (periods of three "gdn" blocks and
+one "gated_attn", every FFN "moe", norms with the gain (1 + w)) and
+Kimi-Linear ("kda" and "mla" from two lists, the last period short,
+the first block's FFN "dense", norms with the gain w, a sigmoid
+router).
+
+The pattern is cut into PERIODS, each ending with its attention block
+(the last one may have none), and a run of equal periods is ONE
+`lax.scan` with the period's blocks unrolled inside it: one scan for
+Qwen3-Next, three for Kimi-Linear. Parameters are stacked by kind:
+
+  embed (V, H) · final_ln (H,) · lm_head (H, V)
+  every block, (L, ...):      input_ln, post_ln
+  "moe" blocks, (Lm, ...):    w_router, [router_bias,] w_gate_up,
+                              w_down, ws_gate_up, ws_down[, w_sgate]
+  "dense" blocks, (Ld, ...):  wd_gate_up, wd_down
+  a mixer kind's blocks:      `_MIXER_LEAVES` below
+
+What a slot carries between steps (`Cache`): pages for the attention
+blocks, in the pool's layout (keys and values, or one latent row a
+token), and for each delta-net block a recurrent state and the
+convolution's last inputs.
+
+No stack is cut: a scan's body takes each block's row of every stacked
+leaf, and of the state, by index (`lax.dynamic_index_in_dim`), so the
+weights are read where they are. (Handing the scan a period's share of
+a stack copies it every step: 0.85 GB of one leaf alone at
+Kimi-Linear's widths; PERF.md, PR 35.)
+
+This family runs on ONE chip of an expert-parallel group: the mixers
+for the chip's own requests, the experts it holds. There is no
+exchange and no code in place of the absent chips; a mesh of more than
+one device is refused.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from triton_dist_tpu.layers.gated_attn import (
+    GatedAttnParams,
+    GatedAttnSpec,
+    gated_attn_fwd,
+)
+from triton_dist_tpu.layers.gated_delta_net import (
+    GDNParams,
+    GDNSpec,
+    KDAParams,
+    gated_delta_net_fwd,
+    kda_fwd,
+)
+from triton_dist_tpu.layers.held_moe import (
+    HeldMoEParams,
+    RouterForm,
+    held_moe_fwd,
+    swiglu_fwd,
+)
+from triton_dist_tpu.layers.latent_attn import (
+    LatentAttnParams,
+    LatentAttnSpec,
+    latent_attn_fwd,
+)
+from triton_dist_tpu.layers.norm import rms_norm
+from triton_dist_tpu.layers.rope import rope_table
+from triton_dist_tpu.models.config import ModelConfig
+from triton_dist_tpu.models.dense import _INIT_SCALE, _draw
+from triton_dist_tpu.models.kv_cache import KVCache
+
+STATE_MIXERS = ("gdn", "kda")  # keep per-slot state beside the pages
+PAGE_MIXERS = ("gated_attn", "mla")  # keep pages
+
+
+class Cache(NamedTuple):
+    """The serve step's cache for this family (`KVPool.state`, named)."""
+
+    pages: tuple  # (k, v) each (Lf, P, page, Hkv, D), or one latent pool
+    rec: jax.Array  # (Ll, slots, Hv, dk, dv) float32
+    conv: jax.Array  # (Ll, slots, K - 1, channels)
+
+
+def gdn_spec(cfg: ModelConfig) -> GDNSpec:
+    return GDNSpec(cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+                   cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+                   cfg.linear_conv_kernel_dim)
+
+
+def attn_spec(cfg: ModelConfig) -> GatedAttnSpec:
+    return GatedAttnSpec(cfg.num_q_heads, cfg.num_kv_heads, cfg.head_dim,
+                         int(cfg.head_dim * cfg.partial_rotary_factor))
+
+
+def latent_spec(cfg: ModelConfig) -> LatentAttnSpec:
+    return LatentAttnSpec(cfg.num_q_heads, cfg.kv_lora_rank,
+                          cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+
+
+def segments(cfg: ModelConfig):
+    """The layer pattern as [(period, repeats)]: a period is the
+    ((mixer, ffn), ...) of consecutive blocks up to and with an
+    attention block; equal periods in a row are one entry."""
+    out, period = [], []
+    blocks = list(zip(cfg.mixer_kinds, cfg.ffn_kinds))
+    for at, block in enumerate(blocks):
+        period.append(block)
+        if block[0] in PAGE_MIXERS or at == len(blocks) - 1:
+            if out and out[-1][0] == tuple(period):
+                out[-1][1] += 1
+            else:
+                out.append([tuple(period), 1])
+            period = []
+    return [(p, n) for p, n in out]
+
+
+def check(cfg: ModelConfig, n_devices: int) -> None:
+    if n_devices != 1:
+        raise NotImplementedError(
+            f"the hybrid family runs one chip of an expert-parallel group "
+            f"(got a tp axis of {n_devices}): the mixers have no "
+            "tensor-parallel form and the expert layer no exchange")
+    assert cfg.expert_offset + cfg.num_experts_held <= cfg.num_experts
+    assert cfg.linear_num_value_heads % cfg.linear_num_key_heads == 0
+    assert cfg.router_score in ("softmax", "sigmoid")
+    assert 0 <= cfg.first_k_dense < cfg.num_layers
+    assert not cfg.tie_word_embeddings
+    kinds = set(cfg.mixer_kinds)  # the lists name every block once
+    if "kda" in kinds:
+        assert cfg.linear_num_key_heads == cfg.linear_num_value_heads
+        assert cfg.linear_gate_rank > 0
+    if "mla" in kinds:
+        assert cfg.kv_lora_rank and cfg.v_head_dim <= cfg.kv_lora_rank
+
+
+# (name, shape, init) in the order that fixes each leaf's key,
+# fold_in(PRNGKey(seed), position): "normal" is N(0, _INIT_SCALE);
+# a block's "gain" starts at its identity, 0 under (1 + w) and 1 under
+# w (`norm_zero_centred`); a mixer's own norms are its kind's: (1 + w)
+# in gated attention, w in the delta nets and the latent
+def _mixer_leaves(cfg: ModelConfig, kind: str, n: int):
+    h = cfg.hidden_size
+    g = gdn_spec(cfg)
+    vw = g.num_v_heads * g.v_dim
+    if kind == "gdn":
+        return (
+            ("w_qkvz", (n, h, g.channels + vw), "normal"),
+            ("w_ba", (n, h, 2 * g.num_v_heads), "normal"),
+            ("conv_w", (n, g.conv, g.channels), "normal"),
+            ("a_log", (n, g.num_v_heads), "normal"),
+            ("dt_bias", (n, g.num_v_heads), "normal"),
+            ("gdn_norm", (n, g.v_dim), "ones"),
+            ("w_out", (n, vw, h), "normal"),
+        )
+    if kind == "kda":
+        r = cfg.linear_gate_rank
+        return (
+            ("kda_w_qkv", (n, h, g.channels), "normal"),
+            ("kda_w_fgb", (n, h, 2 * r + g.num_v_heads), "normal"),
+            ("kda_w_fb", (n, r, g.num_k_heads * g.k_dim), "normal"),
+            ("kda_w_gb", (n, r, vw), "normal"),
+            ("kda_conv_w", (n, g.conv, g.channels), "normal"),
+            ("kda_a_log", (n, g.num_v_heads), "normal"),
+            ("kda_dt_bias", (n, g.num_k_heads * g.k_dim), "normal"),
+            ("kda_norm", (n, g.v_dim), "ones"),
+            ("kda_w_out", (n, vw, h), "normal"),
+        )
+    if kind == "gated_attn":
+        a = attn_spec(cfg)
+        hq, hkv, d = a.num_q_heads, a.num_kv_heads, a.head_dim
+        return (
+            ("w_q", (n, h, hq * 2 * d), "normal"),
+            ("w_kv", (n, h, 2 * hkv * d), "normal"),
+            ("q_norm", (n, d), "zeros"),
+            ("k_norm", (n, d), "zeros"),
+            ("w_o", (n, hq * d, h), "normal"),
+        )
+    m = latent_spec(cfg)
+    return (
+        ("mla_w_q", (n, h, m.num_q_heads * (m.nope_dim + m.rope_dim)),
+         "normal"),
+        ("mla_w_a", (n, h, m.row), "normal"),
+        ("mla_kv_norm", (n, m.rank), "ones"),
+        ("mla_w_b", (n, m.rank, m.num_q_heads * (m.nope_dim + m.v_dim)),
+         "normal"),
+        ("mla_w_o", (n, m.num_q_heads * m.v_dim, h), "normal"),
+    )
+
+
+_MIXERS = ("gdn", "kda", "gated_attn", "mla")  # the leaves' order
+
+
+def _moe_leaves(cfg: ModelConfig, n: int):
+    h, e, eh = cfg.hidden_size, cfg.num_experts, cfg.num_experts_held
+    i, ish = cfg.moe_intermediate_size, cfg.shared_expert_intermediate_size
+    return (
+        (("w_router", (n, h, e), "normal"),)
+        + ((("router_bias", (n, e), "normal"),) if cfg.router_bias else ())
+        + (("w_gate_up", (n, eh, h, 2 * i), "normal"),
+           ("w_down", (n, eh, i, h), "normal"),
+           ("ws_gate_up", (n, h, 2 * ish), "normal"),
+           ("ws_down", (n, ish, h), "normal"))
+        + ((("w_sgate", (n, h), "normal"),) if cfg.shared_expert_gate
+           else ()))
+
+
+def _dense_leaves(cfg: ModelConfig, n: int):
+    h, i = cfg.hidden_size, cfg.intermediate_size
+    return (("wd_gate_up", (n, h, 2 * i), "normal"),
+            ("wd_down", (n, i, h), "normal"))
+
+
+def leaves(cfg: ModelConfig):
+    L, h, v = cfg.num_layers, cfg.hidden_size, cfg.vocab_size
+    mixers = cfg.mixer_kinds
+    ld = cfg.first_k_dense
+    return (
+        (("embed", (v, h), "normal"),
+         ("final_ln", (h,), "gain"),
+         ("lm_head", (h, v), "normal"),
+         ("input_ln", (L, h), "gain"),
+         ("post_ln", (L, h), "gain"))
+        + _moe_leaves(cfg, L - ld)
+        + (_dense_leaves(cfg, ld) if ld else ())
+        + tuple(leaf for kind in _MIXERS if kind in mixers
+                for leaf in _mixer_leaves(cfg, kind, mixers.count(kind))))
+
+
+def init_params(cfg: ModelConfig, mesh, seed: int = 0,
+                fast: bool = False) -> dict:
+    """Random parameters on the mesh's one device. fast=True draws on
+    the device (each leaf under its own folded key, in slabs, as
+    `models.dense._draw` does); fast=False from one host numpy stream
+    in the order of `leaves`."""
+    dt = jnp.dtype(cfg.dtype)
+    where = NamedSharding(mesh, P())
+    spec = leaves(cfg)
+    gain = "zeros" if cfg.norm_zero_centred else "ones"
+    spec = tuple((n, s, gain if i == "gain" else i) for n, s, i in spec)
+    const = {"zeros": jnp.zeros, "ones": jnp.ones}
+    if fast:
+        def draw(key):
+            return {name: const[init](shape, dt) if init != "normal"
+                    else _draw(jax.random.fold_in(key, i), shape, dt)
+                    for i, (name, shape, init) in enumerate(spec)}
+
+        return jax.jit(draw, out_shardings=where)(jax.random.PRNGKey(seed))
+    rng = np.random.default_rng(seed)
+    host = {"zeros": np.zeros, "ones": np.ones}
+    return {name: jax.device_put(
+        (host[init](shape, np.float32) if init != "normal" else np.asarray(
+            rng.standard_normal(shape) * _INIT_SCALE, np.float32)
+         ).astype(dt), where) for name, shape, init in spec}
+
+
+# a block's leaves by kind; the experts' own stacks (w_gate_up,
+# w_down) go to the expert layer whole (`moe` below)
+_MOE = ("w_router", "router_bias", "ws_gate_up", "ws_down", "w_sgate")
+_MIXER_LEAVES = {
+    "gdn": ("w_qkvz", "w_ba", "conv_w", "a_log", "dt_bias", "gdn_norm",
+            "w_out"),
+    "kda": ("kda_w_qkv", "kda_w_fgb", "kda_w_fb", "kda_w_gb", "kda_conv_w",
+            "kda_a_log", "kda_dt_bias", "kda_norm", "kda_w_out"),
+    "gated_attn": ("w_q", "w_kv", "q_norm", "k_norm", "w_o"),
+    "mla": ("mla_w_q", "mla_w_a", "mla_kv_norm", "mla_w_b", "mla_w_o"),
+}
+_MIXER_PARAMS = {"gdn": GDNParams, "kda": KDAParams,
+                 "gated_attn": GatedAttnParams, "mla": LatentAttnParams}
+
+
+def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
+                  table, lengths, n_valid, attn_impl: str):
+    """One (slots, chunk) block through the model. Slot s holds
+    `lengths[s]` cached positions and `n_valid[s]` real columns.
+    Returns (logits (K, C, V) float32, the chunk's new rows of the
+    attention blocks, one (Lf, K, C, Hkv, D) an array of the pages,
+    rec, conv, {counter: () int32})."""
+    slots, chunk = tokens.shape
+    g, a, m = gdn_spec(cfg), attn_spec(cfg), latent_spec(cfg)
+    eps = cfg.rms_eps
+    router = RouterForm(cfg.router_score, cfg.routed_scaling_factor)
+    if "gated_attn" in cfg.mixer_kinds:
+        cos, sin = rope_table(a.rotary_dim, cfg.max_positions,
+                              cfg.rope_theta)
+    positions = lengths[:, None] + jnp.arange(chunk)[None, :]
+    kv_len = lengths + chunk
+    valid = (jnp.arange(chunk)[None, :] < n_valid[:, None]).reshape(-1)
+    fresh = lengths == 0
+    pages = KVCache.of(cache.pages, lengths, table)
+
+    def normed(x, gain):
+        return rms_norm(x, gain, eps, zero_centred=cfg.norm_zero_centred)
+
+    def moe(x, gain, p, layer):
+        # the experts' stacks whole, this block's by `layer`: a
+        # per-period slice of them would be copied every step
+        p = HeldMoEParams(p["w_router"], params["w_gate_up"],
+                          params["w_down"], p["ws_gate_up"], p["ws_down"],
+                          p.get("w_sgate"), p.get("router_bias"))
+        y, here, absent = held_moe_fwd(
+            normed(x, gain).reshape(slots * chunk, -1), valid, p,
+            cfg.num_experts_per_tok, cfg.expert_offset, layer=layer,
+            router=router)
+        return x + y.reshape(x.shape), here, absent
+
+    def dense(x, gain, p):
+        return x + swiglu_fwd(normed(x, gain), p["wd_gate_up"],
+                              p["wd_down"]).astype(x.dtype)
+
+    def run(period, start, count):
+        """The scan body of a run of `period`s whose first blocks are
+        the `start[kind]`-th of their kinds, `count[kind]` a period.
+        Every stacked leaf stays whole and a block takes its own row
+        of it by index: a period's share cut out of a stack (or handed
+        to the scan to cut) is a copy of those weights every step."""
+        def one_period(x, i):
+            def row(stack, kind, at):
+                return jax.lax.dynamic_index_in_dim(
+                    stack, start[kind] + i * count[kind] + at,
+                    keepdims=False)
+
+            here = absent = jnp.int32(0)
+            recs, convs, rows = [], [], ()
+            at = {kind: 0 for kind in count}
+            for j, (mixer, ffn) in enumerate(period):
+                p = _MIXER_PARAMS[mixer](*(
+                    row(params[n], mixer, at[mixer])
+                    for n in _MIXER_LEAVES[mixer]))
+                hid = normed(x, row(params["input_ln"], "block", j))
+                if mixer in STATE_MIXERS:
+                    fwd = gated_delta_net_fwd if mixer == "gdn" else kda_fwd
+                    y, r, c = fwd(hid, p, g,
+                                  row(cache.rec, "state", at["state"]),
+                                  row(cache.conv, "state", at["state"]),
+                                  n_valid, fresh, eps)
+                    recs.append(r)
+                    convs.append(c)
+                    at["state"] += 1
+                else:
+                    view = pages.layer_view(start["page"] + i)
+                    if mixer == "gated_attn":
+                        y, rows = gated_attn_fwd(
+                            hid, p, a, cos, sin, positions, view, kv_len,
+                            attn_impl, eps)
+                    else:
+                        y, rows = latent_attn_fwd(
+                            hid, p, m, positions, view[0], kv_len,
+                            n_valid, attn_impl, eps)
+                at[mixer] += 1
+                gain = row(params["post_ln"], "block", j)
+                if ffn == "moe":
+                    x, h_j, a_j = moe(
+                        x + y, gain,
+                        {n: row(params[n], "moe", at["moe"]) for n in _MOE
+                         if n in params},
+                        start["moe"] + i * count["moe"] + at["moe"])
+                    here, absent = here + h_j, absent + a_j
+                else:
+                    x = dense(x + y, gain, {
+                        n: row(params[n], "dense", at["dense"])
+                        for n in ("wd_gate_up", "wd_down")})
+                at[ffn] += 1
+            return x, (jnp.stack(recs), jnp.stack(convs), rows, here,
+                       absent)
+
+        return one_period
+
+    x = params["embed"][tokens]
+    start = {kind: 0 for kind in _MIXERS + ("moe", "dense", "block",
+                                            "state", "page")}
+    outs = []
+    for period, n in segments(cfg):
+        count = {kind: 0 for kind in start}
+        for mixer, ffn in period:
+            for kind in (mixer, ffn, "block"):
+                count[kind] += 1
+            count["state"] += mixer in STATE_MIXERS
+            count["page"] += mixer in PAGE_MIXERS
+        assert count["state"], "a period without a delta-net block"
+        x, out = jax.lax.scan(run(period, dict(start), count), x,
+                              jnp.arange(n))
+        outs.append(out)
+        for kind in start:
+            start[kind] += n * count[kind]
+
+    def joined(parts, flat: bool):
+        parts = [p.reshape((-1,) + p.shape[2:]) if flat else p
+                 for p in parts]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+    rec = joined([o[0] for o in outs], True)
+    conv = joined([o[1] for o in outs], True)
+    rows = tuple(joined([o[2][k] for o in outs if o[2]], False)
+                 for k in range(len(cache.pages)))
+    x = normed(x, params["final_ln"])
+    logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"],
+                        preferred_element_type=jnp.float32)
+    stats = {"moe_pairs_here": sum(jnp.sum(o[3]) for o in outs),
+             "moe_pairs_absent": sum(jnp.sum(o[4]) for o in outs)}
+    return logits, rows, rec, conv, stats
+
+
+def state_shapes(cfg: ModelConfig, slots: int):
+    """Shapes of the per-slot state beside the pages: (rec, conv)."""
+    g = gdn_spec(cfg)
+    ll = cfg.num_layers - cfg.num_kv_layers
+    return ((ll, slots, g.num_v_heads, g.k_dim, g.v_dim),
+            (ll, slots, g.conv - 1, g.channels))

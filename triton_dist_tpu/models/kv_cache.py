@@ -17,6 +17,11 @@ layer's dense (B, T, Hkv, D) view, so the step's round trip moves each
 byte once: every layer reads its own view through the table
 (`layer_view`), and the step's new rows go back as page slabs in place
 (`scatter_step`). No whole-model view is built in between.
+
+What a page keeps of a token is the family's (`ModelConfig.page_arrays`):
+keys and values a kv head, the two pools k and v; or, under latent
+attention, ONE row a token from which both come, (L, P, page, 1, W),
+and then `v` is None: there is no second pool.
 """
 
 from __future__ import annotations
@@ -29,14 +34,26 @@ import jax.numpy as jnp
 
 class KVCache(NamedTuple):
     k: jax.Array  # (L, B, T_max, Hkv, D); paged: (L, P, page, Hkv, D)
-    v: jax.Array
+    v: Optional[jax.Array]  # None: the one latent pool is `k`
     length: jax.Array  # (B,) valid entries per sequence
     # a PAGED cache (the serve plane's): k/v are page pools and `table`
     # (B, MAXP) maps each sequence's page grid onto pool pages
     table: Optional[jax.Array] = None
 
+    @staticmethod
+    def of(pools, length, table=None) -> "KVCache":
+        """The cache over `pools`: (k, v), or a latent cache's (k,)."""
+        return KVCache(pools[0], pools[1] if len(pools) == 2 else None,
+                       length, table)
+
+    @property
+    def pools(self) -> tuple:
+        """The arrays that hold pages: (k, v), or (k,) of a latent cache."""
+        return (self.k,) if self.v is None else (self.k, self.v)
+
     def layer_view(self, i):
-        """Layer i's dense (k, v), each (B, T, Hkv, D) — what the layer
+        """Layer i's dense (k, v), each (B, T, Hkv, D) (a latent
+        cache's one (B, T, 1, W) array, as a 1-tuple) — what the layer
         lays its rows into and attends over (models/dense.py's layer
         scan calls this in its body). Of a paged cache it is the
         layer's pages gathered through the table, `pool[i, table]` with
@@ -49,11 +66,11 @@ class KVCache(NamedTuple):
         they gather sits beyond each sequence's length and is masked
         by attention's kv_len/causal bounds."""
         if self.table is None:
-            return self.k[i], self.v[i]
+            return tuple(pool[i] for pool in self.pools)
         b, maxp = self.table.shape
-        _, _, page, hkv, d = self.k.shape
-        return tuple(pool[i, self.table].reshape(b, maxp * page, hkv, d)
-                     for pool in (self.k, self.v))
+        page = self.k.shape[2]
+        return tuple(pool[i, self.table].reshape(
+            (b, maxp * page) + pool.shape[3:]) for pool in self.pools)
 
     @staticmethod
     def dense_view(pool_k, pool_v, table, lengths) -> "KVCache":
@@ -62,11 +79,15 @@ class KVCache(NamedTuple):
         page axes read as one. The SNAPSHOT form (KVPool.to_dense, the
         megakernel bridge's reference, tests); the serve step never
         builds it — its layers each read their own view."""
-        L, _, page, Hkv, D = pool_k.shape
+        L, _, page = pool_k.shape[:3]
         B = table.shape[0]
         t = KVCache.dense_view_tokens(table.shape, page) // B
-        return KVCache(pool_k[:, table].reshape(L, B, t, Hkv, D),
-                       pool_v[:, table].reshape(L, B, t, Hkv, D), lengths)
+
+        def view(pool):
+            return None if pool is None else pool[:, table].reshape(
+                (L, B, t) + pool.shape[3:])
+
+        return KVCache(view(pool_k), view(pool_v), lengths)
 
     @staticmethod
     def dense_view_tokens(table_shape, page: int) -> int:
@@ -79,10 +100,10 @@ class KVCache(NamedTuple):
         return slots * maxp * page
 
     @staticmethod
-    def scatter_step(pool_k, pool_v, rows_k, rows_v, table, lengths,
-                     n_valid):
+    def scatter_step(pools, rows, table, lengths, n_valid):
         """A serve step's K/V rows back into the paged pool — the write
-        path beside `layer_view`. rows_k/rows_v (L, B, C, Hkv, D) are
+        path beside `layer_view`. `pools` are the page arrays, (k, v)
+        or a latent cache's one, and `rows` (each (L, B, C, Hkv, D))
         the rows the layers computed for the step's C columns; slot
         s's columns [0, n_valid[s]) belong at positions lengths[s] ..
         of its pages, the rest are padding and are written nowhere.
@@ -95,11 +116,10 @@ class KVCache(NamedTuple):
         is updated in place, at every C alike. A slab with no valid row
         is the null page's (page 0), so an index past a slot's pages
         never reaches a live page."""
-        L, _, page, Hkv, D = pool_k.shape
+        page = pools[0].shape[2]
         slots, max_pages = table.shape
-        chunk = rows_k.shape[2]
+        chunk = rows[0].shape[2]
         touch = (chunk + page - 2) // page + 1
-        slab = (L, 1, page, Hkv, D)
 
         def one_slot(s, pools):
             first, at = lengths[s] // page, lengths[s] % page
@@ -110,6 +130,8 @@ class KVCache(NamedTuple):
                               table[s, logical], 0)
 
             def into(pool, rows):
+                L, _, _, Hkv, D = pool.shape
+                slab = (L, 1, page, Hkv, D)
                 old = jnp.concatenate(
                     [jax.lax.dynamic_slice(pool, (0, pages[i], 0, 0, 0),
                                            slab) for i in range(touch)],
@@ -125,9 +147,9 @@ class KVCache(NamedTuple):
                         (0, pages[i], 0, 0, 0))
                 return pool
 
-            return into(pools[0], rows_k), into(pools[1], rows_v)
+            return tuple(into(p, r) for p, r in zip(pools, rows))
 
-        return jax.lax.fori_loop(0, slots, one_slot, (pool_k, pool_v))
+        return jax.lax.fori_loop(0, slots, one_slot, tuple(pools))
 
     @staticmethod
     def create(num_layers, batch, max_len, num_kv_heads, head_dim,

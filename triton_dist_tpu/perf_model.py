@@ -1243,7 +1243,7 @@ _RAGGED_DOT_HBM_SHARE = 0.2
 def estimate_hybrid_step_ms(cfg, n_tokens: int, kv_tokens: int = 0,
                             chip: Optional[ChipSpec] = None) -> float:
     """`estimate_serve_step_ms` for the hybrid family
-    (models/qwen3_next.py), from ITS sizes: the weight stream is every
+    (models/hybrid.py), from ITS sizes and block kinds: the weight stream is every
     expert the chip holds (each is read when any token takes it, and a
     step's tokens take nearly all), the mixers by kind and the head;
     the operations are those of the experts a token is routed to HERE
@@ -1258,29 +1258,50 @@ def estimate_hybrid_step_ms(cfg, n_tokens: int, kv_tokens: int = 0,
     every candidate, so the largest wins."""
     chip = chip or detect_chip()
     b = _dtype_bytes(cfg.dtype)
-    L, h = cfg.num_layers, cfg.hidden_size
-    lf = cfg.num_kv_layers
-    ll = L - lf
+    h = cfg.hidden_size
+    mixers = cfg.mixer_kinds
+    lf, lm = cfg.num_kv_layers, cfg.num_moe_layers
+    ll = cfg.num_layers - lf
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
-    hqd, kwd = cfg.num_q_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
+    hq = cfg.num_q_heads
     held = cfg.num_experts_held
     expert = 3 * h * cfg.moe_intermediate_size
     shared = 3 * h * cfg.shared_expert_intermediate_size + h
-    gdn = h * (2 * hk * dk + 2 * hv * dv + 2 * hv) + hv * dv * h
-    attn = h * (2 * hqd + 2 * kwd) + hqd * h
+    dense_mlp = 3 * h * cfg.intermediate_size
     router = h * cfg.num_experts
-    w_params = (L * (shared + router) + ll * gdn + lf * attn
-                + h * cfg.vocab_size)
+    # a mixer's projections by kind, and what an attention block reads
+    # of a cached position and multiplies a (query, position) pair by
+    rank = cfg.linear_gate_rank
+    row = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    mixer = {
+        "gdn": h * (2 * hk * dk + 2 * hv * dv + 2 * hv) + hv * dv * h,
+        "kda": h * (3 * hv * dk + 2 * rank + hv) + 2 * rank * hv * dk
+        + hv * dv * h,
+        "gated_attn": h * 2 * cfg.head_dim * (hq + cfg.num_kv_heads)
+        + hq * cfg.head_dim * h,
+        "mla": h * (hq * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+                    + row) + cfg.kv_lora_rank * hq * (
+            cfg.qk_nope_head_dim + cfg.v_head_dim) + hq * cfg.v_head_dim * h,
+    }
+    mixers_w = sum(mixer[kind] for kind in mixers)
+    if cfg.kv_lora_rank:
+        kv_row, pair = row, hq * (row + cfg.kv_lora_rank)
+    else:
+        kv_row = 2 * cfg.num_kv_heads * cfg.head_dim
+        pair = 2 * hq * cfg.head_dim
+    w_params = (lm * (shared + router) + cfg.first_k_dense * dense_mlp
+                + mixers_w + h * cfg.vocab_size)
     state = ll * hv * dk * dv * 4 * 2  # read and written, every slot's
-    mem_ms = (L * held * expert * b / _RAGGED_DOT_HBM_SHARE + w_params * b
-              + 2 * lf * kwd * kv_tokens * b
+    mem_ms = (lm * held * expert * b / _RAGGED_DOT_HBM_SHARE + w_params * b
+              + lf * kv_row * kv_tokens * b
               + state) / (chip.hbm_gbps * 1e9) * 1e3
     routed = cfg.num_experts_per_tok * held / cfg.num_experts * expert
-    per_token = (L * (routed + shared + router) + ll * gdn + lf * attn
+    per_token = (lm * (routed + shared + router)
+                 + cfg.first_k_dense * dense_mlp + mixers_w
                  + h * cfg.vocab_size)
     flops = 2.0 * n_tokens * per_token \
-        + 4.0 * n_tokens * kv_tokens * lf * hqd \
+        + 2.0 * n_tokens * kv_tokens * lf * pair \
         + 4.0 * n_tokens * ll * hv * dk * dv
     compute_ms = flops / (
         chip.bf16_tflops * 1e12 * 0.85

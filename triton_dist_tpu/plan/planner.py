@@ -688,13 +688,18 @@ def plan_ep_chunks(m: int, hidden: int, inter: int, e_loc: int, n: int,
 
 
 def route_gated_attention(b: int, s: int, t: int, hq: int, hkv: int,
-                          d: int, dtype) -> str:
-    """The attention implementation of the hybrid family's full
-    attention blocks (models/qwen3_next.py), decided once when its
-    serve step is built and never left to fall through. On the chip
-    that is the Pallas flash-prefill kernel ("pallas") or an error: a
-    head shape the kernel does not take, per-grid-step residents
-    over the VMEM ceiling, or a step of one query row (which
+                          d: int, dtype,
+                          v_prefix: Optional[int] = None) -> str:
+    """The attention implementation of the hybrid family's softmax
+    blocks (models/hybrid.py), decided once when its serve step is
+    built and never left to fall through: the gated full attention
+    (`hq` / `hkv` heads of size `d`), and with `v_prefix` the latent
+    one (layers/latent_attn.py: `hq` query heads of width `d` over ONE
+    shared head whose first `v_prefix` columns are the values, the
+    heads stacked into the rows and each latent page read once). On
+    the chip that is the Pallas flash-prefill kernel ("pallas") or an
+    error: a head shape the kernel does not take, per-grid-step
+    residents over the VMEM ceiling, or a step of one query row (which
     `gqa_attention` never hands to the kernel) would otherwise compile
     a dense (S, T) float32 logits chain in silence. Under the
     interpreter (the CPU mesh of the tests) it is the XLA formulation,
@@ -707,23 +712,38 @@ def route_gated_attention(b: int, s: int, t: int, hq: int, hkv: int,
 
     if use_interpret():
         return "xla"
+    what = (f"{hq} q / {hkv} kv heads of size {d}" if v_prefix is None
+            else f"{hq} q heads over a latent row of {d} with {v_prefix} "
+                 "value columns")
     if s < 2:
         raise NotImplementedError(
             "one query row never reaches flash-prefill (gqa_attention "
-            "takes it through the dense chain), and the gated attention "
-            "blocks have no other route on the chip: the family's serve "
+            "takes it through the dense chain), and the hybrid family's "
+            "attention blocks have no other route on the chip: its serve "
             "step keeps the chunk's width (Engine.serve_widths)")
-    if not supports_flash_prefill(hq, hkv, d):
+    if not supports_flash_prefill(hq, hkv, d, v_prefix=v_prefix):
         raise NotImplementedError(
-            f"flash-prefill does not take {hq} q / {hkv} kv heads of size "
-            f"{d}, and the gated attention blocks have no other route on "
-            "the chip")
-    if not flash_prefill_fits(s, t, hq, hkv, d, dtype=dtype):
+            f"flash-prefill does not take {what}, and the hybrid family's "
+            "attention blocks have no other route on the chip")
+    if not flash_prefill_fits(s, t, hq, hkv, d, dtype=dtype,
+                              v_prefix=v_prefix):
         raise NotImplementedError(
-            f"flash-prefill's residents for {s} rows x {hq} heads of {d} "
-            f"over {t} positions pass the VMEM ceiling, and the gated "
+            f"flash-prefill's residents for {s} rows of {what} over {t} "
+            "positions pass the VMEM ceiling, and the hybrid family's "
             "attention blocks have no other route on the chip")
     return "pallas"
+
+
+def route_hybrid_attention(cfg, b: int, s: int, t: int) -> str:
+    """The one routing decision of a hybrid configuration's serve
+    step: its attention blocks' implementation, from the widths of
+    their kind (a latent family's one page array is its one head)."""
+    if cfg.kv_lora_rank:
+        (hkv, d), = cfg.page_arrays
+        return route_gated_attention(b, s, t, cfg.num_q_heads, hkv, d,
+                                     cfg.dtype, v_prefix=cfg.kv_lora_rank)
+    return route_gated_attention(b, s, t, cfg.num_q_heads,
+                                 cfg.num_kv_heads, cfg.head_dim, cfg.dtype)
 
 
 def route_prefill_impl(b: int, s: int, t: int, hq: int, hkv: int,

@@ -43,10 +43,17 @@ null-page / leak / alias assertions: every page's refcount must equal
 its holder count (slot table occurrences + external holds), and the
 free list is exactly the refcount-0 pages.
 
-A second kind of state (the hybrid family, models/qwen3_next.py): pages
-hold the keys and values of the full-attention blocks only, and every
-delta-net block keeps PER-SLOT state beside them — `rec`
-(Ll, slots, Hv, dk, dv) float32 and `conv` (Ll, slots, K-1, channels).
+What a page keeps of a token is the family's
+(`ModelConfig.page_arrays`): keys and values a kv head in two pools,
+or, for a family with latent attention, ONE row a token in one pool
+`k` (Lf, P, page, 1, W) from which keys and values both come; `v` is
+then None and nothing is allocated in its place.
+
+A second kind of state (the hybrid family, models/hybrid.py): pages
+hold what the attention blocks keep only, and every delta-net block
+keeps PER-SLOT state beside them — `rec`
+(Ll, slots, Hv, dk, dv) float32 and `conv` (Ll, slots, K-1, channels),
+their shapes the family's (`models.hybrid.state_shapes`).
 It needs no allocator: a slot's life covers it, because the serve step
 starts a slot whose length is 0 from zero state and leaves the state of
 a slot with no valid column as it was; admit, eviction with re-prefill
@@ -109,22 +116,24 @@ class KVPool:
         assert self.capacity >= 1, "pool needs at least one page"
 
         n = int(engine.mesh.shape[engine.axis])
-        hkv = cfg.num_kv_heads // n * n
         dt = jnp.dtype(cfg.dtype)
-        shape = (cfg.num_kv_layers, 1 + self.capacity, page, hkv,
-                 cfg.head_dim)
         sharding = NamedSharding(engine.mesh,
                                  P(None, None, None, engine.axis, None))
-        # zeros created IN the sharding: never whole on one device
-        self.k = jnp.zeros(shape, dt, device=sharding)
-        self.v = jnp.zeros(shape, dt, device=sharding)
+        # one pool an array of the family's page (module doc); zeros
+        # created IN the sharding: never whole on one device
+        pools = [jnp.zeros((cfg.num_kv_layers, 1 + self.capacity, page,
+                            heads // n * n, width), dt, device=sharding)
+                 for heads, width in cfg.page_arrays]
+        self.k, self.v = pools if len(pools) == 2 else (pools[0], None)
+        # pool bytes of one position, all page layers together
+        self.kv_bytes_per_token = cfg.num_kv_layers * cfg.kv_bytes_per_token
         # the delta-net blocks' per-slot state (module doc)
         self.rec = self.conv = None
         self.state_bytes_per_slot = 0
         if cfg.is_hybrid:
-            from triton_dist_tpu.models import qwen3_next
+            from triton_dist_tpu.models import hybrid
 
-            rec, conv = qwen3_next.state_shapes(cfg, slots)
+            rec, conv = hybrid.state_shapes(cfg, slots)
             here = NamedSharding(engine.mesh, P())
             self.rec = jnp.zeros(rec, jnp.float32, device=here)
             self.conv = jnp.zeros(conv, dt, device=here)
@@ -145,16 +154,16 @@ class KVPool:
     def state(self):
         """Everything the serve step carries, as ONE pytree (the step's
         `cache` argument and third result)."""
+        pages = KVCache(self.k, self.v, None).pools
         if self.rec is None:
-            return (self.k, self.v)
-        return (self.k, self.v, self.rec, self.conv)
+            return pages
+        return pages + (self.rec, self.conv)
 
     @state.setter
     def state(self, new) -> None:
-        if self.rec is None:
-            self.k, self.v = new
-        else:
-            self.k, self.v, self.rec, self.conv = new
+        if self.rec is not None:
+            *new, self.rec, self.conv = new
+        self.k, self.v = new if len(new) == 2 else (new[0], None)
 
     def _pages_only(self, what: str) -> None:
         if self.rec is not None:

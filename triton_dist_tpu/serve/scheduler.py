@@ -628,10 +628,15 @@ class Scheduler:
             rows[req.state.value] += n
         for state, n in rows.items():
             self.obs.inc("serve_rows", n, state=state)
-        self.obs.inc("serve_kv_tokens_live",
-                     self.pool.live_tokens(p[0] for p in plans))
-        self.obs.inc("serve_kv_tokens_gathered",
-                     self.pool.dense_view_tokens())
+        live = self.pool.live_tokens(p[0] for p in plans)
+        gathered = self.pool.dense_view_tokens()
+        self.obs.inc("serve_kv_tokens_live", live)
+        self.obs.inc("serve_kv_tokens_gathered", gathered)
+        # the same positions in pool bytes, all page layers together:
+        # a latent row and a key-value row read in one unit
+        per_token = self.pool.kv_bytes_per_token
+        self.obs.inc("serve_kv_bytes_live", per_token * live)
+        self.obs.inc("serve_kv_bytes_gathered", per_token * gathered)
         per_slot = self.pool.state_bytes_per_slot
         if per_slot:
             # the hybrid family: the per-slot state beside the pages
@@ -649,7 +654,7 @@ class Scheduler:
             self.obs.inc("moe_pairs", stats["moe_pairs_absent"],
                          held="absent")
             self.obs.inc("moe_expert_steps",
-                         cfg.num_layers * cfg.num_experts_held)
+                         cfg.num_moe_layers * cfg.num_experts_held)
 
     def _attempt_with_backoff(self, retry_span, body):
         """The retrying half of the degradation ladder: run `body` with
