@@ -229,6 +229,7 @@ def reference_logits(eng, prompts, served):
     from jax.sharding import PartitionSpec as P
 
     from triton_dist_tpu.models.dense import (
+        ALL_COLS,
         cache_specs,
         forward,
         param_specs,
@@ -241,7 +242,7 @@ def reference_logits(eng, prompts, served):
 
     def per_rank(params, tokens, cache, first):
         logits, _ = forward(cfg, params, tokens, cache, mode="xla",
-                            axis=axis, return_full_logits=True,
+                            axis=axis, head_cols=ALL_COLS,
                             attn_impl="xla")
         return jax.lax.dynamic_slice_in_dim(logits[0], first, NEW_TOKENS)
 

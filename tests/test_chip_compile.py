@@ -224,19 +224,39 @@ def _pool_moves_once(text, depth, slots, max_len, page, hkv, d):
                 if v in text]
 
 
+def _one_row_of_logits_a_slot(text, slots, width, vocab, tp=1):
+    """The one-emission step's head reads one hidden row a slot
+    (ISSUE 36): the compiled program holds (slots, V) float32 logits
+    and no array with every row's, `f32[slots,width,...]` over the
+    vocabulary, a chip's share of it, or anything wider than a model
+    width (the all-rows form holds `f32[8,128,151936]`, at tp=4 also
+    `f32[8,128,37984]` and four pieces `f32[8,128,30464]`)."""
+    import re
+
+    assert f"f32[{slots},{vocab}]" in text
+    if width > 1:
+        wide = set(map(int, re.findall(
+            rf"f32\[{slots},{width},(\d+)\]", text)))
+        assert not [v for v in wide
+                    if v in (vocab, vocab // tp) or v > 20_000], wide
+
+
 @pytest.mark.parametrize("width", [1, 128])
 @pytest.mark.parametrize("tp", [1, 4], ids=["qwen3-8b.1chip", "qwen3-8b.tp4"])
 def test_serve_step_moves_the_pool_s_bytes_once(chip, tp, width):
     """Both serve programs of both dense configurations (8 slots,
     64-token pages) hold no copy of the pool and no whole view
     (`_pool_moves_once`), and the decode-only one keeps nothing of a
-    view's size alive from layer to layer."""
+    view's size alive from layer to layer; neither holds more than one
+    row of logits a slot (`_one_row_of_logits_a_slot`)."""
     eng = _engine(chip, tp)
     cfg, slots, page = eng.cfg, 8, 64
     compiled = _serve_step_compiled(eng, slots, width, page)
     hkv = cfg.num_kv_heads // tp
     _pool_moves_once(compiled.as_text(), cfg.num_layers, slots, MAX_LEN,
                      page, hkv, cfg.head_dim)
+    _one_row_of_logits_a_slot(compiled.as_text(), slots, width,
+                              cfg.vocab_size, tp)
     if width == 1:
         one_layer_s_view = 2 * slots * MAX_LEN * hkv * cfg.head_dim * 2
         assert (compiled.memory_analysis().temp_size_in_bytes
@@ -308,6 +328,7 @@ def test_latent_serve_step_one_chip(chip):
     assert launch["widths"] == (640, 512) and launch["streams"] == 1
     text = compiled.as_text()
     assert "bf16[3,1025,64,1,640]" in text  # the one latent pool
+    _one_row_of_logits_a_slot(text, slots, 128, cfg.vocab_size)
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
@@ -326,6 +347,7 @@ def test_hybrid_serve_step_one_chip(chip):
     compiled = _hybrid_step_compiled(chip, cfg, slots, page, max_len)
     assert _kernels(compiled) == {"_fp_local_kernel": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    _one_row_of_logits_a_slot(compiled.as_text(), slots, 128, cfg.vocab_size)
     _pool_moves_once(compiled.as_text(), cfg.num_kv_layers, slots, max_len,
                      page, cfg.num_kv_heads, cfg.head_dim)
 

@@ -375,6 +375,24 @@ def test_prefill_in_chunks_then_decode_agrees_with_the_reference(
         assert list(np.argmax(want, -1)) == list(r.out_tokens)
 
 
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+def test_the_head_reads_the_row_a_slot_emits_from(eng, sampled):
+    """The step takes each slot's hidden row at column `n_valid - 1`
+    before the final norm and the head (ISSUE 36): bit for bit the
+    all-rows form with the column taken afterwards, `n_valid` in
+    {0, 1, 3, chunk}."""
+    from _head_rows import check_hybrid_step
+
+    check_hybrid_step(eng, sampled)
+
+
+def test_the_lowered_step_holds_one_row_of_logits_a_slot(cfg, mesh1):
+    from _head_rows import check_hybrid_lowering
+
+    check_hybrid_lowering(cfg, mesh1)
+
+
 def test_seed_names_the_same_weights_in_program_and_reference(eng, weights):
     assert set(weights) == set(eng.params)
     for name, leaf in eng.params.items():

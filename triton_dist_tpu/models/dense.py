@@ -304,6 +304,10 @@ def _layer_fwd(cfg: ModelConfig, spec: TPAttnSpec, cos, sin, positions,
     return x, rows
 
 
+# `forward_rows(head_cols=ALL_COLS)`: the head reads every column
+ALL_COLS = "all"
+
+
 def forward_rows(
     cfg: ModelConfig,
     params: DenseLLMParams,
@@ -311,14 +315,21 @@ def forward_rows(
     cache: Optional[KVCache],  # per-rank head shards
     mode: str = "dist",
     axis: str = TP_AXIS,
-    return_full_logits: bool = False,
+    head_cols=None,
     attn_impl: Optional[str] = None,
     plan: Optional[Plan] = None,
 ):
     """Per-device forward (inside shard_map) over a cache it only
     READS — dense or paged, each layer through `cache.layer_view`.
-    Returns (logits, (k, v)): logits (B, V) for the last position (or
-    (B, S, V) if return_full_logits), and the step's new K/V rows
+    Returns (logits, (k, v)). `head_cols` says which rows the head
+    reads: None, the last column of every row of the batch; a (B,)
+    int32 array, one column a row (the serve step's `n_valid - 1`);
+    `ALL_COLS`, every one. The one-column forms take their (B, H)
+    hidden rows BEFORE the final norm and the vocabulary projection,
+    so the norm, the head and the tp all-gather of the logits work on
+    B rows and return (B, V) f32; `ALL_COLS` returns (B, S, V) (the
+    per-position serve step, a teacher-forced reference). (k, v) are
+    the step's new K/V rows
     (L, B, S, Hkv, D) for positions cache.length .. + S — what leaves
     the layer scan is the rows, never the (B, T, Hkv, D) views they
     were laid into: `forward` lays them into a KVCache, the serve step
@@ -370,19 +381,25 @@ def forward_rows(
         step, x, (jnp.arange(cfg.num_layers), lp_local))
 
     x = plan_exec.gather_tokens(x, axis, plan)  # (M, H) when sharded
+    every_col = isinstance(head_cols, str)
+    if every_col and head_cols != ALL_COLS:
+        raise ValueError(f"head_cols {head_cols!r}: None, a (B,) int32 "
+                         "array or ALL_COLS")
+    if not every_col:
+        col = s - 1 if head_cols is None else head_cols
+        x = x[jnp.arange(b) * s + col]  # (B, H): the rows the head reads
     x = rms_norm(x, params.final_ln, cfg.rms_eps)
-    x = x.reshape(b, s, h_dim)
-    if not return_full_logits:
-        x = x[:, -1:]
+    if every_col:
+        x = x.reshape(b, s, h_dim)
     head = params.lm_head[0]  # strip n dim
     # bf16 operands + f32 accumulation: avoids materialising an f32 copy
     # of the (H, V/n) head shard (the MXU accumulates in f32 natively).
     logits = jnp.einsum(
-        "bsh,hv->bsv", x, head, preferred_element_type=jnp.float32
+        "...h,hv->...v", x, head, preferred_element_type=jnp.float32
     )
-    logits = jax.lax.all_gather(logits, axis, axis=2, tiled=True)  # (B,S,V)
-    if not return_full_logits:
-        logits = logits[:, 0]
+    # (B, V), or (B, S, V) for ALL_COLS
+    logits = jax.lax.all_gather(logits, axis, axis=logits.ndim - 1,
+                                tiled=True)
     return logits, rows
 
 

@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.dense import (
+    ALL_COLS,
     DenseLLMParams,
     cache_specs,
     forward,
@@ -57,7 +58,9 @@ def _serve_step_math(cfg, mode, axis, params, tokens, pool_k, pool_v,
     no prefill route).
 
     per_pos=False: keys (K, 2) u32, the returned token is sampled at
-    column n_valid-1 only — the classic one-emission step. per_pos=True
+    column n_valid-1 only — the classic one-emission step, which hands
+    the final norm and the head that ONE hidden row a slot
+    (`forward_rows(head_cols=)`): its only logits are `last`. per_pos=True
     (the spec-verify form, ISSUE 14): keys (K, C, 2) — EVERY column is
     sampled under its own key and the returned token array is (K, C);
     column j's token is what sequential decode would emit after
@@ -66,37 +69,47 @@ def _serve_step_math(cfg, mode, axis, params, tokens, pool_k, pool_v,
     bit-identity oracle the longest-accepted-prefix rule needs
     (triton_dist_tpu.spec.verify)."""
     cache = KVCache(pool_k, pool_v, lengths, table)  # paged: read in place
-    logits, (k_rows, v_rows) = forward_rows(
-        cfg, params, tokens, cache, mode=mode, axis=axis,
-        return_full_logits=True, plan=plan,
-    )  # logits (K, C, V) f32, rows (L, K, C, Hkv, D)
-    tok, last = _sample_step(logits, n_valid, temps, keys, per_pos)
+    kw = dict(mode=mode, axis=axis, plan=plan)
+    # rows (L, K, C, Hkv, D) either way
+    if per_pos:  # every row through the head: logits (K, C, V) f32
+        logits, (k_rows, v_rows) = forward_rows(
+            cfg, params, tokens, cache, head_cols=ALL_COLS, **kw)
+        tok, last = _sample_every_col(logits, n_valid, temps, keys)
+    else:  # ONE hidden row a slot through the head: last (K, V) f32
+        last, (k_rows, v_rows) = forward_rows(
+            cfg, params, tokens, cache,
+            head_cols=jnp.maximum(n_valid - 1, 0), **kw)
+        tok = _sample_last(last, temps, keys)
     pool_k, pool_v = KVCache.scatter_step(
         (pool_k, pool_v), (k_rows, v_rows), table, lengths, n_valid)
     return tok, last, pool_k, pool_v
 
 
-def _sample_step(logits, n_valid, temps, keys, per_pos: bool):
-    """A step's tokens from its (K, C, V) logits: (tok, last) with
-    `last` the (K, V) logits at column n_valid - 1 (see
-    `_serve_step_math` for `per_pos`)."""
+def _sample_last(last, temps, keys):
+    """The one-emission step's (K,) tokens from `last`, the (K, V)
+    logits of each slot's column n_valid - 1: the only logits that
+    step holds."""
+    greedy = jnp.argmax(last, -1).astype(jnp.int32)
+    temp = jnp.maximum(temps, 1e-6)[:, None]
+    sampled = jax.vmap(jax.random.categorical)(
+        keys, last / temp
+    ).astype(jnp.int32)
+    return jnp.where(temps > 0.0, sampled, greedy)
+
+
+def _sample_every_col(logits, n_valid, temps, keys):
+    """The per-position step's (tok, last) from its (K, C, V) logits:
+    (K, C) tokens, every column under its own key, and the (K, V)
+    logits at column n_valid - 1 (see `_serve_step_math`)."""
     slots = logits.shape[0]
     last = logits[jnp.arange(slots),
                   jnp.maximum(n_valid - 1, 0)]  # (K, V)
-    if per_pos:
-        greedy_all = jnp.argmax(logits, -1).astype(jnp.int32)  # (K, C)
-        temp = jnp.maximum(temps, 1e-6)[:, None, None]
-        sampled_all = jax.vmap(jax.vmap(jax.random.categorical))(
-            keys, logits / temp
-        ).astype(jnp.int32)
-        tok = jnp.where(temps[:, None] > 0.0, sampled_all, greedy_all)
-    else:
-        greedy = jnp.argmax(last, -1).astype(jnp.int32)
-        temp = jnp.maximum(temps, 1e-6)[:, None]
-        sampled = jax.vmap(jax.random.categorical)(
-            keys, last / temp
-        ).astype(jnp.int32)
-        tok = jnp.where(temps > 0.0, sampled, greedy)
+    greedy_all = jnp.argmax(logits, -1).astype(jnp.int32)  # (K, C)
+    temp = jnp.maximum(temps, 1e-6)[:, None, None]
+    sampled_all = jax.vmap(jax.vmap(jax.random.categorical))(
+        keys, logits / temp
+    ).astype(jnp.int32)
+    tok = jnp.where(temps[:, None] > 0.0, sampled_all, greedy_all)
     return tok, last
 
 
@@ -111,10 +124,10 @@ def _hybrid_step_math(cfg, attn_impl, params, tokens, cache, table,
     from triton_dist_tpu.models import hybrid
 
     *pages, rec, conv = cache
-    logits, rows, rec, conv, stats = hybrid.forward_chunk(
+    last, rows, rec, conv, stats = hybrid.forward_chunk(
         cfg, params, tokens, hybrid.Cache(tuple(pages), rec, conv), table,
-        lengths, n_valid, attn_impl)
-    tok, last = _sample_step(logits, n_valid, temps, keys, False)
+        lengths, n_valid, attn_impl)  # last (K, V) f32
+    tok = _sample_last(last, temps, keys)
     pages = KVCache.scatter_step(pages, rows, table, lengths, n_valid)
     return tok, last, (*pages, rec, conv), stats
 

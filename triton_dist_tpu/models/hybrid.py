@@ -290,9 +290,32 @@ def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                   table, lengths, n_valid, attn_impl: str):
     """One (slots, chunk) block through the model. Slot s holds
     `lengths[s]` cached positions and `n_valid[s]` real columns.
-    Returns (logits (K, C, V) float32, the chunk's new rows of the
-    attention blocks, one (Lf, K, C, Hkv, D) an array of the pages,
-    rec, conv, {counter: () int32})."""
+    Returns (last (K, V) float32, the logits of each slot's column
+    `n_valid - 1` (column 0 where `n_valid` is 0): the (K, H) hidden
+    rows are taken BEFORE the final norm and the head, which see K
+    rows and never the chunk's K x C; then what `chunk_hidden`
+    returns after its hidden rows)."""
+    x, *rest = chunk_hidden(cfg, params, tokens, cache, table, lengths,
+                            n_valid, attn_impl)
+    x = x[jnp.arange(x.shape[0]), jnp.maximum(n_valid - 1, 0)]  # (K, H)
+    return head_logits(cfg, params, x), *rest
+
+
+def head_logits(cfg: ModelConfig, params: dict, x):
+    """The final norm and the vocabulary projection over hidden rows
+    (..., H): float32 logits (..., V)."""
+    x = rms_norm(x, params["final_ln"], cfg.rms_eps,
+                 zero_centred=cfg.norm_zero_centred)
+    return jnp.einsum("...h,hv->...v", x, params["lm_head"],
+                      preferred_element_type=jnp.float32)
+
+
+def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
+                 table, lengths, n_valid, attn_impl: str):
+    """The blocks of `forward_chunk`: (hidden rows (K, C, H) before
+    the final norm; the chunk's new rows of the attention blocks, one
+    (Lf, K, C, Hkv, D) an array of the pages; rec; conv;
+    {counter: () int32})."""
     slots, chunk = tokens.shape
     g, a, m = gdn_spec(cfg), attn_spec(cfg), latent_spec(cfg)
     eps = cfg.rms_eps
@@ -410,12 +433,9 @@ def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
     conv = joined([o[1] for o in outs], True)
     rows = tuple(joined([o[2][k] for o in outs if o[2]], False)
                  for k in range(len(cache.pages)))
-    x = normed(x, params["final_ln"])
-    logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
     stats = {"moe_pairs_here": sum(jnp.sum(o[3]) for o in outs),
              "moe_pairs_absent": sum(jnp.sum(o[4]) for o in outs)}
-    return logits, rows, rec, conv, stats
+    return x, rows, rec, conv, stats
 
 
 def state_shapes(cfg: ModelConfig, slots: int):

@@ -623,6 +623,10 @@ class Scheduler:
         # any other of the worker's widths
         self.obs.inc("serve_steps",
                      shape="wide" if width == self.chunk else "narrow")
+        # rows the step took through the final norm and the head: one
+        # a slot, or every column of the per-position (spec) step
+        self.obs.inc("serve_head_rows",
+                     self.pool.slots * (width if self.worker.per_pos else 1))
         rows = {"prefill": 0, "decode": 0}
         for _slot, req, n, _emits, _drafts in plans:
             rows[req.state.value] += n
