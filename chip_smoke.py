@@ -13,7 +13,11 @@ head; weights random from --seed):
       them, the hybrid family's latent attention alone at
       Kimi-Linear's widths: 8 slots x 128 columns over 8,192 cached
       rows of 640 on the planner's route, against the expanded form
-      in float32, with cached rows broken on purpose (`latent_phase`).
+      in float32, with cached rows broken on purpose (`latent_phase`);
+      then the hybrid family's window and global grouped-query blocks
+      at K-EXAONE's widths through a per-slot tail and a paged view,
+      against plain float32 attention, each broken four ways
+      (`window_phase`).
   --chips 4           the cross-chip path and nothing else: full depth
       (36 layers), tp=4 over the four chips, the same four requests.
 
@@ -30,6 +34,7 @@ anything when JAX's first device is not a TPU. Last line of stdout:
 """
 
 import argparse
+import functools
 import json
 import statistics
 import sys
@@ -525,6 +530,184 @@ def latent_phase(seed: int, max_len: int = 8192) -> None:
             f"and the broken caches' {one_page:.4f}, {every:.4f} over it")
 
 
+# Window and global attention on the chip: 8 slots x 128 columns at
+# K-EXAONE's widths, one window block through its per-slot tail and one
+# global block through a view of 8,192 positions. Lengths and valid
+# columns a slot: a full view, odd lengths, decode rows of one valid
+# column, a slot younger than the window, a fresh slot.
+WINDOW_LENS = (8192, 6001, 4097, 2049, 1025, 513, 180, 128)
+WINDOW_VALID = (128, 128, 1, 128, 1, 128, 72, 128)
+# Largest difference from plain float32 attention, as a share of its
+# largest output: what bf16 leaves against what a cache broken on
+# purpose gives (window_phase prints each).
+WINDOW_TOL = 0.04
+
+
+def window_phase(seed: int, max_len: int = 8192) -> None:
+    """`layers.gqa_attn.window_attn_fwd` and `global_attn_fwd` on the
+    routes the planner names for the chip, at K-EXAONE's widths,
+    against plain attention in float32 at `highest` written out here
+    (one slot at a time over the slot's whole timeline, the window a
+    mask, rotary in the window block alone): what the benchmark's
+    `correct` may not see under random weights. Broken on purpose, each
+    has to read over the tolerance: the tail zeroed, the window one key
+    too wide, rotary on the global block, the global block's cached
+    pages zeroed."""
+    import jax
+    import jax.numpy as jnp
+
+    from triton_dist_tpu.layers import gqa_attn
+    from triton_dist_tpu.layers.rope import apply_rope, rope_table
+    from triton_dist_tpu.models import ModelConfig
+    from triton_dist_tpu.plan.planner import (
+        route_hybrid_attention,
+        route_window_attention,
+    )
+
+    cfg = ModelConfig.k_exaone_236b(num_layers=8, max_positions=max_len)
+    spec = gqa_attn.GQAttnSpec(cfg.num_q_heads, cfg.num_kv_heads,
+                               cfg.head_dim)
+    hq, hkv, d = spec
+    win, hidden, cols = cfg.sliding_window, cfg.hidden_size, 128
+    slots = len(WINDOW_LENS)
+    lens = np.minimum(WINDOW_LENS, max_len)
+    valid = np.minimum(WINDOW_VALID, lens)
+    routes = {"global": route_hybrid_attention(cfg, slots, cols, max_len),
+              "window": route_window_attention(cfg, slots, cols)}
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+    shapes = ((hidden, hq * d), (hidden, 2 * hkv * d), (hq * d, hidden))
+    w_q, w_kv, w_o = ((0.02 * jax.random.normal(k, s, f32)).astype(bf16)
+                      for k, s in zip(keys, shapes))
+    p = gqa_attn.GQAttnParams(w_q, w_kv, jnp.ones((d,), bf16),
+                              jnp.ones((d,), bf16), w_o)
+    x = jax.random.normal(keys[3], (slots, cols, hidden), f32).astype(bf16)
+    # what every earlier position left in the cache: keys as stored
+    # (normed, so of unit size a value, three times over: a row attends
+    # few keys), values of unit size
+    cached = tuple(
+        (scale * jax.random.normal(k, (slots, max_len, hkv, d), f32)
+         ).astype(bf16) for k, scale in zip(keys[4:6], (3.0, 1.0)))
+    start = jnp.asarray(lens - valid, jnp.int32)
+    pos = start[:, None] + jnp.arange(cols)[None, :]
+    kv_len, n_valid = (jnp.asarray(a, jnp.int32) for a in (lens, valid))
+    here = jnp.arange(max_len)[None, :] < start[:, None]
+    # past a slot's cached rows lies what another request left there
+    view = tuple(jnp.where(here[..., None, None], c, 50.0).astype(bf16)
+                 for c in cached)
+    # the tail: row t holds position start - window + t, and whatever
+    # the slot's last tenant left where that is negative
+    at = start[:, None] - win + jnp.arange(win)[None, :]
+    tail = tuple(jnp.where(
+        (at >= 0)[..., None, None],
+        jnp.take_along_axis(c, jnp.maximum(at, 0)[..., None, None], axis=1),
+        50.0).astype(bf16) for c in cached)
+    cos, sin = rope_table(d, max_len, cfg.rope_theta)
+
+    def window_fwd(x, p, tail):
+        return gqa_attn.window_attn_fwd(
+            x, p, spec, cos, sin, pos, tail, start, n_valid, win,
+            routes["window"], cfg.rms_eps)[0]
+
+    def global_fwd(x, p, view):
+        return gqa_attn.global_attn_fwd(
+            x, p, spec, pos, view, kv_len, routes["global"], cfg.rms_eps)[0]
+
+    hi = jax.lax.Precision.HIGHEST
+
+    @functools.partial(jax.jit, static_argnums=(5,))
+    def plain(x_i, k_i, v_i, start_i, w, sliding):
+        """One slot: x_i (cols, H); k_i, v_i (max_len, Hkv, D) as cached."""
+        w_q, w_kv, w_o = (a.astype(f32) for a in w)
+        xs = x_i.astype(f32)
+
+        def normed(a):
+            return a * jax.lax.rsqrt(
+                jnp.mean(a * a, -1, keepdims=True) + cfg.rms_eps)
+
+        q = normed(jnp.dot(xs, w_q, precision=hi).reshape(cols, hq, d))
+        kv = jnp.dot(xs, w_kv, precision=hi)
+        k = normed(kv[:, :hkv * d].reshape(cols, hkv, d))
+        v = kv[:, hkv * d:].reshape(cols, hkv, d)
+        mine = start_i + jnp.arange(cols)
+        if sliding:
+            q, k = (apply_rope(a, cos, sin, mine) for a in (q, k))
+        # the cache holds what the program stored: bfloat16 rows
+        k_all = jax.lax.dynamic_update_slice(
+            k_i.astype(f32), k.astype(bf16).astype(f32), (start_i, 0, 0))
+        v_all = jax.lax.dynamic_update_slice(
+            v_i.astype(f32), v.astype(bf16).astype(f32), (start_i, 0, 0))
+        att = jnp.einsum("sjgd,tjd->jgst",
+                         q.reshape(cols, hkv, hq // hkv, d) * d ** -0.5,
+                         k_all, precision=hi)
+        t = jnp.arange(max_len)[None, :]
+        seen = t <= mine[:, None]
+        if sliding:
+            seen &= t > mine[:, None] - win
+        prob = jax.nn.softmax(jnp.where(seen[None, None], att, -jnp.inf), -1)
+        o = jnp.einsum("jgst,tjd->sjgd", prob, v_all, precision=hi)
+        return jnp.dot(o.reshape(cols, hq * d), w_o, precision=hi)
+
+    def worst(fwd, cache, wants) -> float:
+        """Over the slots' valid columns (the others are discarded)."""
+        got = np.asarray(fwd(x, p, cache).astype(f32))
+        return max(float(np.abs(got[i, :len(want)] - want).max()
+                         / np.abs(want).max())
+                   for i, want in enumerate(wants))
+
+    weights = (w_q, w_kv, w_o)
+    readings = {}
+    for kind, fwd, cache in (("window", window_fwd, tail),
+                             ("global", global_fwd, view)):
+        jitted = jax.jit(fwd)
+        kernels, secs = compile_and_name(jitted, x, p, cache)
+        say(f"{kind} attention: route {routes[kind]!r}, kernels "
+            f"{kernels or 'none'}, compiled in {secs:.1f}s")
+        if routes[kind] == "pallas":
+            require(kernels, ["_fp_local_kernel"], f"{kind} attention")
+        wants = [np.asarray(plain(x[i], cached[0][i], cached[1][i],
+                                  start[i], weights, kind == "window"))[:n]
+                 for i, n in enumerate(valid)]
+        readings[kind] = worst(jitted, cache, wants)
+        zeroed = tuple(jnp.where(here[..., None, None], 0, c).astype(bf16)
+                       for c in cache) if kind == "global" else tuple(
+            jnp.zeros_like(c) for c in cache)
+        readings[f"{kind}, cache zeroed"] = worst(jitted, zeroed, wants)
+        real = gqa_attn.gqa_attention if kind == "window" else gqa_attn._qkv
+        try:  # the model broken underneath, compiled anew
+            if kind == "window":
+                gqa_attn.gqa_attention = lambda *a, window, **kw: real(
+                    *a, window=window + 1, **kw)
+                name = "window, one key too wide"
+            else:
+                def turned(*a):
+                    q, k, v = real(*a)
+                    return (apply_rope(q, cos, sin, pos),
+                            apply_rope(k, cos, sin, pos), v)
+
+                gqa_attn._qkv = turned
+                name = "global, rotary on"
+            # a new function: the same one would find its compiled self
+            readings[name] = worst(jax.jit(lambda *a: fwd(*a)), cache,
+                                   wants)
+        finally:
+            if kind == "window":
+                gqa_attn.gqa_attention = real
+            else:
+                gqa_attn._qkv = real
+    say("window and global attention against plain float32 attention, "
+        "largest difference over largest output (tolerance "
+        f"{WINDOW_TOL}): " + "; ".join(
+            f"{k} {v:.4f}" for k, v in readings.items()))
+    own = max(readings["window"], readings["global"])
+    broken = min(v for k, v in readings.items() if "," in k)
+    if not own < WINDOW_TOL < broken:
+        raise RuntimeError(
+            f"window and global attention: {own:.4f} has to lie under "
+            f"{WINDOW_TOL} and every broken reading ({broken:.4f} the "
+            "least) over it")
+
+
 def run(cfg, mesh, seed: int, prompts, cross_chip: bool) -> None:
     """Every phase on `mesh`. cross_chip: the tp>1 contract (kernels of
     the overlapped collectives by name, no megakernel phase)."""
@@ -612,6 +795,7 @@ def main() -> int:
 
     if args.chips == 1:
         latent_phase(args.seed)
+        window_phase(args.seed)
     run(cfg, make_mesh((args.chips,), ("tp",)), args.seed, prompts,
         cross_chip=args.chips > 1)
 

@@ -84,22 +84,28 @@ def hybrid_all_rows_step(eng, sch):
     per-position step to stand for it)."""
     from triton_dist_tpu.models import hybrid
     from triton_dist_tpu.models.kv_cache import KVCache
-    from triton_dist_tpu.plan.planner import route_hybrid_attention
+    from triton_dist_tpu.plan.planner import (
+        route_hybrid_attention,
+        route_window_attention,
+    )
 
     cfg, pool = eng.cfg, sch.pool
     attn_impl = route_hybrid_attention(cfg, SLOTS, CHUNK,
                                        pool.max_pages * PAGE)
+    window_impl = (route_window_attention(cfg, SLOTS, CHUNK)
+                   if cfg.num_window_layers else None)
 
     def step(params, tokens, cache, table, lengths, n_valid, temps, keys):
-        *pages, rec, conv = cache
-        x, rows, rec, conv, _ = hybrid.chunk_hidden(
-            cfg, params, tokens, hybrid.Cache(tuple(pages), rec, conv),
-            table, lengths, n_valid, attn_impl)
+        cache = hybrid.Cache.of(cfg, cache)
+        x, rows, rec, conv, win, _ = hybrid.chunk_hidden(
+            cfg, params, tokens, cache, table, lengths, n_valid, attn_impl,
+            window_impl)
         logits = hybrid.head_logits(cfg, params, x)
         assert logits.shape == (SLOTS, CHUNK, cfg.vocab_size)
         tok, last = sample_afterwards(logits, n_valid, temps, keys)
-        pages = KVCache.scatter_step(pages, rows, table, lengths, n_valid)
-        return tok, last, (*pages, rec, conv)
+        pages = KVCache.scatter_step(cache.pages, rows, table, lengths,
+                                     n_valid)
+        return tok, last, hybrid.Cache(pages, rec, conv, win).flat()
 
     return jax.jit(step)
 

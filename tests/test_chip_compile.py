@@ -280,9 +280,11 @@ def _hybrid_step_compiled(chip, cfg, slots, page, max_len):
     pools = tuple(SDS((cfg.num_kv_layers, 1 + slots * max_pages, page,
                        heads, width), BF16, sharding=rep)
                   for heads, width in cfg.page_arrays)
-    rec, conv = hybrid.state_shapes(cfg, slots)
-    cache = pools + (SDS(rec, jnp.float32, sharding=rep),
-                     SDS(conv, BF16, sharding=rep))
+    cache = pools + tuple(
+        SDS(shape, dt, sharding=rep) for shape, dt in zip(
+            hybrid.state_shapes(cfg, slots), (jnp.float32, BF16))) + tuple(
+        SDS(shape, BF16, sharding=rep)
+        for shape in hybrid.window_shapes(cfg, slots))
     return eng.make_serve_step(slots, chunk, page, max_pages).lower(
         params, SDS((slots, chunk), jnp.int32, sharding=rep), cache,
         SDS((slots, max_pages), jnp.int32, sharding=rep),
@@ -350,6 +352,51 @@ def test_hybrid_serve_step_one_chip(chip):
     _one_row_of_logits_a_slot(compiled.as_text(), slots, 128, cfg.vocab_size)
     _pool_moves_once(compiled.as_text(), cfg.num_kv_layers, slots, max_len,
                      page, cfg.num_kv_heads, cfg.head_dim)
+
+
+def test_window_and_global_serve_step_one_chip(chip):
+    """K-EXAONE's pattern at the benchmark's widths, depth and geometry
+    (blocks 0-7: the leading dense block and two periods `L L L G`; 16
+    experts of 128 held, an eighth of the vocabulary; 8 slots x the
+    chooser's 128-token chunk, 64-token pages, 8,192 positions): every
+    attention block runs `_fp_local_kernel`, the six window blocks over
+    their slot's tail and the chunk (256 positions, the window's bound
+    in the kernel), the two global ones over the slot's pages; pages
+    exist for the global blocks alone; the step fits the chip."""
+    from triton_dist_tpu.kernels import flash_prefill
+    from triton_dist_tpu.plan.planner import (
+        route_hybrid_attention,
+        route_window_attention,
+    )
+
+    max_len, slots, page = 8192, 8, 64
+    cfg = ModelConfig.k_exaone_236b(
+        num_layers=8, experts_held=16, vocab_size=19_200,
+        max_positions=max_len)
+    assert route_hybrid_attention(cfg, slots, 128, max_len) == "pallas"
+    assert route_window_attention(cfg, slots, 128) == "pallas"
+    with pytest.raises(NotImplementedError, match="one query row"):
+        route_window_attention(cfg, slots, 1)
+    compiled = _hybrid_step_compiled(chip, cfg, slots, page, max_len)
+    # one body a period and kind: 2 scans x (window, global)
+    assert _kernels(compiled) == {"_fp_local_kernel": 8}
+    launch = flash_prefill.last_launch()  # the last traced: a global block
+    assert launch["widths"] == (128, 128) and launch["streams"] == 2
+    text = compiled.as_text()
+    assert "bf16[2,1025,64,8,128]" in text  # pages: the 2 global blocks'
+    assert "bf16[6,8,128,8,128]" in text  # the 6 window blocks' tails
+    # one row of logits a slot (the helper's bound on other widths does
+    # not hold here: block 0's gate | up is 36,864 wide)
+    assert "f32[8,19200]" in text and "f32[8,128,19200]" not in text
+    mem = compiled.memory_analysis()
+    print(f"k-exaone step: arguments {mem.argument_size_in_bytes} "
+          f"temporaries {mem.temp_size_in_bytes} "
+          f"output {mem.output_size_in_bytes} "
+          f"alias {mem.alias_size_in_bytes}")
+    # the weights 11.96 GB, the pages 0.54, the tails 0.025
+    assert 12.3e9 < mem.argument_size_in_bytes < 12.8e9
+    assert mem.temp_size_in_bytes < 2.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9
 
 
 def test_gated_attention_has_no_silent_route_on_the_chip(chip):

@@ -218,13 +218,19 @@ def flash_prefill_fits(s_q: int, t: int, hq: int, hkv: int, d: int,
 # -- shared fold math (kernel body AND the bit-exact host replay) ------------
 
 
-def _block_live(s: int, blk: int, base, qp_col, valid_len, causal: bool):
+def _block_live(s: int, blk: int, base, qp_col, valid_len, causal: bool,
+                window: Optional[int] = None, valid_from=None):
     """(S, blk) liveness mask of one KV block at global offset `base`:
-    rows are q positions (qp_col (S,1) i32), columns KV positions."""
+    rows are q positions (qp_col (S,1) i32), columns KV positions.
+    With `window` (static) a row at position i also sees no key before
+    i - window + 1, and none before `valid_from`."""
     kpos = jax.lax.broadcasted_iota(jnp.int32, (s, blk), 1) + base
     live = kpos < valid_len
     if causal:
         live = jnp.logical_and(live, kpos <= qp_col)
+    if window is not None:
+        live = jnp.logical_and(live, kpos > qp_col - window)
+        live = jnp.logical_and(live, kpos >= valid_from)
     return live
 
 
@@ -295,8 +301,8 @@ def _q_slabs(qf, hq: int, d: int, scale: float):
 # -- local kernel (n = 1 core; serves blockwise prefill + serve chunks) ------
 
 
-def _fp_local_kernel(hq, hkv, d, s, t, blk, causal, scale, dv,
-                     len_ref, q_ref, qpos_ref, *refs):
+def _fp_local_kernel(hq, hkv, d, s, t, blk, causal, scale, dv, window,
+                     len_ref, *refs):
     """One grid step = `s` query rows (one fit_q_rows tile) of one batch
     row: stream (blk, Hkv*D) KV pages double-buffered from HBM and fold
     each into the per-head online-softmax states (the prefill
@@ -307,8 +313,12 @@ def _fp_local_kernel(hq, hkv, d, s, t, blk, causal, scale, dv,
     them changes no bit of any row's fold; a causal tile also stops at
     ITS last row's page, not the chunk's. With `dv` (the values a
     column prefix of the key page, `flash_prefill_local`'s `v_prefix`)
-    there is no v operand: one stream of pages, each read once."""
-    *pages, o_ref, vkv, sems = refs  # (k_ref, v_ref), or (k_ref,)
+    there is no v operand: one stream of pages, each read once. With
+    `window` a second scalar operand follows the lengths, each batch
+    row's first valid KV position, and `_block_live` takes both."""
+    if window is not None:
+        from_ref, *refs = refs
+    q_ref, qpos_ref, *pages, o_ref, vkv, sems = refs  # pages: (k, v) | (k,)
     b = pl.program_id(0)
     g = hq // hkv
     nblk = t // blk
@@ -345,7 +355,8 @@ def _fp_local_kernel(hq, hkv, d, s, t, blk, causal, scale, dv,
 
         kv_wait(ci % 2)
         kv = vkv[ci % 2].astype(jnp.float32)  # (streams, blk, W)
-        live = _block_live(s, blk, ci * blk, qp_col, valid, causal)
+        live = _block_live(s, blk, ci * blk, qp_col, valid, causal, window,
+                           None if window is None else from_ref[b])
         return tuple(_fold_block_heads(slabs, kv[0], kv[len(pages) - 1], live,
                                        list(states), hkv, g, d, dv))
 
@@ -380,6 +391,8 @@ def flash_prefill_local(
     scale: Optional[float] = None,
     block: Optional[int] = None,
     v_prefix: Optional[int] = None,
+    window: Optional[int] = None,
+    kv_from: Optional[jax.Array] = None,  # (B,) first valid KV position
 ) -> jax.Array:
     """Pallas blockwise (flash) GQA prefill over local KV: same contract
     as layers.attention.gqa_attention_blockwise, but KV streams through
@@ -392,8 +405,13 @@ def flash_prefill_local(
     absorbed form). The pages are then read once, not once as keys and
     once as values; the query heads, which all meet the same page, are
     stacked into the rows (`shared_head_rows`); the result is
-    (B, S, Hq, v_prefix)."""
+    (B, S, Hq, v_prefix).
+
+    `window`: a row at position i attends the keys at i - window + 1
+    .. i alone, and none before `kv_from` (default 0). A static branch:
+    with no window the kernel's body and operands are what they were."""
     global _last_launch
+    assert window is not None or kv_from is None, "kv_from needs a window"
     out_heads = q.shape[2:3] + (v_prefix or q.shape[3],)
     if v_prefix is not None:
         assert v is None and k.shape[2] == 1, (
@@ -434,13 +452,16 @@ def flash_prefill_local(
     qpos = q_positions.astype(jnp.int32).reshape(b, s, 1)
     itemsize = jnp.dtype(k.dtype).itemsize
     dv = v_prefix or d
+    scalars = (len_arr,)
+    if window is not None:
+        scalars += (jnp.zeros((b,), jnp.int32) if kv_from is None
+                    else jnp.reshape(kv_from, (-1,)).astype(jnp.int32),)
     out = tpu_call(
         functools.partial(_fp_local_kernel, hq, hkv, d, tq, t, blk,
-                          causal, scale, v_prefix),
+                          causal, scale, v_prefix, window),
         grid=grid,
         out_shape=jax.ShapeDtypeStruct((b, s, hq * dv), q.dtype),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * len(scalars) + [
             pl.BlockSpec((1, tq, hq * d), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, tq, 1), lambda i, j: (i, j, 0),
@@ -461,7 +482,7 @@ def flash_prefill_local(
             flops=2 * b * s * hq * t * (d + dv),
             bytes_accessed=len(pages) * b * t * w * itemsize,
         ),
-    )(len_arr, q.reshape(b, s, hq * d), qpos,
+    )(*scalars, q.reshape(b, s, hq * d), qpos,
       *(x.reshape(b, t, w) for x in pages))
     return out.reshape((b, -1) + out_heads)
 
@@ -696,6 +717,8 @@ def flash_prefill_ref(
     scale: Optional[float] = None,
     kv_len: Optional[jax.Array] = None,
     block: Optional[int] = None,
+    window: Optional[int] = None,
+    kv_from: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Plain-transport replay of sp_flash_prefill: XLA all_gathers the
     KV shards, then folds segments in the SAME swizzle order through the
@@ -731,8 +754,10 @@ def flash_prefill_ref(
             for j in range(nblk):
                 kpage = kseg[j * blk:(j + 1) * blk].astype(jnp.float32)
                 vpage = vseg[j * blk:(j + 1) * blk].astype(jnp.float32)
-                live = _block_live(s, blk, chunk * s + j * blk, qp_col,
-                                   len_arr[bi], causal)
+                live = _block_live(
+                    s, blk, chunk * s + j * blk, qp_col, len_arr[bi], causal,
+                    window, None if window is None else (
+                        0 if kv_from is None else kv_from[bi]))
                 states = _fold_block_heads(slabs, kpage, vpage, live,
                                            states, hkv, g, d)
         outs.append(_finalize(states))

@@ -63,6 +63,8 @@ def gqa_attention_blockwise(
     scale: Optional[float] = None,
     chunk: int = 512,
     impl: str = "auto",
+    window: Optional[int] = None,
+    kv_from: Optional[jnp.ndarray] = None,
 ):
     """Blockwise (flash) GQA prefill: same contract as gqa_attention but
     KV is folded chunk-by-chunk through the online softmax, never
@@ -74,7 +76,10 @@ def gqa_attention_blockwise(
     v may be narrower than k (latent attention, layers/latent_attn.py:
     one shared head whose values are the first columns of its keys);
     the result then has v's width. The kernel is told so and reads
-    each key page once, taking the values out of it."""
+    each key page once, taking the values out of it.
+
+    window / kv_from: gqa_attention's; the kernel's alone (the scan
+    below knows no lower bound)."""
     from triton_dist_tpu.kernels.sp_attention import _block_update
 
     if impl == "auto":
@@ -92,9 +97,10 @@ def gqa_attention_blockwise(
         return flash_prefill_local(
             q, k, None if prefix else v, q_positions=q_positions,
             q_offset=q_offset, kv_len=kv_len, causal=causal, scale=scale,
-            block=chunk, v_prefix=prefix,
+            block=chunk, v_prefix=prefix, window=window, kv_from=kv_from,
         )
     assert impl == "xla", f"unknown blockwise impl {impl!r}"
+    assert window is None, "the blockwise scan has no window bound"
 
     b, s, hq, d = q.shape
     _, t, hkv, _ = k.shape
@@ -156,6 +162,8 @@ def gqa_attention(
     scale: Optional[float] = None,
     prefill_impl: Optional[str] = None,
     prefill_block: Optional[int] = None,
+    window: Optional[int] = None,
+    kv_from: Optional[jnp.ndarray] = None,
 ):
     """Grouped-query attention forward.
 
@@ -170,7 +178,11 @@ def gqa_attention(
     _BLOCKWISE_T, the dense einsum chain otherwise). prefill_block:
     override the blockwise KV page height (the planner's tune-cache
     attn_block; None keeps the 512 default, so an empty cache compiles
-    exactly the legacy program). Returns (B, S, Hq, D) in q.dtype.
+    exactly the legacy program). window: a row at position i attends
+    the keys at i - window + 1 .. i alone, and none before kv_from
+    ((B,), default 0); a static branch, the Pallas kernel's or the
+    dense chain's (a window's T is short). Returns (B, S, Hq, D) in
+    q.dtype.
     """
     b, s, hq, d = q.shape
     _, t, hkv, _ = k.shape
@@ -185,7 +197,7 @@ def gqa_attention(
             return gqa_attention_blockwise(
                 q, k, v, causal=causal, q_offset=q_offset,
                 q_positions=q_positions, kv_len=kv_len, scale=scale,
-                impl="pallas", **blk,
+                impl="pallas", window=window, kv_from=kv_from, **blk,
             )
         if t >= _BLOCKWISE_T:
             # long-context prefill: O(S*chunk) blockwise path (decode
@@ -219,6 +231,13 @@ def gqa_attention(
         valid = kpos[None, :] < jnp.reshape(kv_len, (-1, 1))  # (B, T)
         valid = valid[:, None, None, None, :]
         mask = valid if mask is None else jnp.logical_and(mask, valid)
+    if window is not None:
+        assert causal and q_positions is not None
+        near = kpos[None, None, :] > q_positions[:, :, None] - window
+        if kv_from is not None:
+            near = near & (kpos[None, None, :]
+                           >= jnp.reshape(kv_from, (-1, 1, 1)))
+        mask = jnp.logical_and(mask, near[:, None, None])
     if mask is not None:
         logits = jnp.where(mask, logits, NEG_INF)
 

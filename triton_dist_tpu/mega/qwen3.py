@@ -130,11 +130,13 @@ class PagedMegaKVCache(NamedTuple):
 
 def _dense_only(cfg: ModelConfig) -> None:
     if cfg.is_hybrid:
+        from triton_dist_tpu.models.hybrid import slot_state
+
         raise NotImplementedError(
             "the megakernel's task graph is the dense decoder's: a "
-            "configuration with recurrent (gated-delta-net) layers has no "
-            "delta-rule, convolution or held-expert task, and no state "
-            "buffer beside the KV pages")
+            f"configuration whose slots carry {slot_state(cfg)} has no "
+            "delta-rule, convolution, window or held-expert task, and no "
+            "state buffer beside the KV pages")
 
 
 def build_qwen3_graph(

@@ -10,8 +10,9 @@ grouped-query attention and an MLP, or experts sliced across TP:
 models/dense.py) is every configuration that states no layer pattern.
 The hybrid family (models/hybrid.py) is one whose blocks are data: a
 mixer kind and an FFN kind a block, read off `mixer_kinds` /
-`ffn_kinds`, stated as an interval (`qwen3_next_80b`, `tiny_next`) or
-as two lists (`kimi_linear_48b`, `tiny_kimi`); what a page of its
+`ffn_kinds`, stated as an interval (`qwen3_next_80b`, `tiny_next`), as
+two lists (`kimi_linear_48b`, `tiny_kimi`) or as the source's own
+`layer_types` list (`k_exaone_236b`, `tiny_exaone`); what a page of its
 cache keeps of a token is `page_arrays`.
 """
 
@@ -41,12 +42,16 @@ class ModelConfig:
     num_experts_per_tok: int = 8
     moe_intermediate_size: int = 0
     # The hybrid family (models/hybrid.py): every block is a MIXER kind
-    # and an FFN kind, and the pattern is data. Two ways to state it:
+    # and an FFN kind, and the pattern is data. Three ways to state it:
     # an interval (Qwen3-Next: block i is gated full attention where
     # (i + 1) % full_attention_interval == 0 and a scalar-gated delta
-    # net otherwise), or two lists that count from 1 as the source does
+    # net otherwise), two lists that count from 1 as the source does
     # (Kimi-Linear: `kda_layers` channel-gated delta nets,
-    # `full_attn_layers` latent attention without rotary); the first
+    # `full_attn_layers` latent attention without rotary), or the
+    # source's `layer_types`, one name a block (K-EXAONE:
+    # "sliding_attention" is grouped-query attention with rotary over
+    # the last `sliding_window` positions, "full_attention" the same
+    # heads over every position and WITHOUT rotary); the first
     # `first_k_dense` blocks have a dense MLP of `intermediate_size`,
     # the others experts. 0 / () = every block the dense family's.
     # The family also means: a router over all `num_experts` of which
@@ -65,6 +70,8 @@ class ModelConfig:
     expert_offset: int = 0
     kda_layers: tuple = ()
     full_attn_layers: tuple = ()
+    layer_types: tuple = ()
+    sliding_window: int = 0
     first_k_dense: int = 0
     # latent attention: the cache holds kv_lora_rank + qk_rope_head_dim
     # values a token, from which every head's keys and values come
@@ -91,13 +98,23 @@ class ModelConfig:
 
     @property
     def is_hybrid(self) -> bool:
-        return self.full_attention_interval > 0 or bool(self.kda_layers)
+        return (self.full_attention_interval > 0 or bool(self.kda_layers)
+                or bool(self.layer_types))
 
     @property
     def mixer_kinds(self) -> tuple:
         """The hybrid family's mixer of each block, in order: "gdn"
         (scalar-gated delta net), "kda" (channel-gated), "gated_attn",
-        "mla" (latent attention)."""
+        "mla" (latent attention), "window_attn" / "global_attn"
+        (grouped-query attention over the last `sliding_window`
+        positions with rotary, over all of them without)."""
+        if self.layer_types:
+            kinds = {"sliding_attention": "window_attn",
+                     "full_attention": "global_attn"}
+            assert len(self.layer_types) == self.num_layers, (
+                "layer_types must name every block once")
+            assert self.sliding_window > 0
+            return tuple(kinds[t] for t in self.layer_types)
         if self.kda_layers:
             kda, full = set(self.kda_layers), set(self.full_attn_layers)
             assert not kda & full and kda | full == set(
@@ -150,7 +167,16 @@ class ModelConfig:
         """Blocks that keep keys and values (pages in the serve pool)."""
         if not self.is_hybrid:
             return self.num_layers
-        return sum(k in ("gated_attn", "mla") for k in self.mixer_kinds)
+        return sum(k in ("gated_attn", "mla", "global_attn")
+                   for k in self.mixer_kinds)
+
+    @property
+    def num_window_layers(self) -> int:
+        """Blocks that keep a fixed per-slot tail of the last
+        `sliding_window` keys and values, and no pages."""
+        if not self.layer_types:
+            return 0
+        return self.mixer_kinds.count("window_attn")
 
     # The published presets fix every WIDTH; depth (`num_layers`) is the
     # one cut a single chip may force, so it is the presets' only
@@ -270,6 +296,52 @@ class ModelConfig:
             linear_conv_kernel_dim=4, linear_gate_rank=8,
             norm_zero_centred=False, router_score="sigmoid",
             router_bias=True, routed_scaling_factor=2.446,
+            shared_expert_gate=False,
+        )
+        defaults.update(kw)
+        return ModelConfig(**defaults)
+
+    @staticmethod
+    def k_exaone_236b(num_layers: int = 48, **kw) -> "ModelConfig":
+        """K-EXAONE-236B-A23B geometry: `L L L G` twelve times (L
+        grouped-query attention over the last 128 positions with
+        rotary, G over every position without; 64 q / 8 kv heads of
+        128, q and k RMS-normalised a head), block 0 a dense MLP of
+        18,432, the others 128 experts of width 2,048 with 8 a token
+        under a sigmoid router with a selection bias, scaled by 2.5,
+        plus an ungated shared expert. A cut depth keeps the pattern's
+        first `num_layers` blocks; what one chip of an expert-parallel
+        group holds is `experts_held` / `expert_offset` and a
+        `vocab_size` slice in **kw."""
+        types = ("sliding_attention",) * 3 + ("full_attention",)
+        defaults = dict(
+            vocab_size=153_600, hidden_size=6144, intermediate_size=18_432,
+            num_q_heads=64, num_kv_heads=8, head_dim=128,
+            rope_theta=1_000_000.0, rms_eps=1e-5, num_experts=128,
+            num_experts_per_tok=8, moe_intermediate_size=2048,
+            shared_expert_intermediate_size=2048,
+            layer_types=(types * 12)[:num_layers], sliding_window=128,
+            first_k_dense=1, norm_zero_centred=False,
+            router_score="sigmoid", router_bias=True,
+            routed_scaling_factor=2.5, shared_expert_gate=False,
+        )
+        defaults.update(kw)
+        return ModelConfig(num_layers=num_layers, **defaults)
+
+    @staticmethod
+    def tiny_exaone(**kw) -> "ModelConfig":
+        """Test-scale K-EXAONE pattern: a leading dense block and two
+        whole periods (`L L L G L L L G`), a window of 8, 8 experts."""
+        types = ("sliding_attention",) * 3 + ("full_attention",)
+        defaults = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=96,
+            num_layers=8, num_q_heads=4, num_kv_heads=2, head_dim=16,
+            rope_theta=1_000_000.0, rms_eps=1e-5, max_positions=64,
+            dtype="float32", num_experts=8, num_experts_per_tok=2,
+            moe_intermediate_size=32, shared_expert_intermediate_size=32,
+            layer_types=types * 2, sliding_window=8, first_k_dense=1,
+            norm_zero_centred=False, router_score="sigmoid",
+            router_bias=True, routed_scaling_factor=2.5,
             shared_expert_gate=False,
         )
         defaults.update(kw)

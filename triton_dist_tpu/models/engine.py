@@ -113,23 +113,25 @@ def _sample_every_col(logits, n_valid, temps, keys):
     return tok, last
 
 
-def _hybrid_step_math(cfg, attn_impl, params, tokens, cache, table,
-                      lengths, n_valid, temps, keys):
+def _hybrid_step_math(cfg, attn_impl, window_impl, params, tokens, cache,
+                      table, lengths, n_valid, temps, keys):
     """The serve step of the hybrid family (models/hybrid.py): the
     same fixed-geometry forward, sampling and page scatter, with the
-    delta-net blocks' per-slot state carried beside the pages (the
-    family's own: key-value pools or one latent pool). `cache` is
-    `KVPool.state`, (*pages, rec, conv). Returns
+    delta-net blocks' per-slot state and the window blocks' tails
+    carried beside the pages (the family's own: key-value pools or one
+    latent pool). `cache` is `KVPool.state`, `hybrid.Cache.flat()`:
+    (*pages, rec, conv) or, with window blocks and no delta net,
+    (*pages, win_k, win_v). Returns
     (tok, last, cache, {counter: () int32})."""
     from triton_dist_tpu.models import hybrid
 
-    *pages, rec, conv = cache
-    last, rows, rec, conv, stats = hybrid.forward_chunk(
-        cfg, params, tokens, hybrid.Cache(tuple(pages), rec, conv), table,
-        lengths, n_valid, attn_impl)  # last (K, V) f32
+    cache = hybrid.Cache.of(cfg, cache)
+    last, rows, rec, conv, win, stats = hybrid.forward_chunk(
+        cfg, params, tokens, cache, table, lengths, n_valid, attn_impl,
+        window_impl)  # last (K, V) f32
     tok = _sample_last(last, temps, keys)
-    pages = KVCache.scatter_step(pages, rows, table, lengths, n_valid)
-    return tok, last, (*pages, rec, conv), stats
+    pages = KVCache.scatter_step(cache.pages, rows, table, lengths, n_valid)
+    return tok, last, hybrid.Cache(pages, rec, conv, win).flat(), stats
 
 
 class Engine:
@@ -230,11 +232,13 @@ class Engine:
 
     def _refuse_hybrid(self, what: str) -> None:
         if self.cfg.is_hybrid:
+            from triton_dist_tpu.models import hybrid
+
             raise NotImplementedError(
-                f"{what} is not built for a configuration with recurrent "
-                "(gated-delta-net) layers: their per-slot state is carried "
-                "by the serve step alone (Engine.make_serve_step, "
-                "serve.Scheduler)")
+                f"{what} is not built for a configuration whose slots "
+                f"carry {hybrid.slot_state(self.cfg)}: that per-slot "
+                "state is carried by the serve step alone "
+                "(Engine.make_serve_step, serve.Scheduler)")
 
     def plan_for(self, batch: int, seq: int, kind: str = "decode"):
         """The fusion plan (triton_dist_tpu.plan.Plan) this engine's
@@ -370,7 +374,9 @@ class Engine:
         pools, or ONE of latent rows) and the
         delta-net blocks' per-slot recurrent and convolution state
         (a padding column leaves both as they were; a slot whose
-        length is 0 starts from zero state inside the step). `stats`
+        length is 0 starts from zero state inside the step); where the
+        pattern has window blocks, their per-slot tails (win_k, win_v)
+        follow, and a kind the pattern lacks adds nothing. `stats`
         is a dict of () int32 counts the step made on the device
         (empty for the dense family; `moe_pairs_here` /
         `moe_pairs_absent` for the hybrid one).
@@ -438,15 +444,20 @@ class Engine:
         if cfg.is_hybrid:
             if per_pos:
                 self._refuse_hybrid("the per-position (spec-verify) step")
-            from triton_dist_tpu.plan.planner import route_hybrid_attention
+            from triton_dist_tpu.plan.planner import (
+                route_hybrid_attention,
+                route_window_attention,
+            )
 
             attn_impl = route_hybrid_attention(cfg, slots, chunk, t_pool)
+            window_impl = (route_window_attention(cfg, slots, chunk)
+                           if cfg.num_window_layers else None)
 
             def per_rank(params, tokens, cache, table, lengths, n_valid,
                          temps, keys):
                 return _hybrid_step_math(
-                    cfg, attn_impl, params, tokens, cache, table, lengths,
-                    n_valid, temps, keys)
+                    cfg, attn_impl, window_impl, params, tokens, cache,
+                    table, lengths, n_valid, temps, keys)
 
             cache_spec = P()
         else:

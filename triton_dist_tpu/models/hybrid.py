@@ -9,32 +9,44 @@ mixer and the FFN are is read off the configuration
          "kda"         channel-gated delta net   layers/gated_delta_net.py
          "gated_attn"  gated full attention      layers/gated_attn.py
          "mla"         latent attention, no rotary  layers/latent_attn.py
+         "window_attn" grouped-query attention over the last
+                       `sliding_window` positions, rotary
+         "global_attn" the same heads over every position, no
+                       rotary                    layers/gqa_attn.py
   FFN    "moe"         a router over all experts, the ones this chip
                        holds, a shared expert    layers/held_moe.py
          "dense"       a SwiGLU MLP of `intermediate_size`
 
-Two published members: Qwen3-Next (periods of three "gdn" blocks and
-one "gated_attn", every FFN "moe", norms with the gain (1 + w)) and
+Three published members: Qwen3-Next (periods of three "gdn" blocks and
+one "gated_attn", every FFN "moe", norms with the gain (1 + w)),
 Kimi-Linear ("kda" and "mla" from two lists, the last period short,
 the first block's FFN "dense", norms with the gain w, a sigmoid
-router).
+router) and K-EXAONE ("window_attn" and "global_attn" from the
+source's `layer_types`, three to one, no delta-net block at all; the
+FFNs and the router Kimi-Linear's).
 
-The pattern is cut into PERIODS, each ending with its attention block
-(the last one may have none), and a run of equal periods is ONE
+The pattern is cut into PERIODS, each ending with a block that keeps
+pages (the last one may have none), and a run of equal periods is ONE
 `lax.scan` with the period's blocks unrolled inside it: one scan for
-Qwen3-Next, three for Kimi-Linear. Parameters are stacked by kind:
+Qwen3-Next, three for Kimi-Linear, two for K-EXAONE (the first period
+holds the dense block). Parameters are stacked by kind:
 
   embed (V, H) · final_ln (H,) · lm_head (H, V)
   every block, (L, ...):      input_ln, post_ln
   "moe" blocks, (Lm, ...):    w_router, [router_bias,] w_gate_up,
                               w_down, ws_gate_up, ws_down[, w_sgate]
   "dense" blocks, (Ld, ...):  wd_gate_up, wd_down
-  a mixer kind's blocks:      `_MIXER_LEAVES` below
+  a mixer kind's blocks:      `_MIXER_LEAVES` below ("window_attn" and
+                              "global_attn" share ONE set, stacked
+                              in the blocks' order)
 
-What a slot carries between steps (`Cache`): pages for the attention
-blocks, in the pool's layout (keys and values, or one latent row a
-token), and for each delta-net block a recurrent state and the
-convolution's last inputs.
+What a slot carries between steps (`Cache`): pages for the blocks that
+attend every position, in the pool's layout (keys and values, or one
+latent row a token); for each delta-net block a recurrent state and
+the convolution's last inputs; for each window block a TAIL, the keys
+and values of the slot's last `sliding_window` positions, which is all
+it ever reads of the past. A kind the pattern lacks carries nothing:
+no array stands in its place.
 
 No stack is cut: a scan's body takes each block's row of every stacked
 leaf, and of the state, by index (`lax.dynamic_index_in_dim`), so the
@@ -50,7 +62,7 @@ one device is refused.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +73,12 @@ from triton_dist_tpu.layers.gated_attn import (
     GatedAttnParams,
     GatedAttnSpec,
     gated_attn_fwd,
+)
+from triton_dist_tpu.layers.gqa_attn import (
+    GQAttnParams,
+    GQAttnSpec,
+    global_attn_fwd,
+    window_attn_fwd,
 )
 from triton_dist_tpu.layers.gated_delta_net import (
     GDNParams,
@@ -87,15 +105,34 @@ from triton_dist_tpu.models.dense import _INIT_SCALE, _draw
 from triton_dist_tpu.models.kv_cache import KVCache
 
 STATE_MIXERS = ("gdn", "kda")  # keep per-slot state beside the pages
-PAGE_MIXERS = ("gated_attn", "mla")  # keep pages
+PAGE_MIXERS = ("gated_attn", "mla", "global_attn")  # keep pages
+WINDOW_MIXERS = ("window_attn",)  # keep a per-slot tail, no pages
+GQA_MIXERS = ("window_attn", "global_attn")  # share their leaves
 
 
 class Cache(NamedTuple):
-    """The serve step's cache for this family (`KVPool.state`, named)."""
+    """The serve step's cache for this family (`KVPool.state`, named).
+    `KVPool.state` is the flat tuple `flat()` returns, with nothing in
+    the place of what the pattern has no block for."""
 
     pages: tuple  # (k, v) each (Lf, P, page, Hkv, D), or one latent pool
-    rec: jax.Array  # (Ll, slots, Hv, dk, dv) float32
-    conv: jax.Array  # (Ll, slots, K - 1, channels)
+    rec: Optional[jax.Array]  # (Ll, slots, Hv, dk, dv) float32
+    conv: Optional[jax.Array]  # (Ll, slots, K - 1, channels)
+    win: tuple = ()  # (k, v) each (Lw, slots, window, Hkv, D)
+
+    @staticmethod
+    def of(cfg: ModelConfig, flat) -> "Cache":
+        n = len(cfg.page_arrays)
+        pages, rest = tuple(flat[:n]), tuple(flat[n:])
+        rec = conv = None
+        if any(k in STATE_MIXERS for k in cfg.mixer_kinds):
+            (rec, conv), rest = rest[:2], rest[2:]
+        return Cache(pages, rec, conv, rest)
+
+    def flat(self) -> tuple:
+        return (self.pages
+                + (() if self.rec is None else (self.rec, self.conv))
+                + self.win)
 
 
 def gdn_spec(cfg: ModelConfig) -> GDNSpec:
@@ -107,6 +144,10 @@ def gdn_spec(cfg: ModelConfig) -> GDNSpec:
 def attn_spec(cfg: ModelConfig) -> GatedAttnSpec:
     return GatedAttnSpec(cfg.num_q_heads, cfg.num_kv_heads, cfg.head_dim,
                          int(cfg.head_dim * cfg.partial_rotary_factor))
+
+
+def gqa_spec(cfg: ModelConfig) -> GQAttnSpec:
+    return GQAttnSpec(cfg.num_q_heads, cfg.num_kv_heads, cfg.head_dim)
 
 
 def latent_spec(cfg: ModelConfig) -> LatentAttnSpec:
@@ -139,11 +180,12 @@ def check(cfg: ModelConfig, n_devices: int) -> None:
             f"(got a tp axis of {n_devices}): the mixers have no "
             "tensor-parallel form and the expert layer no exchange")
     assert cfg.expert_offset + cfg.num_experts_held <= cfg.num_experts
-    assert cfg.linear_num_value_heads % cfg.linear_num_key_heads == 0
     assert cfg.router_score in ("softmax", "sigmoid")
     assert 0 <= cfg.first_k_dense < cfg.num_layers
     assert not cfg.tie_word_embeddings
     kinds = set(cfg.mixer_kinds)  # the lists name every block once
+    if kinds & set(STATE_MIXERS):
+        assert cfg.linear_num_value_heads % cfg.linear_num_key_heads == 0
     if "kda" in kinds:
         assert cfg.linear_num_key_heads == cfg.linear_num_value_heads
         assert cfg.linear_gate_rank > 0
@@ -193,6 +235,15 @@ def _mixer_leaves(cfg: ModelConfig, kind: str, n: int):
             ("k_norm", (n, d), "zeros"),
             ("w_o", (n, hq * d, h), "normal"),
         )
+    if kind == "gqa":
+        hq, hkv, d = gqa_spec(cfg)
+        return (
+            ("attn_w_q", (n, h, hq * d), "normal"),
+            ("attn_w_kv", (n, h, 2 * hkv * d), "normal"),
+            ("attn_q_norm", (n, d), "ones"),
+            ("attn_k_norm", (n, d), "ones"),
+            ("attn_w_o", (n, hq * d, h), "normal"),
+        )
     m = latent_spec(cfg)
     return (
         ("mla_w_q", (n, h, m.num_q_heads * (m.nope_dim + m.rope_dim)),
@@ -205,7 +256,13 @@ def _mixer_leaves(cfg: ModelConfig, kind: str, n: int):
     )
 
 
-_MIXERS = ("gdn", "kda", "gated_attn", "mla")  # the leaves' order
+# the leaves' order; "gqa" is the one set of the window and the global
+# grouped-query blocks together
+_MIXERS = ("gdn", "kda", "gated_attn", "mla", "gqa")
+
+
+def _leaf_kind(mixer: str) -> str:
+    return "gqa" if mixer in GQA_MIXERS else mixer
 
 
 def _moe_leaves(cfg: ModelConfig, n: int):
@@ -230,7 +287,7 @@ def _dense_leaves(cfg: ModelConfig, n: int):
 
 def leaves(cfg: ModelConfig):
     L, h, v = cfg.num_layers, cfg.hidden_size, cfg.vocab_size
-    mixers = cfg.mixer_kinds
+    mixers = tuple(_leaf_kind(k) for k in cfg.mixer_kinds)
     ld = cfg.first_k_dense
     return (
         (("embed", (v, h), "normal"),
@@ -281,13 +338,17 @@ _MIXER_LEAVES = {
             "kda_a_log", "kda_dt_bias", "kda_norm", "kda_w_out"),
     "gated_attn": ("w_q", "w_kv", "q_norm", "k_norm", "w_o"),
     "mla": ("mla_w_q", "mla_w_a", "mla_kv_norm", "mla_w_b", "mla_w_o"),
+    "gqa": ("attn_w_q", "attn_w_kv", "attn_q_norm", "attn_k_norm",
+            "attn_w_o"),
 }
 _MIXER_PARAMS = {"gdn": GDNParams, "kda": KDAParams,
-                 "gated_attn": GatedAttnParams, "mla": LatentAttnParams}
+                 "gated_attn": GatedAttnParams, "mla": LatentAttnParams,
+                 "gqa": GQAttnParams}
 
 
 def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
-                  table, lengths, n_valid, attn_impl: str):
+                  table, lengths, n_valid, attn_impl: str,
+                  window_impl: Optional[str] = None):
     """One (slots, chunk) block through the model. Slot s holds
     `lengths[s]` cached positions and `n_valid[s]` real columns.
     Returns (last (K, V) float32, the logits of each slot's column
@@ -296,7 +357,7 @@ def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
     rows and never the chunk's K x C; then what `chunk_hidden`
     returns after its hidden rows)."""
     x, *rest = chunk_hidden(cfg, params, tokens, cache, table, lengths,
-                            n_valid, attn_impl)
+                            n_valid, attn_impl, window_impl)
     x = x[jnp.arange(x.shape[0]), jnp.maximum(n_valid - 1, 0)]  # (K, H)
     return head_logits(cfg, params, x), *rest
 
@@ -311,17 +372,24 @@ def head_logits(cfg: ModelConfig, params: dict, x):
 
 
 def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
-                 table, lengths, n_valid, attn_impl: str):
+                 table, lengths, n_valid, attn_impl: str,
+                 window_impl: Optional[str] = None):
     """The blocks of `forward_chunk`: (hidden rows (K, C, H) before
-    the final norm; the chunk's new rows of the attention blocks, one
-    (Lf, K, C, Hkv, D) an array of the pages; rec; conv;
-    {counter: () int32})."""
+    the final norm; the chunk's new rows of the page blocks, one
+    (Lf, K, C, Hkv, D) an array of the pages; rec; conv (None without
+    a delta-net block); the window blocks' new tails (k, v), or ();
+    {counter: () int32}). `attn_impl` is the page blocks' route,
+    `window_impl` the window blocks'."""
     slots, chunk = tokens.shape
     g, a, m = gdn_spec(cfg), attn_spec(cfg), latent_spec(cfg)
+    gq = gqa_spec(cfg)
     eps = cfg.rms_eps
     router = RouterForm(cfg.router_score, cfg.routed_scaling_factor)
     if "gated_attn" in cfg.mixer_kinds:
         cos, sin = rope_table(a.rotary_dim, cfg.max_positions,
+                              cfg.rope_theta)
+    elif "window_attn" in cfg.mixer_kinds:
+        cos, sin = rope_table(gq.head_dim, cfg.max_positions,
                               cfg.rope_theta)
     positions = lengths[:, None] + jnp.arange(chunk)[None, :]
     kv_len = lengths + chunk
@@ -361,12 +429,13 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                     keepdims=False)
 
             here = absent = jnp.int32(0)
-            recs, convs, rows = [], [], ()
+            recs, convs, rows, tails = [], [], [], []
             at = {kind: 0 for kind in count}
             for j, (mixer, ffn) in enumerate(period):
-                p = _MIXER_PARAMS[mixer](*(
-                    row(params[n], mixer, at[mixer])
-                    for n in _MIXER_LEAVES[mixer]))
+                leaf = _leaf_kind(mixer)
+                p = _MIXER_PARAMS[leaf](*(
+                    row(params[n], leaf, at[leaf])
+                    for n in _MIXER_LEAVES[leaf]))
                 hid = normed(x, row(params["input_ln"], "block", j))
                 if mixer in STATE_MIXERS:
                     fwd = gated_delta_net_fwd if mixer == "gdn" else kda_fwd
@@ -377,17 +446,33 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                     recs.append(r)
                     convs.append(c)
                     at["state"] += 1
+                elif mixer in WINDOW_MIXERS:
+                    y, tail = window_attn_fwd(
+                        hid, p, gq, cos, sin, positions,
+                        tuple(row(w, "window", at["window"])
+                              for w in cache.win),
+                        lengths, n_valid, cfg.sliding_window, window_impl,
+                        eps)
+                    tails.append(tail)
+                    at["window"] += 1
                 else:
-                    view = pages.layer_view(start["page"] + i)
+                    view = pages.layer_view(
+                        start["page"] + i * count["page"] + at["page"])
                     if mixer == "gated_attn":
-                        y, rows = gated_attn_fwd(
+                        y, new = gated_attn_fwd(
                             hid, p, a, cos, sin, positions, view, kv_len,
                             attn_impl, eps)
+                    elif mixer == "global_attn":
+                        y, new = global_attn_fwd(
+                            hid, p, gq, positions, view, kv_len, attn_impl,
+                            eps)
                     else:
-                        y, rows = latent_attn_fwd(
+                        y, new = latent_attn_fwd(
                             hid, p, m, positions, view[0], kv_len,
                             n_valid, attn_impl, eps)
-                at[mixer] += 1
+                    rows.append(new)
+                    at["page"] += 1
+                at[leaf] += 1
                 gain = row(params["post_ln"], "block", j)
                 if ffn == "moe":
                     x, h_j, a_j = moe(
@@ -401,46 +486,90 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                         n: row(params[n], "dense", at["dense"])
                         for n in ("wd_gate_up", "wd_down")})
                 at[ffn] += 1
-            return x, (jnp.stack(recs), jnp.stack(convs), rows, here,
-                       absent)
+            # a period's one page block hands its rows on as they are;
+            # several are stacked, like the state and the tails
+            if len(rows) == 1:
+                rows, = rows
+            else:
+                rows = tuple(jnp.stack(r) for r in zip(*rows))
+            state = (jnp.stack(recs), jnp.stack(convs)) if recs else ()
+            tails = tuple(jnp.stack(t) for t in zip(*tails))
+            return x, (state, rows, tails, here, absent)
 
         return one_period
 
     x = params["embed"][tokens]
     start = {kind: 0 for kind in _MIXERS + ("moe", "dense", "block",
-                                            "state", "page")}
-    outs = []
+                                            "state", "page", "window")}
+    outs, pages_a_period = [], []
     for period, n in segments(cfg):
         count = {kind: 0 for kind in start}
         for mixer, ffn in period:
-            for kind in (mixer, ffn, "block"):
+            for kind in (_leaf_kind(mixer), ffn, "block"):
                 count[kind] += 1
             count["state"] += mixer in STATE_MIXERS
             count["page"] += mixer in PAGE_MIXERS
-        assert count["state"], "a period without a delta-net block"
+            count["window"] += mixer in WINDOW_MIXERS
         x, out = jax.lax.scan(run(period, dict(start), count), x,
                               jnp.arange(n))
         outs.append(out)
+        pages_a_period.append(count["page"])
         for kind in start:
             start[kind] += n * count[kind]
 
-    def joined(parts, flat: bool):
-        parts = [p.reshape((-1,) + p.shape[2:]) if flat else p
-                 for p in parts]
+    def joined(parts):
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
-    rec = joined([o[0] for o in outs], True)
-    conv = joined([o[1] for o in outs], True)
-    rows = tuple(joined([o[2][k] for o in outs if o[2]], False)
-                 for k in range(len(cache.pages)))
+    def flat(p):  # (repeats, a period's blocks, ...) -> (blocks, ...)
+        return p.reshape((-1,) + p.shape[2:])
+
+    def per_block(at: int, width: int):
+        """`width` arrays a block from the scans' stacked results, of
+        the periods that have any."""
+        return tuple(joined([flat(o[at][k]) for o in outs if o[at]])
+                     for k in range(width))
+
+    rec, conv = per_block(0, 2) if start["state"] else (None, None)
+    rows = tuple(
+        joined([flat(o[1][k]) if n > 1 else o[1][k]
+                for o, n in zip(outs, pages_a_period) if n])
+        for k in range(len(cache.pages)))
     stats = {"moe_pairs_here": sum(jnp.sum(o[3]) for o in outs),
              "moe_pairs_absent": sum(jnp.sum(o[4]) for o in outs)}
-    return x, rows, rec, conv, stats
+    return x, rows, rec, conv, per_block(2, len(cache.win)), stats
 
 
 def state_shapes(cfg: ModelConfig, slots: int):
-    """Shapes of the per-slot state beside the pages: (rec, conv)."""
+    """Shapes of the delta-net blocks' per-slot state beside the
+    pages, (rec, conv); () for a pattern without such a block."""
+    ll = sum(k in STATE_MIXERS for k in cfg.mixer_kinds)
+    if not ll:
+        return ()
     g = gdn_spec(cfg)
-    ll = cfg.num_layers - cfg.num_kv_layers
     return ((ll, slots, g.num_v_heads, g.k_dim, g.v_dim),
             (ll, slots, g.conv - 1, g.channels))
+
+
+def window_shapes(cfg: ModelConfig, slots: int):
+    """Shapes of the window blocks' per-slot tails, (k, v): the last
+    `sliding_window` positions a block and slot, whatever the context;
+    () for a pattern without a window block."""
+    lw = cfg.num_window_layers
+    if not lw:
+        return ()
+    return ((lw, slots, cfg.sliding_window, cfg.num_kv_heads,
+             cfg.head_dim),) * 2
+
+
+def slot_state(cfg: ModelConfig) -> str:
+    """What this configuration's slots carry beside their pages, as
+    the refusals name it (serve/scheduler.py, serve/kv_pool.py,
+    models/engine.py, mega/qwen3.py)."""
+    kinds = set(cfg.mixer_kinds)
+    what = []
+    if kinds & set(STATE_MIXERS):
+        what.append("recurrent (gated-delta-net) state")
+    if kinds & set(WINDOW_MIXERS):
+        what.append("a window block's tail (the last sliding_window keys "
+                    "and values a slot)")
+    return " and ".join(what)

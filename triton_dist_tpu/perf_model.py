@@ -1261,7 +1261,8 @@ def estimate_hybrid_step_ms(cfg, n_tokens: int, kv_tokens: int = 0,
     h = cfg.hidden_size
     mixers = cfg.mixer_kinds
     lf, lm = cfg.num_kv_layers, cfg.num_moe_layers
-    ll = cfg.num_layers - lf
+    lw = cfg.num_window_layers  # attend a tail and the chunk, no pages
+    ll = cfg.num_layers - lf - lw
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     hq = cfg.num_q_heads
@@ -1284,6 +1285,9 @@ def estimate_hybrid_step_ms(cfg, n_tokens: int, kv_tokens: int = 0,
                     + row) + cfg.kv_lora_rank * hq * (
             cfg.qk_nope_head_dim + cfg.v_head_dim) + hq * cfg.v_head_dim * h,
     }
+    mixer["window_attn"] = mixer["global_attn"] = (
+        h * cfg.head_dim * (hq + 2 * cfg.num_kv_heads)
+        + hq * cfg.head_dim * h)
     mixers_w = sum(mixer[kind] for kind in mixers)
     if cfg.kv_lora_rank:
         kv_row, pair = row, hq * (row + cfg.kv_lora_rank)
@@ -1302,6 +1306,7 @@ def estimate_hybrid_step_ms(cfg, n_tokens: int, kv_tokens: int = 0,
                  + h * cfg.vocab_size)
     flops = 2.0 * n_tokens * per_token \
         + 2.0 * n_tokens * kv_tokens * lf * pair \
+        + 2.0 * n_tokens * min(kv_tokens, cfg.sliding_window) * lw * pair \
         + 4.0 * n_tokens * ll * hv * dk * dv
     compute_ms = flops / (
         chip.bf16_tflops * 1e12 * 0.85

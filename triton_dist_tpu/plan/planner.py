@@ -746,6 +746,19 @@ def route_hybrid_attention(cfg, b: int, s: int, t: int) -> str:
                                  cfg.num_kv_heads, cfg.head_dim, cfg.dtype)
 
 
+def route_window_attention(cfg, b: int, s: int) -> str:
+    """The route of a hybrid configuration's WINDOW blocks
+    (layers/gqa_attn.py `window_attn_fwd`): the same kernel under the
+    same checks as its global blocks, over the `sliding_window + s`
+    positions a step attends (the slot's tail and the chunk) and never
+    more; the kernel takes the window's lower bound itself
+    (`flash_prefill_local(window=)`)."""
+    assert cfg.sliding_window > 0
+    return route_gated_attention(b, s, cfg.sliding_window + s,
+                                 cfg.num_q_heads, cfg.num_kv_heads,
+                                 cfg.head_dim, cfg.dtype)
+
+
 def route_prefill_impl(b: int, s: int, t: int, hq: int, hkv: int,
                        d: int, dtype) -> str:
     """THE prefill-impl routing predicate ("pallas" | "xla"): native

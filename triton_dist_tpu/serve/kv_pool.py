@@ -62,6 +62,16 @@ step carries, as one pytree. What would have to copy or ship that
 state (prefix sharing, copy-on-write, page export / install, the
 megakernel bridge) refuses such a pool.
 
+A third kind, of the same standing: a WINDOW block (attention over the
+last `sliding_window` positions) keeps no pages at all but a fixed
+per-slot TAIL, `win` = (k, v) each (Lw, slots, window, Hkv, D)
+(`models.hybrid.window_shapes`): allocated once, here; shifted by the
+step itself (a slot's valid rows in, as many out); never grown and
+never read through the table, so its bytes a slot are the same at
+every context. A slot of length 0 reads none of it. The pages, the
+table and every counter of them are then the GLOBAL blocks' alone.
+The same refusals hold, for the same reason.
+
 Host/device split: page bookkeeping (free list, per-slot page lists,
 lengths, refcounts) is host-side numpy — the scheduler reads it every
 step — while k/v live on device and are donated through the step
@@ -127,18 +137,27 @@ class KVPool:
         self.k, self.v = pools if len(pools) == 2 else (pools[0], None)
         # pool bytes of one position, all page layers together
         self.kv_bytes_per_token = cfg.num_kv_layers * cfg.kv_bytes_per_token
-        # the delta-net blocks' per-slot state (module doc)
+        # the delta-net blocks' per-slot state and the window blocks'
+        # tails (module doc): nothing where the pattern has no such block
         self.rec = self.conv = None
-        self.state_bytes_per_slot = 0
+        self.win = ()
+        self.state_bytes_per_slot = self.window_bytes_per_slot = 0
+        self._slot_state = ""
         if cfg.is_hybrid:
             from triton_dist_tpu.models import hybrid
 
-            rec, conv = hybrid.state_shapes(cfg, slots)
             here = NamedSharding(engine.mesh, P())
-            self.rec = jnp.zeros(rec, jnp.float32, device=here)
-            self.conv = jnp.zeros(conv, dt, device=here)
-            self.state_bytes_per_slot = (
-                self.rec.nbytes + self.conv.nbytes) // slots
+            self._slot_state = hybrid.slot_state(cfg)
+            shapes = hybrid.state_shapes(cfg, slots)
+            if shapes:
+                self.rec = jnp.zeros(shapes[0], jnp.float32, device=here)
+                self.conv = jnp.zeros(shapes[1], dt, device=here)
+                self.state_bytes_per_slot = (
+                    self.rec.nbytes + self.conv.nbytes) // slots
+            self.win = tuple(jnp.zeros(shape, dt, device=here)
+                             for shape in hybrid.window_shapes(cfg, slots))
+            self.window_bytes_per_slot = sum(
+                w.nbytes for w in self.win) // slots
 
         self.table = np.zeros((slots, self.max_pages), np.int32)
         self.lengths = np.zeros((slots,), np.int32)
@@ -155,22 +174,24 @@ class KVPool:
         """Everything the serve step carries, as ONE pytree (the step's
         `cache` argument and third result)."""
         pages = KVCache(self.k, self.v, None).pools
-        if self.rec is None:
-            return pages
-        return pages + (self.rec, self.conv)
+        if self.rec is not None:
+            pages += (self.rec, self.conv)
+        return pages + self.win
 
     @state.setter
     def state(self, new) -> None:
+        if self.win:
+            new, self.win = new[:-len(self.win)], tuple(new[-len(self.win):])
         if self.rec is not None:
             *new, self.rec, self.conv = new
         self.k, self.v = new if len(new) == 2 else (new[0], None)
 
     def _pages_only(self, what: str) -> None:
-        if self.rec is not None:
+        if self._slot_state:
             raise NotImplementedError(
                 f"{what} moves pages, and this pool's slots also carry "
-                "recurrent (gated-delta-net) state that it has no way to "
-                "copy, share or ship")
+                f"{self._slot_state} that it has no way to copy, share or "
+                "ship")
 
     # -- queries --------------------------------------------------------
 

@@ -126,25 +126,27 @@ class Scheduler:
         # obs.spans.default_log() is the newest scheduler's
         self.spans = new_default_log()
         if engine.cfg.is_hybrid:
-            # what would have to copy, verify or ship a slot's
-            # recurrent state, and cannot yet (docs/serving.md)
+            # what would have to copy, verify or ship what a slot
+            # carries beside its pages, and cannot yet (docs/serving.md)
+            from triton_dist_tpu.models.hybrid import slot_state
+
             for asked, what in (
                     (prefix_cache, "prefix_cache: a cached prefix is "
-                     "pages, and a delta-net block's state after the "
-                     "prefix is kept nowhere"),
+                     "pages, and a delta-net block's state or a window "
+                     "block's tail after the prefix is kept nowhere"),
                     (spec is not None and getattr(spec, "k", 0) > 0,
-                     "spec: a rejected draft would have to roll the "
-                     "recurrent state back, and the step keeps no "
-                     "per-column state"),
+                     "spec: a rejected draft would have to roll that "
+                     "state back, and the step keeps no per-column "
+                     "state"),
                     (role != "both" or migrate_to is not None
                      or admit_from is not None,
                      "xslice migration (role / migrate_to / admit_from):"
                      " the wire image holds pages only")):
                 if asked:
                     raise NotImplementedError(
-                        f"a configuration with recurrent "
-                        f"(gated-delta-net) layers cannot be served with "
-                        f"{what}")
+                        f"a configuration whose slots carry "
+                        f"{slot_state(engine.cfg)} beside their pages "
+                        f"cannot be served with {what}")
         self.pool = KVPool(engine, slots, page, max_pages=max_pages,
                            total_pages=total_pages)
         if chunk is None:
@@ -641,24 +643,28 @@ class Scheduler:
         per_token = self.pool.kv_bytes_per_token
         self.obs.inc("serve_kv_bytes_live", per_token * live)
         self.obs.inc("serve_kv_bytes_gathered", per_token * gathered)
-        per_slot = self.pool.state_bytes_per_slot
-        if per_slot:
-            # the hybrid family: the per-slot state beside the pages
-            # (what the step reads and writes is every slot's) and the
-            # expert layer's routing, counted by the step on the device
-            cfg = self.pool.engine.cfg
-            self.obs.inc("serve_state_bytes_live", per_slot * len(plans))
-            self.obs.inc("serve_state_bytes_moved",
-                         per_slot * self.pool.slots)
-            self.obs.inc("serve_state_resets", sum(
-                1 for slot, _r, n, _e, _d in plans
-                if int(self.pool.lengths[slot]) == n))
-            stats = self.worker.last_stats
-            self.obs.inc("moe_pairs", stats["moe_pairs_here"], held="here")
-            self.obs.inc("moe_pairs", stats["moe_pairs_absent"],
-                         held="absent")
-            self.obs.inc("moe_expert_steps",
-                         cfg.num_moe_layers * cfg.num_experts_held)
+        cfg = self.pool.engine.cfg
+        if not cfg.is_hybrid:
+            return
+        # the hybrid family: what a slot carries beside the pages (the
+        # step reads and writes every slot's) and the expert layer's
+        # routing, counted by the step on the device
+        for name, per_slot in (
+                ("state", self.pool.state_bytes_per_slot),
+                ("window", self.pool.window_bytes_per_slot)):
+            if per_slot:
+                self.obs.inc(f"serve_{name}_bytes_live",
+                             per_slot * len(plans))
+                self.obs.inc(f"serve_{name}_bytes_moved",
+                             per_slot * self.pool.slots)
+        self.obs.inc("serve_state_resets", sum(
+            1 for slot, _r, n, _e, _d in plans
+            if int(self.pool.lengths[slot]) == n))
+        stats = self.worker.last_stats
+        self.obs.inc("moe_pairs", stats["moe_pairs_here"], held="here")
+        self.obs.inc("moe_pairs", stats["moe_pairs_absent"], held="absent")
+        self.obs.inc("moe_expert_steps",
+                     cfg.num_moe_layers * cfg.num_experts_held)
 
     def _attempt_with_backoff(self, retry_span, body):
         """The retrying half of the degradation ladder: run `body` with
