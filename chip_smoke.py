@@ -17,7 +17,11 @@ head; weights random from --seed):
       then the hybrid family's window and global grouped-query blocks
       at K-EXAONE's widths through a per-slot tail and a paged view,
       against plain float32 attention, each broken four ways
-      (`window_phase`).
+      (`window_phase`); then the held experts' grouped matmul, both
+      products of an expert block at the three hybrid configurations'
+      widths and the cells' group sizes, against the loop over
+      experts, with one group's offset shifted by a row
+      (`experts_phase`).
   --chips 4           the cross-chip path and nothing else: full depth
       (36 layers), tp=4 over the four chips, the same four requests.
 
@@ -708,6 +712,93 @@ def window_phase(seed: int, max_len: int = 8192) -> None:
             "least) over it")
 
 
+# The held experts' grouped matmul on the chip, a hybrid configuration
+# a row: its constructor, the experts its cell holds, and the (token,
+# choice) pairs one of them sees a step there (the mean; the draw is
+# Poisson about it). DEPLOYED_LOAD: what a deployment's batches bring.
+EXPERT_CELLS = (("qwen3_next_80b", 128, 7), ("kimi_linear_48b", 16, 12),
+                ("k_exaone_236b", 16, 21))
+DEPLOYED_LOAD = 180
+# Largest difference from the loop over experts, as a share of its
+# largest output: the kernel sums K in tiles and rounds once to bf16,
+# as the loop does (one bf16 step is 2**-8 of a value), against one
+# group's offset shifted by ONE row, whose row meets another expert's
+# weights (experts_phase prints both).
+EXPERTS_TOL = 0.01
+
+
+def experts_phase(seed: int, rows=None) -> None:
+    """`kernels.grouped_gemm` on the route the chip gives it, both
+    products of an expert block at the three hybrid configurations'
+    widths over a stack of two layers' experts of which the second's
+    groups alone are not empty (as `held_moe_fwd` hands it), against
+    `grouped_gemm_ref` over that layer's experts and the groups' rows.
+    The group sizes are the cells' and a deployment's (as many of 180
+    as the step's rows hold). Broken on purpose: the same result
+    against the reference with ONE group's offset shifted by a row has
+    to read over the tolerance."""
+    import jax
+    import jax.numpy as jnp
+
+    from triton_dist_tpu.kernels.grouped_gemm import (
+        grouped_gemm,
+        grouped_gemm_ref,
+        grouped_gemm_route,
+    )
+    from triton_dist_tpu.models import ModelConfig
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    rng = np.random.default_rng(seed)
+    layers, layer = 2, 1
+    for name, held, load in EXPERT_CELLS:
+        cfg = getattr(ModelConfig, name)()
+        hidden, inter = cfg.hidden_size, cfg.moe_intermediate_size
+        t = rows or 1024 * cfg.num_experts_per_tok
+        for k, n in ((hidden, 2 * inter), (inter, hidden)):
+            route = grouped_gemm_route(t, k, n)
+            keys = jax.random.split(jax.random.PRNGKey(seed + k + n), 2)
+            w = (0.02 * jax.random.normal(keys[0], (layers * held, k, n),
+                                          f32)).astype(bf16)
+            x = jax.random.normal(keys[1], (t, k), f32).astype(bf16)
+            fwd = jax.jit(grouped_gemm)
+            for mean in (load, min(DEPLOYED_LOAD, t // held)):
+                own = np.minimum(rng.poisson(mean, held), t // held)
+                sizes = np.zeros(layers * held, np.int32)
+                sizes[layer * held:] = own
+                if mean == load:
+                    kernels, secs = compile_and_name(fwd, x, w, sizes)
+                    say(f"experts {name} ({k}, {n}): route {route!r}, "
+                        f"kernels {kernels or 'none'}, compiled in "
+                        f"{secs:.1f}s")
+                    if route == "pallas":
+                        require(kernels, ["_moe_gmm_kernel"],
+                                f"{name}'s grouped matmul ({k}, {n})")
+                m = int(own.sum())
+                got = np.asarray(fwd(x, w, sizes)[:m], np.float32)
+                # a row moves from the group behind the first boundary
+                # to the group in front of it
+                at = int(np.flatnonzero(own[1:] > 0)[0]) + 1
+                shifted = own.copy()
+                shifted[at - 1] += 1
+                shifted[at] -= 1
+                reads = []
+                for wanted in (own, shifted):
+                    ref = np.asarray(grouped_gemm_ref(
+                        x[:m], w[layer * held:], jnp.asarray(wanted),
+                        out_dtype=f32))
+                    reads.append(float(np.abs(got - ref).max()
+                                       / np.abs(ref).max()))
+                say(f"experts {name} ({k}, {n}) at {mean} rows an expert "
+                    f"({m} rows in {held} groups): {reads[0]:.5f} of the "
+                    f"largest output; one offset shifted {reads[1]:.4f} "
+                    f"(tolerance {EXPERTS_TOL})")
+                if not reads[0] < EXPERTS_TOL < reads[1]:
+                    raise RuntimeError(
+                        f"grouped matmul {name} ({k}, {n}): {reads[0]:.5f} "
+                        f"has to lie under {EXPERTS_TOL} and the shifted "
+                        f"offset's {reads[1]:.4f} over it")
+
+
 def run(cfg, mesh, seed: int, prompts, cross_chip: bool) -> None:
     """Every phase on `mesh`. cross_chip: the tp>1 contract (kernels of
     the overlapped collectives by name, no megakernel phase)."""
@@ -796,6 +887,7 @@ def main() -> int:
     if args.chips == 1:
         latent_phase(args.seed)
         window_phase(args.seed)
+        experts_phase(args.seed)
     run(cfg, make_mesh((args.chips,), ("tp",)), args.seed, prompts,
         cross_chip=args.chips > 1)
 

@@ -16,6 +16,7 @@ else: only the xdist worker that is handed this file loads libtpu.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -294,6 +295,20 @@ def _hybrid_step_compiled(chip, cfg, slots, page, max_len):
         SDS((slots, 2), jnp.uint32, sharding=rep)).compile()
 
 
+def _experts_stream_through_the_kernel(text, cfg):
+    """The held experts' products are `_moe_gmm_kernel`'s: no
+    `ragged_dot` is left in the step, and no expert stack (or slice of
+    one) is copied on its way to the kernel: the stacks go in as they
+    lie in HBM."""
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    held, h, i = (cfg.num_experts_held, cfg.hidden_size,
+                  cfg.moe_intermediate_size)
+    moved = re.findall(
+        rf"= bf16\[(?:\d+,)*{held},(?:{h},{2 * i}|{i},{h})\]\S* "
+        r"(?:copy|fusion|dynamic-slice)\(", text)
+    assert not moved, moved
+
+
 def test_latent_serve_step_one_chip(chip):
     """The Kimi-Linear pattern at the benchmark's widths and geometry,
     cut to its first two periods and the short last one (11 blocks:
@@ -322,7 +337,10 @@ def test_latent_serve_step_one_chip(chip):
     with pytest.raises(NotImplementedError, match="one query row"):
         route_hybrid_attention(cfg, slots, 1, max_len)
     compiled = _hybrid_step_compiled(chip, cfg, slots, page, max_len)
-    assert _kernels(compiled) == {"_fp_local_kernel": 3}
+    # the ten expert blocks' two products each, and no `ragged_dot`
+    assert _kernels(compiled) == {"_fp_local_kernel": 3,
+                                  "_moe_gmm_kernel": 20}
+    _experts_stream_through_the_kernel(compiled.as_text(), cfg)
     launch = flash_prefill.last_launch()
     # 32 heads stacked into the rows: 4,096 rows in tiles of 512, one
     # stream of pages, keys 640 wide and values their first 512
@@ -347,7 +365,10 @@ def test_hybrid_serve_step_one_chip(chip):
         num_layers=8, experts_held=128, vocab_size=37_984,
         max_positions=max_len)
     compiled = _hybrid_step_compiled(chip, cfg, slots, page, max_len)
-    assert _kernels(compiled) == {"_fp_local_kernel": 1}
+    # one scan body of a period: four expert blocks' two products each
+    assert _kernels(compiled) == {"_fp_local_kernel": 1,
+                                  "_moe_gmm_kernel": 8}
+    _experts_stream_through_the_kernel(compiled.as_text(), cfg)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
     _one_row_of_logits_a_slot(compiled.as_text(), slots, 128, cfg.vocab_size)
     _pool_moves_once(compiled.as_text(), cfg.num_kv_layers, slots, max_len,
@@ -378,8 +399,11 @@ def test_window_and_global_serve_step_one_chip(chip):
     with pytest.raises(NotImplementedError, match="one query row"):
         route_window_attention(cfg, slots, 1)
     compiled = _hybrid_step_compiled(chip, cfg, slots, page, max_len)
-    # one body a period and kind: 2 scans x (window, global)
-    assert _kernels(compiled) == {"_fp_local_kernel": 8}
+    # one body a period and kind: 2 scans x (window, global); the seven
+    # expert blocks' two products each
+    assert _kernels(compiled) == {"_fp_local_kernel": 8,
+                                  "_moe_gmm_kernel": 14}
+    _experts_stream_through_the_kernel(compiled.as_text(), cfg)
     launch = flash_prefill.last_launch()  # the last traced: a global block
     assert launch["widths"] == (128, 128) and launch["streams"] == 2
     text = compiled.as_text()
@@ -408,6 +432,20 @@ def test_gated_attention_has_no_silent_route_on_the_chip(chip):
                                  "bfloat16") == "pallas"
     with pytest.raises(NotImplementedError, match="no other route"):
         route_gated_attention(8, 128, 8192, 16, 2, 96, "bfloat16")
+
+
+def test_grouped_matmul_has_no_silent_route_on_the_chip(chip):
+    """The six (K, N) the hybrid cells serve, at their steps' rows,
+    take the kernel on the chip; what it does not tile takes
+    `ragged_dot` by its shape, in the open."""
+    from triton_dist_tpu.kernels.grouped_gemm import grouped_gemm_route
+
+    for rows, shapes in ((10_240, ((2048, 1024), (512, 2048))),
+                         (8192, ((2304, 2048), (1024, 2304))),
+                         (8192, ((6144, 4096), (2048, 6144)))):
+        for k, n in shapes:
+            assert grouped_gemm_route(rows, k, n) == "pallas", (k, n)
+    assert grouped_gemm_route(8192, 2048, 96) == "xla"
 
 
 def test_hybrid_family_keeps_the_chunk_s_width_on_the_chip(chip):

@@ -54,5 +54,6 @@ from triton_dist_tpu.layers.gated_attn import (  # noqa: F401
 )
 from triton_dist_tpu.layers.held_moe import (  # noqa: F401
     HeldMoEParams,
+    held_moe_counted,
     held_moe_fwd,
 )

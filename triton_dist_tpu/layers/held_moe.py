@@ -16,10 +16,14 @@ weight; the chosen weights multiplied by a scale after the division;
 the shared expert under a sigmoid gate (`w_sgate`) or, with no such
 parameter, under none.
 
-The held experts' products are XLA's grouped matmul
-(`kernels.grouped_gemm`, `lax.ragged_dot`) over the pairs sorted by
-expert; absent pairs and padding rows sort behind every group and are
-multiplied by nothing.
+The held experts' products are the grouped matmul
+(`kernels.grouped_gemm`: on the chip the Pallas kernel
+`_moe_gmm_kernel`, which streams each non-empty group's weights once
+a row tile; `lax.ragged_dot` under the interpreter) over the pairs
+sorted by expert; absent pairs and padding rows sort behind every
+group, are multiplied by nothing and hold nothing a caller may read
+(the kernel visits no tile behind the groups): what comes back from
+the second product is masked to the groups' rows here.
 
   w_router (H, E) · w_gate_up (held, H, 2 I) gate | up · w_down
   (held, I, H) · ws_gate_up (H, 2 Is) · ws_down (Is, H) · w_sgate (H,)
@@ -40,7 +44,10 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from triton_dist_tpu.kernels.grouped_gemm import grouped_gemm
+from triton_dist_tpu.kernels.grouped_gemm import (
+    grouped_gemm,
+    grouped_gemm_tile_rows,
+)
 from triton_dist_tpu.kernels.moe_utils import (
     silu_mul,
     sort_by_expert,
@@ -78,6 +85,15 @@ def held_moe_fwd(x, valid, p: HeldMoEParams, top_k: int, offset: int,
     part plus the shared expert, and how many of the valid rows'
     (token, choice) pairs fell on a held and on an absent expert.
     With `layer`, the expert stacks are all layers' (module doc)."""
+    return held_moe_counted(x, valid, p, top_k, offset, layer, router)[:3]
+
+
+def held_moe_counted(x, valid, p: HeldMoEParams, top_k: int, offset: int,
+                     layer=None, router: RouterForm = RouterForm()):
+    """`held_moe_fwd` and a fourth result, `tile_rows`: the rows of
+    the tiles its two grouped matmuls visit (`grouped_gemm_tile_rows`;
+    `pairs_here` x 2 over it is the share of multiplied rows that are
+    real, 0 rows on the `ragged_dot` route)."""
     m, _ = x.shape
     w_gate_up, w_down = p.w_gate_up, p.w_down
     held = w_gate_up.shape[-3]
@@ -105,9 +121,12 @@ def held_moe_fwd(x, valid, p: HeldMoEParams, top_k: int, offset: int,
     # the held experts' weighted sum; each token's terms are summed in
     # the order of its choices, so a row's result is the same bit for
     # bit whatever else rides the step
-    h = grouped_gemm(x[sort.token_idx], w_gate_up, sizes)
+    xs = x[sort.token_idx]
+    h = grouped_gemm(xs, w_gate_up, sizes)
     act = silu_mul(h).astype(x.dtype)
     y = grouped_gemm(act, w_down, sizes)  # in the model's dtype
+    tile_rows = (grouped_gemm_tile_rows(xs, w_gate_up, sizes)
+                 + grouped_gemm_tile_rows(act, w_down, sizes))
     y = jnp.where((jnp.arange(pairs) < n_here)[:, None], y,
                   jnp.zeros((), y.dtype))
     out = jnp.einsum(
@@ -122,4 +141,4 @@ def held_moe_fwd(x, valid, p: HeldMoEParams, top_k: int, offset: int,
     out = out + shared
     pairs_here = jnp.sum(here, dtype=jnp.int32)
     pairs_absent = jnp.sum(valid[:, None] & ~held_here, dtype=jnp.int32)
-    return out.astype(x.dtype), pairs_here, pairs_absent
+    return out.astype(x.dtype), pairs_here, pairs_absent, tile_rows

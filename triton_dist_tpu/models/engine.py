@@ -379,7 +379,7 @@ class Engine:
         follow, and a kind the pattern lacks adds nothing. `stats`
         is a dict of () int32 counts the step made on the device
         (empty for the dense family; `moe_pairs_here` /
-        `moe_pairs_absent` for the hybrid one).
+        `moe_pairs_absent` / `moe_gmm_tile_rows` for the hybrid one).
 
         next_token is greedy argmax where temps<=0, else categorical on
         logits/temp under the slot's key — keys are derived host-side

@@ -90,7 +90,7 @@ from triton_dist_tpu.layers.gated_delta_net import (
 from triton_dist_tpu.layers.held_moe import (
     HeldMoEParams,
     RouterForm,
-    held_moe_fwd,
+    held_moe_counted,
     swiglu_fwd,
 )
 from triton_dist_tpu.layers.latent_attn import (
@@ -406,11 +406,11 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
         p = HeldMoEParams(p["w_router"], params["w_gate_up"],
                           params["w_down"], p["ws_gate_up"], p["ws_down"],
                           p.get("w_sgate"), p.get("router_bias"))
-        y, here, absent = held_moe_fwd(
+        y, *counts = held_moe_counted(
             normed(x, gain).reshape(slots * chunk, -1), valid, p,
             cfg.num_experts_per_tok, cfg.expert_offset, layer=layer,
             router=router)
-        return x + y.reshape(x.shape), here, absent
+        return x + y.reshape(x.shape), *counts
 
     def dense(x, gain, p):
         return x + swiglu_fwd(normed(x, gain), p["wd_gate_up"],
@@ -428,7 +428,7 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                     stack, start[kind] + i * count[kind] + at,
                     keepdims=False)
 
-            here = absent = jnp.int32(0)
+            here = absent = tile_rows = jnp.int32(0)
             recs, convs, rows, tails = [], [], [], []
             at = {kind: 0 for kind in count}
             for j, (mixer, ffn) in enumerate(period):
@@ -475,12 +475,13 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                 at[leaf] += 1
                 gain = row(params["post_ln"], "block", j)
                 if ffn == "moe":
-                    x, h_j, a_j = moe(
+                    x, h_j, a_j, t_j = moe(
                         x + y, gain,
                         {n: row(params[n], "moe", at["moe"]) for n in _MOE
                          if n in params},
                         start["moe"] + i * count["moe"] + at["moe"])
                     here, absent = here + h_j, absent + a_j
+                    tile_rows = tile_rows + t_j
                 else:
                     x = dense(x + y, gain, {
                         n: row(params[n], "dense", at["dense"])
@@ -494,7 +495,7 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                 rows = tuple(jnp.stack(r) for r in zip(*rows))
             state = (jnp.stack(recs), jnp.stack(convs)) if recs else ()
             tails = tuple(jnp.stack(t) for t in zip(*tails))
-            return x, (state, rows, tails, here, absent)
+            return x, (state, rows, tails, here, absent, tile_rows)
 
         return one_period
 
@@ -535,7 +536,8 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                 for o, n in zip(outs, pages_a_period) if n])
         for k in range(len(cache.pages)))
     stats = {"moe_pairs_here": sum(jnp.sum(o[3]) for o in outs),
-             "moe_pairs_absent": sum(jnp.sum(o[4]) for o in outs)}
+             "moe_pairs_absent": sum(jnp.sum(o[4]) for o in outs),
+             "moe_gmm_tile_rows": sum(jnp.sum(o[5]) for o in outs)}
     return x, rows, rec, conv, per_block(2, len(cache.win)), stats
 
 

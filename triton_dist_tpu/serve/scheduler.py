@@ -663,6 +663,7 @@ class Scheduler:
         stats = self.worker.last_stats
         self.obs.inc("moe_pairs", stats["moe_pairs_here"], held="here")
         self.obs.inc("moe_pairs", stats["moe_pairs_absent"], held="absent")
+        self.obs.inc("moe_gmm_tile_rows", stats["moe_gmm_tile_rows"])
         self.obs.inc("moe_expert_steps",
                      cfg.num_moe_layers * cfg.num_experts_held)
 
