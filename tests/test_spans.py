@@ -261,7 +261,7 @@ def test_a_step_appends_a_fixed_number_of_spans_and_no_jax_call(
         monkeypatch.setattr(mod, name, counting(getattr(mod, name)))
 
     class NoSpans(SpanLog):
-        def span(self, name, step=None, request=None):
+        def span(self, name, step=None, request=None, counts=None):
             return contextlib.nullcontext()
 
     per_step = {}
@@ -463,3 +463,211 @@ def test_a_profiler_session_sees_the_spans_as_tdt_events(eng1, tmp_path):
     assert len(waits) == len(seen)
     assert statistics.median(
         abs(a - b) for a, b in zip(waits, seen)) < 1_000_000
+
+
+# ---------- what a record counts (ISSUE 40) ----------
+
+
+def test_counts_are_kept_by_records_and_ignored_by_triples():
+    log = SpanLog()
+    with log.span("worker.step", step=2, counts={"width": 4, "rows": 9}):
+        with log.span("worker.put", step=2):
+            pass
+    log.add("step.retry", 5, 6, step=2, counts={"attempt": 1})
+    log.add("mark", 7, 7)
+    put, step, retry, mark = log.records()
+    assert step.counts == {"width": 4, "rows": 9}
+    assert retry.counts == {"attempt": 1}
+    assert put.counts is None and mark.counts is None  # the default
+    assert step[-1] is step.counts and len(step) == 8  # the one new field
+    assert log.triples() == [(r.name, r.t0_ns, r.t1_ns)
+                             for r in (put, step, retry, mark)]
+
+
+def _steps_agree_with_history(sch):
+    spans = {r.step: r for r in sch.spans.records()
+             if r.name == "worker.step"}
+    steps = [h for h in sch.history if h.get("kind") == "step"]
+    assert steps and sorted(spans) == [h["step"] for h in steps]
+    for h in steps:
+        assert spans[h["step"]].counts == {
+            "width": h["width"],
+            "rows": sum(n for _rid, _state, n in h["slots"].values())}
+    return {h["width"] for h in steps}
+
+
+def test_worker_step_counts_the_width_and_rows_history_has(served):
+    sch, _reqs, records = served
+    # prompts of 9-12 tokens in chunks of 4, then decode rows alone:
+    # both compiled widths ran
+    assert _steps_agree_with_history(sch) == {1, GEO["chunk"]}
+    others = [r for r in records if r.name != "worker.step"]
+    assert others and all(r.counts is None or r.name.startswith("jit.")
+                          for r in others)
+
+
+def test_a_hybrid_worker_step_counts_its_one_width():
+    mesh = make_mesh(mesh_shape=(1,), axis_names=("tp",))
+    cfg = ModelConfig.tiny_next(max_positions=64)
+    eng = Engine(cfg, mesh, max_len=64, fast_init=True)
+    sch = Scheduler(eng, slots=2, page=8)
+    for p in _prompts(eng, lens=(7, 5)):
+        sch.submit(p, max_new_tokens=3)
+    sch.run()
+    assert _steps_agree_with_history(sch) == {sch.chunk}
+
+
+# ---------- jit.* records (ISSUE 40) ----------
+
+
+def _jit_records(log, fun):
+    return [r for r in log.records() if r.name.startswith("jit.")
+            and r.counts and fun in r.counts.get("fun", "")]
+
+
+def test_a_function_jitted_here_leaves_jit_records_in_the_default_log(eng1):
+    import jax
+    import jax.numpy as jnp
+
+    sch = Scheduler(eng1, **GEO)  # makes the default log, installs
+    log = default_log()
+    assert log is sch.spans
+
+    @jax.jit
+    def a_function_of_this_test(x):
+        return jnp.tanh(x) * 3.0
+
+    before = time.perf_counter_ns()
+    a_function_of_this_test(jnp.ones((5, 7))).block_until_ready()
+    after = time.perf_counter_ns()
+    mine = _jit_records(log, "a_function_of_this_test")
+    names = [r.name for r in mine]
+    assert "jit.trace" in names and "jit.lower" in names
+    assert ("jit.compile" in names) != ("jit.cache_load" in names)
+    for r in mine:
+        assert r.parent is None and r.request is None  # roots, as add()'s
+        assert r.t0_ns <= r.t1_ns and before <= r.t1_ns <= after
+        assert r.step == log.step  # None: this scheduler ran no step
+    # outside `sched.` / `worker.`: the idle split leaves them out
+    assert not any(r.name.startswith(("sched.", "worker."))
+                   for r in mine)
+    # a second call is a cache hit in memory: nothing new
+    a_function_of_this_test(jnp.ones((5, 7))).block_until_ready()
+    assert len(_jit_records(log, "a_function_of_this_test")) == len(mine)
+
+
+def test_only_the_outermost_trace_is_recorded():
+    """JAX reports a trace for every jitted helper the traced function
+    calls, each inside the outer one's seconds: one record, whose
+    seconds are the whole of the tracing."""
+    import jax
+    import jax.numpy as jnp
+
+    from triton_dist_tpu.obs import spans
+
+    heard = []
+
+    def every_report(event, seconds, **kw):
+        if event.endswith("jaxpr_trace_duration"):
+            heard.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(every_report)
+
+    @jax.jit
+    def a_helper_of_this_test(x):
+        return jnp.where(x > 0, jnp.sin(x), 0.0)
+
+    @jax.jit
+    def the_outer_function_of_this_test(x):
+        return a_helper_of_this_test(x).sum() + a_helper_of_this_test(2 * x)
+
+    x = jnp.ones((3, 5))  # its own helpers are traced before the log
+    log = spans.new_default_log()
+    before = time.perf_counter_ns()
+    try:
+        the_outer_function_of_this_test(x).block_until_ready()
+    finally:
+        from jax._src import monitoring
+
+        monitoring.unregister_event_duration_listener(every_report)
+    wall = time.perf_counter_ns() - before
+    traced = [r for r in log.records() if r.name == "jit.trace"]
+    assert [r.counts["fun"] for r in traced] == [
+        "the_outer_function_of_this_test"]
+    # JAX told of the helper and of jnp's own, inside the outer's time
+    assert "a_helper_of_this_test" in heard and len(heard) > 3
+    assert sum(r.t1_ns - r.t0_ns for r in log.records()
+               if r.name.startswith("jit.")) <= wall
+    # the helper, called from the top, is an outermost trace itself
+    a_helper_of_this_test(jnp.ones((2,))).block_until_ready()
+    assert [r.counts["fun"] for r in log.records()
+            if r.name == "jit.trace"][-1] == "a_helper_of_this_test"
+
+
+def test_an_unrelated_event_leaves_no_record_and_nothing_raises():
+    import jax
+
+    from triton_dist_tpu.obs import spans
+
+    spans.install_jit_listener()
+    log = spans.new_default_log()
+    jax.monitoring.record_event_duration_secs(
+        "/jax/some/other/duration", 1.5, fun_name="f")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    assert len(log) == 0
+    # one of the four, without a name and with one that is no string
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/jaxpr_trace_duration", 0.25)
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/jaxpr_to_mlir_module_duration", 0.5, fun_name=7)
+    trace, lower = log.records()
+    assert (trace.name, trace.counts) == ("jit.trace", None)
+    assert (lower.name, lower.counts) == ("jit.lower", {"fun": "7"})
+    assert trace.t1_ns - trace.t0_ns == 250_000_000
+    # a broken log must not reach JAX's compile
+    spans._default = None
+    try:
+        jax.monitoring.record_event_duration_secs(
+            "/jax/core/compile/backend_compile_duration", 0.1, fun_name="f")
+    finally:
+        spans.new_default_log()
+
+
+def test_a_cache_hit_is_a_cache_load_with_the_function_s_name():
+    import jax
+
+    from triton_dist_tpu.obs import spans
+
+    log = spans.new_default_log()
+    record = jax.monitoring.record_event_duration_secs
+    record("/jax/compilation_cache/cache_retrieval_time_sec", 0.75)
+    assert len(log) == 0  # the hit has no name yet
+    record("/jax/core/compile/backend_compile_duration", 1.0,
+           fun_name="jit(step)")
+    record("/jax/core/compile/backend_compile_duration", 9.0,
+           fun_name="jit(other)")
+    load, compiled = log.records()
+    assert (load.name, load.counts) == ("jit.cache_load",
+                                        {"fun": "jit(step)"})
+    assert (compiled.name, compiled.counts) == ("jit.compile",
+                                                {"fun": "jit(other)"})
+
+
+def test_a_listener_installed_twice_writes_once():
+    import jax
+
+    from triton_dist_tpu.obs import spans
+
+    def mine():
+        from jax._src import monitoring
+
+        return [cb for cb in monitoring.get_event_duration_listeners()
+                if cb is spans._on_jit_duration]
+
+    spans.install_jit_listener()
+    spans.install_jit_listener()
+    log = spans.new_default_log()  # installs too
+    assert len(mine()) == 1
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/jaxpr_trace_duration", 0.5, fun_name="g")
+    assert [r.name for r in log.records()] == ["jit.trace"]

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from triton_dist_tpu.layers.attention import gqa_attention
 from triton_dist_tpu.layers.norm import rms_norm
+from triton_dist_tpu.layers.parts import part
 from triton_dist_tpu.layers.rope import apply_rope
 from triton_dist_tpu.layers.tp_attn import _scatter_kv
 
@@ -58,25 +59,28 @@ def gated_attn_fwd(x, p: GatedAttnParams, spec: GatedAttnSpec, cos, sin,
     into this call's own copy of the view at `positions`)."""
     b, c, _ = x.shape
     hq, hkv, d = spec.num_q_heads, spec.num_kv_heads, spec.head_dim
-    qg = jnp.dot(x, p.w_q, preferred_element_type=jnp.float32).astype(
-        x.dtype).reshape(b, c, hq, 2 * d)
-    q, gate = qg[..., :d], qg[..., d:]
-    kv = jnp.dot(x, p.w_kv, preferred_element_type=jnp.float32).astype(
-        x.dtype)
-    k = kv[..., :hkv * d].reshape(b, c, hkv, d)
-    v = kv[..., hkv * d:].reshape(b, c, hkv, d)
-    q = rms_norm(q, p.q_norm, eps, zero_centred=True)
-    k = rms_norm(k, p.k_norm, eps, zero_centred=True)
-    q = _partial_rope(q, cos, sin, positions, spec.rotary_dim)
-    k = _partial_rope(k, cos, sin, positions, spec.rotary_dim)
-    k_cache, v_cache = kv_cache
-    k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
-    out = gqa_attention(q, _scatter_kv(k_cache, k, positions),
-                        _scatter_kv(v_cache, v, positions), causal=True,
-                        q_positions=positions, kv_len=kv_len,
-                        prefill_impl=attn_impl)
-    out = out.astype(jnp.float32) * jax.nn.sigmoid(
-        gate.astype(jnp.float32))
-    y = jnp.dot(out.reshape(b, c, hq * d).astype(x.dtype), p.w_o,
-                preferred_element_type=jnp.float32).astype(x.dtype)
+    with part("attn.proj"):
+        qg = jnp.dot(x, p.w_q, preferred_element_type=jnp.float32).astype(
+            x.dtype).reshape(b, c, hq, 2 * d)
+        q, gate = qg[..., :d], qg[..., d:]
+        kv = jnp.dot(x, p.w_kv, preferred_element_type=jnp.float32).astype(
+            x.dtype)
+        k = kv[..., :hkv * d].reshape(b, c, hkv, d)
+        v = kv[..., hkv * d:].reshape(b, c, hkv, d)
+    with part("attn.core"):
+        q = rms_norm(q, p.q_norm, eps, zero_centred=True)
+        k = rms_norm(k, p.k_norm, eps, zero_centred=True)
+        q = _partial_rope(q, cos, sin, positions, spec.rotary_dim)
+        k = _partial_rope(k, cos, sin, positions, spec.rotary_dim)
+        k_cache, v_cache = kv_cache
+        k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+        out = gqa_attention(q, _scatter_kv(k_cache, k, positions),
+                            _scatter_kv(v_cache, v, positions), causal=True,
+                            q_positions=positions, kv_len=kv_len,
+                            prefill_impl=attn_impl)
+    with part("attn.proj"):
+        out = out.astype(jnp.float32) * jax.nn.sigmoid(
+            gate.astype(jnp.float32))
+        y = jnp.dot(out.reshape(b, c, hq * d).astype(x.dtype), p.w_o,
+                    preferred_element_type=jnp.float32).astype(x.dtype)
     return y, (k, v)

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from triton_dist_tpu.layers.norm import rms_norm
+from triton_dist_tpu.layers.parts import part
 
 # the delta rule's products are float32 and stay float32 on the MXU:
 # the default precision would round the state's operands to bfloat16
@@ -222,6 +223,7 @@ def _l2norm(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
+@part("mixer.conv")
 def _causal_conv(mixed, conv, conv_w, n_valid):
     """SiLU of the causal depthwise convolution of `mixed` (B, C,
     channels) over [carried K-1 inputs | chunk], and the K-1 inputs to
@@ -246,40 +248,48 @@ def gated_delta_net_fwd(x, p: GDNParams, spec: GDNSpec, rec, conv,
     hk, hv, dk, dv = (spec.num_k_heads, spec.num_v_heads, spec.k_dim,
                       spec.v_dim)
     ch = spec.channels
-    valid = jnp.arange(c)[None, :] < n_valid[:, None]  # (B, C)
-    rec = jnp.where(fresh[:, None, None, None], 0.0, rec)
-    conv = jnp.where(fresh[:, None, None], jnp.zeros((), conv.dtype), conv)
+    with part("mixer.rule"):  # a fresh slot's state
+        valid = jnp.arange(c)[None, :] < n_valid[:, None]  # (B, C)
+        rec = jnp.where(fresh[:, None, None, None], 0.0, rec)
+    with part("mixer.conv"):
+        conv = jnp.where(fresh[:, None, None], jnp.zeros((), conv.dtype),
+                         conv)
 
-    qkvz = jnp.dot(x, p.w_qkvz,
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    ba = jnp.dot(x, p.w_ba, preferred_element_type=jnp.float32)
-    mixed, z = qkvz[..., :ch], qkvz[..., ch:]
+    with part("mixer.proj"):
+        qkvz = jnp.dot(x, p.w_qkvz,
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+        ba = jnp.dot(x, p.w_ba, preferred_element_type=jnp.float32)
+        mixed, z = qkvz[..., :ch], qkvz[..., ch:]
 
     mixed, conv = _causal_conv(mixed, conv, p.conv_w, n_valid)
 
     f32 = jnp.float32
-    q = mixed[..., :hk * dk].reshape(b, c, hk, dk).astype(f32)
-    k = mixed[..., hk * dk:2 * hk * dk].reshape(b, c, hk, dk).astype(f32)
-    v = mixed[..., 2 * hk * dk:].reshape(b, c, hv, dv).astype(f32)
-    beta = jnp.where(valid[..., None], jax.nn.sigmoid(ba[..., :hv]), 0.0)
-    g = -jnp.exp(p.a_log.astype(f32)) * jax.nn.softplus(
-        ba[..., hv:] + p.dt_bias.astype(f32))
-    g = jnp.where(valid[..., None], g, 0.0)
-    rep = hv // hk
-    q = jnp.repeat(_l2norm(q) * dk ** -0.5, rep, axis=2)
-    k = jnp.repeat(_l2norm(k), rep, axis=2)
 
     def heads_first(t):
         return jnp.moveaxis(t, 2, 1)
 
-    o, rec = chunk_gated_delta_rule(
-        heads_first(q), heads_first(k), heads_first(v),
-        heads_first(g), heads_first(beta), rec, sub_chunk(c))
-    o = jnp.moveaxis(o, 1, 2)  # (B, C, Hv, dv)
-    zf = z.reshape(b, c, hv, dv).astype(f32)
-    o = rms_norm(o, p.norm, eps) * jax.nn.silu(zf)
-    y = jnp.dot(o.reshape(b, c, hv * dv).astype(x.dtype), p.w_out,
-                preferred_element_type=jnp.float32).astype(x.dtype)
+    with part("mixer.rule"):
+        q = mixed[..., :hk * dk].reshape(b, c, hk, dk).astype(f32)
+        k = mixed[..., hk * dk:2 * hk * dk].reshape(
+            b, c, hk, dk).astype(f32)
+        v = mixed[..., 2 * hk * dk:].reshape(b, c, hv, dv).astype(f32)
+        beta = jnp.where(valid[..., None], jax.nn.sigmoid(ba[..., :hv]),
+                         0.0)
+        g = -jnp.exp(p.a_log.astype(f32)) * jax.nn.softplus(
+            ba[..., hv:] + p.dt_bias.astype(f32))
+        g = jnp.where(valid[..., None], g, 0.0)
+        rep = hv // hk
+        q = jnp.repeat(_l2norm(q) * dk ** -0.5, rep, axis=2)
+        k = jnp.repeat(_l2norm(k), rep, axis=2)
+        o, rec = chunk_gated_delta_rule(
+            heads_first(q), heads_first(k), heads_first(v),
+            heads_first(g), heads_first(beta), rec, sub_chunk(c))
+        o = jnp.moveaxis(o, 1, 2)  # (B, C, Hv, dv)
+    with part("mixer.proj"):
+        zf = z.reshape(b, c, hv, dv).astype(f32)
+        o = rms_norm(o, p.norm, eps) * jax.nn.silu(zf)
+        y = jnp.dot(o.reshape(b, c, hv * dv).astype(x.dtype), p.w_out,
+                    preferred_element_type=jnp.float32).astype(x.dtype)
     return y, rec, conv
 
 
@@ -303,40 +313,47 @@ def kda_fwd(x, p: KDAParams, spec: GDNSpec, rec, conv, n_valid, fresh,
     hv, dk, dv = spec.num_v_heads, spec.k_dim, spec.v_dim
     assert spec.num_k_heads == hv
     rank = p.w_fb.shape[0]
-    valid = jnp.arange(c)[None, :] < n_valid[:, None]  # (B, C)
-    rec = jnp.where(fresh[:, None, None, None], 0.0, rec)
-    conv = jnp.where(fresh[:, None, None], jnp.zeros((), conv.dtype), conv)
+    with part("mixer.rule"):  # a fresh slot's state
+        valid = jnp.arange(c)[None, :] < n_valid[:, None]  # (B, C)
+        rec = jnp.where(fresh[:, None, None, None], 0.0, rec)
+    with part("mixer.conv"):
+        conv = jnp.where(fresh[:, None, None], jnp.zeros((), conv.dtype),
+                         conv)
 
     f32 = jnp.float32
-    mixed = jnp.dot(x, p.w_qkv,
-                    preferred_element_type=f32).astype(x.dtype)
-    fgb = jnp.dot(x, p.w_fgb, preferred_element_type=f32)
-    f_a = fgb[..., :rank].astype(x.dtype)
-    g_a = fgb[..., rank:2 * rank].astype(x.dtype)
+    with part("mixer.proj"):
+        mixed = jnp.dot(x, p.w_qkv,
+                        preferred_element_type=f32).astype(x.dtype)
+        fgb = jnp.dot(x, p.w_fgb, preferred_element_type=f32)
+        f_a = fgb[..., :rank].astype(x.dtype)
+        g_a = fgb[..., rank:2 * rank].astype(x.dtype)
     mixed, conv = _causal_conv(mixed, conv, p.conv_w, n_valid)
-
-    q = mixed[..., :hv * dk].reshape(b, c, hv, dk).astype(f32)
-    k = mixed[..., hv * dk:2 * hv * dk].reshape(b, c, hv, dk).astype(f32)
-    v = mixed[..., 2 * hv * dk:].reshape(b, c, hv, dv).astype(f32)
-    beta = jnp.where(valid[..., None],
-                     jax.nn.sigmoid(fgb[..., 2 * rank:]), 0.0)
-    g = jax.nn.softplus(
-        jnp.dot(f_a, p.w_fb, preferred_element_type=f32)
-        + p.dt_bias.astype(f32)).reshape(b, c, hv, dk)
-    g = -jnp.exp(p.a_log.astype(f32))[:, None] * g
-    g = jnp.where(valid[..., None, None], g, 0.0)
 
     def heads_first(t):
         return jnp.moveaxis(t, 2, 1)
 
-    o, rec = chunk_gated_delta_rule(
-        heads_first(_l2norm(q) * dk ** -0.5), heads_first(_l2norm(k)),
-        heads_first(v), heads_first(g), heads_first(beta), rec,
-        sub_chunk(c))
-    o = jnp.moveaxis(o, 1, 2)  # (B, C, Hv, dv)
-    gate = jnp.dot(g_a, p.w_gb, preferred_element_type=f32)
-    o = rms_norm(o, p.norm, eps) * jax.nn.sigmoid(
-        gate.reshape(b, c, hv, dv))
-    y = jnp.dot(o.reshape(b, c, hv * dv).astype(x.dtype), p.w_out,
-                preferred_element_type=f32).astype(x.dtype)
+    with part("mixer.rule"):
+        q = mixed[..., :hv * dk].reshape(b, c, hv, dk).astype(f32)
+        k = mixed[..., hv * dk:2 * hv * dk].reshape(
+            b, c, hv, dk).astype(f32)
+        v = mixed[..., 2 * hv * dk:].reshape(b, c, hv, dv).astype(f32)
+        beta = jnp.where(valid[..., None],
+                         jax.nn.sigmoid(fgb[..., 2 * rank:]), 0.0)
+        with part("mixer.proj"):  # the gate's second, low-rank factor
+            g = jnp.dot(f_a, p.w_fb, preferred_element_type=f32)
+        g = jax.nn.softplus(g + p.dt_bias.astype(f32)).reshape(
+            b, c, hv, dk)
+        g = -jnp.exp(p.a_log.astype(f32))[:, None] * g
+        g = jnp.where(valid[..., None, None], g, 0.0)
+        o, rec = chunk_gated_delta_rule(
+            heads_first(_l2norm(q) * dk ** -0.5), heads_first(_l2norm(k)),
+            heads_first(v), heads_first(g), heads_first(beta), rec,
+            sub_chunk(c))
+        o = jnp.moveaxis(o, 1, 2)  # (B, C, Hv, dv)
+    with part("mixer.proj"):
+        gate = jnp.dot(g_a, p.w_gb, preferred_element_type=f32)
+        o = rms_norm(o, p.norm, eps) * jax.nn.sigmoid(
+            gate.reshape(b, c, hv, dv))
+        y = jnp.dot(o.reshape(b, c, hv * dv).astype(x.dtype), p.w_out,
+                    preferred_element_type=f32).astype(x.dtype)
     return y, rec, conv

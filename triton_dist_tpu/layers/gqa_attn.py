@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from triton_dist_tpu.layers.attention import gqa_attention
 from triton_dist_tpu.layers.norm import rms_norm
+from triton_dist_tpu.layers.parts import part
 from triton_dist_tpu.layers.rope import apply_rope
 from triton_dist_tpu.layers.tp_attn import _scatter_kv
 
@@ -53,16 +54,19 @@ class GQAttnParams(NamedTuple):
 def _qkv(x, p: GQAttnParams, spec: GQAttnSpec, eps: float):
     b, c, _ = x.shape
     hq, hkv, d = spec
-    q = jnp.dot(x, p.w_q, preferred_element_type=jnp.float32).astype(
-        x.dtype).reshape(b, c, hq, d)
-    kv = jnp.dot(x, p.w_kv, preferred_element_type=jnp.float32).astype(
-        x.dtype)
-    k = kv[..., :hkv * d].reshape(b, c, hkv, d)
-    v = kv[..., hkv * d:].reshape(b, c, hkv, d)
-    return (rms_norm(q, p.q_norm, eps, zero_centred=False),
-            rms_norm(k, p.k_norm, eps, zero_centred=False), v)
+    with part("attn.proj"):
+        q = jnp.dot(x, p.w_q, preferred_element_type=jnp.float32).astype(
+            x.dtype).reshape(b, c, hq, d)
+        kv = jnp.dot(x, p.w_kv, preferred_element_type=jnp.float32).astype(
+            x.dtype)
+        k = kv[..., :hkv * d].reshape(b, c, hkv, d)
+        v = kv[..., hkv * d:].reshape(b, c, hkv, d)
+    with part("attn.core"):
+        return (rms_norm(q, p.q_norm, eps, zero_centred=False),
+                rms_norm(k, p.k_norm, eps, zero_centred=False), v)
 
 
+@part("attn.proj")
 def _out(out, x, p: GQAttnParams):
     b, c, _ = x.shape
     return jnp.dot(out.reshape(b, c, -1).astype(x.dtype), p.w_o,
@@ -75,12 +79,13 @@ def global_attn_fwd(x, p: GQAttnParams, spec: GQAttnSpec, positions,
     (B, C) absolute; kv_len (B,). Returns (y (B, C, H), (k, v): the
     chunk's rows (B, C, Hkv, D) in the cache's dtype)."""
     q, k, v = _qkv(x, p, spec, eps)
-    k_cache, v_cache = kv_cache
-    k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
-    out = gqa_attention(q, _scatter_kv(k_cache, k, positions),
-                        _scatter_kv(v_cache, v, positions), causal=True,
-                        q_positions=positions, kv_len=kv_len,
-                        prefill_impl=attn_impl)
+    with part("attn.core"):
+        k_cache, v_cache = kv_cache
+        k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+        out = gqa_attention(q, _scatter_kv(k_cache, k, positions),
+                            _scatter_kv(v_cache, v, positions), causal=True,
+                            q_positions=positions, kv_len=kv_len,
+                            prefill_impl=attn_impl)
     return _out(out, x, p), (k, v)
 
 
@@ -93,20 +98,23 @@ def window_attn_fwd(x, p: GQAttnParams, spec: GQAttnSpec, cos, sin,
     (y (B, C, H), the new tail (k, v))."""
     b, c, _ = x.shape
     q, k, v = _qkv(x, p, spec, eps)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
-    k_tail, v_tail = tail
-    keys = jnp.concatenate([k_tail, k.astype(k_tail.dtype)], axis=1)
-    vals = jnp.concatenate([v_tail, v.astype(v_tail.dtype)], axis=1)
-    # in the step's own coordinates: the tail is 0 .. window - 1, the
-    # chunk's column j is window + j
-    local = jnp.broadcast_to(window + jnp.arange(c)[None, :], (b, c))
-    out = gqa_attention(q, keys, vals, causal=True, q_positions=local,
-                        prefill_impl=attn_impl, window=window,
-                        kv_from=jnp.maximum(window - lengths, 0))
+    with part("attn.core"):
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+        k_tail, v_tail = tail
+        keys = jnp.concatenate([k_tail, k.astype(k_tail.dtype)], axis=1)
+        vals = jnp.concatenate([v_tail, v.astype(v_tail.dtype)], axis=1)
+        # in the step's own coordinates: the tail is 0 .. window - 1,
+        # the chunk's column j is window + j
+        local = jnp.broadcast_to(window + jnp.arange(c)[None, :], (b, c))
+        out = gqa_attention(q, keys, vals, causal=True, q_positions=local,
+                            prefill_impl=attn_impl, window=window,
+                            kv_from=jnp.maximum(window - lengths, 0))
 
     def shift(rows, n):  # the last `window` of [tail | n valid rows]
         return jax.lax.dynamic_slice_in_dim(rows, n, window, axis=0)
 
-    return _out(out, x, p), (jax.vmap(shift)(keys, n_valid),
-                             jax.vmap(shift)(vals, n_valid))
+    y = _out(out, x, p)
+    with part("pool.scatter"):
+        return y, (jax.vmap(shift)(keys, n_valid),
+                   jax.vmap(shift)(vals, n_valid))

@@ -53,6 +53,7 @@ from triton_dist_tpu.kernels.moe_utils import (
     sort_by_expert,
     topk_routing,
 )
+from triton_dist_tpu.layers.parts import part
 
 
 class HeldMoEParams(NamedTuple):
@@ -94,51 +95,67 @@ def held_moe_counted(x, valid, p: HeldMoEParams, top_k: int, offset: int,
     the tiles its two grouped matmuls visit (`grouped_gemm_tile_rows`;
     `pairs_here` x 2 over it is the share of multiplied rows that are
     real, 0 rows on the `ragged_dot` route)."""
+    # the parts (layers/parts.py) are named where the work stands: no
+    # operation moved for a name's sake
     m, _ = x.shape
     w_gate_up, w_down = p.w_gate_up, p.w_down
     held = w_gate_up.shape[-3]
-    logits = jnp.dot(x.astype(jnp.float32), p.w_router.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    weights, ids = topk_routing(logits, top_k, score=router.score,
-                                bias=p.router_bias, scale=router.scale)
-    local = ids - offset
-    held_here = (local >= 0) & (local < held)
-    here = held_here & valid[:, None]
-    # one group behind the held experts takes what is not computed here
-    sort = sort_by_expert(jnp.where(here, local, held), held + 1)
-    sizes = sort.group_sizes[:held]
-    n_here = jnp.sum(sizes)
-    if layer is not None:
-        layers = w_gate_up.shape[0]
-        sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((layers * held,), sizes.dtype), sizes,
-            (layer * held,))
-        w_gate_up = w_gate_up.reshape((-1,) + w_gate_up.shape[2:])
-        w_down = w_down.reshape((-1,) + w_down.shape[2:])
+    with part("moe.route"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         p.w_router.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        weights, ids = topk_routing(logits, top_k, score=router.score,
+                                    bias=p.router_bias, scale=router.scale)
+        local = ids - offset
+        held_here = (local >= 0) & (local < held)
+        here = held_here & valid[:, None]
+    with part("moe.dispatch"):
+        # one group behind the held experts takes what is not computed
+        # here
+        sort = sort_by_expert(jnp.where(here, local, held), held + 1)
+        sizes = sort.group_sizes[:held]
+        n_here = jnp.sum(sizes)
+        if layer is not None:
+            layers = w_gate_up.shape[0]
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((layers * held,), sizes.dtype), sizes,
+                (layer * held,))
+            w_gate_up = w_gate_up.reshape((-1,) + w_gate_up.shape[2:])
+            w_down = w_down.reshape((-1,) + w_down.shape[2:])
     pairs = m * top_k
-    mine = jnp.where(here, weights, 0.0)
+    with part("moe.route"):
+        mine = jnp.where(here, weights, 0.0)
 
     # the held experts' weighted sum; each token's terms are summed in
     # the order of its choices, so a row's result is the same bit for
     # bit whatever else rides the step
-    xs = x[sort.token_idx]
-    h = grouped_gemm(xs, w_gate_up, sizes)
-    act = silu_mul(h).astype(x.dtype)
-    y = grouped_gemm(act, w_down, sizes)  # in the model's dtype
-    tile_rows = (grouped_gemm_tile_rows(xs, w_gate_up, sizes)
-                 + grouped_gemm_tile_rows(act, w_down, sizes))
-    y = jnp.where((jnp.arange(pairs) < n_here)[:, None], y,
-                  jnp.zeros((), y.dtype))
-    out = jnp.einsum(
-        "mkh,mk->mh",
-        y[sort.unsort_idx].reshape(m, top_k, -1).astype(jnp.float32), mine)
+    with part("moe.dispatch"):
+        xs = x[sort.token_idx]
+    with part("moe.experts"):
+        h = grouped_gemm(xs, w_gate_up, sizes)
+        act = silu_mul(h).astype(x.dtype)
+        y = grouped_gemm(act, w_down, sizes)  # in the model's dtype
+    with part("moe.dispatch"):  # the visits' metadata, counted
+        tile_rows = (grouped_gemm_tile_rows(xs, w_gate_up, sizes)
+                     + grouped_gemm_tile_rows(act, w_down, sizes))
+    with part("moe.combine"):
+        y = jnp.where((jnp.arange(pairs) < n_here)[:, None], y,
+                      jnp.zeros((), y.dtype))
+        out = jnp.einsum(
+            "mkh,mk->mh",
+            y[sort.unsort_idx].reshape(m, top_k, -1).astype(jnp.float32),
+            mine)
 
-    shared = swiglu_fwd(x, p.ws_gate_up, p.ws_down)
-    if p.w_sgate is not None:
-        shared = jax.nn.sigmoid(jnp.dot(
-            x.astype(jnp.float32), p.w_sgate.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))[:, None] * shared
-    out = out + shared
-    pairs_here = jnp.sum(here, dtype=jnp.int32)
-    pairs_absent = jnp.sum(valid[:, None] & ~held_here, dtype=jnp.int32)
-    return out.astype(x.dtype), pairs_here, pairs_absent, tile_rows
+    with part("moe.shared"):
+        shared = swiglu_fwd(x, p.ws_gate_up, p.ws_down)
+        if p.w_sgate is not None:
+            shared = jax.nn.sigmoid(jnp.dot(
+                x.astype(jnp.float32), p.w_sgate.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))[:, None] * shared
+    with part("moe.combine"):
+        out = out + shared
+    with part("moe.route"):
+        pairs_here = jnp.sum(here, dtype=jnp.int32)
+        pairs_absent = jnp.sum(valid[:, None] & ~held_here, dtype=jnp.int32)
+    with part("moe.combine"):
+        return out.astype(x.dtype), pairs_here, pairs_absent, tile_rows

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from triton_dist_tpu.layers.attention import gqa_attention_blockwise
 from triton_dist_tpu.layers.norm import rms_norm
+from triton_dist_tpu.layers.parts import part
 from triton_dist_tpu.layers.tp_attn import _scatter_kv
 
 
@@ -79,28 +80,33 @@ def latent_attn_fwd(x, p: LatentAttnParams, spec: LatentAttnSpec,
     b, c, _ = x.shape
     hq, r, dn, dr, dv = spec
     f32 = jnp.float32
-    q = jnp.dot(x, p.w_q, preferred_element_type=f32).astype(
-        x.dtype).reshape(b, c, hq, dn + dr)
-    a = jnp.dot(x, p.w_a, preferred_element_type=f32).astype(x.dtype)
-    row = jnp.concatenate(
-        [rms_norm(a[..., :r], p.kv_norm, eps), a[..., r:]],
-        axis=-1).astype(view.dtype)[:, :, None, :]
-    w_b = p.w_b.reshape(r, hq, dn + dv)
-    q_hat = jnp.einsum("bchn,rhn->bchr", q[..., :dn], w_b[..., :dn],
+    with part("attn.proj"):
+        q = jnp.dot(x, p.w_q, preferred_element_type=f32).astype(
+            x.dtype).reshape(b, c, hq, dn + dr)
+        a = jnp.dot(x, p.w_a, preferred_element_type=f32).astype(x.dtype)
+    with part("attn.core"):
+        row = jnp.concatenate(
+            [rms_norm(a[..., :r], p.kv_norm, eps), a[..., r:]],
+            axis=-1).astype(view.dtype)[:, :, None, :]
+    with part("attn.proj"):  # the absorbed key projection
+        w_b = p.w_b.reshape(r, hq, dn + dv)
+        q_hat = jnp.einsum("bchn,rhn->bchr", q[..., :dn], w_b[..., :dn],
+                           preferred_element_type=f32).astype(x.dtype)
+    with part("attn.core"):
+        q_abs = jnp.concatenate([q_hat, q[..., dn:]], axis=-1)
+        pad = view.shape[-1] - spec.row  # a page row padded to the lanes
+        if pad:
+            row, q_abs = (jnp.pad(t, ((0, 0),) * 3 + ((0, pad),))
+                          for t in (row, q_abs))
+        view = _scatter_kv(view, row, positions)
+        asks = jnp.where(jnp.arange(c)[None, :] < n_valid[:, None],
+                         positions, -1)
+        o_hat = gqa_attention_blockwise(
+            q_abs, view, view[..., :r], causal=True, q_positions=asks,
+            kv_len=kv_len, scale=(dn + dr) ** -0.5, impl=attn_impl)
+    with part("attn.proj"):  # the absorbed value projection, then out
+        o = jnp.einsum("bchr,rhv->bchv", o_hat, w_b[..., dn:],
                        preferred_element_type=f32).astype(x.dtype)
-    q_abs = jnp.concatenate([q_hat, q[..., dn:]], axis=-1)
-    pad = view.shape[-1] - spec.row  # a page row padded to the lanes
-    if pad:
-        row, q_abs = (jnp.pad(t, ((0, 0),) * 3 + ((0, pad),))
-                      for t in (row, q_abs))
-    view = _scatter_kv(view, row, positions)
-    asks = jnp.where(jnp.arange(c)[None, :] < n_valid[:, None], positions,
-                     -1)
-    o_hat = gqa_attention_blockwise(
-        q_abs, view, view[..., :r], causal=True, q_positions=asks,
-        kv_len=kv_len, scale=(dn + dr) ** -0.5, impl=attn_impl)
-    o = jnp.einsum("bchr,rhv->bchv", o_hat, w_b[..., dn:],
-                   preferred_element_type=f32).astype(x.dtype)
-    y = jnp.dot(o.reshape(b, c, hq * dv), p.w_o,
-                preferred_element_type=f32).astype(x.dtype)
+        y = jnp.dot(o.reshape(b, c, hq * dv), p.w_o,
+                    preferred_element_type=f32).astype(x.dtype)
     return y, (row,)

@@ -32,6 +32,7 @@ from triton_dist_tpu.kernels import (
 )
 from triton_dist_tpu.layers.attention import gqa_attention
 from triton_dist_tpu.layers.norm import rms_norm
+from triton_dist_tpu.layers.parts import part
 from triton_dist_tpu.layers.rope import apply_rope
 from triton_dist_tpu.runtime.init import TP_AXIS
 
@@ -74,6 +75,7 @@ def _qk_norm_rope(q, k, params: TPAttnParams, cos, sin, positions):
     return q, k
 
 
+@part("attn.core")
 def _attn_core(qkv, params, spec, batch, cos, sin, positions, kv_cache,
                kv_len, attn_impl=None, attn_block=None):
     """Shared middle: split + qknorm + rope + (cached) attention.
@@ -132,16 +134,20 @@ def tp_attn_xla_fwd(x_shard, params: TPAttnParams, spec: TPAttnSpec,
                     kv_cache=None, kv_len=None, attn_impl=None,
                     attn_block=None):
     """Unfused parity path (ref torch_fwd, tp_attn.py:180)."""
-    x_full = jax.lax.all_gather(x_shard, axis, tiled=True)
-    qkv = jnp.dot(x_full, params.w_qkv,
-                  preferred_element_type=jnp.float32).astype(x_shard.dtype)
+    with part("attn.proj"):
+        x_full = jax.lax.all_gather(x_shard, axis, tiled=True)
+        qkv = jnp.dot(x_full, params.w_qkv,
+                      preferred_element_type=jnp.float32).astype(
+                          x_shard.dtype)
     out, rows = _attn_core(qkv, params, spec, batch, cos, sin,
                                 positions, kv_cache, kv_len, attn_impl,
                                 attn_block)
-    partial = jnp.dot(out, params.w_o, preferred_element_type=jnp.float32)
-    y = jax.lax.psum_scatter(
-        partial.astype(x_shard.dtype), axis, tiled=True
-    )
+    with part("attn.proj"):
+        partial = jnp.dot(out, params.w_o,
+                          preferred_element_type=jnp.float32)
+        y = jax.lax.psum_scatter(
+            partial.astype(x_shard.dtype), axis, tiled=True
+        )
     return y, rows
 
 
@@ -158,12 +164,14 @@ def tp_attn_dist_fwd(x_shard, params: TPAttnParams, spec: TPAttnSpec,
 
     # primary(): build-safe under trace.building() (buffers dropped; see
     # tp_mlp.dist_fwd)
-    qkv = primary(ag_gemm(x_shard, params.w_qkv, axis=axis,
-                          config=ag_config))
+    with part("attn.proj"):
+        qkv = primary(ag_gemm(x_shard, params.w_qkv, axis=axis,
+                              config=ag_config))
     out, rows = _attn_core(qkv, params, spec, batch, cos, sin,
                                 positions, kv_cache, kv_len, attn_impl,
                                 attn_block)
-    y = primary(gemm_rs(out, params.w_o, axis=axis, config=rs_config))
+    with part("attn.proj"):
+        y = primary(gemm_rs(out, params.w_o, axis=axis, config=rs_config))
     return y, rows
 
 
@@ -174,12 +182,15 @@ def tp_attn_ar_fwd(x_full, params: TPAttnParams, spec: TPAttnSpec,
                    rs_config: Optional[GemmRsConfig] = None):
     """Replicated-activation path (ref AR fwd modes, tp_attn.py:254-330):
     local QKV gemm, attention, fused gemm+allreduce O projection."""
-    qkv = jnp.dot(x_full, params.w_qkv,
-                  preferred_element_type=jnp.float32).astype(x_full.dtype)
+    with part("attn.proj"):
+        qkv = jnp.dot(x_full, params.w_qkv,
+                      preferred_element_type=jnp.float32).astype(
+                          x_full.dtype)
     out, rows = _attn_core(qkv, params, spec, batch, cos, sin,
                                 positions, kv_cache, kv_len, attn_impl,
                                 attn_block)
-    y = gemm_ar(out, params.w_o, axis=axis, config=rs_config)
+    with part("attn.proj"):
+        y = gemm_ar(out, params.w_o, axis=axis, config=rs_config)
     return y, rows
 
 

@@ -40,6 +40,7 @@ from triton_dist_tpu.layers import (
     rms_norm,
     rope_table,
 )
+from triton_dist_tpu.layers.parts import part
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.kv_cache import KVCache
 from triton_dist_tpu.plan import execute as plan_exec
@@ -285,22 +286,28 @@ def _layer_fwd(cfg: ModelConfig, spec: TPAttnSpec, cos, sin, positions,
         q_norm=lp.q_norm if cfg.use_qk_norm else None,
         k_norm=lp.k_norm if cfg.use_qk_norm else None,
     )
-    h = rms_norm(x, lp.input_ln, cfg.rms_eps)
+    # the parts (layers/parts.py): the block's norm goes with the
+    # projection it feeds, the residual with what it adds; the
+    # attention layer names its own projections and core inside
+    with part("attn.proj"):
+        h = rms_norm(x, lp.input_ln, cfg.rms_eps)
     attn_out, rows = plan_exec.attn_fwd(
         plan, h, attn_params, spec, cos, sin, positions, batch,
         axis, kv, kv_len,
     )
-    x = x + attn_out
-    h = rms_norm(x, lp.post_attn_ln, cfg.rms_eps)
+    with part("attn.proj"):
+        x = x + attn_out
     if cfg.is_moe:
         from triton_dist_tpu.layers import TPMoEParams
 
         ffn_params = TPMoEParams(lp.w_router, lp.w_gate_up, lp.w_down)
     else:
         ffn_params = TPMLPParams(lp.w_gate, lp.w_up, lp.w_down)
-    mlp_out = plan_exec.ffn_fwd(plan, h, ffn_params, axis,
-                                top_k=cfg.num_experts_per_tok)
-    x = x + mlp_out
+    with part("moe.experts" if cfg.is_moe else "ffn.dense"):
+        h = rms_norm(x, lp.post_attn_ln, cfg.rms_eps)
+        mlp_out = plan_exec.ffn_fwd(plan, h, ffn_params, axis,
+                                    top_k=cfg.num_experts_per_tok)
+        x = x + mlp_out
     return x, rows
 
 
@@ -364,8 +371,9 @@ def forward_rows(
     positions = start[:, None] + jnp.arange(s)[None, :]  # (B, S)
     kv_len = start + s
 
-    x = params.embed[tokens].reshape(m, h_dim)
-    x = plan_exec.shard_tokens(x, axis, plan)
+    with part("embed"):
+        x = params.embed[tokens].reshape(m, h_dim)
+        x = plan_exec.shard_tokens(x, axis, plan)
 
     def step(x, xs):
         i, lp = xs
@@ -380,26 +388,28 @@ def forward_rows(
     x, rows = jax.lax.scan(
         step, x, (jnp.arange(cfg.num_layers), lp_local))
 
-    x = plan_exec.gather_tokens(x, axis, plan)  # (M, H) when sharded
     every_col = isinstance(head_cols, str)
     if every_col and head_cols != ALL_COLS:
         raise ValueError(f"head_cols {head_cols!r}: None, a (B,) int32 "
                          "array or ALL_COLS")
-    if not every_col:
-        col = s - 1 if head_cols is None else head_cols
-        x = x[jnp.arange(b) * s + col]  # (B, H): the rows the head reads
-    x = rms_norm(x, params.final_ln, cfg.rms_eps)
-    if every_col:
-        x = x.reshape(b, s, h_dim)
-    head = params.lm_head[0]  # strip n dim
-    # bf16 operands + f32 accumulation: avoids materialising an f32 copy
-    # of the (H, V/n) head shard (the MXU accumulates in f32 natively).
-    logits = jnp.einsum(
-        "...h,hv->...v", x, head, preferred_element_type=jnp.float32
-    )
-    # (B, V), or (B, S, V) for ALL_COLS
-    logits = jax.lax.all_gather(logits, axis, axis=logits.ndim - 1,
-                                tiled=True)
+    with part("head"):
+        x = plan_exec.gather_tokens(x, axis, plan)  # (M, H) when sharded
+        if not every_col:
+            col = s - 1 if head_cols is None else head_cols
+            x = x[jnp.arange(b) * s + col]  # (B, H): the head's rows
+        x = rms_norm(x, params.final_ln, cfg.rms_eps)
+        if every_col:
+            x = x.reshape(b, s, h_dim)
+        head = params.lm_head[0]  # strip n dim
+        # bf16 operands + f32 accumulation: avoids materialising an f32
+        # copy of the (H, V/n) head shard (the MXU accumulates in f32
+        # natively).
+        logits = jnp.einsum(
+            "...h,hv->...v", x, head, preferred_element_type=jnp.float32
+        )
+        # (B, V), or (B, S, V) for ALL_COLS
+        logits = jax.lax.all_gather(logits, axis, axis=logits.ndim - 1,
+                                    tiled=True)
     return logits, rows
 
 

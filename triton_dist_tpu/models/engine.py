@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from triton_dist_tpu.layers.parts import part
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.dense import (
     ALL_COLS,
@@ -85,6 +86,7 @@ def _serve_step_math(cfg, mode, axis, params, tokens, pool_k, pool_v,
     return tok, last, pool_k, pool_v
 
 
+@part("sample")
 def _sample_last(last, temps, keys):
     """The one-emission step's (K,) tokens from `last`, the (K, V)
     logits of each slot's column n_valid - 1: the only logits that
@@ -97,6 +99,7 @@ def _sample_last(last, temps, keys):
     return jnp.where(temps > 0.0, sampled, greedy)
 
 
+@part("sample")
 def _sample_every_col(logits, n_valid, temps, keys):
     """The per-position step's (tok, last) from its (K, C, V) logits:
     (K, C) tokens, every column under its own key, and the (K, V)

@@ -99,6 +99,7 @@ from triton_dist_tpu.layers.latent_attn import (
     latent_attn_fwd,
 )
 from triton_dist_tpu.layers.norm import rms_norm
+from triton_dist_tpu.layers.parts import part
 from triton_dist_tpu.layers.rope import rope_table
 from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.dense import _INIT_SCALE, _draw
@@ -358,10 +359,12 @@ def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
     returns after its hidden rows)."""
     x, *rest = chunk_hidden(cfg, params, tokens, cache, table, lengths,
                             n_valid, attn_impl, window_impl)
-    x = x[jnp.arange(x.shape[0]), jnp.maximum(n_valid - 1, 0)]  # (K, H)
+    with part("head"):
+        x = x[jnp.arange(x.shape[0]), jnp.maximum(n_valid - 1, 0)]  # (K, H)
     return head_logits(cfg, params, x), *rest
 
 
+@part("head")
 def head_logits(cfg: ModelConfig, params: dict, x):
     """The final norm and the vocabulary projection over hidden rows
     (..., H): float32 logits (..., V)."""
@@ -406,12 +409,15 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
         p = HeldMoEParams(p["w_router"], params["w_gate_up"],
                           params["w_down"], p["ws_gate_up"], p["ws_down"],
                           p.get("w_sgate"), p.get("router_bias"))
+        with part("moe.route"):
+            hid = normed(x, gain).reshape(slots * chunk, -1)
         y, *counts = held_moe_counted(
-            normed(x, gain).reshape(slots * chunk, -1), valid, p,
-            cfg.num_experts_per_tok, cfg.expert_offset, layer=layer,
-            router=router)
-        return x + y.reshape(x.shape), *counts
+            hid, valid, p, cfg.num_experts_per_tok, cfg.expert_offset,
+            layer=layer, router=router)
+        with part("moe.combine"):
+            return x + y.reshape(x.shape), *counts
 
+    @part("ffn.dense")
     def dense(x, gain, p):
         return x + swiglu_fwd(normed(x, gain), p["wd_gate_up"],
                               p["wd_down"]).astype(x.dtype)
@@ -428,6 +434,9 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                     stack, start[kind] + i * count[kind] + at,
                     keepdims=False)
 
+            # a block's row of what the slots carry between steps
+            carried = part("pool.gather")(row)
+
             here = absent = tile_rows = jnp.int32(0)
             recs, convs, rows, tails = [], [], [], []
             at = {kind: 0 for kind in count}
@@ -436,12 +445,17 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                 p = _MIXER_PARAMS[leaf](*(
                     row(params[n], leaf, at[leaf])
                     for n in _MIXER_LEAVES[leaf]))
-                hid = normed(x, row(params["input_ln"], "block", j))
+                # the block's norm goes with the projections it feeds,
+                # the residual with the one it adds
+                proj = part("mixer.proj" if mixer in STATE_MIXERS
+                            else "attn.proj")
+                with proj:
+                    hid = normed(x, row(params["input_ln"], "block", j))
                 if mixer in STATE_MIXERS:
                     fwd = gated_delta_net_fwd if mixer == "gdn" else kda_fwd
                     y, r, c = fwd(hid, p, g,
-                                  row(cache.rec, "state", at["state"]),
-                                  row(cache.conv, "state", at["state"]),
+                                  carried(cache.rec, "state", at["state"]),
+                                  carried(cache.conv, "state", at["state"]),
                                   n_valid, fresh, eps)
                     recs.append(r)
                     convs.append(c)
@@ -449,7 +463,7 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                 elif mixer in WINDOW_MIXERS:
                     y, tail = window_attn_fwd(
                         hid, p, gq, cos, sin, positions,
-                        tuple(row(w, "window", at["window"])
+                        tuple(carried(w, "window", at["window"])
                               for w in cache.win),
                         lengths, n_valid, cfg.sliding_window, window_impl,
                         eps)
@@ -474,32 +488,37 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                     at["page"] += 1
                 at[leaf] += 1
                 gain = row(params["post_ln"], "block", j)
+                with proj:
+                    x = x + y
                 if ffn == "moe":
                     x, h_j, a_j, t_j = moe(
-                        x + y, gain,
+                        x, gain,
                         {n: row(params[n], "moe", at["moe"]) for n in _MOE
                          if n in params},
                         start["moe"] + i * count["moe"] + at["moe"])
                     here, absent = here + h_j, absent + a_j
                     tile_rows = tile_rows + t_j
                 else:
-                    x = dense(x + y, gain, {
+                    x = dense(x, gain, {
                         n: row(params[n], "dense", at["dense"])
                         for n in ("wd_gate_up", "wd_down")})
                 at[ffn] += 1
             # a period's one page block hands its rows on as they are;
             # several are stacked, like the state and the tails
-            if len(rows) == 1:
-                rows, = rows
-            else:
-                rows = tuple(jnp.stack(r) for r in zip(*rows))
-            state = (jnp.stack(recs), jnp.stack(convs)) if recs else ()
-            tails = tuple(jnp.stack(t) for t in zip(*tails))
+            with part("pool.scatter"):
+                if len(rows) == 1:
+                    rows, = rows
+                else:
+                    rows = tuple(jnp.stack(r) for r in zip(*rows))
+                state = ((jnp.stack(recs), jnp.stack(convs)) if recs
+                         else ())
+                tails = tuple(jnp.stack(t) for t in zip(*tails))
             return x, (state, rows, tails, here, absent, tile_rows)
 
         return one_period
 
-    x = params["embed"][tokens]
+    with part("embed"):
+        x = params["embed"][tokens]
     start = {kind: 0 for kind in _MIXERS + ("moe", "dense", "block",
                                             "state", "page", "window")}
     outs, pages_a_period = [], []
@@ -530,15 +549,18 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
         return tuple(joined([flat(o[at][k]) for o in outs if o[at]])
                      for k in range(width))
 
-    rec, conv = per_block(0, 2) if start["state"] else (None, None)
-    rows = tuple(
-        joined([flat(o[1][k]) if n > 1 else o[1][k]
-                for o, n in zip(outs, pages_a_period) if n])
-        for k in range(len(cache.pages)))
+    with part("pool.scatter"):  # the scans' results, as the pool's
+        rec, conv = per_block(0, 2) if start["state"] else (None, None)
+        rows = tuple(
+            joined([flat(o[1][k]) if n > 1 else o[1][k]
+                    for o, n in zip(outs, pages_a_period) if n])
+            for k in range(len(cache.pages)))
     stats = {"moe_pairs_here": sum(jnp.sum(o[3]) for o in outs),
              "moe_pairs_absent": sum(jnp.sum(o[4]) for o in outs),
              "moe_gmm_tile_rows": sum(jnp.sum(o[5]) for o in outs)}
-    return x, rows, rec, conv, per_block(2, len(cache.win)), stats
+    with part("pool.scatter"):
+        win = per_block(2, len(cache.win))
+    return x, rows, rec, conv, win, stats
 
 
 def state_shapes(cfg: ModelConfig, slots: int):

@@ -31,6 +31,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from triton_dist_tpu.layers.parts import part
+
 
 class KVCache(NamedTuple):
     k: jax.Array  # (L, B, T_max, Hkv, D); paged: (L, P, page, Hkv, D)
@@ -51,6 +53,7 @@ class KVCache(NamedTuple):
         """The arrays that hold pages: (k, v), or (k,) of a latent cache."""
         return (self.k,) if self.v is None else (self.k, self.v)
 
+    @part("pool.gather")
     def layer_view(self, i):
         """Layer i's dense (k, v), each (B, T, Hkv, D) (a latent
         cache's one (B, T, 1, W) array, as a 1-tuple) — what the layer
@@ -100,6 +103,7 @@ class KVCache(NamedTuple):
         return slots * maxp * page
 
     @staticmethod
+    @part("pool.scatter")
     def scatter_step(pools, rows, table, lengths, n_valid):
         """A serve step's K/V rows back into the paged pool — the write
         path beside `layer_view`. `pools` are the page arrays, (k, v)

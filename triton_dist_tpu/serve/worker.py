@@ -142,7 +142,7 @@ class Worker:
             "advance_lengths — the scheduler owns the accepted-count "
             "advance")
         step = self.n_steps
-        with self.spans.span("worker.step", step=step):
+        with self._step_span(step, tokens, n_valid):
             tok = self._dispatch(step, tokens, n_valid, temps, keys)
             self.pool.lengths = self.pool.lengths + np.asarray(n_valid,
                                                                np.int32)
@@ -165,10 +165,19 @@ class Worker:
         emission)."""
         assert self.per_pos, "built without per_pos=True"
         step = self.n_steps
-        with self.spans.span("worker.step", step=step):
+        with self._step_span(step, tokens, n_valid):
             tok = self._dispatch(step, tokens, n_valid, temps, keys)
             self.n_steps += 1
             return self._wait(step, tok)
+
+    def _step_span(self, step: int, tokens, n_valid):
+        """The `worker.step` span of either step, with the counts its
+        boundary knows: `width`, the token block's second dimension
+        (which compiled program runs), and `rows`, the valid rows."""
+        self.spans.step = step
+        return self.spans.span("worker.step", step=step, counts={
+            "width": int(np.shape(tokens)[1]),
+            "rows": int(np.sum(n_valid))})
 
     def _dispatch(self, step: int, tokens, n_valid, temps, keys):
         """Both steps' device half: the injected fault, the six
