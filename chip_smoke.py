@@ -21,7 +21,13 @@ head; weights random from --seed):
       products of an expert block at the three hybrid configurations'
       widths and the cells' group sizes, against the loop over
       experts, with one group's offset shifted by a row
-      (`experts_phase`).
+      (`experts_phase`); then the hybrid family's state-space mixer at
+      granite-4.0-h-micro's widths over three steps with the state
+      carried, against the recurrence position by position in float32,
+      the carry broken three ways (`ssd_phase`), and its attention
+      block with heads of 64 kept 128 wide over a paged view against
+      the plain reference's attention, the scale and the cached pages
+      broken (`nope_phase`).
   --chips 4           the cross-chip path and nothing else: full depth
       (36 layers), tp=4 over the four chips, the same four requests.
 
@@ -40,6 +46,7 @@ anything when JAX's first device is not a TPU. Last line of stdout:
 import argparse
 import functools
 import json
+import os
 import statistics
 import sys
 import time
@@ -799,6 +806,253 @@ def experts_phase(seed: int, rows=None) -> None:
                         f"offset's {reads[1]:.4f} over it")
 
 
+# real columns of each of eight slots in three steps of 128: whole
+# chunks, one column, a part, none; slot 7 is taken by a NEW request in
+# the last step (fresh among warm ones)
+SSD_VALID = ((128, 128, 1, 128, 72, 0, 128, 128),
+             (128, 1, 128, 0, 128, 128, 40, 0),
+             (128, 128, 128, 128, 1, 128, 0, 128))
+# rms of the difference from the recurrence in float32 over the rms of
+# its output, over the steps that start from a carried state: what
+# bfloat16 leaves against what a carry broken on purpose gives
+# (ssd_phase prints each)
+SSD_TOL = 0.012
+
+
+def ssd_phase(seed: int, cols: int = 128) -> None:
+    """`layers.mamba2.mamba2_fwd` (the chunked form, XLA) at
+    granite-4.0-h-micro's widths over three steps with the state
+    carried and ragged `n_valid`, against the recurrence position by
+    position in float32 at `highest` (the benchmark's plain reference,
+    `perfbench/reference/granite_hybrid.py` `mamba`): what the
+    benchmark's `correct` may see faintly under random weights. Broken
+    on purpose, each has to read over the tolerance: the state dropped
+    between steps, the convolution tail dropped, D x dropped."""
+    import jax
+    import jax.numpy as jnp
+
+    from triton_dist_tpu.layers import mamba2
+    from triton_dist_tpu.models import ModelConfig, hybrid
+
+    cfg = ModelConfig.granite_4_h_micro()
+    spec = hybrid.mamba_spec(cfg)
+    hh, pd, n, di, ch = (spec.num_heads, spec.head_dim, spec.state,
+                         spec.inner, spec.channels)
+    hidden, taps = cfg.hidden_size, spec.conv
+    valid = np.minimum(np.asarray(SSD_VALID, np.int32), cols)
+    slots = valid.shape[1]
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+    drawn = {}
+    for k, (name, shape, init) in zip(
+            keys, hybrid._mixer_leaves(cfg, "mamba2", 1)):
+        shape = shape[1:]  # one block's
+        if init == "ones":
+            drawn[name] = jnp.ones(shape, bf16)
+        elif init == "normal":
+            drawn[name] = (0.02 * jax.random.normal(k, shape, f32)
+                           ).astype(bf16)
+        else:  # Mamba-2's published initialisation, as the model draws
+            drawn[name] = hybrid._mamba_init(
+                jax.random.uniform(k, shape, f32), init, jnp,
+                taps).astype(bf16)
+    p = mamba2.Mamba2Params(*(drawn[nm] for nm in hybrid._MIXER_LEAVES[
+        "mamba2"]))
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                          (len(valid), slots, cols, hidden), f32
+                          ).astype(bf16)
+
+    def served(fwd, drop=None):
+        """[y a step] with the state carried; `drop` zeroes one of the
+        two states between steps."""
+        rec = jnp.zeros((slots, hh, pd, n), f32)
+        conv = jnp.zeros((slots, taps - 1, ch), bf16)
+        lengths, out = np.zeros((slots,), np.int32), []
+        for step, nv in enumerate(valid):
+            if step == 2:
+                lengths[7] = 0
+            y, rec, conv = fwd(x[step], p, spec, rec, conv,
+                               jnp.asarray(nv), jnp.asarray(lengths == 0),
+                               cfg.rms_eps)
+            if drop == "state":
+                rec = jnp.zeros_like(rec)
+            if drop == "tail":
+                conv = jnp.zeros_like(conv)
+            out.append(np.asarray(y.astype(f32)))
+            lengths += nv
+        return out
+
+    # the benchmark's plain reference of this mixer (float32 at
+    # `highest`, position by position from zero; imports nothing of the
+    # program) over the same weights, under the names it draws them by
+    from perfbench import harness
+
+    root = os.path.dirname(os.path.abspath(__file__))
+    ref = harness.load_reference(root, "granite_hybrid")
+    sizes = ref.Sizes.from_config(harness.load_json(os.path.join(
+        root, "perfbench", "configs", "granite-4.0-h-micro.1chip.json")))
+    recurrence = jax.jit(lambda rows: ref.mamba(
+        sizes, rows.astype(f32), drawn, None))
+
+    # a slot's sequences: slot 7 has two, the others one
+    wants = {}
+    for slot in range(slots):
+        for which, steps in (((0, (0, 1)), (1, (2,))) if slot == 7
+                             else ((0, (0, 1, 2)),)):
+            rows = [x[st, slot, :valid[st, slot]] for st in steps]
+            full = np.asarray(recurrence(jnp.concatenate(rows)))
+            at = 0
+            for st in steps:
+                wants[(st, slot)] = full[at:at + valid[st, slot]]
+                at += valid[st, slot]
+
+    def reading(ys):
+        """Over the rows of steps 1 and 2 whose slot carries a state
+        into the step."""
+        num = den = 0.0
+        for (st, slot), want in wants.items():
+            if st == 0 or (st == 2 and slot == 7) or not len(want):
+                continue
+            got = ys[st][slot, :len(want)]
+            num += float(((got - want) ** 2).sum())
+            den += float((want ** 2).sum())
+        return (num / den) ** 0.5
+
+    fwd = jax.jit(mamba2.mamba2_fwd, static_argnums=(2, 7))
+    no_skip = jax.jit(
+        lambda h, q, *a: mamba2.mamba2_fwd(
+            h, q._replace(d=jnp.zeros_like(q.d)), *a),
+        static_argnums=(2, 7))
+    readings = {"carried": reading(served(fwd)),
+                "the state dropped": reading(served(fwd, "state")),
+                "the tail dropped": reading(served(fwd, "tail")),
+                "D x dropped": reading(served(no_skip))}
+    say(f"state-space mixer at {hh} heads x {pd} over a state of {n}, "
+        f"{slots} slots x {cols} columns x {len(valid)} steps, against "
+        "the recurrence in float32, rms of the difference over the rms "
+        f"of the output (tolerance {SSD_TOL}): " + "; ".join(
+            f"{k} {v:.4f}" for k, v in readings.items()))
+    broken = min(v for k, v in readings.items() if k != "carried")
+    if not readings["carried"] < SSD_TOL < broken:
+        raise RuntimeError(
+            f"state-space mixer: {readings['carried']:.4f} has to lie "
+            f"under {SSD_TOL} and every broken reading ({broken:.4f} the "
+            "least) over it")
+
+
+# cached positions and real columns of each of eight slots in one step
+# of 128 (as WINDOW_LENS / WINDOW_VALID: whole chunks, one column, a
+# part, a slot that starts)
+NOPE_LENS = (8192, 6001, 4097, 2049, 1025, 513, 180, 128)
+NOPE_VALID = (128, 128, 1, 128, 1, 128, 72, 128)
+# largest difference over largest output: what bfloat16 leaves against
+# what a break on purpose gives (nope_phase prints each)
+NOPE_TOL = 0.04
+
+
+def nope_phase(seed: int, max_len: int = 8192) -> None:
+    """`layers.gqa_attn.global_attn_fwd` with a head NARROWER than a
+    page keeps it, on the route the planner names for the chip, at
+    granite-4.0-h-micro's widths (32 q / 8 kv heads of 64 kept 128
+    wide, no rotary, no q/k norm, the scale 1/64) over a paged view of
+    up to 8,192 cached positions, against the benchmark's plain
+    reference of this block (`perfbench/reference/granite_hybrid.py`
+    `attention`: float32 at `highest`, one causal pass over the slot's
+    whole timeline, heads of 64, no cache): what the benchmark's
+    `correct` does not see under random weights (four such blocks of
+    forty). Broken on purpose, each has to read over the tolerance: the
+    scale taken as 64 ** -0.5, the cached pages zeroed."""
+    import jax
+    import jax.numpy as jnp
+
+    from perfbench import harness
+    from triton_dist_tpu.layers import gqa_attn
+    from triton_dist_tpu.models import ModelConfig, hybrid
+    from triton_dist_tpu.plan.planner import route_hybrid_attention
+
+    cfg = ModelConfig.granite_4_h_micro(max_positions=max_len)
+    spec = hybrid.gqa_spec(cfg)
+    hq, hkv, d = spec[:3]
+    assert (d, spec.store, spec.qk_norm) == (64, 128, False), spec
+    hidden, cols = cfg.hidden_size, 128
+    slots = len(NOPE_LENS)
+    lens = np.minimum(NOPE_LENS, max_len)
+    valid = np.minimum(NOPE_VALID, lens)
+    route = route_hybrid_attention(cfg, slots, cols, max_len)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    names = ("attn_w_q", "attn_w_kv", "attn_w_o")
+    shapes = ((hidden, hq * d), (hidden, 2 * hkv * d), (hq * d, hidden))
+    w = {n: (0.02 * jax.random.normal(k, s, f32)).astype(bf16)
+         for n, k, s in zip(names, keys, shapes)}
+    p = gqa_attn.GQAttnParams(w["attn_w_q"], w["attn_w_kv"], None, None,
+                              w["attn_w_o"])
+    # each slot's whole timeline of normed rows, three times over: at
+    # unit size the scores under 1/64 lie 0.1 apart and every scale
+    # gives the mean of the values
+    line = (3.0 * jax.random.normal(keys[3], (slots, max_len, hidden), f32)
+            ).astype(bf16)
+    start = jnp.asarray(lens - valid, jnp.int32)
+    pos = start[:, None] + jnp.arange(cols)[None, :]
+    x = jnp.take_along_axis(line, jnp.minimum(pos, max_len - 1)[..., None],
+                            axis=1)
+    kv_len = jnp.asarray(lens, jnp.int32)
+    # what the earlier steps left in the pages: the keys and values of
+    # the positions before `start` as the block stores them (bfloat16,
+    # 64 values and 64 zeros a head); past them what another request
+    # left there
+    kv = jnp.einsum("bth,hc->btc", line, w["attn_w_kv"],
+                    preferred_element_type=f32).astype(bf16)
+    here = (jnp.arange(max_len)[None, :] < start[:, None])[..., None, None]
+    view = tuple(jnp.where(here, jnp.pad(
+        half.reshape(slots, max_len, hkv, d),
+        ((0, 0),) * 3 + ((0, spec.store - d),)), 50.0).astype(bf16)
+        for half in (kv[..., :hkv * d], kv[..., hkv * d:]))
+
+    def fwd(x, p, view, spec=spec):
+        return gqa_attn.global_attn_fwd(x, p, spec, pos, view, kv_len,
+                                        route, cfg.rms_eps)[0]
+
+    root = os.path.dirname(os.path.abspath(__file__))
+    ref = harness.load_reference(root, "granite_hybrid")
+    sizes = ref.Sizes.from_config(harness.load_json(os.path.join(
+        root, "perfbench", "configs", "granite-4.0-h-micro.1chip.json")))
+    plain = jax.jit(lambda rows: ref.attention(
+        sizes, rows.astype(f32), w, None))
+    wants = [np.asarray(jax.lax.dynamic_slice_in_dim(
+        plain(line[i]), int(start[i]), int(n))) for i, n in enumerate(valid)]
+
+    def worst(jitted, cache) -> float:
+        """Over the slots' valid columns (the others are discarded)."""
+        got = np.asarray(jitted(x, p, cache).astype(f32))
+        return max(float(np.abs(got[i, :len(want)] - want).max()
+                         / np.abs(want).max())
+                   for i, want in enumerate(wants))
+
+    jitted = jax.jit(fwd)
+    kernels, secs = compile_and_name(jitted, x, p, view)
+    say(f"attention with heads of {d} kept {spec.store} wide: route "
+        f"{route!r}, kernels {kernels or 'none'}, compiled in {secs:.1f}s")
+    if route == "pallas":
+        require(kernels, ["_fp_local_kernel"], "padded-head attention")
+    readings = {
+        "as served": worst(jitted, view),
+        "the cached pages zeroed": worst(jitted, tuple(
+            jnp.where(here, 0, c).astype(bf16) for c in view)),
+        "the scale taken as d ** -0.5": worst(jax.jit(
+            lambda *a: fwd(*a, spec=spec._replace(scale=None))), view)}
+    say(f"{hq} q / {hkv} kv heads of {d} over pages {spec.store} wide "
+        f"against the reference's attention in float32, largest "
+        f"difference over largest output (tolerance {NOPE_TOL}): "
+        + "; ".join(f"{k} {v:.4f}" for k, v in readings.items()))
+    broken = min(v for k, v in readings.items() if k != "as served")
+    if not readings["as served"] < NOPE_TOL < broken:
+        raise RuntimeError(
+            f"padded-head attention: {readings['as served']:.4f} has to "
+            f"lie under {NOPE_TOL} and every broken reading ({broken:.4f} "
+            "the least) over it")
+
+
 def run(cfg, mesh, seed: int, prompts, cross_chip: bool) -> None:
     """Every phase on `mesh`. cross_chip: the tp>1 contract (kernels of
     the overlapped collectives by name, no megakernel phase)."""
@@ -888,6 +1142,8 @@ def main() -> int:
         latent_phase(args.seed)
         window_phase(args.seed)
         experts_phase(args.seed)
+        ssd_phase(args.seed)
+        nope_phase(args.seed)
     run(cfg, make_mesh((args.chips,), ("tp",)), args.seed, prompts,
         cross_chip=args.chips > 1)
 

@@ -10,7 +10,10 @@ readers are in the tree but which `BENCHMARK.json` cannot take until a
 `benchmark` issue lifts the pin on `per_layer[-1]` (ROADMAP C11). This
 is how a builder reads them on the chip meanwhile: everything after
 `--entries` goes to `perfbench/run.py` unchanged, which sees the
-benchmark with the entries appended and nothing else different.
+benchmark with the entries appended and nothing else different. A
+fixture's `lists_to_take_the_cell` (accepted entries that find something
+to read in a cell they cannot list yet) is applied too: the cell joins
+those entries' `workloads` for this run.
 """
 
 import argparse
@@ -20,9 +23,18 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def appended(bench: dict, waiting: list) -> dict:
-    """`bench` with the `waiting` entries at the end of `per_layer`."""
-    return dict(bench, per_layer=bench["per_layer"] + list(waiting))
+def appended(bench: dict, waiting: list, lists=()) -> dict:
+    """`bench` with the `waiting` entries at the end of `per_layer`,
+    and each of `lists` ({"cell": name, "entries": [metric, ...]}: a
+    fixture's `lists_to_take_the_cell`) applied: the cell appended to
+    the `workloads` of the accepted entries it names."""
+    per_layer = [dict(m) for m in bench["per_layer"]] + list(waiting)
+    for take in lists:
+        for m in per_layer:
+            if m["name"] in take["entries"] \
+                    and take["cell"] not in m["workloads"]:
+                m["workloads"] = m["workloads"] + [take["cell"]]
+    return dict(bench, per_layer=per_layer)
 
 
 def entries_of(fixture) -> list:
@@ -40,15 +52,17 @@ def main(argv=None) -> int:
     sys.path.insert(0, ROOT)
     from perfbench import harness, run
 
-    waiting = [m for path in args.entries
-               for m in entries_of(harness.load_json(path))]
+    fixtures = [harness.load_json(path) for path in args.entries]
+    waiting = [m for f in fixtures for m in entries_of(f)]
+    lists = [f["lists_to_take_the_cell"] for f in fixtures
+             if isinstance(f, dict) and "lists_to_take_the_cell" in f]
     bench_path = os.path.join(ROOT, "BENCHMARK.json")
     load = harness.load_json
 
     def load_with_the_entries(path):
         obj = load(path)
         if os.path.abspath(path) == bench_path:
-            obj = appended(obj, waiting)
+            obj = appended(obj, waiting, lists)
         return obj
 
     harness.load_json = load_with_the_entries

@@ -68,7 +68,9 @@ def assert_same_step(got, want, bitwise: bool = True):
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
     a, b = np.asarray(got[1]), np.asarray(want[1])
     assert a.shape == b.shape and a.dtype == np.float32
-    assert float(np.abs(b).max()) > 0.05  # the rows are not all zero
+    # the rows are not all zero (logits of order 0.1; 0.006 where a tied
+    # table is drawn under an embedding multiplier)
+    assert float(np.abs(b).max()) > 1e-3
     if bitwise:
         np.testing.assert_array_equal(a, b)
     else:

@@ -423,6 +423,45 @@ def test_window_and_global_serve_step_one_chip(chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9
 
 
+def test_state_space_serve_step_one_chip(chip):
+    """granite-4.0-h-micro's pattern at its published widths and the
+    benchmark's geometry, cut to `M x5 A`, `M x9 A`, `M x4` (one of the
+    three long periods): the attention blocks' heads of 64 reach
+    `_fp_local_kernel` 128 wide, as their pages keep them (the head
+    itself is refused: no silent dense chain), once a scan; the
+    state-space mixer is XLA's (no other kernel); no expert layer, so
+    no `_moe_gmm_kernel` and no counter; the tied head reads one row a
+    slot."""
+    from triton_dist_tpu.kernels import flash_prefill
+    from triton_dist_tpu.plan.planner import (
+        route_gated_attention,
+        route_hybrid_attention,
+    )
+
+    max_len, slots, page = 8192, 8, 64
+    m, a = "mamba", "attention"
+    types = (m,) * 5 + (a,) + (m,) * 9 + (a,) + (m,) * 4
+    cfg = ModelConfig.granite_4_h_micro(
+        num_layers=20, first_k_dense=20, layer_types=types,
+        max_positions=max_len)
+    assert route_hybrid_attention(cfg, slots, 128, max_len) == "pallas"
+    with pytest.raises(NotImplementedError, match="no other route"):
+        route_gated_attention(slots, 128, max_len, 32, 8, 64, "bfloat16")
+    compiled = _hybrid_step_compiled(chip, cfg, slots, page, max_len)
+    assert _kernels(compiled) == {"_fp_local_kernel": 2}
+    launch = flash_prefill.last_launch()
+    assert launch["widths"] == (128, 128) and launch["streams"] == 2
+    text = compiled.as_text()
+    assert "bf16[2,1025,64,8,128]" in text  # pages: 2 attention blocks'
+    assert "f32[18,8,64,64,128]" in text  # the 18 mixers' state
+    assert "bf16[18,8,3,4352]" in text  # and convolution tails
+    _one_row_of_logits_a_slot(text, slots, 128, cfg.vocab_size)
+    mem = compiled.memory_analysis()
+    # weights 3.40 GB (the embedding 0.41 of them), pages 0.54, state 0.30
+    assert 4.1e9 < mem.argument_size_in_bytes < 4.4e9
+    assert mem.temp_size_in_bytes < 2.5e9
+
+
 def test_gated_attention_has_no_silent_route_on_the_chip(chip):
     """A head shape flash-prefill does not take is an error when the
     hybrid step is built, not a dense XLA chain in its place."""

@@ -428,15 +428,19 @@ def test_eight_shares_and_the_shared_expert_add_up(ref, sizes):
 def test_pages_for_the_global_blocks_alone_and_a_fixed_tail_beside_them(
         eng, cfg):
     pool = Scheduler(eng, **GEO).pool
-    # two global blocks keep pages; six window blocks keep a tail of
-    # `sliding_window` positions a slot, whatever the context
+    # two global blocks keep pages (a head of 16 in a whole 128-value
+    # lane, `ModelConfig.page_head_dim`: the published head of 128 as
+    # it is); six window blocks keep a tail of `sliding_window`
+    # positions a slot, whatever the context, at the head's own width
+    assert (cfg.page_head_dim,
+            ModelConfig.k_exaone_236b().page_head_dim) == (128, 128)
     assert pool.k.shape == pool.v.shape == (
-        2, 1 + GEO["slots"] * 8, GEO["page"], 2, 16)
+        2, 1 + GEO["slots"] * 8, GEO["page"], 2, 128)
     assert [w.shape for w in pool.win] == [
         (6, GEO["slots"], WINDOW, 2, 16)] * 2
     assert pool.rec is None and pool.conv is None
     assert len(pool.state) == 4 and pool.state_bytes_per_slot == 0
-    assert pool.kv_bytes_per_token == 2 * 2 * 2 * 16 * 4
+    assert pool.kv_bytes_per_token == 2 * 2 * 2 * 128 * 4
     assert pool.window_bytes_per_slot == 6 * 2 * WINDOW * 2 * 16 * 4
     before = sum(x.nbytes for x in pool.state)
     pool.admit(0, 60)

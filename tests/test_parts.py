@@ -32,6 +32,10 @@ FAMILIES = {
     "kimi-linear": (ModelConfig.tiny_kimi,
                     COMMON | MOE | MIXER | {"ffn.dense"}),
     "k-exaone": (ModelConfig.tiny_exaone, COMMON | MOE | {"ffn.dense"}),
+    # a state-space mixer under the delta nets' three names, a dense
+    # MLP in every block, no expert layer
+    "granite-4h": (ModelConfig.tiny_granite,
+                   COMMON | MIXER | {"ffn.dense"}),
 }
 
 

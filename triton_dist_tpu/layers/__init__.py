@@ -47,6 +47,11 @@ from triton_dist_tpu.layers.gated_delta_net import (  # noqa: F401
     GDNSpec,
     gated_delta_net_fwd,
 )
+from triton_dist_tpu.layers.mamba2 import (  # noqa: F401
+    Mamba2Params,
+    Mamba2Spec,
+    mamba2_fwd,
+)
 from triton_dist_tpu.layers.gated_attn import (  # noqa: F401
     GatedAttnParams,
     GatedAttnSpec,

@@ -224,16 +224,20 @@ def _l2norm(x, eps: float = 1e-6):
 
 
 @part("mixer.conv")
-def _causal_conv(mixed, conv, conv_w, n_valid):
+def _causal_conv(mixed, conv, conv_w, n_valid, bias=None):
     """SiLU of the causal depthwise convolution of `mixed` (B, C,
     channels) over [carried K-1 inputs | chunk], and the K-1 inputs to
     carry on: those before column n_valid, so the old state where
-    n_valid is 0, untouched by the padding columns."""
+    n_valid is 0, untouched by the padding columns. `bias` (channels,)
+    is added before the SiLU (the state-space mixer's,
+    layers/mamba2.py; the delta nets have none)."""
     c, taps = mixed.shape[1], conv_w.shape[0]
     seq = jnp.concatenate([conv.astype(mixed.dtype), mixed], axis=1)
     w = conv_w.astype(jnp.float32)
     acc = sum(seq[:, j:j + c].astype(jnp.float32) * w[j]
               for j in range(taps))
+    if bias is not None:
+        acc = acc + bias.astype(jnp.float32)
     conv = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(
         s, n, taps - 1, axis=0))(seq, n_valid).astype(conv.dtype)
     return jax.nn.silu(acc).astype(mixed.dtype), conv

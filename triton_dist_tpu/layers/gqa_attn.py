@@ -2,10 +2,16 @@
 chip's heads, in two kinds that share their weights' shapes.
 
 q, k and v come out of two projections (q; k | v); q and k are
-RMS-normalised per head under a gain w; causal grouped-query attention
-at D ** -0.5; the output projection. No output gate (that is
-`layers/gated_attn.py`). What the two kinds differ in is the cache
-they read and the rotary embedding:
+RMS-normalised per head under a gain w (unless the spec says the
+source has no such norm); causal grouped-query attention at D ** -0.5
+(or the spec's own scale); the output projection. No output gate (that
+is `layers/gated_attn.py`). A global block whose heads are narrower
+than a page keeps them (`GQAttnSpec.store`: heads of 64 in whole
+128-value lanes) pads q, k and v with zero columns before the
+attention and keeps the output's first D: the scores and the values
+are the unpadded ones exactly, and the kernel sees a head it takes.
+What the two kinds differ in is the cache they read and the rotary
+embedding:
 
   global  (`global_attn_fwd`)  every cached position, through the
           slot's pages, as the gated block reads them; NO rotary.
@@ -25,7 +31,7 @@ implementation.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -41,19 +47,22 @@ class GQAttnSpec(NamedTuple):
     num_q_heads: int
     num_kv_heads: int
     head_dim: int
+    qk_norm: bool = True  # q and k RMS-normalised a head
+    scale: Optional[float] = None  # the softmax scale; None = D ** -0.5
+    store: int = 0  # the width a page keeps a head in; 0 = head_dim
 
 
 class GQAttnParams(NamedTuple):
     w_q: jax.Array  # (H, Hq D)
     w_kv: jax.Array  # (H, 2 Hkv D): k | v
-    q_norm: jax.Array  # (D,)
-    k_norm: jax.Array
+    q_norm: Optional[jax.Array]  # (D,); None without the head norms
+    k_norm: Optional[jax.Array]
     w_o: jax.Array  # (Hq D, H)
 
 
 def _qkv(x, p: GQAttnParams, spec: GQAttnSpec, eps: float):
     b, c, _ = x.shape
-    hq, hkv, d = spec
+    hq, hkv, d = spec[:3]
     with part("attn.proj"):
         q = jnp.dot(x, p.w_q, preferred_element_type=jnp.float32).astype(
             x.dtype).reshape(b, c, hq, d)
@@ -61,6 +70,8 @@ def _qkv(x, p: GQAttnParams, spec: GQAttnSpec, eps: float):
             x.dtype)
         k = kv[..., :hkv * d].reshape(b, c, hkv, d)
         v = kv[..., hkv * d:].reshape(b, c, hkv, d)
+    if not spec.qk_norm:
+        return q, k, v
     with part("attn.core"):
         return (rms_norm(q, p.q_norm, eps, zero_centred=False),
                 rms_norm(k, p.k_norm, eps, zero_centred=False), v)
@@ -79,13 +90,22 @@ def global_attn_fwd(x, p: GQAttnParams, spec: GQAttnSpec, positions,
     (B, C) absolute; kv_len (B,). Returns (y (B, C, H), (k, v): the
     chunk's rows (B, C, Hkv, D) in the cache's dtype)."""
     q, k, v = _qkv(x, p, spec, eps)
+    d = spec.head_dim
+    pad = (spec.store or d) - d
+    scale = spec.scale
     with part("attn.core"):
+        if pad:  # zero columns: q . k and the first D of p v as they were
+            q, k, v = (jnp.pad(t, ((0, 0),) * 3 + ((0, pad),))
+                       for t in (q, k, v))
+            scale = d ** -0.5 if scale is None else scale
         k_cache, v_cache = kv_cache
         k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
         out = gqa_attention(q, _scatter_kv(k_cache, k, positions),
                             _scatter_kv(v_cache, v, positions), causal=True,
                             q_positions=positions, kv_len=kv_len,
-                            prefill_impl=attn_impl)
+                            scale=scale, prefill_impl=attn_impl)
+        if pad:
+            out = out[..., :d]
     return _out(out, x, p), (k, v)
 
 

@@ -25,11 +25,12 @@ operation lies in is its part. It lives beside the layers because
                 before them) and out (with the output gate)
   attn.core     head norms, rotary, the rows laid into the view, the
                 attention itself (`_fp_local_kernel` or XLA's chain)
-  mixer.proj    a delta-net mixer's projections in (with the block's
-                norm) and out (with its gated norm)
+  mixer.proj    a delta-net or state-space mixer's projections in
+                (with the block's norm) and out (with its gated norm)
   mixer.conv    its causal convolution and the carried inputs
-  mixer.rule    the delta rule proper: gates, L2 norms, the chunked
-                products, the scan over the state
+  mixer.rule    the delta rule proper (gates, L2 norms, the chunked
+                products, the scan over the state) or the selective
+                scan (decays, the chunk's products, the state's update)
   ffn.dense     a SwiGLU MLP with the norm before it
   moe.route     the norm before an expert block, router scores, the
                 top-k, which pairs are held here

@@ -12,8 +12,9 @@ The hybrid family (models/hybrid.py) is one whose blocks are data: a
 mixer kind and an FFN kind a block, read off `mixer_kinds` /
 `ffn_kinds`, stated as an interval (`qwen3_next_80b`, `tiny_next`), as
 two lists (`kimi_linear_48b`, `tiny_kimi`) or as the source's own
-`layer_types` list (`k_exaone_236b`, `tiny_exaone`); what a page of its
-cache keeps of a token is `page_arrays`.
+`layer_types` list (`k_exaone_236b`, `tiny_exaone`: window and global
+attention; `granite_4_h_micro`, `tiny_granite`: Mamba-2 and attention);
+what a page of its cache keeps of a token is `page_arrays`.
 """
 
 from __future__ import annotations
@@ -51,9 +52,12 @@ class ModelConfig:
     # source's `layer_types`, one name a block (K-EXAONE:
     # "sliding_attention" is grouped-query attention with rotary over
     # the last `sliding_window` positions, "full_attention" the same
-    # heads over every position and WITHOUT rotary); the first
+    # heads over every position and WITHOUT rotary; Granite 4.0-H:
+    # "mamba" is a Mamba-2 state-space mixer, "attention" the
+    # full-attention kind again); the first
     # `first_k_dense` blocks have a dense MLP of `intermediate_size`,
-    # the others experts. 0 / () = every block the dense family's.
+    # the others experts (`first_k_dense == num_layers`: no expert
+    # layer at all). 0 / () = every block the dense family's.
     # The family also means: a router over all `num_experts` of which
     # this chip holds `experts_held` from `expert_offset` on (0 held =
     # all), and a shared expert. What differs between its members is
@@ -91,6 +95,20 @@ class ModelConfig:
     router_bias: bool = False
     routed_scaling_factor: float = 1.0
     shared_expert_gate: bool = True
+    # the Mamba-2 mixer (layers/mamba2.py): heads of `mamba_head_dim`
+    # channels over a state of `mamba_state_dim` a channel, one group
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state_dim: int = 0
+    mamba_conv_kernel_dim: int = 4
+    # Granite's four multipliers, each 1 (not multiplied in) where the
+    # source has none: x = m_e E[tokens]; x += m_r Layer(norm(x));
+    # logits / m_l; `attention_multiplier` is the softmax scale in
+    # head_dim ** -0.5's place (0 = that)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float = 0.0
 
     @property
     def is_moe(self) -> bool:
@@ -107,13 +125,16 @@ class ModelConfig:
         (scalar-gated delta net), "kda" (channel-gated), "gated_attn",
         "mla" (latent attention), "window_attn" / "global_attn"
         (grouped-query attention over the last `sliding_window`
-        positions with rotary, over all of them without)."""
+        positions with rotary, over all of them without), "mamba2"
+        (a state-space mixer)."""
         if self.layer_types:
             kinds = {"sliding_attention": "window_attn",
-                     "full_attention": "global_attn"}
+                     "full_attention": "global_attn",
+                     "attention": "global_attn", "mamba": "mamba2"}
             assert len(self.layer_types) == self.num_layers, (
                 "layer_types must name every block once")
-            assert self.sliding_window > 0
+            assert (self.sliding_window > 0
+                    or "sliding_attention" not in self.layer_types)
             return tuple(kinds[t] for t in self.layer_types)
         if self.kda_layers:
             kda, full = set(self.kda_layers), set(self.full_attn_layers)
@@ -138,16 +159,29 @@ class ModelConfig:
         return self.num_layers - self.first_k_dense
 
     @property
+    def page_head_dim(self) -> int:
+        """The width a page keeps a kv head in. A `global_attn` block's
+        head is padded with zeros to whole 128-value lanes, as a latent
+        row is (the chip's kernels take no narrower slice of a page:
+        `supports_flash_prefill`, d % 128), and the block pads q, k and
+        v to match (`GQAttnSpec.store`); from the head size alone, so a
+        head of whole lanes is kept as it is. Every other kind keeps
+        the head's own width."""
+        if self.layer_types and "global_attn" in self.mixer_kinds:
+            return -(-self.head_dim // 128) * 128
+        return self.head_dim
+
+    @property
     def page_arrays(self) -> tuple:
         """(heads, width) of each array a page layer keeps a token in:
-        keys and values a kv head, or ONE latent row (kv_lora_rank |
-        qk_rope_head_dim, padded with zeros to whole 128-value lanes:
-        the chip's kernels take no narrower slice of a page) from which
-        both come."""
+        keys and values a kv head (`page_head_dim` wide), or ONE latent
+        row (kv_lora_rank | qk_rope_head_dim, padded with zeros to
+        whole 128-value lanes: the chip's kernels take no narrower
+        slice of a page) from which both come."""
         if self.kv_lora_rank:
             row = self.kv_lora_rank + self.qk_rope_head_dim
             return ((1, -(-row // 128) * 128),)
-        return ((self.num_kv_heads, self.head_dim),) * 2
+        return ((self.num_kv_heads, self.page_head_dim),) * 2
 
     @property
     def kv_bytes_per_token(self) -> int:
@@ -343,6 +377,52 @@ class ModelConfig:
             norm_zero_centred=False, router_score="sigmoid",
             router_bias=True, routed_scaling_factor=2.5,
             shared_expert_gate=False,
+        )
+        defaults.update(kw)
+        return ModelConfig(**defaults)
+
+    @staticmethod
+    def granite_4_h_micro(**kw) -> "ModelConfig":
+        """granite-4.0-h-micro geometry, whole: 40 blocks, `M x5 A`,
+        `M x9 A` three times, `M x4` (M a Mamba-2 mixer of 64 heads x
+        64 over a state of 128, A grouped-query attention over every
+        position without rotary or q/k norm, 32 q / 8 kv heads of 64
+        at the scale 1/64), a dense SwiGLU of 8,192 in EVERY block (no
+        expert layer), tied embeddings, norms with the gain w and
+        Granite's four multipliers."""
+        m, a = "mamba", "attention"
+        defaults = dict(
+            vocab_size=100_352, hidden_size=2048, intermediate_size=8192,
+            num_layers=40, num_q_heads=32, num_kv_heads=8, head_dim=64,
+            rms_eps=1e-5, use_qk_norm=False, tie_word_embeddings=True,
+            layer_types=((m,) * 5 + (a,) + ((m,) * 9 + (a,)) * 3
+                         + (m,) * 4),
+            first_k_dense=40, norm_zero_centred=False,
+            mamba_num_heads=64, mamba_head_dim=64, mamba_state_dim=128,
+            mamba_conv_kernel_dim=4, embedding_multiplier=12.0,
+            residual_multiplier=0.22, logits_scaling=8.0,
+            attention_multiplier=0.015625,
+        )
+        defaults.update(kw)
+        return ModelConfig(**defaults)
+
+    @staticmethod
+    def tiny_granite(**kw) -> "ModelConfig":
+        """Test-scale Granite 4.0-H pattern: a short first period, two
+        whole ones and a last without attention (`M A`, `M M A` twice,
+        `M`), heads of 16 (a page keeps them 128 wide), a state of
+        16."""
+        m, a = "mamba", "attention"
+        defaults = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=96,
+            num_layers=9, num_q_heads=4, num_kv_heads=2, head_dim=16,
+            rms_eps=1e-5, max_positions=64, dtype="float32",
+            use_qk_norm=False, tie_word_embeddings=True,
+            layer_types=(m, a) + (m, m, a) * 2 + (m,), first_k_dense=9,
+            norm_zero_centred=False, mamba_num_heads=8, mamba_head_dim=16,
+            mamba_state_dim=16, mamba_conv_kernel_dim=4,
+            embedding_multiplier=12.0, residual_multiplier=0.22,
+            logits_scaling=8.0, attention_multiplier=0.0625,
         )
         defaults.update(kw)
         return ModelConfig(**defaults)

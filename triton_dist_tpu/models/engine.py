@@ -120,7 +120,8 @@ def _hybrid_step_math(cfg, attn_impl, window_impl, params, tokens, cache,
                       table, lengths, n_valid, temps, keys):
     """The serve step of the hybrid family (models/hybrid.py): the
     same fixed-geometry forward, sampling and page scatter, with the
-    delta-net blocks' per-slot state and the window blocks' tails
+    delta-net or state-space blocks' per-slot state and the window
+    blocks' tails
     carried beside the pages (the family's own: key-value pools or one
     latent pool). `cache` is `KVPool.state`, `hybrid.Cache.flat()`:
     (*pages, rec, conv) or, with window blocks and no delta net,

@@ -13,25 +13,33 @@ mixer and the FFN are is read off the configuration
                        `sliding_window` positions, rotary
          "global_attn" the same heads over every position, no
                        rotary                    layers/gqa_attn.py
+         "mamba2"      state-space (selective scan)  layers/mamba2.py
   FFN    "moe"         a router over all experts, the ones this chip
                        holds, a shared expert    layers/held_moe.py
          "dense"       a SwiGLU MLP of `intermediate_size`
 
-Three published members: Qwen3-Next (periods of three "gdn" blocks and
+Four published members: Qwen3-Next (periods of three "gdn" blocks and
 one "gated_attn", every FFN "moe", norms with the gain (1 + w)),
 Kimi-Linear ("kda" and "mla" from two lists, the last period short,
 the first block's FFN "dense", norms with the gain w, a sigmoid
 router) and K-EXAONE ("window_attn" and "global_attn" from the
 source's `layer_types`, three to one, no delta-net block at all; the
-FFNs and the router Kimi-Linear's).
+FFNs and the router Kimi-Linear's) and Granite 4.0-H ("mamba2" and
+"global_attn" from the source's `layer_types`, nine to one, the
+attention without q/k norm at the source's own scale over heads of 64
+that a page keeps 128 wide; EVERY FFN "dense", so no expert leaf and no
+router; the embedding is the head (`tie_word_embeddings`: no `lm_head`
+leaf) and four multipliers scale the embedding, each residual branch,
+the attention scores and the logits).
 
 The pattern is cut into PERIODS, each ending with a block that keeps
 pages (the last one may have none), and a run of equal periods is ONE
 `lax.scan` with the period's blocks unrolled inside it: one scan for
 Qwen3-Next, three for Kimi-Linear, two for K-EXAONE (the first period
-holds the dense block). Parameters are stacked by kind:
+holds the dense block), three for Granite 4.0-H (`M x5 A`, `M x9 A`
+three times, `M x4`). Parameters are stacked by kind:
 
-  embed (V, H) · final_ln (H,) · lm_head (H, V)
+  embed (V, H) · final_ln (H,) · lm_head (H, V) unless tied
   every block, (L, ...):      input_ln, post_ln
   "moe" blocks, (Lm, ...):    w_router, [router_bias,] w_gate_up,
                               w_down, ws_gate_up, ws_down[, w_sgate]
@@ -42,8 +50,10 @@ holds the dense block). Parameters are stacked by kind:
 
 What a slot carries between steps (`Cache`): pages for the blocks that
 attend every position, in the pool's layout (keys and values, or one
-latent row a token); for each delta-net block a recurrent state and
-the convolution's last inputs; for each window block a TAIL, the keys
+latent row a token); for each delta-net or state-space block a
+recurrent state and the convolution's last inputs (one kind of the
+three a pattern: they share `rec` / `conv`); for each window block a
+TAIL, the keys
 and values of the slot's last `sliding_window` positions, which is all
 it ever reads of the past. A kind the pattern lacks carries nothing:
 no array stands in its place.
@@ -62,6 +72,7 @@ one device is refused.
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -98,6 +109,11 @@ from triton_dist_tpu.layers.latent_attn import (
     LatentAttnSpec,
     latent_attn_fwd,
 )
+from triton_dist_tpu.layers.mamba2 import (
+    Mamba2Params,
+    Mamba2Spec,
+    mamba2_fwd,
+)
 from triton_dist_tpu.layers.norm import rms_norm
 from triton_dist_tpu.layers.parts import part
 from triton_dist_tpu.layers.rope import rope_table
@@ -105,7 +121,8 @@ from triton_dist_tpu.models.config import ModelConfig
 from triton_dist_tpu.models.dense import _INIT_SCALE, _draw
 from triton_dist_tpu.models.kv_cache import KVCache
 
-STATE_MIXERS = ("gdn", "kda")  # keep per-slot state beside the pages
+# keep per-slot state beside the pages (one of them a pattern)
+STATE_MIXERS = ("gdn", "kda", "mamba2")
 PAGE_MIXERS = ("gated_attn", "mla", "global_attn")  # keep pages
 WINDOW_MIXERS = ("window_attn",)  # keep a per-slot tail, no pages
 GQA_MIXERS = ("window_attn", "global_attn")  # share their leaves
@@ -148,7 +165,14 @@ def attn_spec(cfg: ModelConfig) -> GatedAttnSpec:
 
 
 def gqa_spec(cfg: ModelConfig) -> GQAttnSpec:
-    return GQAttnSpec(cfg.num_q_heads, cfg.num_kv_heads, cfg.head_dim)
+    return GQAttnSpec(cfg.num_q_heads, cfg.num_kv_heads, cfg.head_dim,
+                      cfg.use_qk_norm, cfg.attention_multiplier or None,
+                      cfg.page_head_dim)
+
+
+def mamba_spec(cfg: ModelConfig) -> Mamba2Spec:
+    return Mamba2Spec(cfg.mamba_num_heads, cfg.mamba_head_dim,
+                      cfg.mamba_state_dim, cfg.mamba_conv_kernel_dim)
 
 
 def latent_spec(cfg: ModelConfig) -> LatentAttnSpec:
@@ -182,11 +206,14 @@ def check(cfg: ModelConfig, n_devices: int) -> None:
             "tensor-parallel form and the expert layer no exchange")
     assert cfg.expert_offset + cfg.num_experts_held <= cfg.num_experts
     assert cfg.router_score in ("softmax", "sigmoid")
-    assert 0 <= cfg.first_k_dense < cfg.num_layers
-    assert not cfg.tie_word_embeddings
+    assert 0 <= cfg.first_k_dense <= cfg.num_layers
     kinds = set(cfg.mixer_kinds)  # the lists name every block once
-    if kinds & set(STATE_MIXERS):
+    # `rec` / `conv` are one kind's: the pool stacks them by block
+    assert len(kinds & set(STATE_MIXERS)) <= 1
+    if kinds & {"gdn", "kda"}:
         assert cfg.linear_num_value_heads % cfg.linear_num_key_heads == 0
+    if "mamba2" in kinds:
+        assert min(mamba_spec(cfg)) > 0
     if "kda" in kinds:
         assert cfg.linear_num_key_heads == cfg.linear_num_value_heads
         assert cfg.linear_gate_rank > 0
@@ -195,10 +222,13 @@ def check(cfg: ModelConfig, n_devices: int) -> None:
 
 
 # (name, shape, init) in the order that fixes each leaf's key,
-# fold_in(PRNGKey(seed), position): "normal" is N(0, _INIT_SCALE);
+# fold_in(PRNGKey(seed), position): "normal" is N(0, _INIT_SCALE),
+# "embed" the same over `embedding_multiplier` (`leaves`);
 # a block's "gain" starts at its identity, 0 under (1 + w) and 1 under
 # w (`norm_zero_centred`); a mixer's own norms are its kind's: (1 + w)
-# in gated attention, w in the delta nets and the latent
+# in gated attention, w in the delta nets, the latent and the
+# state-space mixer, whose "a_log", "dt_bias" and "conv" follow
+# Mamba-2's published initialisation (`_mamba_init`)
 def _mixer_leaves(cfg: ModelConfig, kind: str, n: int):
     h = cfg.hidden_size
     g = gdn_spec(cfg)
@@ -237,13 +267,27 @@ def _mixer_leaves(cfg: ModelConfig, kind: str, n: int):
             ("w_o", (n, hq * d, h), "normal"),
         )
     if kind == "gqa":
-        hq, hkv, d = gqa_spec(cfg)
+        hq, hkv, d = gqa_spec(cfg)[:3]
+        norms = (("attn_q_norm", (n, d), "ones"),
+                 ("attn_k_norm", (n, d), "ones")) if cfg.use_qk_norm else ()
         return (
             ("attn_w_q", (n, h, hq * d), "normal"),
             ("attn_w_kv", (n, h, 2 * hkv * d), "normal"),
-            ("attn_q_norm", (n, d), "ones"),
-            ("attn_k_norm", (n, d), "ones"),
+        ) + norms + (
             ("attn_w_o", (n, hq * d, h), "normal"),
+        )
+    if kind == "mamba2":
+        ms = mamba_spec(cfg)
+        return (
+            ("m2_w_in", (n, h, 2 * ms.inner + 2 * ms.state + ms.num_heads),
+             "normal"),
+            ("m2_conv_w", (n, ms.conv, ms.channels), "conv"),
+            ("m2_conv_b", (n, ms.channels), "conv"),
+            ("m2_a_log", (n, ms.num_heads), "a_log"),
+            ("m2_dt_bias", (n, ms.num_heads), "dt_bias"),
+            ("m2_d", (n, ms.num_heads), "ones"),
+            ("m2_norm", (n, ms.inner), "ones"),
+            ("m2_w_out", (n, ms.inner, h), "normal"),
         )
     m = latent_spec(cfg)
     return (
@@ -258,8 +302,9 @@ def _mixer_leaves(cfg: ModelConfig, kind: str, n: int):
 
 
 # the leaves' order; "gqa" is the one set of the window and the global
-# grouped-query blocks together
-_MIXERS = ("gdn", "kda", "gated_attn", "mla", "gqa")
+# grouped-query blocks together. A new kind goes at the END: a leaf's
+# key is its position, and the accepted references draw by position
+_MIXERS = ("gdn", "kda", "gated_attn", "mla", "gqa", "mamba2")
 
 
 def _leaf_kind(mixer: str) -> str:
@@ -287,19 +332,52 @@ def _dense_leaves(cfg: ModelConfig, n: int):
 
 
 def leaves(cfg: ModelConfig):
+    """A leaf the configuration has no use for is ABSENT (a tied head,
+    the experts of a pattern without an expert block, a kind's leaves
+    without a block of the kind), never a stack of zero rows.
+
+    Under an `embedding_multiplier` m_e the table is drawn m_e times
+    smaller ("embed": N(0, _INIT_SCALE / m_e)), so that the stream
+    receives m_e E[token] at the scale every other configuration's
+    does. At _INIT_SCALE itself a TIED head scores the last token
+    m_e |E[token]|^2 over the rest: at Granite's widths 10.9 standard
+    deviations of a logit over the stream's rms, where the best of
+    100,352 others reaches 4.4, so the model would echo its input
+    whatever its blocks compute."""
     L, h, v = cfg.num_layers, cfg.hidden_size, cfg.vocab_size
     mixers = tuple(_leaf_kind(k) for k in cfg.mixer_kinds)
     ld = cfg.first_k_dense
     return (
-        (("embed", (v, h), "normal"),
-         ("final_ln", (h,), "gain"),
-         ("lm_head", (h, v), "normal"),
-         ("input_ln", (L, h), "gain"),
-         ("post_ln", (L, h), "gain"))
-        + _moe_leaves(cfg, L - ld)
+        (("embed", (v, h),
+          "normal" if cfg.embedding_multiplier == 1.0 else "embed"),
+         ("final_ln", (h,), "gain"))
+        + (() if cfg.tie_word_embeddings
+           else (("lm_head", (h, v), "normal"),))
+        + (("input_ln", (L, h), "gain"),
+           ("post_ln", (L, h), "gain"))
+        + (_moe_leaves(cfg, L - ld) if L > ld else ())
         + (_dense_leaves(cfg, ld) if ld else ())
         + tuple(leaf for kind in _MIXERS if kind in mixers
                 for leaf in _mixer_leaves(cfg, kind, mixers.count(kind))))
+
+
+def _mamba_init(u, init: str, xp, taps: int):
+    """Mamba-2's published initialisation from `u` uniform in [0, 1):
+    "a_log" is log(a), a uniform in [1, 16]; "dt_bias" the inverse
+    softplus of a step log-uniform in [1e-3, 1e-1]; "conv" (the
+    depthwise convolution's taps and bias) uniform within
+    taps ** -0.5 of 0, its framework's default for such a layer.
+    Under N(0, 0.02) the decay exp(-softplus(~0) exp(~0)) would halve
+    the state every token, and x, B and C would come out of the
+    convolution so small that the state's share of y, which the gated
+    norm rescales, is 1e-4 of D x: a carry broken between steps would
+    change nothing a comparison could see."""
+    if init == "conv":
+        return (2.0 * u - 1.0) * taps ** -0.5
+    if init == "a_log":
+        return xp.log(1.0 + 15.0 * u)
+    step = xp.exp(u * math.log(100.0) + math.log(1e-3))
+    return step + xp.log(-xp.expm1(-step))
 
 
 def init_params(cfg: ModelConfig, mesh, seed: int = 0,
@@ -314,19 +392,38 @@ def init_params(cfg: ModelConfig, mesh, seed: int = 0,
     gain = "zeros" if cfg.norm_zero_centred else "ones"
     spec = tuple((n, s, gain if i == "gain" else i) for n, s, i in spec)
     const = {"zeros": jnp.zeros, "ones": jnp.ones}
+    taps = cfg.mamba_conv_kernel_dim
     if fast:
+        def one(key, shape, init):
+            if init in const:
+                return const[init](shape, dt)
+            if init == "normal":
+                return _draw(key, shape, dt)
+            if init == "embed":
+                return (_draw(key, shape, jnp.float32)
+                        / cfg.embedding_multiplier).astype(dt)
+            return _mamba_init(jax.random.uniform(key, shape, jnp.float32),
+                               init, jnp, taps).astype(dt)
+
         def draw(key):
-            return {name: const[init](shape, dt) if init != "normal"
-                    else _draw(jax.random.fold_in(key, i), shape, dt)
+            return {name: one(jax.random.fold_in(key, i), shape, init)
                     for i, (name, shape, init) in enumerate(spec)}
 
         return jax.jit(draw, out_shardings=where)(jax.random.PRNGKey(seed))
     rng = np.random.default_rng(seed)
     host = {"zeros": np.zeros, "ones": np.ones}
+
+    def one(shape, init):
+        if init in host:
+            return host[init](shape, np.float32)
+        if init in ("normal", "embed"):
+            return (rng.standard_normal(shape) * _INIT_SCALE
+                    / (cfg.embedding_multiplier if init == "embed" else 1.0))
+        return _mamba_init(rng.uniform(size=shape), init, np, taps)
+
     return {name: jax.device_put(
-        (host[init](shape, np.float32) if init != "normal" else np.asarray(
-            rng.standard_normal(shape) * _INIT_SCALE, np.float32)
-         ).astype(dt), where) for name, shape, init in spec}
+        np.asarray(one(shape, init), np.float32).astype(dt), where)
+        for name, shape, init in spec}
 
 
 # a block's leaves by kind; the experts' own stacks (w_gate_up,
@@ -341,10 +438,12 @@ _MIXER_LEAVES = {
     "mla": ("mla_w_q", "mla_w_a", "mla_kv_norm", "mla_w_b", "mla_w_o"),
     "gqa": ("attn_w_q", "attn_w_kv", "attn_q_norm", "attn_k_norm",
             "attn_w_o"),
+    "mamba2": ("m2_w_in", "m2_conv_w", "m2_conv_b", "m2_a_log",
+               "m2_dt_bias", "m2_d", "m2_norm", "m2_w_out"),
 }
 _MIXER_PARAMS = {"gdn": GDNParams, "kda": KDAParams,
                  "gated_attn": GatedAttnParams, "mla": LatentAttnParams,
-                 "gqa": GQAttnParams}
+                 "gqa": GQAttnParams, "mamba2": Mamba2Params}
 
 
 def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
@@ -367,11 +466,19 @@ def forward_chunk(cfg: ModelConfig, params: dict, tokens, cache: Cache,
 @part("head")
 def head_logits(cfg: ModelConfig, params: dict, x):
     """The final norm and the vocabulary projection over hidden rows
-    (..., H): float32 logits (..., V)."""
+    (..., H): float32 logits (..., V). A tied head is the embedding
+    transposed; `logits_scaling` divides them."""
     x = rms_norm(x, params["final_ln"], cfg.rms_eps,
                  zero_centred=cfg.norm_zero_centred)
-    return jnp.einsum("...h,hv->...v", x, params["lm_head"],
-                      preferred_element_type=jnp.float32)
+    if cfg.tie_word_embeddings:
+        logits = jnp.einsum("...h,vh->...v", x, params["embed"],
+                            preferred_element_type=jnp.float32)
+    else:
+        logits = jnp.einsum("...h,hv->...v", x, params["lm_head"],
+                            preferred_element_type=jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
@@ -386,6 +493,8 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
     slots, chunk = tokens.shape
     g, a, m = gdn_spec(cfg), attn_spec(cfg), latent_spec(cfg)
     gq = gqa_spec(cfg)
+    state_spec = mamba_spec(cfg) if "mamba2" in cfg.mixer_kinds else g
+    m_r = cfg.residual_multiplier
     eps = cfg.rms_eps
     router = RouterForm(cfg.router_score, cfg.routed_scaling_factor)
     if "gated_attn" in cfg.mixer_kinds:
@@ -403,6 +512,9 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
     def normed(x, gain):
         return rms_norm(x, gain, eps, zero_centred=cfg.norm_zero_centred)
 
+    def added(x, y):  # a residual branch, under the multiplier if any
+        return x + y if m_r == 1.0 else x + (m_r * y).astype(x.dtype)
+
     def moe(x, gain, p, layer):
         # the experts' stacks whole, this block's by `layer`: a
         # per-period slice of them would be copied every step
@@ -415,12 +527,12 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
             hid, valid, p, cfg.num_experts_per_tok, cfg.expert_offset,
             layer=layer, router=router)
         with part("moe.combine"):
-            return x + y.reshape(x.shape), *counts
+            return added(x, y.reshape(x.shape)), *counts
 
     @part("ffn.dense")
     def dense(x, gain, p):
-        return x + swiglu_fwd(normed(x, gain), p["wd_gate_up"],
-                              p["wd_down"]).astype(x.dtype)
+        return added(x, swiglu_fwd(normed(x, gain), p["wd_gate_up"],
+                                   p["wd_down"]).astype(x.dtype))
 
     def run(period, start, count):
         """The scan body of a run of `period`s whose first blocks are
@@ -442,8 +554,10 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
             at = {kind: 0 for kind in count}
             for j, (mixer, ffn) in enumerate(period):
                 leaf = _leaf_kind(mixer)
+                # a leaf the configuration lacks (the head norms
+                # where the source has none) is None
                 p = _MIXER_PARAMS[leaf](*(
-                    row(params[n], leaf, at[leaf])
+                    row(params[n], leaf, at[leaf]) if n in params else None
                     for n in _MIXER_LEAVES[leaf]))
                 # the block's norm goes with the projections it feeds,
                 # the residual with the one it adds
@@ -452,11 +566,13 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                 with proj:
                     hid = normed(x, row(params["input_ln"], "block", j))
                 if mixer in STATE_MIXERS:
-                    fwd = gated_delta_net_fwd if mixer == "gdn" else kda_fwd
-                    y, r, c = fwd(hid, p, g,
-                                  carried(cache.rec, "state", at["state"]),
-                                  carried(cache.conv, "state", at["state"]),
-                                  n_valid, fresh, eps)
+                    fwd = (gated_delta_net_fwd if mixer == "gdn"
+                           else kda_fwd if mixer == "kda" else mamba2_fwd)
+                    y, r, c = fwd(
+                        hid, p, state_spec,
+                        carried(cache.rec, "state", at["state"]),
+                        carried(cache.conv, "state", at["state"]),
+                        n_valid, fresh, eps)
                     recs.append(r)
                     convs.append(c)
                     at["state"] += 1
@@ -489,7 +605,7 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                 at[leaf] += 1
                 gain = row(params["post_ln"], "block", j)
                 with proj:
-                    x = x + y
+                    x = added(x, y)
                 if ffn == "moe":
                     x, h_j, a_j, t_j = moe(
                         x, gain,
@@ -519,6 +635,8 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
 
     with part("embed"):
         x = params["embed"][tokens]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
     start = {kind: 0 for kind in _MIXERS + ("moe", "dense", "block",
                                             "state", "page", "window")}
     outs, pages_a_period = [], []
@@ -555,20 +673,27 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
             joined([flat(o[1][k]) if n > 1 else o[1][k]
                     for o, n in zip(outs, pages_a_period) if n])
             for k in range(len(cache.pages)))
-    stats = {"moe_pairs_here": sum(jnp.sum(o[3]) for o in outs),
-             "moe_pairs_absent": sum(jnp.sum(o[4]) for o in outs),
-             "moe_gmm_tile_rows": sum(jnp.sum(o[5]) for o in outs)}
+    # a pattern without an expert block counts nothing on the device
+    stats = {} if "moe" not in cfg.ffn_kinds else {
+        "moe_pairs_here": sum(jnp.sum(o[3]) for o in outs),
+        "moe_pairs_absent": sum(jnp.sum(o[4]) for o in outs),
+        "moe_gmm_tile_rows": sum(jnp.sum(o[5]) for o in outs)}
     with part("pool.scatter"):
         win = per_block(2, len(cache.win))
     return x, rows, rec, conv, win, stats
 
 
 def state_shapes(cfg: ModelConfig, slots: int):
-    """Shapes of the delta-net blocks' per-slot state beside the
-    pages, (rec, conv); () for a pattern without such a block."""
+    """Shapes of the delta-net or state-space blocks' per-slot state
+    beside the pages, (rec, conv); () for a pattern without such a
+    block."""
     ll = sum(k in STATE_MIXERS for k in cfg.mixer_kinds)
     if not ll:
         return ()
+    if "mamba2" in cfg.mixer_kinds:
+        ms = mamba_spec(cfg)
+        return ((ll, slots, ms.num_heads, ms.head_dim, ms.state),
+                (ll, slots, ms.conv - 1, ms.channels))
     g = gdn_spec(cfg)
     return ((ll, slots, g.num_v_heads, g.k_dim, g.v_dim),
             (ll, slots, g.conv - 1, g.channels))
@@ -591,8 +716,10 @@ def slot_state(cfg: ModelConfig) -> str:
     models/engine.py, mega/qwen3.py)."""
     kinds = set(cfg.mixer_kinds)
     what = []
-    if kinds & set(STATE_MIXERS):
+    if kinds & {"gdn", "kda"}:
         what.append("recurrent (gated-delta-net) state")
+    if "mamba2" in kinds:
+        what.append("state-space (Mamba-2) state")
     if kinds & set(WINDOW_MIXERS):
         what.append("a window block's tail (the last sliding_window keys "
                     "and values a slot)")

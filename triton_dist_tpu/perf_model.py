@@ -1288,6 +1288,9 @@ def estimate_hybrid_step_ms(cfg, n_tokens: int, kv_tokens: int = 0,
     mixer["window_attn"] = mixer["global_attn"] = (
         h * cfg.head_dim * (hq + 2 * cfg.num_kv_heads)
         + hq * cfg.head_dim * h)
+    m_inner = cfg.mamba_num_heads * cfg.mamba_head_dim
+    mixer["mamba2"] = (h * (2 * m_inner + 2 * cfg.mamba_state_dim
+                            + cfg.mamba_num_heads) + m_inner * h)
     mixers_w = sum(mixer[kind] for kind in mixers)
     if cfg.kv_lora_rank:
         kv_row, pair = row, hq * (row + cfg.kv_lora_rank)
@@ -1296,11 +1299,18 @@ def estimate_hybrid_step_ms(cfg, n_tokens: int, kv_tokens: int = 0,
         pair = 2 * hq * cfg.head_dim
     w_params = (lm * (shared + router) + cfg.first_k_dense * dense_mlp
                 + mixers_w + h * cfg.vocab_size)
+    # a state-space block's state in a delta net's place (a pattern has
+    # one of the two): heads x channels x state, and as many
+    # multiply-adds a token as the delta rule's one product
+    if "mamba2" in mixers:
+        hv, dk, dv = (cfg.mamba_num_heads, cfg.mamba_state_dim,
+                      cfg.mamba_head_dim)
     state = ll * hv * dk * dv * 4 * 2  # read and written, every slot's
     mem_ms = (lm * held * expert * b / _RAGGED_DOT_HBM_SHARE + w_params * b
               + lf * kv_row * kv_tokens * b
               + state) / (chip.hbm_gbps * 1e9) * 1e3
-    routed = cfg.num_experts_per_tok * held / cfg.num_experts * expert
+    routed = (cfg.num_experts_per_tok * held / cfg.num_experts * expert
+              if lm else 0.0)
     per_token = (lm * (routed + shared + router)
                  + cfg.first_k_dense * dense_mlp + mixers_w
                  + h * cfg.vocab_size)

@@ -737,13 +737,16 @@ def route_gated_attention(b: int, s: int, t: int, hq: int, hkv: int,
 def route_hybrid_attention(cfg, b: int, s: int, t: int) -> str:
     """The one routing decision of a hybrid configuration's serve
     step: its attention blocks' implementation, from the widths of
-    their kind (a latent family's one page array is its one head)."""
+    their kind (a latent family's one page array is its one head; a
+    head narrower than a lane tile reaches the kernel as wide as its
+    page keeps it, `ModelConfig.page_head_dim`)."""
     if cfg.kv_lora_rank:
         (hkv, d), = cfg.page_arrays
         return route_gated_attention(b, s, t, cfg.num_q_heads, hkv, d,
                                      cfg.dtype, v_prefix=cfg.kv_lora_rank)
     return route_gated_attention(b, s, t, cfg.num_q_heads,
-                                 cfg.num_kv_heads, cfg.head_dim, cfg.dtype)
+                                 cfg.num_kv_heads, cfg.page_head_dim,
+                                 cfg.dtype)
 
 
 def route_window_attention(cfg, b: int, s: int) -> str:

@@ -53,7 +53,10 @@ A second kind of state (the hybrid family, models/hybrid.py): pages
 hold what the attention blocks keep only, and every delta-net block
 keeps PER-SLOT state beside them — `rec`
 (Ll, slots, Hv, dk, dv) float32 and `conv` (Ll, slots, K-1, channels),
-their shapes the family's (`models.hybrid.state_shapes`).
+their shapes the family's (`models.hybrid.state_shapes`); a
+state-space (Mamba-2) block keeps the same two, `rec` (Ls, slots,
+heads, P, N) float32 and `conv` over its x | B | C channels, under
+the same names and counters.
 It needs no allocator: a slot's life covers it, because the serve step
 starts a slot whose length is 0 from zero state and leaves the state of
 a slot with no valid column as it was; admit, eviction with re-prefill

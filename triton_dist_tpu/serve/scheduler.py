@@ -132,8 +132,9 @@ class Scheduler:
 
             for asked, what in (
                     (prefix_cache, "prefix_cache: a cached prefix is "
-                     "pages, and a delta-net block's state or a window "
-                     "block's tail after the prefix is kept nowhere"),
+                     "pages, and a delta-net or state-space block's "
+                     "state or a window block's tail after the prefix is "
+                     "kept nowhere"),
                     (spec is not None and getattr(spec, "k", 0) > 0,
                      "spec: a rejected draft would have to roll that "
                      "state back, and the step keeps no per-column "
@@ -660,10 +661,14 @@ class Scheduler:
         self.obs.inc("serve_state_resets", sum(
             1 for slot, _r, n, _e, _d in plans
             if int(self.pool.lengths[slot]) == n))
+        # {} from a pattern without an expert block: the counters read
+        # 0 and the step spends nothing on them
         stats = self.worker.last_stats
-        self.obs.inc("moe_pairs", stats["moe_pairs_here"], held="here")
-        self.obs.inc("moe_pairs", stats["moe_pairs_absent"], held="absent")
-        self.obs.inc("moe_gmm_tile_rows", stats["moe_gmm_tile_rows"])
+        self.obs.inc("moe_pairs", stats.get("moe_pairs_here", 0),
+                     held="here")
+        self.obs.inc("moe_pairs", stats.get("moe_pairs_absent", 0),
+                     held="absent")
+        self.obs.inc("moe_gmm_tile_rows", stats.get("moe_gmm_tile_rows", 0))
         self.obs.inc("moe_expert_steps",
                      cfg.num_moe_layers * cfg.num_experts_held)
 
