@@ -39,6 +39,7 @@ def main(argv=None) -> int:
 
     sys.path.insert(0, ROOT)
     from perfbench import harness
+    t_harness = time.perf_counter()
 
     bench = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = harness.find(bench["workloads"], args.workload, "workload")
@@ -46,7 +47,17 @@ def main(argv=None) -> int:
         else bench["run_seconds"]
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # the TPU runtime pins a host buffer for transfers, 4 GiB unless told;
+    # on hosts without transparent huge pages that pinning is most of the
+    # device start-up (v5e: 6-11 s, swinging by seconds; 1.5-2.5 s at
+    # 256 MiB, PERF.md). A run puts no more than a few MiB at a time
+    os.environ.setdefault("TPU_PREMAPPED_BUFFER_SIZE", str(256 << 20))
     import jax
+    t_jax = time.perf_counter()
+    # every program goes into the compile cache, however quickly it
+    # compiled, so that a run after the first compiles nothing (JAX keeps
+    # by default only what took a second or more)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     try:
         from triton_dist_tpu import lang
@@ -54,7 +65,9 @@ def main(argv=None) -> int:
         print(f"the program under test is not in this checkout: {e}",
               file=sys.stderr)
         return 3
+    t_program = time.perf_counter()
     devices = jax.devices()
+    t_devices = time.perf_counter()
     if devices[0].platform != "tpu" or lang.use_interpret():
         print("perfbench measures on a TPU only (JAX's first device is "
               f"{devices[0].platform!r}, interpret={lang.use_interpret()}): "
@@ -65,6 +78,9 @@ def main(argv=None) -> int:
               f"{len(devices)}", file=sys.stderr)
         return 2
 
+    say(f"start-up: benchmark {t_harness - T_START:.2f}s, jax "
+        f"{t_jax - t_harness:.2f}s, program {t_program - t_jax:.2f}s, "
+        f"devices {t_devices - t_program:.2f}s")
     result = harness.run_cell(ROOT, bench, cell, args.seed, seconds,
                               bool(args.trace), T_START, say=say,
                               dump_trace=args.dump_trace)

@@ -1,6 +1,6 @@
 """The K-EXAONE family's benchmark files: the repository's
 BENCHMARK.json keeps the contract with the configuration and the cell
-added, and with the fixture's two per-layer entries appended; the
+added, and with the family's two per-layer entries appended; the
 configuration against its record; the work counts against hand counts;
 the two readers this family brings, on planted counters and on the
 fixture's trace; and a tiny copy of the family (a leading dense block
@@ -13,6 +13,7 @@ import os
 
 import pytest
 
+import by_name as entries
 import perfbench_tiny as tiny
 from perfbench import contract, harness, work
 from perfbench.sources import device_trace
@@ -21,8 +22,13 @@ REPO = tiny.REPO
 CELL = "kx236b-1chip.longdoc-closed"
 CONFIG = "k-exaone-236b.1chip"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-FIXTURE = os.path.join(REPO, "perfbench", "fixtures",
-                       "per_layer.exaone_moe.json")
+# the entries the `q3n` cell brought: the expert layer's two read this
+# cell's counters as they are; the state's and the full-attention
+# kernel's find nothing here (no delta-net block; this family's kernel
+# share is `kernel.window_attn.roofline_pct`)
+MOE = ("moe.local_pairs_pct", "moe.pairs_per_expert_step")
+NOT_HERE = ("state.live_pct", "kernel.flash_prefill_full.roofline_pct")
+APPENDED = ("kernel.window_attn.roofline_pct", "kv.window_live_pct")
 CUT = ("num_hidden_layers", "num_experts", "vocab_size", "layer_types",
        "mlp_layer_types", "sliding_windows")
 
@@ -68,10 +74,10 @@ def cfg(bench):
 
 def test_the_repository_s_benchmark_keeps_the_contract(bench):
     assert contract.violations(bench, REPO) == []
-    # the two pins this PR may not lift (ROADMAP C11) still hold
-    assert bench["per_layer"][-1]["name"] == "sched.narrow_steps_pct"
-    assert sum(m.get("workloads") == ["q3n-1chip.longdoc-closed"]
-               for m in bench["per_layer"]) == 4
+    # the four entries the `q3n` cell brought list it
+    for name in MOE + NOT_HERE:
+        assert "q3n-1chip.longdoc-closed" in entries.metric(
+            bench, name)["workloads"]
 
 
 def test_the_configuration_cuts_depth_experts_and_vocabulary_alone(
@@ -148,46 +154,29 @@ def test_the_cell_and_the_entries_that_list_it(bench):
            if "workloads" not in m or CELL in m["workloads"]}
     assert {"itl_p95_ms", "setup_s"} <= e2e <= {"itl_p95_ms", "setup_s",
                                                "ttft_p95_ms"}
-    lists = {m["name"] for m in bench["per_layer"]
-             if CELL in m["workloads"]}
-    every = {m["name"] for m in bench["per_layer"]
-             if "kl48b-1chip.longdoc-closed" in m["workloads"]}
-    assert lists == every and len(lists) == 15
-    assert {"step.mfu_pct", "step.wall_p50_ms", "kv.gather_live_pct",
-            "sched.prefill_rows_pct", "device.idle_pct"} <= lists
+    lists = entries.listing(bench, CELL)
+    assert set(entries.GENERIC + MOE + APPENDED) <= lists
+    assert not lists & set(NOT_HERE)
 
 
-def _with_the_waiting_entries(bench, also=()):
-    """`bench` with the two per-layer entries this family's readers
-    wait for, APPENDED: where a `benchmark` PR puts them once the two
-    pins are lifted (ROADMAP C11)."""
-    with open(FIXTURE) as f:
-        waiting = json.load(f)["per_layer"]
-    for m in waiting:
-        m["workloads"] += list(also)
-    return dict(bench, per_layer=bench["per_layer"] + waiting), waiting
-
-
-def test_the_waiting_entries_keep_the_contract_and_have_their_files(bench):
-    full, waiting = _with_the_waiting_entries(bench)
-    assert contract.violations(full, REPO) == []
+def test_the_appended_entries_keep_the_contract_and_have_their_files(
+        bench):
+    assert contract.violations(bench, REPO) == []
+    appended = [entries.metric(bench, name) for name in APPENDED]
     assert [(m["name"], m["unit"], m["better"], m["source"], m["layer"],
-             m["moves"]) for m in waiting] == [
+             m["moves"]) for m in appended] == [
         ("kernel.window_attn.roofline_pct", "%", "higher", "device_trace",
          "attention kernels", "itl_p95_ms"),
         ("kv.window_live_pct", "%", "higher", "program_counter", "KV pool",
          "itl_p95_ms")]
-    layers = {m["layer"] for m in bench["per_layer"]}
-    for m in waiting:
-        assert m["workloads"] == [CELL] and m["layer"] in layers
+    names = [m["name"] for m in bench["per_layer"]]
+    accepted = names.index("sched.narrow_steps_pct")
+    layers = {m["layer"] for m in bench["per_layer"]
+              if m["name"] not in APPENDED}
+    for m in appended:
+        assert names.index(m["name"]) > accepted  # appended, not inserted
+        assert CELL in m["workloads"] and m["layer"] in layers
         assert callable(harness.load_reader(REPO, m["name"]).read)
-        assert m["name"] not in {x["name"] for x in bench["per_layer"]}
-    with open(FIXTURE) as f:
-        kept_out = json.load(f)["lists_to_take_the_cell"]
-    assert kept_out["cell"] == CELL
-    assert sorted(kept_out["entries"]) == sorted(
-        m["name"] for m in bench["per_layer"]
-        if m["workloads"] == ["q3n-1chip.longdoc-closed"])
 
 
 SIZES = dict(L=8, Lw=6, Lf=2, Ld=1, Lm=7, H=6144, V=19200, I=18432, hq=64,
@@ -283,11 +272,9 @@ def test_window_roofline_on_the_fixture_and_on_nothing():
 
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
-    root, bench, cell = tiny.make_root(tmp_path_factory.mktemp("kx"),
-                                       mix=MIX, config=TINY_EXAONE)
-    bench, _ = _with_the_waiting_entries(bench, also=[cell["name"]])
-    tiny.write_json(root, "BENCHMARK.json", bench)
-    return root, bench, cell
+    # tiny.add_cell lists the cell under every entry that lists cells
+    return tiny.make_root(tmp_path_factory.mktemp("kx"), mix=MIX,
+                          config=TINY_EXAONE)
 
 
 def test_a_tiny_cell_of_the_family_reads_correct_and_its_metrics(
@@ -306,7 +293,7 @@ def test_a_tiny_cell_of_the_family_reads_correct_and_its_metrics(
     # other hybrid members; there is no delta-net state to count
     assert 35.0 < got["moe.local_pairs_pct"] < 65.0  # 4 of 8 held
     assert "state.live_pct" not in got
-    # the waiting entries, through the harness: the tails of the slots
+    # the appended entries, through the harness: the tails of the slots
     # with a valid row over every slot's; the CPU's route runs no
     # kernel, so the roofline's reader says nothing
     assert 0.0 < got["kv.window_live_pct"] <= 100.0

@@ -1,20 +1,20 @@
 """The Granite 4.0-H family's benchmark files: the repository's
 BENCHMARK.json keeps the contract with the configuration and the cell
-added, and with the fixture's lists applied; the configuration against
-its record (nothing reduced, every width as published); the work
-counts against hand counts; the two accepted readers the fixture names,
-on planted counters and on the fixture's trace under this family's
-sizes; and a tiny copy of the family (`M A`, `M M A` twice, `M`, a
-state of 16, float32) through `run_cell` on the CPU: `correct` as
-served, not `correct` with one token altered, with the state or the
-convolution tail not carried."""
+added, and with the two accepted entries that read something in the
+cell listing it; the configuration against its record (nothing
+reduced, every width as published); the work counts against hand
+counts; those two readers, on planted counters and on the fixture's
+trace under this family's sizes; and a tiny copy of the family (`M A`,
+`M M A` twice, `M`, a state of 16, float32) through `run_cell` on the
+CPU: `correct` as served, not `correct` with one token altered, with
+the state or the convolution tail not carried."""
 
-import importlib.util
 import json
 import os
 
 import pytest
 
+import by_name as entries
 import perfbench_tiny as tiny
 from perfbench import contract, harness, work
 from perfbench.sources import device_trace
@@ -23,8 +23,12 @@ REPO = tiny.REPO
 CELL = "g4hm-1chip.longdoc-closed"
 CONFIG = "granite-4.0-h-micro.1chip"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-FIXTURE = os.path.join(REPO, "perfbench", "fixtures",
-                       "per_layer.granite_hybrid.json")
+# the accepted entries that read something here: the state-space state
+# under the delta nets' counters, `_fp_local_kernel`'s events against
+# work `flash_prefill_full_layers`, whose formulae fit this family's
+# sizes (d the useful 64); the expert layer's two find nothing here
+EXTENDED = ("state.live_pct", "kernel.flash_prefill_full.roofline_pct")
+MOE = ("moe.local_pairs_pct", "moe.pairs_per_expert_step")
 M, A = "mamba", "attention"
 
 TINY_GRANITE = {
@@ -71,25 +75,11 @@ def cfg(bench):
     return harness.load_json(os.path.join(REPO, entry["file"]))
 
 
-@pytest.fixture(scope="module")
-def run_waiting():
-    spec = importlib.util.spec_from_file_location(
-        "run_waiting_g4h", os.path.join(REPO, "scripts", "run_waiting.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def test_the_repository_s_benchmark_keeps_the_contract(bench):
     assert contract.violations(bench, REPO) == []
-    # the two pins this PR may not lift (ROADMAP C11) still hold
-    assert bench["per_layer"][-1]["name"] == "sched.narrow_steps_pct"
-    assert sum(m.get("workloads") == ["q3n-1chip.longdoc-closed"]
-               for m in bench["per_layer"]) == 4
-    # one configuration and one cell at the end, nothing reduced
-    assert bench["configs"][-1]["name"] == CONFIG
-    assert bench["workloads"][-1]["name"] == CELL
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    # the configuration and its cell, found by their names
+    assert entries.cell(bench, CELL)["config"] == entries.config(
+        bench, CONFIG)["name"]
 
 
 def test_the_configuration_is_the_record_whole(bench, cfg):
@@ -152,48 +142,17 @@ def test_the_cell_and_the_entries_that_list_it(bench):
            if "workloads" not in m or CELL in m["workloads"]}
     assert {"itl_p95_ms", "setup_s"} <= e2e <= {"itl_p95_ms", "setup_s",
                                                "ttft_p95_ms"}
-    lists = {m["name"] for m in bench["per_layer"]
-             if CELL in m["workloads"]}
-    every = {m["name"] for m in bench["per_layer"]
-             if "kl48b-1chip.longdoc-closed" in m["workloads"]
-             and "kx236b-1chip.longdoc-closed" in m["workloads"]}
-    assert lists == every and len(lists) == 15
-    assert {"step.mfu_pct", "step.wall_p50_ms", "kv.gather_live_pct",
-            "sched.prefill_rows_pct", "device.idle_pct"} <= lists
+    lists = entries.listing(bench, CELL)
+    assert set(entries.GENERIC + EXTENDED) <= lists
+    assert not lists & set(MOE)
 
 
-def _with_the_fixture(bench, run_waiting, also=()):
-    """`bench` as `scripts/run_waiting.py --entries` hands it to a run:
-    the fixture's entries appended (it has none of its own yet) and its
-    lists applied, the cell joining the accepted entries it names."""
-    with open(FIXTURE) as f:
-        fixture = json.load(f)
-    take = dict(fixture["lists_to_take_the_cell"])
-    lists = [take] + [dict(take, cell=c) for c in also]
-    return run_waiting.appended(bench, fixture["per_layer"], lists), fixture
-
-
-def test_the_fixture_s_lists_keep_the_contract_and_have_their_files(
-        bench, run_waiting):
-    full, fixture = _with_the_fixture(bench, run_waiting)
-    assert contract.violations(full, REPO) == []
-    assert fixture["per_layer"] == []
-    take = fixture["lists_to_take_the_cell"]
-    assert take["cell"] == CELL
-    assert take["entries"] == ["state.live_pct",
-                               "kernel.flash_prefill_full.roofline_pct"]
-    for name in take["entries"]:
-        before = harness.find(bench["per_layer"], name, "metric")
-        after = harness.find(full["per_layer"], name, "metric")
-        assert before["workloads"] == ["q3n-1chip.longdoc-closed"]
-        assert after["workloads"] == before["workloads"] + [CELL]
+def test_the_extended_lists_keep_the_contract_and_have_their_files(bench):
+    assert contract.violations(bench, REPO) == []
+    for name in EXTENDED:
+        cells = entries.metric(bench, name)["workloads"]
+        assert {"q3n-1chip.longdoc-closed", CELL} <= set(cells)
         assert callable(harness.load_reader(REPO, name).read)
-    # nothing else moved, and the repository's own file is as it was
-    assert len(full["per_layer"]) == len(bench["per_layer"])
-    assert sum(m != n for m, n in zip(full["per_layer"],
-                                      bench["per_layer"])) == 2
-    # a fixture without lists goes through as before
-    assert run_waiting.appended(bench, []) == bench
 
 
 SIZES = dict(L=40, Ls=36, Lf=4, H=2048, V=100352, I=8192, hq=32, hkv=8,
@@ -281,13 +240,10 @@ def test_attention_roofline_on_the_fixture_under_this_family_s_sizes():
 
 
 @pytest.fixture(scope="module")
-def tiny_root(tmp_path_factory, run_waiting):
-    root, bench, cell = tiny.make_root(tmp_path_factory.mktemp("g4h"),
-                                       mix=MIX, config=TINY_GRANITE)
-    # tiny.add_cell lists the cell under every entry that lists cells,
-    # the fixture's two among them
-    tiny.write_json(root, "BENCHMARK.json", bench)
-    return root, bench, cell
+def tiny_root(tmp_path_factory):
+    # tiny.add_cell lists the cell under every entry that lists cells
+    return tiny.make_root(tmp_path_factory.mktemp("g4h"), mix=MIX,
+                          config=TINY_GRANITE)
 
 
 def test_a_tiny_cell_of_the_family_reads_correct_and_its_metrics(

@@ -11,6 +11,7 @@ import sys
 import numpy as np
 import pytest
 
+import by_name as entries
 import perfbench_tiny as tiny
 from perfbench import harness
 
@@ -447,8 +448,7 @@ def run_command(cwd, *args):
 
 
 def test_the_command_refuses_to_measure_without_a_tpu():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        name = json.load(f)["workloads"][0]["name"]
+    name = entries.cell(entries.load(), "q8b-1chip.chat-closed")["name"]
     out = run_command(REPO, "--workload", name, "--seed", "1",
                       "--seconds", "1", "--trace", "0")
     assert out.returncode not in (0, None)

@@ -1,8 +1,9 @@
 """The Kimi-Linear family's benchmark files: the repository's
 BENCHMARK.json keeps the contract with the configuration and the cell
-added; the work counts against hand counts; the two readers this
-family brings, on planted counters and on the fixture's trace; and a
-tiny copy of the family (a leading dense block, a whole period and a
+added, and with the family's two per-layer entries appended; the work
+counts against hand counts; the two readers this family brings, on
+planted counters and on the fixture's trace; and a tiny copy of the
+family (a leading dense block, a whole period and a
 short one, 8 experts of which 4 held, float32) through `run_cell` on
 the CPU: `correct` as served, not `correct` with one token altered."""
 
@@ -11,6 +12,7 @@ import os
 
 import pytest
 
+import by_name as entries
 import perfbench_tiny as tiny
 from perfbench import contract, harness, work
 from perfbench.sources import device_trace
@@ -19,6 +21,11 @@ REPO = tiny.REPO
 CELL = "kl48b-1chip.longdoc-closed"
 CONFIG = "kimi-linear-48b.1chip"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+APPENDED = ("kernel.mla_attn.roofline_pct", "kv.bytes_per_live_token")
+# the entries the `q3n` cell brought that read this cell's counters as
+# they are: the expert layer's and the delta nets' state
+SHARED = ("moe.local_pairs_pct", "moe.pairs_per_expert_step",
+          "state.live_pct")
 
 TINY_KIMI = {
     "model_type": "kimi_linear", "vocab_size": 256, "hidden_size": 64,
@@ -126,41 +133,29 @@ def test_the_cell_and_the_entries_that_list_it(bench):
     # the final step two spread it 3.2% and 3.6%, over half its bound
     # (PERF.md section 6, PR 35)
     assert e2e == {"itl_p95_ms", "setup_s"}
-    lists = {m["name"] for m in bench["per_layer"]
-             if CELL in m["workloads"]}
-    assert {"step.mfu_pct", "step.wall_p50_ms", "kv.gather_live_pct",
-            "sched.prefill_rows_pct", "device.idle_pct"} <= lists
+    lists = entries.listing(bench, CELL)
+    assert set(entries.GENERIC + APPENDED + SHARED) <= lists
     # its work counts keys and values a head
     assert "kernel.flash_prefill_full.roofline_pct" not in lists
 
 
-def _with_the_waiting_entries(bench, also=()):
-    """`bench` with the two per-layer entries this family's readers
-    wait for, APPENDED: where a `benchmark` PR puts them once
-    test_perfbench_narrow_steps.py finds its entry by name and not at
-    `per_layer[-1]` (PERF.md, section 7)."""
-    with open(os.path.join(REPO, "perfbench", "fixtures",
-                           "per_layer.kimi_linear.json")) as f:
-        waiting = json.load(f)
-    for m in waiting:
-        m["workloads"] += list(also)
-    return dict(bench, per_layer=bench["per_layer"] + waiting), waiting
-
-
-def test_the_waiting_entries_keep_the_contract_and_have_their_files(bench):
-    full, waiting = _with_the_waiting_entries(bench)
-    assert contract.violations(full, REPO) == []
+def test_the_appended_entries_keep_the_contract_and_have_their_files(
+        bench):
+    assert contract.violations(bench, REPO) == []
+    appended = [entries.metric(bench, name) for name in APPENDED]
     assert [(m["name"], m["unit"], m["better"], m["source"], m["moves"])
-            for m in waiting] == [
+            for m in appended] == [
         ("kernel.mla_attn.roofline_pct", "%", "higher", "device_trace",
          "itl_p95_ms"),
         ("kv.bytes_per_live_token", "B", "lower", "program_counter",
          "itl_p95_ms")]
-    assert waiting[0]["workloads"] == [CELL]
-    assert waiting[1]["workloads"] == ["q3n-1chip.longdoc-closed", CELL]
-    for m in waiting:
+    assert CELL in appended[0]["workloads"]
+    assert {"q3n-1chip.longdoc-closed", CELL} <= set(
+        appended[1]["workloads"])
+    names = [m["name"] for m in bench["per_layer"]]
+    for m in appended:  # appended, not inserted
+        assert names.index(m["name"]) > names.index("sched.narrow_steps_pct")
         assert callable(harness.load_reader(REPO, m["name"]).read)
-        assert m["name"] not in {x["name"] for x in bench["per_layer"]}
 
 
 SIZES = dict(L=27, Lk=20, Lf=7, Ld=1, Lm=26, H=2304, V=163840, I=9216,
@@ -250,11 +245,9 @@ def test_mla_roofline_on_the_fixture_and_on_nothing():
 
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
-    root, bench, cell = tiny.make_root(tmp_path_factory.mktemp("kl"),
-                                       mix=MIX, config=TINY_KIMI)
-    bench, _ = _with_the_waiting_entries(bench, also=[cell["name"]])
-    tiny.write_json(root, "BENCHMARK.json", bench)
-    return root, bench, cell
+    # tiny.add_cell lists the cell under every entry that lists cells
+    return tiny.make_root(tmp_path_factory.mktemp("kl"), mix=MIX,
+                          config=TINY_KIMI)
 
 
 def test_a_tiny_cell_of_the_family_reads_correct_and_its_metrics(
@@ -273,7 +266,7 @@ def test_a_tiny_cell_of_the_family_reads_correct_and_its_metrics(
     # mean for the other hybrid family
     assert 35.0 < got["moe.local_pairs_pct"] < 65.0  # 4 of 8 held
     assert 0.0 < got["state.live_pct"] <= 100.0
-    # the waiting entries, through the harness: a gathered position is
+    # the appended entries, through the harness: a gathered position is
     # the view's whole width of ONE latent row a page layer (2 layers x
     # 128 float32 values), a live one costs more than that; the CPU's
     # route runs no kernel, so the roofline's reader says nothing

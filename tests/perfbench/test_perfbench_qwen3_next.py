@@ -9,12 +9,16 @@ import os
 
 import pytest
 
+import by_name as entries
 import perfbench_tiny as tiny
 from perfbench import contract, harness
 
 REPO = tiny.REPO
 CELL = "q3n-1chip.longdoc-closed"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+# the per-layer entries this cell brought
+OWN = ("kernel.flash_prefill_full.roofline_pct", "moe.local_pairs_pct",
+       "moe.pairs_per_expert_step", "state.live_pct")
 
 TINY_NEXT = {
     "model_type": "qwen3_next", "vocab_size": 256, "hidden_size": 64,
@@ -107,18 +111,14 @@ def test_the_cell_and_the_entries_that_list_it(bench):
     # of some 55 requests; only a window of 100 s or more would bring
     # it under (PERF.md, PR 30)
     assert e2e == {"itl_p95_ms", "setup_s", "ttft_p95_ms"}
-    lists = {m["name"] for m in bench["per_layer"]
-             if CELL in m["workloads"]}
-    assert {"step.mfu_pct", "kernel.flash_prefill_full.roofline_pct",
-            "moe.local_pairs_pct", "moe.pairs_per_expert_step",
-            "state.live_pct", "kv.gather_live_pct",
-            "sched.prefill_rows_pct", "device.idle_pct"} <= lists
+    lists = entries.listing(bench, CELL)
+    assert set(entries.GENERIC + OWN) | {"kv.bytes_per_live_token"} <= lists
     assert not lists & {"kernel.flash_prefill.roofline_pct",
                         "kernel.gemm_rs.roofline_pct",
                         "coll.unfused_ms_per_step"}
-    new = [m for m in bench["per_layer"] if m["workloads"] == [CELL]]
-    assert len(new) == 4 and all(
-        m["better"] == "higher" and m["moves"] == "itl_p95_ms" for m in new)
+    for name in OWN:
+        m = entries.metric(bench, name)
+        assert (m["better"], m["moves"]) == ("higher", "itl_p95_ms")
 
 
 def test_a_gap_quantile_cuts_each_request_s_widest_gaps():

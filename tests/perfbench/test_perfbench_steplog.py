@@ -1,17 +1,16 @@
 """The readers of what the program's `worker.step` records COUNT
 (ISSUE 40): `step.wide_wall_p50_ms`, `step.narrow_wall_p50_ms` and
 `step.wide_rows_valid_pct` over hand-made logs (two widths, one width,
-records without counts), their entries (which wait as data in
-`perfbench/fixtures/per_layer.steplog.json`: ROADMAP C11) appended to
-`BENCHMARK.json` under the contract, the `jit.*` line, and the tiny
-cell's traced rehearsal through the three files."""
+records without counts), their entries, appended to `BENCHMARK.json`
+after `sched.narrow_steps_pct`, under the contract, the `jit.*` line,
+and the tiny cell's traced rehearsal through the three files."""
 
 import importlib.util
-import json
 import os
 
 import pytest
 
+import by_name as entries
 import perfbench_tiny as tiny
 from perfbench import contract, harness
 from perfbench.sources import program_spanlog as sl
@@ -183,8 +182,6 @@ def test_the_jit_line_is_said_once_a_run_and_not_without_records(
 
 # ---------- the entries ----------
 
-FIXTURE = os.path.join(REPO, "perfbench", "fixtures",
-                       "per_layer.steplog.json")
 _spec = importlib.util.spec_from_file_location(
     "run_waiting", os.path.join(REPO, "scripts", "run_waiting.py"))
 run_waiting = importlib.util.module_from_spec(_spec)
@@ -193,33 +190,19 @@ _spec.loader.exec_module(run_waiting)
 
 @pytest.fixture(scope="module")
 def bench():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        return json.load(f)
+    return entries.load()
 
 
-def with_the_waiting_entries(bench, also=()):
-    """`bench` with the three entries APPENDED: where a `benchmark` PR
-    puts them once `test_perfbench_narrow_steps.py:40` and
-    `test_perfbench_exaone_moe.py:72` find `sched.narrow_steps_pct` by
-    name and not at `per_layer[-1]` (ROADMAP C11). The driver refuses an
-    entry INSERTED before the last as a change to it, and one appended
-    fails those two pins, whose files a PR that adds may not edit: so
-    they wait as data."""
-    waiting = run_waiting.entries_of(harness.load_json(FIXTURE))
-    for m in waiting:
-        m["workloads"] += list(also)
-    return run_waiting.appended(bench, waiting), waiting
-
-
-def test_the_benchmark_is_as_it_was_and_the_three_wait_as_data(bench):
+def test_the_three_are_appended_after_the_narrow_share(bench):
+    """Appended, not inserted: an entry inserted before an accepted one
+    moves that one, which reads as a change to it."""
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-1] == "sched.narrow_steps_pct"
-    assert not {WIDE, NARROW, ROWS} & set(names)
-    _, waiting = with_the_waiting_entries(bench)
-    assert [m["name"] for m in waiting] == [WIDE, NARROW, ROWS]
-    for m in waiting:  # each has its reader beside the accepted ones
+    at = [names.index(n) for n in ("sched.narrow_steps_pct", WIDE, NARROW,
+                                   ROWS)]
+    assert at == sorted(at)
+    for name in (WIDE, NARROW, ROWS):  # each has its reader beside the others
         assert os.path.isfile(os.path.join(
-            REPO, "perfbench", "metrics", m["name"] + ".py"))
+            REPO, "perfbench", "metrics", name + ".py"))
 
 
 @pytest.mark.parametrize("name, unit, better, moves, cells", [
@@ -233,46 +216,48 @@ def test_the_benchmark_is_as_it_was_and_the_three_wait_as_data(bench):
       "q8b-tp4.chat-closed"])])
 def test_an_entry_lists_cells_that_report_what_it_moves(
         bench, name, unit, better, moves, cells):
-    full, _ = with_the_waiting_entries(bench)
-    entry = next(m for m in full["per_layer"] if m["name"] == name)
-    assert entry == {"name": name, "unit": unit, "better": better,
-                     "source": "program_span", "layer": "worker / step",
-                     "moves": moves, "workloads": cells}
+    entry = entries.metric(bench, name)
+    assert dict(entry, workloads=None) == {
+        "name": name, "unit": unit, "better": better,
+        "source": "program_span", "layer": "worker / step", "moves": moves,
+        "workloads": None}
+    assert set(cells) <= set(entry["workloads"])
     moved = next(m for m in bench["end_to_end"] if m["name"] == moves)
     all_cells = [w["name"] for w in bench["workloads"]]
-    assert set(cells) <= set(moved.get("workloads", all_cells))
+    assert set(entry["workloads"]) <= set(moved.get("workloads", all_cells))
 
 
 def test_the_contract_passes_with_the_entries_appended(bench):
+    assert {WIDE, NARROW, ROWS} <= {m["name"] for m in bench["per_layer"]}
     assert contract.violations(bench, REPO) == []
-    full, _ = with_the_waiting_entries(bench)
-    assert contract.violations(full, REPO) == []
 
 
 def test_the_wrapper_hands_the_run_the_benchmark_with_them_appended(
-        bench, monkeypatch):
-    """`scripts/run_waiting.py`: what a builder runs on the chip until
-    the entries are in `BENCHMARK.json`. `perfbench/run.py` gets its own
-    arguments and reads the benchmark with the entries at its end."""
+        bench, monkeypatch, tmp_path):
+    """`scripts/run_waiting.py --entries <file>`: `perfbench/run.py`
+    gets its own arguments and reads the benchmark with the file's
+    entries at its end, and any other file as it is."""
     from perfbench import run
+    waiting = dict(entries.metric(bench, WIDE), name="step.waiting_ms")
+    path = str(tmp_path / "waiting.json")
+    tiny.write_json(str(tmp_path), "waiting.json",
+                    {"what": "test", "per_layer": [waiting]})
     seen = {}
 
     def main(argv):
         seen["argv"] = argv
         seen["bench"] = harness.load_json(
             os.path.join(REPO, "BENCHMARK.json"))
-        seen["other"] = harness.load_json(FIXTURE)
+        seen["other"] = harness.load_json(path)
         return 0
 
     monkeypatch.setattr(run, "main", main)
     monkeypatch.setattr(harness, "load_json", harness.load_json)
     rest = ["--workload", "q8b-1chip.chat-closed", "--trace", "1"]
-    assert run_waiting.main(["--entries", FIXTURE] + rest) == 0
+    assert run_waiting.main(["--entries", path] + rest) == 0
     assert seen["argv"] == rest
-    assert seen["bench"]["per_layer"][:-3] == bench["per_layer"]
-    assert [m["name"] for m in seen["bench"]["per_layer"][-3:]] \
-        == [WIDE, NARROW, ROWS]
-    assert set(seen["other"]) == {"what", "per_layer"}
+    assert seen["bench"]["per_layer"] == bench["per_layer"] + [waiting]
+    assert seen["other"] == {"what": "test", "per_layer": [waiting]}
 
 
 # ---------- the tiny cell through the three files ----------
@@ -280,13 +265,11 @@ def test_the_wrapper_hands_the_run_the_benchmark_with_them_appended(
 
 def test_tiny_traced_rehearsal_reports_the_three(tmp_path):
     """The tiny cell's prefills run wide and its decode tails narrow:
-    with the waiting entries appended all three are on the line (which
+    all three are on the line (which
     of the two steps is the shorter is the chip's to say: at this size
     on the CPU both are overhead, 2.4 ms each), and the run's log has
     the `jit.*` line with the warm-up's compiles before the window."""
     root, bench, cell = tiny.make_root(tmp_path)
-    bench, _ = with_the_waiting_entries(bench, also=[cell["name"]])
-    tiny.write_json(root, "BENCHMARK.json", bench)
     traced, lines = tiny.rehearse(root, bench, cell, trace=True)
     assert traced["correct"]
     said = [line for line in lines if line.startswith("jit records")]
@@ -302,9 +285,11 @@ def test_tiny_traced_rehearsal_reports_the_three(tmp_path):
 
 def test_tiny_traced_rehearsal_without_the_entries_is_the_accepted_line(
         tmp_path):
-    """`BENCHMARK.json` as it stands names none of the three: no reader
-    of this PR's runs, the line is the accepted one."""
+    """A `BENCHMARK.json` that names none of the three, as the parent's
+    did: none of their readers runs, and the line is the parent's."""
     root, bench, cell = tiny.make_root(tmp_path)
+    bench["per_layer"] = [m for m in bench["per_layer"]
+                          if m["name"] not in (WIDE, NARROW, ROWS)]
     traced, lines = tiny.rehearse(root, bench, cell, trace=True)
     assert traced["correct"]
     assert not {WIDE, NARROW, ROWS} & set(traced["metrics"])
