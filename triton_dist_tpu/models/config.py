@@ -13,8 +13,9 @@ mixer kind and an FFN kind a block, read off `mixer_kinds` /
 `ffn_kinds`, stated as an interval (`qwen3_next_80b`, `tiny_next`), as
 two lists (`kimi_linear_48b`, `tiny_kimi`) or as the source's own
 `layer_types` list (`k_exaone_236b`, `tiny_exaone`: window and global
-attention; `granite_4_h_micro`, `tiny_granite`: Mamba-2 and attention);
-what a page of its cache keeps of a token is `page_arrays`.
+attention; `granite_4_h_micro`, `tiny_granite`: Mamba-2 and attention
+with a dense MLP; `granite_4_h_small`, `tiny_granite_moe`: the same with
+experts); what a page of its cache keeps of a token is `page_arrays`.
 """
 
 from __future__ import annotations
@@ -423,6 +424,65 @@ class ModelConfig:
             mamba_state_dim=16, mamba_conv_kernel_dim=4,
             embedding_multiplier=12.0, residual_multiplier=0.22,
             logits_scaling=8.0, attention_multiplier=0.0625,
+        )
+        defaults.update(kw)
+        return ModelConfig(**defaults)
+
+    @staticmethod
+    def granite_4_h_small(num_layers: int = 40, **kw) -> "ModelConfig":
+        """granite-4.0-h-small geometry: 40 blocks, `M x5 A`, `M x9 A`
+        three times, `M x4` (M a Mamba-2 mixer of 128 heads x 64 over a
+        state of 128, A grouped-query attention over every position
+        without rotary or q/k norm, 32 q / 8 kv heads of 128 at the
+        scale 1/128); in EVERY block 72 experts of width 768, 10 a
+        token under a softmax router (the top 10 logits through a
+        softmax: the same weights as a softmax over all 72, its top 10
+        divided by their sum), and an ungated shared MLP of 1,536; tied
+        embeddings, norms with the gain w and Granite's four
+        multipliers. No block is dense: `intermediate_size` is the
+        shared MLP's and unused. A cut depth keeps the pattern's first
+        `num_layers` blocks; what one chip of an expert-parallel group
+        holds is `experts_held` / `expert_offset` and a `vocab_size`
+        slice in **kw."""
+        m, a = "mamba", "attention"
+        types = (m,) * 5 + (a,) + ((m,) * 9 + (a,)) * 3 + (m,) * 4
+        defaults = dict(
+            vocab_size=100_352, hidden_size=4096, intermediate_size=1536,
+            num_q_heads=32, num_kv_heads=8, head_dim=128, rms_eps=1e-5,
+            use_qk_norm=False, tie_word_embeddings=True,
+            layer_types=types[:num_layers], first_k_dense=0,
+            norm_zero_centred=False, num_experts=72,
+            num_experts_per_tok=10, moe_intermediate_size=768,
+            shared_expert_intermediate_size=1536, router_score="softmax",
+            shared_expert_gate=False, mamba_num_heads=128,
+            mamba_head_dim=64, mamba_state_dim=128,
+            mamba_conv_kernel_dim=4, embedding_multiplier=12.0,
+            residual_multiplier=0.22, logits_scaling=16.0,
+            attention_multiplier=0.0078125,
+        )
+        defaults.update(kw)
+        return ModelConfig(num_layers=num_layers, **defaults)
+
+    @staticmethod
+    def tiny_granite_moe(**kw) -> "ModelConfig":
+        """Test-scale granite-4.0-h-small pattern: a period twice and a
+        last without attention (`M A` twice, `M M`), 8 experts of 32, 2
+        a token, an ungated shared MLP of 48 in every block, heads of
+        16 (a page keeps them 128 wide), a state of 16."""
+        m, a = "mamba", "attention"
+        defaults = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=48,
+            num_layers=6, num_q_heads=4, num_kv_heads=2, head_dim=16,
+            rms_eps=1e-5, max_positions=64, dtype="float32",
+            use_qk_norm=False, tie_word_embeddings=True,
+            layer_types=(m, a) * 2 + (m, m), first_k_dense=0,
+            norm_zero_centred=False, num_experts=8, num_experts_per_tok=2,
+            moe_intermediate_size=32, shared_expert_intermediate_size=48,
+            router_score="softmax", shared_expert_gate=False,
+            mamba_num_heads=8, mamba_head_dim=16, mamba_state_dim=16,
+            mamba_conv_kernel_dim=4, embedding_multiplier=12.0,
+            residual_multiplier=0.22, logits_scaling=16.0,
+            attention_multiplier=0.0625,
         )
         defaults.update(kw)
         return ModelConfig(**defaults)

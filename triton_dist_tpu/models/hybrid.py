@@ -26,18 +26,22 @@ router) and K-EXAONE ("window_attn" and "global_attn" from the
 source's `layer_types`, three to one, no delta-net block at all; the
 FFNs and the router Kimi-Linear's) and Granite 4.0-H ("mamba2" and
 "global_attn" from the source's `layer_types`, nine to one, the
-attention without q/k norm at the source's own scale over heads of 64
-that a page keeps 128 wide; EVERY FFN "dense", so no expert leaf and no
-router; the embedding is the head (`tie_word_embeddings`: no `lm_head`
-leaf) and four multipliers scale the embedding, each residual branch,
-the attention scores and the logits).
+attention without q/k norm at the source's own scale; the embedding is
+the head (`tie_word_embeddings`: no `lm_head` leaf) and four
+multipliers scale the embedding, each residual branch, the attention
+scores and the logits). Granite 4.0-H comes with experts and without:
+h-micro has EVERY FFN "dense", so no expert leaf and no router, over
+heads of 64 that a page keeps 128 wide; h-small has EVERY FFN "moe",
+a softmax router and an ungated shared MLP under the one residual
+multiplier, over heads of 128.
 
 The pattern is cut into PERIODS, each ending with a block that keeps
 pages (the last one may have none), and a run of equal periods is ONE
 `lax.scan` with the period's blocks unrolled inside it: one scan for
 Qwen3-Next, three for Kimi-Linear, two for K-EXAONE (the first period
 holds the dense block), three for Granite 4.0-H (`M x5 A`, `M x9 A`
-three times, `M x4`). Parameters are stacked by kind:
+three times, `M x4`; two for h-small's first ten blocks, `M x5 A`,
+`M x4`). Parameters are stacked by kind:
 
   embed (V, H) · final_ln (H,) · lm_head (H, V) unless tied
   every block, (L, ...):      input_ln, post_ln
