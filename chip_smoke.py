@@ -20,8 +20,10 @@ head; weights random from --seed):
       (`window_phase`); then the held experts' grouped matmul, both
       products of an expert block at the three hybrid configurations'
       widths and the cells' group sizes, against the loop over
-      experts, with one group's offset shifted by a row
-      (`experts_phase`); then the hybrid family's state-space mixer at
+      experts, with one group's offset shifted by a row, and their
+      combine at granite-4.0-h-small's widths against the jnp forms,
+      with two held pairs swapped (`experts_phase`); then the hybrid
+      family's state-space mixer at
       granite-4.0-h-micro's widths over three steps with the state
       carried, against the recurrence position by position in float32,
       the carry broken three ways (`ssd_phase`), and its attention
@@ -804,6 +806,88 @@ def experts_phase(seed: int, rows=None) -> None:
                         f"grouped matmul {name} ({k}, {n}): {reads[0]:.5f} "
                         f"has to lie under {EXPERTS_TOL} and the shifted "
                         f"offset's {reads[1]:.4f} over it")
+    combine_case(seed)
+
+
+# The held experts' combine at granite-4.0-h-small's widths: hidden,
+# choices a token, experts routed over, experts held, the step's rows;
+# the rows valid in each case: about the cell's third, and every row
+# (whose held pairs overflow the kernel's stage: the jnp fold runs)
+COMBINE_CELL = (4096, 10, 72, 36, 1024)
+COMBINE_VALID = (352, 1024)
+
+
+def combine_case(seed: int) -> None:
+    """`kernels.moe_combine` on the route the chip gives it against the
+    jnp forms on the same routed pairs: on the kernel's route bit for
+    bit the left fold in choice order (`combine_fold`), within
+    EXPERTS_TOL the un-sort and einsum (`combine_unsort`), with the
+    sorted rows behind the held pairs NaN, and the rows it says it
+    read. Broken on purpose: two tokens' held pairs swapped have to
+    read over the tolerance."""
+    import jax
+    import jax.numpy as jnp
+
+    from triton_dist_tpu.kernels.moe_combine import (
+        combine_fold,
+        combine_unsort,
+        moe_combine,
+        moe_combine_route,
+    )
+    from triton_dist_tpu.kernels.moe_utils import sort_by_expert, topk_routing
+
+    h, top_k, experts, held, m = COMBINE_CELL
+    route = moe_combine_route(m, top_k, h)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    weights, ids = topk_routing(
+        jax.random.normal(keys[0], (m, experts), jnp.float32), top_k)
+    y = jax.random.normal(keys[1], (m * top_k, h), jnp.float32)
+    fwd = jax.jit(moe_combine)
+    for valid in COMBINE_VALID:
+        here = (ids < held) & (jnp.arange(m) < valid)[:, None]
+        sort = sort_by_expert(jnp.where(here, ids, held), held + 1)
+        n = jnp.sum(sort.group_sizes[:held])
+        mine = jnp.where(here, weights, 0.0)
+        rows = jnp.where((jnp.arange(m * top_k) < n)[:, None], y,
+                         jnp.nan).astype(jnp.bfloat16)
+        args = (rows, sort.unsort_idx, n, mine)
+        if valid == COMBINE_VALID[0]:
+            kernels, secs = compile_and_name(fwd, *args)
+            say(f"combine ({m} x {top_k}, {h}): route {route!r}, kernels "
+                f"{kernels or 'none'}, compiled in {secs:.1f}s")
+            if route == "pallas":
+                require(kernels, ["_moe_combine_kernel"],
+                        "the held experts' combine")
+        got, read = fwd(*args)
+        got = np.asarray(got)
+        fold = np.asarray(jax.jit(combine_fold)(*args))
+        unsort = np.asarray(jax.jit(combine_unsort)(*args))
+        # the first held pair and the first of another token
+        flat = np.flatnonzero(np.asarray(here).reshape(-1))
+        pair = np.array([flat[0], flat[flat // top_k > flat[0] // top_k][0]])
+        swapped = sort.unsort_idx.at[pair].set(sort.unsort_idx[pair[::-1]])
+        broken = np.asarray(fwd(rows, swapped, n, mine)[0])
+        scale = np.abs(unsort).max()
+        reads = [float(np.abs(a - unsort).max() / scale)
+                 for a in (got, broken)]
+        differ = int(np.sum(got != fold))
+        say(f"combine at {valid} valid rows ({int(n)} held pairs of "
+            f"{m * top_k}, {int(read)} rows read): {differ} values not "
+            f"the fold's bits; {reads[0]:.2e} of the largest output from "
+            f"the un-sort; two held pairs swapped {reads[1]:.4f} "
+            f"(tolerance {EXPERTS_TOL})")
+        # the kernel's route is the fold's sum (the jnp route's einsum
+        # sums in an order of its own)
+        if not np.isfinite(got).all() or (route == "pallas" and differ):
+            raise RuntimeError(
+                f"combine at {valid} valid rows: {differ} values differ "
+                "from the left fold in choice order, or a row behind the "
+                "held pairs reached the output")
+        if not reads[0] < EXPERTS_TOL < reads[1]:
+            raise RuntimeError(
+                f"combine at {valid} valid rows: {reads[0]:.2e} has to lie "
+                f"under {EXPERTS_TOL} and the swapped pairs' "
+                f"{reads[1]:.4f} over it")
 
 
 # real columns of each of eight slots in three steps of 128: whole

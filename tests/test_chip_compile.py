@@ -337,8 +337,10 @@ def test_latent_serve_step_one_chip(chip):
     with pytest.raises(NotImplementedError, match="one query row"):
         route_hybrid_attention(cfg, slots, 1, max_len)
     compiled = _hybrid_step_compiled(chip, cfg, slots, page, max_len)
-    # the ten expert blocks' two products each, and no `ragged_dot`
+    # the ten expert blocks' two products and combine each, and no
+    # `ragged_dot`
     assert _kernels(compiled) == {"_fp_local_kernel": 3,
+                                  "_moe_combine_kernel": 10,
                                   "_moe_gmm_kernel": 20}
     _experts_stream_through_the_kernel(compiled.as_text(), cfg)
     launch = flash_prefill.last_launch()
@@ -365,8 +367,10 @@ def test_hybrid_serve_step_one_chip(chip):
         num_layers=8, experts_held=128, vocab_size=37_984,
         max_positions=max_len)
     compiled = _hybrid_step_compiled(chip, cfg, slots, page, max_len)
-    # one scan body of a period: four expert blocks' two products each
+    # one scan body of a period: four expert blocks' two products and
+    # combine each
     assert _kernels(compiled) == {"_fp_local_kernel": 1,
+                                  "_moe_combine_kernel": 4,
                                   "_moe_gmm_kernel": 8}
     _experts_stream_through_the_kernel(compiled.as_text(), cfg)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
@@ -400,8 +404,9 @@ def test_window_and_global_serve_step_one_chip(chip):
         route_window_attention(cfg, slots, 1)
     compiled = _hybrid_step_compiled(chip, cfg, slots, page, max_len)
     # one body a period and kind: 2 scans x (window, global); the seven
-    # expert blocks' two products each
+    # expert blocks' two products and combine each
     assert _kernels(compiled) == {"_fp_local_kernel": 8,
+                                  "_moe_combine_kernel": 7,
                                   "_moe_gmm_kernel": 14}
     _experts_stream_through_the_kernel(compiled.as_text(), cfg)
     launch = flash_prefill.last_launch()  # the last traced: a global block

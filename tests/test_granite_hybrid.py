@@ -564,6 +564,7 @@ def test_counters_say_what_the_state_and_the_pages_are(eng, cfg, prompts):
     assert sch.worker.last_stats == {}
     assert c["moe_pairs{held=here}"] == c["moe_pairs{held=absent}"] == 0
     assert c["moe_gmm_tile_rows"] == c["moe_expert_steps"] == 0
+    assert c["moe_combine_rows"] == c["moe_combine_pairs"] == 0
     # the page counters are the three attention blocks' alone, a head
     # as wide as the page keeps it
     assert sch.pool.kv_bytes_per_token == \

@@ -272,13 +272,19 @@ def test_the_step_counts_its_pairs_and_expert_steps(served, cfg):
     assert cfg.num_moe_layers == cfg.num_layers == 6
     assert c["moe_expert_steps"] == c["serve_steps"] * 6 * HELD
     assert set(sch.worker.last_stats) == {
-        "moe_pairs_here", "moe_pairs_absent", "moe_gmm_tile_rows"}
+        "moe_pairs_here", "moe_pairs_absent", "moe_gmm_tile_rows",
+        "moe_combine_rows", "moe_combine_pairs"}
     here, absent = c["moe_pairs{held=here}"], c["moe_pairs{held=absent}"]
     # every valid row's two choices in each of the six blocks
     assert here + absent == 2 * 6 * sum(
         v for k, v in c.items() if k.startswith("serve_rows"))
     assert 0 < here < here + absent
     assert c["moe_gmm_tile_rows"] == 0  # the interpreter's ragged_dot
+    # every pair of the (slots, chunk) step, two choices in six blocks;
+    # the interpreter's jnp combine reads every one of them
+    assert c["moe_combine_pairs"] == (
+        c["serve_steps"] * 6 * 2 * GEO["slots"] * GEO["chunk"])
+    assert c["moe_combine_rows"] == c["moe_combine_pairs"]
     # the benchmark's configuration: ten expert blocks of 36 held
     big = ModelConfig.granite_4_h_small(num_layers=10, experts_held=36)
     assert big.num_moe_layers * big.num_experts_held == 10 * 36

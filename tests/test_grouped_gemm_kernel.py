@@ -218,13 +218,13 @@ def test_held_moe_counts_the_rows_of_the_tiles_it_visits(monkeypatch):
     p = HeldMoEParams(arr(h, e), arr(held, h, 2 * inter),
                       arr(held, inter, h), arr(h, 2 * inter), arr(inter, h))
     x, valid = arr(m, h, scale=1.0), jnp.ones((m,), bool)
-    want, here, _, rows = held_moe_counted(x, valid, p, top_k, 0)
+    want, here, _, rows, *_ = held_moe_counted(x, valid, p, top_k, 0)
     assert int(here) == m * top_k and int(rows) == 0
     monkeypatch.setattr(gg, "use_interpret", lambda: False)
     kernel, calls = gg.moe_gmm, []
     monkeypatch.setattr(gg, "moe_gmm", lambda *a: (
         calls.append(a[1].shape), kernel(*a))[1])
-    got, here, _, rows = held_moe_counted(x, valid, p, top_k, 0)
+    got, here, _, rows, *_ = held_moe_counted(x, valid, p, top_k, 0)
     assert calls == [(held, h, 2 * inter), (held, inter, h)]
     # 128 pairs fill one tile, which each of the four groups visits
     assert int(rows) == 2 * held * TILE_ROWS
@@ -235,14 +235,20 @@ def test_held_moe_counts_the_rows_of_the_tiles_it_visits(monkeypatch):
 
 def test_chip_smoke_experts_phase_rehearsal(monkeypatch, capsys):
     """`chip_smoke.experts_phase`'s own control flow at a tiny size
-    (the route is `ragged_dot` here; the chip run takes the kernel and
-    requires it by name): both products, the cell's load and a
-    deployment's, each with the shifted offset seen."""
+    (the routes are `ragged_dot` and the jnp combine here; the chip run
+    takes the kernels and requires them by name): both products, the
+    cell's load and a deployment's, each with the shifted offset seen;
+    the combine at a third of the rows valid and at all, each with the
+    swapped pairs seen."""
     import chip_smoke
 
     monkeypatch.setattr(chip_smoke, "EXPERT_CELLS",
                         (("tiny_next", 4, 3), ("tiny_exaone", 4, 5)))
+    monkeypatch.setattr(chip_smoke, "COMBINE_CELL", (128, 4, 8, 4, 64))
+    monkeypatch.setattr(chip_smoke, "COMBINE_VALID", (22, 64))
     chip_smoke.experts_phase(7, rows=256)
     out = capsys.readouterr().out
-    assert out.count("route 'xla', kernels none") == 4
+    assert out.count("route 'xla', kernels none") == 5
     assert out.count("one offset shifted") == 8
+    assert out.count("values not the fold's bits") == 2
+    assert out.count("two held pairs swapped") == 2

@@ -22,8 +22,11 @@ The held experts' products are the grouped matmul
 a row tile; `lax.ragged_dot` under the interpreter) over the pairs
 sorted by expert; absent pairs and padding rows sort behind every
 group, are multiplied by nothing and hold nothing a caller may read
-(the kernel visits no tile behind the groups): what comes back from
-the second product is masked to the groups' rows here.
+(the kernel visits no tile behind the groups). The combine
+(`kernels.moe_combine`: on the chip the Pallas kernel
+`_moe_combine_kernel`, which reads each of the groups' rows once and
+no row behind them; the jnp un-sort of every pair under the
+interpreter) sums each token's rows in the order of its choices.
 
   w_router (H, E) · w_gate_up (held, H, 2 I) gate | up · w_down
   (held, I, H) · ws_gate_up (H, 2 Is) · ws_down (Is, H) · w_sgate (H,)
@@ -48,6 +51,7 @@ from triton_dist_tpu.kernels.grouped_gemm import (
     grouped_gemm,
     grouped_gemm_tile_rows,
 )
+from triton_dist_tpu.kernels.moe_combine import moe_combine
 from triton_dist_tpu.kernels.moe_utils import (
     silu_mul,
     sort_by_expert,
@@ -91,10 +95,13 @@ def held_moe_fwd(x, valid, p: HeldMoEParams, top_k: int, offset: int,
 
 def held_moe_counted(x, valid, p: HeldMoEParams, top_k: int, offset: int,
                      layer=None, router: RouterForm = RouterForm()):
-    """`held_moe_fwd` and a fourth result, `tile_rows`: the rows of
+    """`held_moe_fwd` and three more results: `tile_rows`, the rows of
     the tiles its two grouped matmuls visit (`grouped_gemm_tile_rows`;
     `pairs_here` x 2 over it is the share of multiplied rows that are
-    real, 0 rows on the `ragged_dot` route)."""
+    real, 0 rows on the `ragged_dot` route); `combine_rows`, the rows of
+    the second product the combine read (`moe_combine`: `pairs_here`
+    on the kernel's route, every pair on the jnp one); `combine_pairs`,
+    the M x top_k pairs it combined."""
     # the parts (layers/parts.py) are named where the work stands: no
     # operation moved for a name's sake
     m, _ = x.shape
@@ -139,12 +146,7 @@ def held_moe_counted(x, valid, p: HeldMoEParams, top_k: int, offset: int,
         tile_rows = (grouped_gemm_tile_rows(xs, w_gate_up, sizes)
                      + grouped_gemm_tile_rows(act, w_down, sizes))
     with part("moe.combine"):
-        y = jnp.where((jnp.arange(pairs) < n_here)[:, None], y,
-                      jnp.zeros((), y.dtype))
-        out = jnp.einsum(
-            "mkh,mk->mh",
-            y[sort.unsort_idx].reshape(m, top_k, -1).astype(jnp.float32),
-            mine)
+        out, combine_rows = moe_combine(y, sort.unsort_idx, n_here, mine)
 
     with part("moe.shared"):
         shared = swiglu_fwd(x, p.ws_gate_up, p.ws_down)
@@ -158,4 +160,5 @@ def held_moe_counted(x, valid, p: HeldMoEParams, top_k: int, offset: int,
         pairs_here = jnp.sum(here, dtype=jnp.int32)
         pairs_absent = jnp.sum(valid[:, None] & ~held_here, dtype=jnp.int32)
     with part("moe.combine"):
-        return out.astype(x.dtype), pairs_here, pairs_absent, tile_rows
+        return (out.astype(x.dtype), pairs_here, pairs_absent, tile_rows,
+                combine_rows, jnp.int32(pairs))

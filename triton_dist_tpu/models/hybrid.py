@@ -553,7 +553,8 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
             # a block's row of what the slots carry between steps
             carried = part("pool.gather")(row)
 
-            here = absent = tile_rows = jnp.int32(0)
+            here = absent = tile_rows = combine_rows = combine_pairs = (
+                jnp.int32(0))
             recs, convs, rows, tails = [], [], [], []
             at = {kind: 0 for kind in count}
             for j, (mixer, ffn) in enumerate(period):
@@ -611,13 +612,15 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                 with proj:
                     x = added(x, y)
                 if ffn == "moe":
-                    x, h_j, a_j, t_j = moe(
+                    x, h_j, a_j, t_j, r_j, p_j = moe(
                         x, gain,
                         {n: row(params[n], "moe", at["moe"]) for n in _MOE
                          if n in params},
                         start["moe"] + i * count["moe"] + at["moe"])
                     here, absent = here + h_j, absent + a_j
                     tile_rows = tile_rows + t_j
+                    combine_rows = combine_rows + r_j
+                    combine_pairs = combine_pairs + p_j
                 else:
                     x = dense(x, gain, {
                         n: row(params[n], "dense", at["dense"])
@@ -633,7 +636,8 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
                 state = ((jnp.stack(recs), jnp.stack(convs)) if recs
                          else ())
                 tails = tuple(jnp.stack(t) for t in zip(*tails))
-            return x, (state, rows, tails, here, absent, tile_rows)
+            return x, (state, rows, tails, here, absent, tile_rows,
+                       combine_rows, combine_pairs)
 
         return one_period
 
@@ -681,7 +685,9 @@ def chunk_hidden(cfg: ModelConfig, params: dict, tokens, cache: Cache,
     stats = {} if "moe" not in cfg.ffn_kinds else {
         "moe_pairs_here": sum(jnp.sum(o[3]) for o in outs),
         "moe_pairs_absent": sum(jnp.sum(o[4]) for o in outs),
-        "moe_gmm_tile_rows": sum(jnp.sum(o[5]) for o in outs)}
+        "moe_gmm_tile_rows": sum(jnp.sum(o[5]) for o in outs),
+        "moe_combine_rows": sum(jnp.sum(o[6]) for o in outs),
+        "moe_combine_pairs": sum(jnp.sum(o[7]) for o in outs)}
     with part("pool.scatter"):
         win = per_block(2, len(cache.win))
     return x, rows, rec, conv, win, stats

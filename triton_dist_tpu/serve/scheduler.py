@@ -668,7 +668,9 @@ class Scheduler:
                      held="here")
         self.obs.inc("moe_pairs", stats.get("moe_pairs_absent", 0),
                      held="absent")
-        self.obs.inc("moe_gmm_tile_rows", stats.get("moe_gmm_tile_rows", 0))
+        for name in ("moe_gmm_tile_rows", "moe_combine_rows",
+                     "moe_combine_pairs"):
+            self.obs.inc(name, stats.get(name, 0))
         self.obs.inc("moe_expert_steps",
                      cfg.num_moe_layers * cfg.num_experts_held)
 
